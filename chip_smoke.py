@@ -1,0 +1,480 @@
+#!/usr/bin/env python
+"""The quickest proof that horovod_tpu still starts on the chip.
+
+    python chip_smoke.py            # one TPU chip: kernels, trainer, server
+    python chip_smoke.py --chips 4  # four chips: the data-parallel trainer
+
+Drives the repo's two programs through the entry points a user calls, at
+full width with seeded random weights, and checks what comes out by the
+repo's own references:
+
+* **kernels** — the four Pallas kernels compiled for the chip
+  (``interpret=False``) against their dense / gather references;
+* **trainer** — ``horovodrun -np 1 python examples/synthetic_benchmark.py``:
+  ResNet-50, 1000 classes, 224², bf16, sync-BN, batch 128, seven steps;
+* **server** — ``hvdserve --model gpt2-small`` answering ``/generate``
+  requests of 5 to 300 prompt tokens over HTTP, then draining on SIGTERM.
+
+With ``--chips 4`` it runs only the ResNet-50 ``shard_step`` over four
+chips and the same global batch on one chip, which must agree.
+
+A chip belongs to one process at a time, so this process never
+initialises a JAX backend: it runs each phase as a child, one after
+another, and builds its last line from what the children reported.  A
+platform other than ``tpu``, a value out of tolerance, a child that exits
+non-zero or outlives its limit: each ends the script with a non-zero code
+and no ``ok`` line.  The last line of standard output is
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+"""
+
+import argparse
+import contextlib
+import functools
+import json
+import math
+import os
+import random
+import re
+import signal
+import subprocess
+import sys
+import threading
+import time
+import urllib.request
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+REPORT = "chip_smoke report: "  # a child's last line: this + one JSON object
+PLATFORM = "tpu"
+SEED = 0
+
+# -- kernels: the shapes tests/test_tpu_compile.py compiles ---------------
+FLASH_SHAPE = dict(batch=8, heads=16, head_dim=64)   # bf16, S in FLASH_SEQS
+FLASH_SEQS = (128, 1024)
+POOL = dict(num_blocks=256, block_tokens=16, heads=12, head_dim=64)
+SERVE_BATCH, TABLE_BLOCKS, PREFILL_CHUNK = 8, 16, 64
+# Max-abs error allowed, as a share of the reference's largest magnitude
+# (or of 1 where that is smaller).  Every dot in these kernels goes through
+# the MXU as one bf16 pass — Mosaic's default for float32 operands, as it
+# is XLA's — so the float32 and int8 pools see the same 2^-9 rounding of
+# each operand as the bf16 flash inputs, and flash rounds its outputs and
+# gradients to bf16 once more.  Two bf16 ulps, against references computed
+# at the highest matmul precision.
+MXU_BF16_TOL = 2.0 ** -7
+
+# -- trainer ---------------------------------------------------------------
+TRAINER_CMD = [sys.executable, "-m", "horovod_tpu.runner.launch", "-np", "1",
+               sys.executable, "examples/synthetic_benchmark.py",
+               "--model", "resnet50", "--batch-size", "128",
+               "--num-warmup-batches", "2", "--num-iters", "5"]
+
+# -- server ----------------------------------------------------------------
+SERVER_CMD = [sys.executable, "-m", "horovod_tpu.serve",
+              "--model", "gpt2-small", "--replicas", "1", "--port", "0",
+              "--max-len", "512", "--max-batch", "8"]
+VOCAB = 50257
+PROMPT_LENGTHS = (5, 40, 130, 300)  # one block .. five 64-token chunks
+TWIN_LENGTH = 70                    # sent twice, concurrently with the rest
+NEW_TOKENS = 16
+
+# -- four chips ------------------------------------------------------------
+DP_MODEL, DP_GLOBAL_BATCH, DP_STEPS = "resnet50", 128, 3
+# Sync-BN and hvd.Average make the four-chip and the one-chip step the same
+# computation in another order of summation, carried in bf16 activations:
+# the losses (about ln 1000 = 6.9) agree to a few bf16 ulps of the logits.
+DP_LOSS_TOL = 5e-2
+
+# Seconds a phase may take, compilation included; the whole stays inside
+# the 1200 s the contract allows.
+LIMITS = {"kernels": 300, "trainer": 400, "server": 400, "dp4": 900}
+
+
+class SmokeFailure(Exception):
+    """A phase failed; the message says which check."""
+
+
+def check(ok: bool, what: str) -> None:
+    if not ok:
+        raise SmokeFailure(what)
+
+
+# ---------------------------------------------------------------------------
+# Phases that hold the chip themselves (run as `--phase NAME` children)
+# ---------------------------------------------------------------------------
+
+def require_platform() -> dict:
+    """The device as JAX reports it; anything but the chip is a failure,
+    not a fallback.  First thing a chip-holding phase does."""
+    import jax
+    devices = jax.devices()
+    device = {"platform": devices[0].platform,
+              "kind": devices[0].device_kind, "count": len(devices)}
+    check(device["platform"] == PLATFORM,
+          f"JAX found platform {device['platform']!r}, not {PLATFORM!r}")
+    return device
+
+
+def max_abs_error(got, want):
+    """``(max |got - want|, max |want|)`` of two arrays of one shape."""
+    import numpy as np
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    check(got.shape == want.shape, f"shape {got.shape} != {want.shape}")
+    check(bool(np.isfinite(got).all()), "non-finite values from a kernel")
+    return float(np.abs(got - want).max()), float(np.abs(want).max())
+
+
+def flash_errors(interpret: bool):
+    """``flash_attention`` forward and ``jax.grad`` against the dense
+    attention the tests use, on seeded bf16 inputs."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from horovod_tpu.parallel.flash import flash_attention
+    from horovod_tpu.parallel.ring import ring_attention_reference
+
+    B, H, D = (FLASH_SHAPE[k] for k in ("batch", "heads", "head_dim"))
+    for seq in FLASH_SEQS:
+        rng = np.random.RandomState(SEED + seq)
+        q, k, v = (jnp.asarray(rng.randn(B, seq, H, D), jnp.bfloat16)
+                   for _ in range(3))
+        cotangent = jnp.asarray(rng.randn(B, seq, H, D), jnp.float32)
+        for causal in (False, True):
+            # The cotangent is an argument, not a closed-over constant: a
+            # constant is compiled into the executable, 32 MB of it here.
+            def kernel(q, k, v, cotangent):
+                out = flash_attention(q, k, v, causal=causal,
+                                      interpret=interpret)
+                return (out.astype(jnp.float32) * cotangent).sum(), out
+
+            def dense(q, k, v, cotangent):
+                with jax.default_matmul_precision("highest"):
+                    out = ring_attention_reference(q, k, v, causal=causal)
+                return (out * cotangent).sum(), out
+
+            grad = lambda f: jax.jit(jax.value_and_grad(  # noqa: E731
+                f, argnums=(0, 1, 2), has_aux=True))
+            (_, out), grads = grad(kernel)(q, k, v, cotangent)
+            (_, want), want_grads = grad(dense)(
+                *(x.astype(jnp.float32) for x in (q, k, v)), cotangent)
+            name = f"flash S={seq} {'causal' if causal else 'full'}"
+            yield f"{name} forward", max_abs_error(out, want), \
+                MXU_BF16_TOL
+            for which, g, w in zip("qkv", grads, want_grads):
+                yield f"{name} d{which}", max_abs_error(g, w), \
+                    MXU_BF16_TOL
+
+
+def paged_inputs(rng, chunk: int):
+    """A seeded pool and ``SERVE_BATCH`` sequences of mixed length laid
+    over it the way the engine does: distinct physical blocks in table
+    order, the hole sentinel past each sequence's last block.  ``chunk``
+    query rows per sequence (1 = decode); returns the first query
+    position of each row."""
+    import numpy as np
+    NB, BT = POOL["num_blocks"], POOL["block_tokens"]
+    span = TABLE_BLOCKS * BT
+    firsts = rng.randint(0, span - chunk + 1, size=SERVE_BATCH)
+    firsts[0], firsts[1] = 0, span - chunk  # no context / a full table
+    tables = np.full((SERVE_BATCH, TABLE_BLOCKS), NB, np.int32)
+    free = rng.permutation(NB)
+    for b, first in enumerate(firsts):
+        need = (first + chunk - 1) // BT + 1
+        tables[b, :need], free = free[:need], free[need:]
+    return tables, firsts.astype(np.int32)
+
+
+def paged_errors(interpret: bool):
+    """``paged_decode_attention`` / ``paged_prefill_attention`` against
+    ``paged_attention_reference`` over a float32 and an int8 pool."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from horovod_tpu.serve import paged_attention as pa
+
+    NB, BT, H, Dh = (POOL[k] for k in ("num_blocks", "block_tokens",
+                                       "heads", "head_dim"))
+    rng = np.random.RandomState(SEED)
+    k_pool, v_pool = (jnp.asarray(rng.randn(NB, BT, H, Dh), jnp.float32)
+                      for _ in range(2))
+    pools = {"f32": dict(k=k_pool, v=v_pool, scales={})}
+    (k8, ks), (v8, vs) = (pa.quantize_kv(p, "int8")
+                          for p in (k_pool, v_pool))
+    pools["int8"] = dict(k=k8, v=v8, scales=dict(k_scale=ks, v_scale=vs))
+    for phase, chunk, attend in (
+            ("decode", 1, pa.paged_decode_attention),
+            ("prefill", PREFILL_CHUNK, pa.paged_prefill_attention)):
+        tables, firsts = paged_inputs(rng, chunk)
+        q = jnp.asarray(rng.randn(SERVE_BATCH, chunk, H, Dh), jnp.float32)
+        if phase == "decode":
+            q = q[:, 0]
+        for kv, pool in pools.items():
+            got = jax.jit(lambda q, k, v, scales: attend(
+                q, k, v, tables, firsts, interpret=interpret, **scales))(
+                    q, pool["k"], pool["v"], pool["scales"])
+            reference = functools.partial(
+                pa.paged_attention_reference, q, pool["k"], pool["v"],
+                jnp.asarray(tables), jnp.asarray(firsts), **pool["scales"])
+            with jax.default_matmul_precision("highest"):
+                want = reference()
+            yield f"paged {phase} {kv} pool", max_abs_error(got, want), \
+                MXU_BF16_TOL
+            # For the record, not a check: the gather path the kernel
+            # replaces, at the precision the engine runs it.
+            yield f"paged {phase} {kv} pool, gather path at default " \
+                f"precision", max_abs_error(reference(), want), math.inf
+
+
+def phase_kernels() -> dict:
+    device = require_platform()
+    import horovod_tpu as hvd
+    hvd.init()  # the compile cache
+    print(f"kernels: compiled for {device}", flush=True)
+    outside = []
+    for errors in (flash_errors, paged_errors):
+        for name, (err, scale), tol in errors(interpret=False):
+            allowed = tol * max(1.0, scale)
+            print(f"kernels: {name}: max-abs error {err:.3e} against a "
+                  f"reference of magnitude {scale:.2f} (allowed "
+                  f"{allowed:.1e})", flush=True)
+            if not err <= allowed:
+                outside.append(name)
+    check(not outside, f"kernels out of tolerance: {', '.join(outside)}")
+    return device
+
+
+def phase_dp4() -> dict:
+    """The trainer's ``shard_step`` over four chips, then the same global
+    batch on a one-device mesh in this same process."""
+    device = require_platform()
+    check(device["count"] == 4, f"{device['count']} devices, not 4")
+    import jax
+    import horovod_tpu as hvd
+    from examples import synthetic_benchmark
+
+    losses = {}
+    for slots in (4, 1):
+        if slots == 1:
+            # A one-rank world over the first device only: the knob for
+            # fewer ranks than local devices (topology.detect).
+            os.environ["HVD_TPU_EMULATE_RANKS"] = "1"
+        hvd.init()
+        check(hvd.num_slots() == slots,
+              f"num_slots() is {hvd.num_slots()}, not {slots}")
+        step, state, batch = synthetic_benchmark.build(
+            DP_MODEL, DP_GLOBAL_BATCH // slots)
+        holders = {s.device for s in batch[0].addressable_shards}
+        check(len(holders) == slots,
+              f"the batch's shards sit on {len(holders)} devices, "
+              f"not {slots}")
+        print(f"dp4: {slots}-chip world: batch {batch[0].shape} in "
+              f"{len(batch[0].addressable_shards)} shards on "
+              f"{sorted(d.id for d in holders)}", flush=True)
+        if slots > 1:
+            text = step.lower(*state, *batch).compile().as_text()
+            all_reduces = re.findall(r"\ball-reduce(?:-start)?\(", text)
+            check(bool(all_reduces), "the compiled step holds no all-reduce")
+            print(f"dp4: the compiled step holds {len(all_reduces)} "
+                  f"all-reduce ops", flush=True)
+        losses[slots] = []
+        for _ in range(DP_STEPS):
+            *state, loss = step(*state, *batch)
+            losses[slots].append(float(loss))
+        print(f"dp4: {slots}-chip losses {losses[slots]}", flush=True)
+        del step, state, batch
+        hvd.shutdown()
+    for four, one in zip(losses[4], losses[1]):
+        check(math.isfinite(four) and math.isfinite(one),
+              "non-finite loss")
+        check(abs(four - one) <= DP_LOSS_TOL,
+              f"four-chip loss {four} and one-chip loss {one} differ by "
+              f"more than {DP_LOSS_TOL}")
+    check(losses[4][0] != losses[4][-1], "the parameters did not move")
+    return device
+
+
+CHILD_PHASES = {"kernels": phase_kernels, "dp4": phase_dp4}
+
+
+# ---------------------------------------------------------------------------
+# The parent: never touches a JAX backend
+# ---------------------------------------------------------------------------
+
+def stop(proc: subprocess.Popen) -> None:
+    """Kill a child and whatever it started (it leads its own session)."""
+    if proc.poll() is None:
+        try:
+            os.killpg(proc.pid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass
+    proc.wait()
+
+
+@contextlib.contextmanager
+def child(name: str, cmd):
+    """``cmd`` as a child with its output piped here, killed with whatever
+    it started when the phase's limit runs out or the block is left."""
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        filter(None, [REPO, os.environ.get("PYTHONPATH")])))
+    proc = subprocess.Popen(cmd, cwd=REPO, env=env, stdout=subprocess.PIPE,
+                            text=True, start_new_session=True)
+    timer = threading.Timer(LIMITS[name], stop, [proc])
+    timer.start()
+    try:
+        yield proc
+    finally:
+        timer.cancel()
+        stop(proc)
+
+
+def run_child(name: str, cmd) -> str:
+    """Run ``cmd`` to its end inside the phase's limit, passing its output
+    through; returns that output.  Non-zero exit or timeout fails."""
+    lines = []
+    with child(name, cmd) as proc:
+        for line in proc.stdout:
+            print(line, end="", flush=True)
+            lines.append(line)
+        rc = proc.wait()
+    check(rc == 0, f"{name}: exit code {rc} from {' '.join(cmd)}")
+    return "".join(lines)
+
+
+def child_report(name: str) -> dict:
+    out = run_child(name, [sys.executable, os.path.abspath(__file__),
+                           "--phase", name])
+    last = out.rstrip("\n").rsplit("\n", 1)[-1]
+    check(last.startswith(REPORT), f"{name}: no report line")
+    return json.loads(last[len(REPORT):])
+
+
+def run_trainer() -> None:
+    out = run_child("trainer", TRAINER_CMD)
+    platform = re.search(r"platform (\w+)", out)
+    losses = re.search(
+        r"Loss after warm-up: (\S+), after \d+ more steps: (\S+)", out)
+    check(platform is not None and losses is not None,
+          "trainer: no platform or loss line")
+    check(platform.group(1) == PLATFORM,
+          f"trainer ran on {platform.group(1)!r}, not {PLATFORM!r}")
+    warm, final = (float(x) for x in losses.groups())
+    check(math.isfinite(warm) and math.isfinite(final),
+          f"trainer: non-finite loss ({warm}, {final})")
+    check(warm != final, "trainer: the loss did not move in five steps")
+    print(f"trainer: losses {warm} -> {final} on {PLATFORM}", flush=True)
+
+
+def http(port: int, path: str, payload=None, timeout: float = 300):
+    data = None if payload is None else json.dumps(payload).encode()
+    with urllib.request.urlopen(
+            urllib.request.Request(f"http://127.0.0.1:{port}{path}",
+                                   data=data), timeout=timeout) as resp:
+        check(resp.status == 200, f"{path}: HTTP {resp.status}")
+        return resp.read().decode()
+
+
+def run_server() -> None:
+    with child("server", SERVER_CMD) as proc:
+        port = None
+        for line in proc.stdout:
+            print(line, end="", flush=True)
+            banner = re.search(r"listening on :(\d+)", line)
+            if banner:
+                port = int(banner.group(1))
+                break
+        check(port is not None, "server: exited before its banner")
+
+        rng = random.Random(SEED)
+        prompts = [[rng.randrange(VOCAB) for _ in range(n)]
+                   for n in PROMPT_LENGTHS + (TWIN_LENGTH,)]
+        prompts.append(list(prompts[-1]))  # the twin
+        answers = [None] * len(prompts)
+
+        def ask(i: int) -> None:
+            answers[i] = json.loads(http(port, "/generate", {
+                "tokens": prompts[i], "max_new_tokens": NEW_TOKENS}))
+
+        threads = [threading.Thread(target=ask, args=(i,))
+                   for i in range(len(prompts))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        check(all(a is not None for a in answers),
+              "server: a /generate request failed")
+        for prompt, answer in zip(prompts, answers):
+            tokens = answer["tokens"]
+            check(len(tokens) == NEW_TOKENS
+                  and all(0 <= t < VOCAB for t in tokens),
+                  f"server: bad tokens for a {len(prompt)}-token prompt: "
+                  f"{tokens}")
+            print(f"server: {len(prompt):3d}-token prompt -> {tokens}",
+                  flush=True)
+        check(answers[-1]["tokens"] == answers[-2]["tokens"],
+              "server: identical prompts gave different tokens")
+        print("server: identical prompts gave identical tokens",
+              flush=True)
+
+        health = json.loads(http(port, "/healthz"))
+        check(health["status"] == "ok", f"server: /healthz says {health}")
+        impls = [r["attn_impl"] for r in health["replicas"]]
+        check(impls == ["kernel"],
+              f"server: attention {impls}, not the kernel 'auto' picks "
+              f"on a TPU")
+        counted = re.search(r"^hvd_serve_tokens_total (\d+)$",
+                            http(port, "/metrics"), re.M)
+        served = sum(len(a["tokens"]) for a in answers)
+        check(counted is not None and int(counted.group(1)) == served,
+              f"server: /metrics counts {counted and counted.group(1)} "
+              f"tokens, {served} were served")
+        print(f"server: healthz ok, attention {impls[0]}, /metrics counts "
+              f"{served} tokens", flush=True)
+
+        proc.send_signal(signal.SIGTERM)
+        for line in proc.stdout:
+            print(line, end="", flush=True)
+        rc = proc.wait()
+        check(rc == 0, f"server: exit code {rc} after SIGTERM")
+        print("server: drained and exited 0 on SIGTERM", flush=True)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--phase", choices=sorted(CHILD_PHASES),
+                    help=argparse.SUPPRESS)  # how the parent runs a child
+    args = ap.parse_args(argv)
+    started = time.monotonic()
+    try:
+        if args.phase:
+            sys.path.insert(0, REPO)
+            print(REPORT + json.dumps(CHILD_PHASES[args.phase]()),
+                  flush=True)
+            return 0
+        def timed(name, phase, *phase_args):
+            t0 = time.monotonic()
+            result = phase(*phase_args)
+            print(f"chip_smoke: {name} passed in "
+                  f"{time.monotonic() - t0:.0f} s", flush=True)
+            return result
+
+        if args.chips == 4:
+            device = timed("dp4", child_report, "dp4")
+        else:
+            device = timed("kernels", child_report, "kernels")
+            timed("trainer", run_trainer)
+            timed("server", run_server)
+        check(device["platform"] == PLATFORM
+              and device["count"] == args.chips,
+              f"device {device} is not {args.chips} x {PLATFORM}")
+    except SmokeFailure as failure:
+        print(f"chip_smoke: FAILED: {failure}", file=sys.stderr, flush=True)
+        return 1
+    print(f"chip_smoke: every phase passed in "
+          f"{time.monotonic() - started:.0f} s", flush=True)
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -96,26 +96,13 @@ def main(argv=None):
     nslots = hvd.num_slots()
     attn = args.attention
     if attn == "auto":
-        # flash only when the kernels actually COMPILE here, for THIS
-        # model's shape/dtype (a Mosaic rejection must degrade to dense,
-        # not kill the bench run — parallel/flash.py flash_supported).
-        from horovod_tpu.parallel.flash import flash_supported
-        probe_cfg = TINY if args.size == "tiny" else \
-            {"base": BERT_BASE, "large": BERT_LARGE}[args.size]
-        attn = "flash" if (
-            jax.default_backend() == "tpu"
-            and flash_supported(
-                dtype=str(jnp.dtype(probe_cfg.dtype)),
-                head_dim=probe_cfg.d_model // probe_cfg.num_heads,
-                seq_len=args.seq_len, causal=probe_cfg.causal)
-        ) else "dense"
+        attn = "flash" if jax.default_backend() == "tpu" else "dense"
     attn_impl = "flash" if attn == "flash" else None
     if args.size == "tiny":
         cfg = dataclasses.replace(TINY, attention_impl=attn_impl)
     else:
         cfg = {"base": BERT_BASE, "large": BERT_LARGE}[args.size]
-        # scan_layers: ~num_layers x faster compile at identical numerics
-        # (BERT-large's ~7 min remote compile was the bench-window risk).
+        # scan_layers: ~num_layers x faster compile at identical numerics.
         cfg = dataclasses.replace(
             cfg, max_len=args.seq_len, remat=args.remat,
             attention_impl=attn_impl, scan_layers=True)
@@ -161,8 +148,8 @@ def main(argv=None):
                        and jax.default_backend() != "tpu"))
 
     # Keep per-step losses ON DEVICE: a float() per step is a host
-    # round-trip that serializes dispatch (catastrophic through a remote
-    # PJRT transport); fetch the whole trace once at the end.
+    # round-trip that serializes dispatch; fetch the whole trace once at
+    # the end.
     losses_dev = []
     t0 = time.perf_counter()
     for i in range(args.steps):

@@ -53,9 +53,8 @@ def main(argv=None):
 
     hvd.init()
     nslots = hvd.num_slots()
-    # scan_layers (factory default): ~num_layers x faster compile — the
-    # >10 min remote-compile that blocked on-chip GPT-2 captures in rounds
-    # 2-4.  Adasum's per-tensor coefficient granularity (adasum.h:396-409)
+    # scan_layers (factory default): ~num_layers x faster compile.
+    # Adasum's per-tensor coefficient granularity (adasum.h:396-409)
     # survives the stacked [L, ...] layout via per_layer_stacked below:
     # the scanned blocks get one coefficient pair PER LAYER SLICE, exactly
     # what the unrolled layout computed.

@@ -18,7 +18,6 @@ Typical use (the Horovod idiom, TPU-compiled)::
     avg_grads = hvd.allreduce(grads, op=hvd.Average)
 """
 
-from . import compat  # noqa: F401  (installs jax API shims; must be first)
 from .version import __version__  # noqa: F401
 
 from .core import (  # noqa: F401

@@ -35,13 +35,23 @@ import jax
 
 from .findings import Finding
 
-# Axis-name collective primitives across jax versions; unknown names
-# simply never match.
+# Axis-name collective primitives.
 COLLECTIVE_PRIMS = {
     "psum", "pmin", "pmax", "ppermute", "pshuffle", "all_gather",
     "all_to_all", "reduce_scatter", "psum_scatter", "pbroadcast",
-    "pgather", "psum_invariant",
+    "pgather",
 }
+
+
+def collective_name(eqn) -> Optional[str]:
+    """The census name of a collective eqn, None for any other eqn.
+    Under shard_map's varying-axes tracking ``jax.lax.psum`` of a varying
+    value traces as ``psum_invariant`` — the same all-reduce on the wire,
+    so it is counted as ``psum``."""
+    name = eqn.primitive.name
+    if name == "psum_invariant":
+        return "psum"
+    return name if name in COLLECTIVE_PRIMS else None
 
 
 @dataclasses.dataclass
@@ -86,7 +96,7 @@ class JaxprReport:
 
 def _as_jaxpr(obj: Any):
     """Unwrap ClosedJaxpr → Jaxpr; pass Jaxpr through; None otherwise."""
-    core = jax.core
+    from jax.extend import core
     if isinstance(obj, core.ClosedJaxpr):
         return obj.jaxpr
     if isinstance(obj, core.Jaxpr):
@@ -136,15 +146,15 @@ def _signature(jaxpr) -> Tuple:
 
     def rec(j) -> None:
         for eqn in j.eqns:
-            name = eqn.primitive.name
-            if name in COLLECTIVE_PRIMS:
+            coll = collective_name(eqn)
+            if coll is not None:
                 shapes = tuple(
                     (tuple(getattr(v.aval, "shape", ())),
                      str(getattr(v.aval, "dtype", "?")))
                     for v in eqn.invars if getattr(v, "aval", None)
                     is not None)
-                sig.append((name, _axis_names(eqn.params), shapes))
-            elif name == "scan":
+                sig.append((coll, _axis_names(eqn.params), shapes))
+            elif eqn.primitive.name == "scan":
                 length = int(eqn.params.get("length", 1) or 1)
                 sig.extend(_signature(eqn.params.get("jaxpr")) * length)
             else:
@@ -178,7 +188,7 @@ class _Walker:
             message=message, source="jaxpr"))
 
     def record(self, eqn, mult: int) -> None:
-        name = eqn.primitive.name
+        name = collective_name(eqn)
         entry = self.report.census.setdefault(
             name, {"count": 0, "bytes": 0})
         entry["count"] += mult
@@ -190,18 +200,19 @@ class _Walker:
         if j is None:
             return
         for eqn in j.eqns:
-            name = eqn.primitive.name
-            if name in COLLECTIVE_PRIMS:
+            coll = collective_name(eqn)
+            if coll is not None:
                 self.record(eqn, mult)
                 if declared is not None:
                     for axis in _axis_names(eqn.params):
                         if axis not in declared:
                             self.emit(
                                 "HVD101",
-                                f"collective '{name}' reduces over axis "
+                                f"collective '{coll}' reduces over axis "
                                 f"'{axis}' but the enclosing mesh only "
                                 f"declares {sorted(declared)}")
                 continue
+            name = eqn.primitive.name
             if name == "cond":
                 self._walk_cond(eqn, declared, mult)
             elif name == "scan":

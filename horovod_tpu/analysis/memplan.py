@@ -214,8 +214,8 @@ class _LivenessWalker:
 
     def __init__(self, report: MemReport, fusion_threshold: int,
                  upcast_min: int):
-        import jax
-        self._var = jax.core.Var
+        from jax.extend import core
+        self._var = core.Var
         self.report = report
         self.fusion_threshold = fusion_threshold
         self.upcast_min = upcast_min
@@ -225,12 +225,8 @@ class _LivenessWalker:
     # -- helpers ------------------------------------------------------------
 
     def _as_jaxpr(self, obj):
-        import jax
-        if isinstance(obj, jax.core.ClosedJaxpr):
-            return obj.jaxpr
-        if isinstance(obj, jax.core.Jaxpr):
-            return obj
-        return None
+        from .jaxpr_check import _as_jaxpr
+        return _as_jaxpr(obj)
 
     def _sub_jaxprs(self, eqn) -> List[Any]:
         subs: List[Any] = []
@@ -404,18 +400,16 @@ def _unwrap_wrappers(jaxpr, donated: Optional[Tuple[bool, ...]],
             return jaxpr, donated, divisors
         eqn = jaxpr.eqns[0]
         name = eqn.primitive.name
-        if name not in ("pjit", "shard_map"):
+        if name not in ("jit", "shard_map"):
             return jaxpr, donated, divisors
         if list(eqn.invars) != list(jaxpr.invars) or \
                 list(eqn.outvars) != list(jaxpr.outvars):
             return jaxpr, donated, divisors
-        inner = eqn.params.get("jaxpr")
-        import jax
-        if isinstance(inner, jax.core.ClosedJaxpr):
-            inner = inner.jaxpr
+        from .jaxpr_check import _as_jaxpr
+        inner = _as_jaxpr(eqn.params.get("jaxpr"))
         if inner is None or len(inner.invars) != len(jaxpr.invars):
             return jaxpr, donated, divisors
-        if name == "pjit":
+        if name == "jit":
             if donated is None:
                 flags = eqn.params.get("donated_invars")
                 if flags is not None:

@@ -295,14 +295,8 @@ class _CommWalker:
     def __init__(self, report: CommReport, fabrics: Dict[str, str],
                  dcn_axes: Optional[Sequence[str]],
                  reshard_min: int, replicated_min: int):
-        import jax
-        from .jaxpr_check import COLLECTIVE_PRIMS
-        self._var = jax.core.Var
-        # shard_map's rewrite mode (check_rep/vma tracking ON) rewrites
-        # psum to the psum2 primitive; the repo's compat shim traces with
-        # check_rep=False so repo programs keep plain psum, but the census
-        # must count both so raw/modern-jax traces measure identically.
-        self._collectives = COLLECTIVE_PRIMS | {"psum2"}
+        from jax.extend import core
+        self._var = core.Var
         self.report = report
         self.fabrics = fabrics          # axis -> "ici" | "dcn"
         self.dcn_axes = dcn_axes
@@ -340,10 +334,8 @@ class _CommWalker:
     # -- per-eqn handlers ---------------------------------------------------
 
     def _record_collective(self, eqn, mult: int) -> None:
-        from .jaxpr_check import _axis_names, _payload_bytes
-        name = eqn.primitive.name
-        if name == "psum2":  # rewrite-mode spelling of psum (same wire cost)
-            name = "psum"
+        from .jaxpr_check import _axis_names, _payload_bytes, collective_name
+        name = collective_name(eqn)
         axes = _axis_names(eqn.params)
         payload = _payload_bytes(eqn)
         # Wire bytes: payload x communicator group size (the all-gather/
@@ -476,16 +468,16 @@ class _CommWalker:
 
     def walk(self, jaxpr, mult: int = 1,
              known: Optional[Dict[Any, Optional[Tuple]]] = None) -> None:
-        from .jaxpr_check import _as_jaxpr, _sub_jaxprs
+        from .jaxpr_check import _as_jaxpr, _sub_jaxprs, collective_name
         j = _as_jaxpr(jaxpr)
         if j is None:
             return
         known = {} if known is None else known
         for eqn in j.eqns:
             name = eqn.primitive.name
-            if name in self._collectives:
+            if collective_name(eqn) is not None:
                 self._record_collective(eqn, mult)
-            elif name == "pjit":
+            elif name == "jit":
                 self._handle_pjit(eqn, known, mult)
                 self.walk(eqn.params.get("jaxpr"), mult)
             elif name == "sharding_constraint":

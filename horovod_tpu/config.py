@@ -57,7 +57,6 @@ HOROVOD_RENDEZVOUS_PORT = "HOROVOD_GLOO_RENDEZVOUS_PORT"
 # TPU-build specific knobs (new; no reference analog).
 HVD_TPU_EMULATE_RANKS = "HVD_TPU_EMULATE_RANKS"  # treat N local devices as N ranks
 HVD_TPU_MESH_AXIS = "HVD_TPU_MESH_AXIS"          # mesh axis name, default "hvd"
-HVD_TPU_COMPILATION_CACHE = "HVD_TPU_COMPILATION_CACHE"  # persistent XLA cache dir
 HOROVOD_AUTOTUNE_SEARCH = "HOROVOD_AUTOTUNE_SEARCH"      # 'sweep' | 'bayes'
 HOROVOD_AUTOTUNE_BAYES_ROUNDS = "HOROVOD_AUTOTUNE_BAYES_ROUNDS"
 
@@ -125,7 +124,6 @@ class Config:
     # TPU-specific.
     emulate_ranks: int = 0
     mesh_axis: str = "hvd"
-    compilation_cache_dir: Optional[str] = None
 
     @classmethod
     def from_env(cls) -> "Config":
@@ -155,5 +153,4 @@ class Config:
             log_hide_timestamp=env_bool(HOROVOD_LOG_HIDE_TIME),
             emulate_ranks=env_int(HVD_TPU_EMULATE_RANKS, 0),
             mesh_axis=os.environ.get(HVD_TPU_MESH_AXIS, "hvd"),
-            compilation_cache_dir=os.environ.get(HVD_TPU_COMPILATION_CACHE),
         )

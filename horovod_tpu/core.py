@@ -115,32 +115,31 @@ def _maybe_join_distributed(cfg: _config.Config) -> None:
     # Healthy same-world resets clear the barrier in well under a second.
     shutdown_timeout = int(float(os.environ.get(
         "HVD_TPU_DIST_SHUTDOWN_TIMEOUT_S", "60")))
-    # Multi-process CPU worlds (the hermetic e2e test environment, and any
-    # CPU-fallback deployment) need a cross-host collectives transport; on
-    # jax 0.4.x the CPU backend refuses multiprocess computations unless
-    # the gloo implementation is selected BEFORE the backend client is
-    # created.  A no-op where unsupported/already-default, and irrelevant
-    # to TPU backends (the flag only affects CPU clients).
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass
-    kwargs = dict(
+    jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=int(size),
         process_id=int(rank),
         initialization_timeout=init_timeout,
         shutdown_timeout_seconds=shutdown_timeout,
     )
-    try:
-        jax.distributed.initialize(**kwargs)
-    except TypeError:
-        # Older jax (< 0.6) has no shutdown_timeout_seconds: the barrier
-        # bound is lost (a doomed survivor hangs the full default before
-        # aborting), but the world still forms — strictly better than not
-        # initializing at all.
-        kwargs.pop("shutdown_timeout_seconds")
-        jax.distributed.initialize(**kwargs)
+
+
+def _enable_compile_cache() -> None:
+    """The one persistent XLA compile cache of this checkout: elastic
+    resizes, relaunches and restarted servers re-trace every program
+    (SURVEY.md §7 "hide latency with compilation cache"), and with the
+    cache the re-compile is a disk hit.  Where ``JAX_COMPILATION_CACHE_DIR``
+    places it from outside, JAX reads that itself; otherwise it lives at a
+    fixed path — the path is part of the cache's key, so one that moves
+    never hits.  The floors go to zero so that the small, fast-compiling
+    serve bucket programs persist too."""
+    import jax
+    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+        checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(checkout, ".jax_cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
 def init(comm: Optional[Sequence[int]] = None,
@@ -168,18 +167,7 @@ def init(comm: Optional[Sequence[int]] = None,
             _analysis_hook.reset()
             _state.analysis_reports = []
         cfg = _config.Config.from_env()
-        if cfg.compilation_cache_dir:
-            # Persistent XLA compilation cache: elastic world resizes and
-            # relaunches re-trace every program (SURVEY.md §7 "hide latency
-            # with compilation cache") — this makes the re-compile a disk hit.
-            import jax
-            try:
-                jax.config.update("jax_compilation_cache_dir",
-                                  cfg.compilation_cache_dir)
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 0.5)
-            except Exception as e:
-                get_logger().warning("compilation cache setup failed: %s", e)
+        _enable_compile_cache()
         _maybe_join_distributed(cfg)
         topo = _topology.detect(cfg)
         if comm is not None and list(comm) != list(range(topo.size)):

@@ -193,8 +193,8 @@ class ResNet(nn.Module):
             # FusedBatchNorm (sync_batch_norm.py): f32 statistics, folded
             # per-channel scale/offset applied in the activation dtype, so
             # the BN+ReLU+add epilogue fuses with its conv neighbors
-            # instead of a standalone f32 normalize chain (PERF_r02's
-            # BN-chain headroom; same param/stat tree as flax BatchNorm).
+            # instead of a standalone f32 normalize chain (same
+            # param/stat tree as flax BatchNorm).
             from ..sync_batch_norm import FusedBatchNorm
             norm = partial(FusedBatchNorm, use_running_average=not train,
                            momentum=0.9, epsilon=1e-5, dtype=self.dtype,

@@ -232,11 +232,9 @@ class Transformer(nn.Module):
         if cfg.scan_layers:
             # One traced block, lax.scan'd over stacked [L, ...] params:
             # the HLO carries ONE block body instead of num_layers copies,
-            # which divides XLA compile time by ~the depth — the lever
-            # that brought GPT-2-medium's remote compile (>10 min through
-            # the relay, TODO.md r4) back into budget.  Param tree changes
-            # shape (blocks/block/... stacked) — stack_block_params
-            # migrates unrolled checkpoints.
+            # which divides XLA compile time by ~the depth.  Param tree
+            # changes shape (blocks/block/... stacked) —
+            # stack_block_params migrates unrolled checkpoints.
             if cfg.moe_experts > 0 and cfg.moe_every != 1:
                 raise ValueError(
                     "scan_layers needs homogeneous blocks; interleaved "
@@ -322,7 +320,7 @@ def create_gpt2(size: str = "medium", **overrides) -> Transformer:
     """Factories default ``scan_layers=True``: one traced block lax.scan'd
     over stacked params compiles ~num_layers x faster at identical step
     numerics (24-layer measurement: 59.7 -> 5.2 s CPU compile, StableHLO
-    943 -> 137 kB) — the fix for GPT-2-medium's >10 min remote compile.
+    943 -> 137 kB).
     Pass ``scan_layers=False`` for the unrolled block_i param layout;
     ``stack_block_params``/``unstack_block_params`` convert checkpoints.
     Caveat: per-TENSOR gradient methods see stacked leaves as one tensor —

@@ -67,16 +67,17 @@ def _is_invariant(x, axis: str) -> bool:
     under shard_map, gradients w.r.t. replicated parameters come back
     *already psum'd* by the transpose rule, so they are axis-invariant.
 
-    Without vma tracking (jax 0.4.x via compat.py shims) the aval carries
-    no ``vma`` set at all.  There the OLD shard_map transpose (check_rep
-    False) hands back the shard-LOCAL cotangent for replicated params —
-    nothing arrives pre-summed — so the correct degraded answer is
-    "everything varies": always run the reduction.  Returning invariant
-    on a missing attribute would silently skip every psum."""
-    vma = getattr(jax.typeof(x), "vma", None)
-    if vma is None:
-        return False
-    return axis not in vma
+    Under ``shard_map(check_vma=False)`` or a bare ``axis_env`` trace
+    nothing is tracked: every value types as invariant and no transpose
+    pre-sums anything, so there every gradient is local and "varies"."""
+    return axis not in jax.typeof(x).vma and _vma_tracked((axis,))
+
+
+def _vma_tracked(axes) -> bool:
+    """Whether the enclosing trace tracks varying manual axes: an
+    explicitly varying probe value carries them exactly when it does."""
+    return bool(jax.typeof(
+        jax.lax.pcast(jnp.zeros(()), axes, to="varying")).vma)
 
 
 def _to_varying(tree, axis: str):
@@ -139,9 +140,8 @@ def _reduce_multi_axis_leaf(l, op, prescale, postscale, reduce_axes,
     axis that shards weights but NOT the batch must not appear in
     reduce_axes — its gradients are already complete per shard and the
     uniform divisor would shrink them by that axis's size."""
-    vma = getattr(jax.typeof(l), "vma", frozenset())
-    param_vma = getattr(jax.typeof(param), "vma", frozenset()) \
-        if param is not None else frozenset()
+    vma = jax.typeof(l).vma
+    param_vma = jax.typeof(param).vma if param is not None else frozenset()
     from .ops import collective_ops as C
     l = C._apply_scale(l, prescale)
     varying = tuple(a for a in reduce_axes
@@ -176,12 +176,10 @@ def _allreduce_tree(grads, op, compression, prescale, postscale, process_set,
                     f"shard_map over a mesh carrying those axes")
         # Under shard_map(check_vma=False) vma tracking is OFF: every
         # value types as frozenset() and would be treated as pre-reduced,
-        # silently skipping the psum.  Probe with pvary — if even an
-        # explicitly varying value carries no vma, tracking is off and we
-        # cannot tell local from pre-summed gradients; fail loudly rather
-        # than diverge quietly.
-        probe = jax.lax.pvary(jnp.zeros(()), axes)
-        if not getattr(jax.typeof(probe), "vma", frozenset()):
+        # silently skipping the psum.  Then we cannot tell which axes a
+        # gradient is still local on; fail loudly rather than diverge
+        # quietly.
+        if not _vma_tracked(axes):
             raise ValueError(
                 "reduce_axes requires varying-manual-axes tracking to "
                 "tell local gradients from pre-reduced ones; use "

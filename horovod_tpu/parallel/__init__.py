@@ -95,6 +95,11 @@ def shard_step(fn: Callable,
     cache = {}
     analyzed_gen = {}  # arity -> analysis generation it was checked in
 
+    def built(nargs: int):
+        if nargs not in cache:
+            cache[nargs] = build(nargs)
+        return cache[nargs]
+
     def wrapper(*args, **kwargs):
         if kwargs:
             raise TypeError(
@@ -102,9 +107,7 @@ def shard_step(fn: Callable,
                 "only (shard_map in_specs are positional); pass "
                 f"{sorted(kwargs)} positionally")
         key = len(args)
-        if key not in cache:
-            cache[key] = build(key)
-        jitted, mapped = cache[key]
+        jitted, mapped = built(key)
         if _analysis_hook.enabled() and \
                 analyzed_gen.get(key) != _analysis_hook.generation():
             # Trace-time correctness check on first compile (HVD_ANALYZE=1,
@@ -128,6 +131,9 @@ def shard_step(fn: Callable,
                 mesh=mesh)
         return jitted(*args)
 
+    # jit's ahead-of-time door: ``step.lower(*args).compile()`` gives the
+    # program the calls run, for its text and its memory analysis.
+    wrapper.lower = lambda *args: built(len(args))[0].lower(*args)
     return wrapper
 
 

@@ -23,11 +23,13 @@ kernel serves.  FlashAttention-2 structure, mapped onto the Mosaic pipeline:
 Parity note: the reference has no attention kernels at all (it is a
 communication library); this is part of the TPU build's "beat the baseline"
 surface (SURVEY.md §5.8).  Numerics (forward AND gradients) are validated
-against the dense reference implementation in tests (CPU interpret mode)
-and the kernel is exercised on the real chip by bench/examples.
+against the dense reference implementation in tests (CPU interpret mode),
+the kernels are compiled for the chip in tests/test_tpu_compile.py and run
+against the same reference on it by chip_smoke.py.
 
-Layout: [B, S, H, D] public API; internally [B*H, S, D].  Block sizes
-default to 128 (MXU tile) and clamp to the sequence length.
+Layout: [B, S, H, D] public API; internally [B*H, S, D], per-row
+statistics [B*H, 1, S].  Block sizes default to 128 (MXU tile) and clamp
+to the sequence length.
 """
 
 from __future__ import annotations
@@ -39,11 +41,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128  # VMEM lane width: (block_q, LANES) scratch keeps m/l aligned
@@ -116,10 +114,24 @@ def online_softmax_block(s, v, m_ref, l_ref, acc_ref):
 
 
 def online_softmax_flush(m_ref, l_ref, acc_ref):
-    """Finalize the online softmax: returns ``(out [Bq, D], lse [Bq])``
-    from the scratch state after the last contributing block."""
-    l_final = jnp.maximum(l_ref[:, :1], 1e-30)
-    return acc_ref[...] / l_final, m_ref[:, 0] + jnp.log(l_final[:, 0])
+    """Finalize the online softmax: returns ``(out [Bq, D], lse
+    [Bq, LANES])`` from the scratch state after the last contributing
+    block; the logsumexp stays lane-broadcast like the state it is made
+    from."""
+    l_final = jnp.maximum(l_ref[...], 1e-30)
+    return acc_ref[...] / l_final[:, :1], m_ref[...] + jnp.log(l_final)
+
+
+def _col_to_row(x):
+    """``[Bq, LANES]`` lane-broadcast per-row statistic → ``[1, Bq]`` row,
+    the layout it is stored in (see ``_flash_fwd``)."""
+    return x.T[:1]
+
+
+def _row_to_col(row):
+    """``[1, Bq]`` stored statistic → ``[Bq, 1]`` column that broadcasts
+    against a ``[Bq, Bk]`` score tile."""
+    return jnp.broadcast_to(row, (LANES, row.shape[1])).T[:, :1]
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l, *,
@@ -152,7 +164,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l, *,
     def _flush():
         out, lse = online_softmax_flush(m, l, acc)
         o_ref[0] = out.astype(o_ref.dtype)
-        lse_ref[0] = lse
+        lse_ref[0] = _col_to_row(lse)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -178,11 +190,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             q, k, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         s = causal_mask(s, qi * block_q, kb * block_k, mask_mode)
-        p = jnp.exp(s - lse_ref[0][:, None])          # [Bq, Bk]
+        p = jnp.exp(s - _row_to_col(lse_ref[0]))      # [Bq, Bk]
         dp = jax.lax.dot_general(
             do, v, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, None])
+        ds = p * (dp - _row_to_col(delta_ref[0]))
         dq_acc[...] += jax.lax.dot_general(
             ds, k, dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -217,14 +229,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             q, k, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         s = causal_mask(s, qi * block_q, kb * block_k, mask_mode)
-        p = jnp.exp(s - lse_ref[0][:, None])          # [Bq, Bk]
+        p = jnp.exp(s - _row_to_col(lse_ref[0]))      # [Bq, Bk]
         dv_acc[...] += jax.lax.dot_general(
             p, do, dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)       # [Bk, D]
         dp = jax.lax.dot_general(
             do, v, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, None])
+        ds = p * (dp - _row_to_col(delta_ref[0]))
         dk_acc[...] += jax.lax.dot_general(
             ds, q, dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)       # [Bk, D]
@@ -237,25 +249,22 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _out_struct(shape, dtype, like):
-    vma = getattr(jax.typeof(like), "vma", None)
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _compiler_params(interpret):
-    if interpret or pltpu is None:
+    if interpret:
         return None
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _require_pltpu():
-    if pltpu is None:  # pragma: no cover
-        raise ImportError(
-            "flash_attention needs jax.experimental.pallas.tpu (for VMEM "
-            "scratch allocation, used even by the CPU interpreter); this "
-            "JAX build does not provide it")
+def _stat_spec(block_q, index_map):
+    """BlockSpec of a per-row statistic (logsumexp, delta).  They are kept
+    as ``[BH, 1, S]`` rows so that a block's last two dimensions are the
+    whole unit dimension and a lane-dense ``block_q`` — a ``(1, block_q)``
+    block of a ``[BH, S]`` array is refused by the TPU lowering."""
+    return pl.BlockSpec((1, 1, block_q), index_map)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -275,7 +284,7 @@ def _flash_fwd(q, k, v, mask_mode, scale, block_q, block_k, interpret):
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[_out_struct((BH, S, D), q.dtype, q),
-                   _out_struct((BH, S), jnp.float32, q)],
+                   _out_struct((BH, 1, S), jnp.float32, q)],
         grid=(BH, num_qb, num_kb),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, qi, kb: (bh, qi, 0)),
@@ -284,7 +293,7 @@ def _flash_fwd(q, k, v, mask_mode, scale, block_q, block_k, interpret):
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, qi, kb: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, qi, kb: (bh, qi)),
+            _stat_spec(block_q, lambda bh, qi, kb: (bh, 0, qi)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),
@@ -294,7 +303,7 @@ def _flash_fwd(q, k, v, mask_mode, scale, block_q, block_k, interpret):
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
     )(q, k, v)
-    return out, (q, k, v, out, lse)
+    return out, (q, k, v, out, lse.reshape(BH, S))
 
 
 def _flash_bwd(mask_mode, scale, block_q, block_k, interpret, res, g):
@@ -315,6 +324,7 @@ def _run_bwd_kernels(q, k, v, do, lse, delta, mask_mode, scale,
     ``delta``; see ``_flash_lse_bwd``)."""
     BH, S, D = q.shape
     num_qb, num_kb = S // block_q, S // block_k
+    lse, delta = lse.reshape(BH, 1, S), delta.reshape(BH, 1, S)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, mask_mode=mask_mode,
@@ -326,8 +336,8 @@ def _run_bwd_kernels(q, k, v, do, lse, delta, mask_mode, scale,
             pl.BlockSpec((1, block_k, D), lambda bh, qi, kb: (bh, kb, 0)),
             pl.BlockSpec((1, block_k, D), lambda bh, qi, kb: (bh, kb, 0)),
             pl.BlockSpec((1, block_q, D), lambda bh, qi, kb: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, qi, kb: (bh, qi)),
-            pl.BlockSpec((1, block_q), lambda bh, qi, kb: (bh, qi)),
+            _stat_spec(block_q, lambda bh, qi, kb: (bh, 0, qi)),
+            _stat_spec(block_q, lambda bh, qi, kb: (bh, 0, qi)),
         ],
         out_specs=pl.BlockSpec((1, block_q, D),
                                lambda bh, qi, kb: (bh, qi, 0)),
@@ -348,8 +358,8 @@ def _run_bwd_kernels(q, k, v, do, lse, delta, mask_mode, scale,
             pl.BlockSpec((1, block_k, D), lambda bh, kb, qi: (bh, kb, 0)),
             pl.BlockSpec((1, block_k, D), lambda bh, kb, qi: (bh, kb, 0)),
             pl.BlockSpec((1, block_q, D), lambda bh, kb, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, kb, qi: (bh, qi)),
-            pl.BlockSpec((1, block_q), lambda bh, kb, qi: (bh, qi)),
+            _stat_spec(block_q, lambda bh, kb, qi: (bh, 0, qi)),
+            _stat_spec(block_q, lambda bh, kb, qi: (bh, 0, qi)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda bh, kb, qi: (bh, kb, 0)),
@@ -404,45 +414,6 @@ def _flash_lse_bwd(mask_mode, scale, block_q, block_k, interpret,
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
-@functools.lru_cache(maxsize=None)
-def flash_supported(dtype: str = "bfloat16", head_dim: int = 64,
-                    seq_len: int = 256, causal: bool = True) -> bool:
-    """Whether the Pallas kernels COMPILE on the current default backend
-    for THIS configuration (Mosaic tiling/masking differs per shape,
-    dtype, and causality — a verdict for one instantiation says nothing
-    about another, so callers pass the config they are about to run).
-
-    The kernels are numerics-validated in interpret mode, but Mosaic (the
-    TPU kernel compiler) can still reject a construct only at compile
-    time — and a rejection inside a fused train step kills the whole
-    program.  Automatic backend selection (examples/bert_pretraining
-    ``--attention auto``, i.e. the bench battery) probes this first: a
-    tiny fwd+bwd AOT compile of the gated config decides (seconds, and
-    the persistent compile cache makes repeats free), with dense
-    attention as the fallback.  Off-TPU the interpret path is used,
-    which always works."""
-    if pltpu is None:
-        return False
-    if jax.default_backend() != "tpu":
-        return True
-    try:
-        q = jnp.zeros((1, seq_len, 1, head_dim), jnp.dtype(dtype))
-
-        def f(x):
-            return flash_attention(x, x, x, causal=causal).sum()
-
-        jax.jit(jax.grad(f)).lower(q).compile()
-        return True
-    except Exception as e:
-        from ..utils import get_logger
-        get_logger().warning(
-            "Pallas flash attention (dtype=%s head_dim=%d seq=%d "
-            "causal=%s) does not compile on this backend (%s: %s); auto "
-            "attention selection falls back to dense",
-            dtype, head_dim, seq_len, causal, type(e).__name__, e)
-        return False
-
-
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     *,
                     causal: bool = False,
@@ -457,7 +428,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     under shard_map, pass ``check_vma=False`` to the shard_map (the
     interpreter inlines the kernel, mixing invariant loop indices with
     varying data); the compiled TPU path needs no such escape hatch."""
-    _require_pltpu()
     B, S, H, D = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     if interpret is None:
@@ -492,7 +462,6 @@ def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array,
     nonzero).  ``mask_mode`` is one of MASK_NONE / MASK_CAUSAL /
     MASK_STRICT applied on LOCAL block indices (ring hops pick the mode
     per hop from the block owner)."""
-    _require_pltpu()
     B, S, H, D = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     if interpret is None:

@@ -83,38 +83,6 @@ def _next_pow2(n: int, floor: int = 1) -> int:
     return b
 
 
-_COMPILE_CACHE_ENABLED = False
-
-
-def maybe_enable_compile_cache() -> None:
-    """Zero cold-start, persistent half (docs/serving.md warmup):
-    point jax's compilation cache at ``HVD_SERVE_COMPILE_CACHE`` (a
-    directory) so a restarted server — or a controller-grown replica in
-    a fresh process — REUSES the previous process's XLA executables
-    instead of re-lowering every (bucket, batch) program.  Idempotent;
-    a failure is logged and serving proceeds uncached (the AOT warmup
-    still hides the compiles off the request path)."""
-    global _COMPILE_CACHE_ENABLED
-    path = os.environ.get("HVD_SERVE_COMPILE_CACHE", "")
-    if not path or _COMPILE_CACHE_ENABLED:
-        return
-    try:
-        import jax
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        # Serve-bucket programs are small and compile fast; without
-        # these floors the cache would skip exactly the programs the
-        # warmup wants persisted.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        _COMPILE_CACHE_ENABLED = True
-    except Exception as e:  # pragma: no cover - config-dependent
-        get_logger().warning(
-            "serve: could not enable the persistent compile cache at "
-            "%s: %s", path, e)
-
-
 # ---------------------------------------------------------------------------
 # Model adapters
 # ---------------------------------------------------------------------------
@@ -1386,7 +1354,6 @@ class InferenceEngine:
                  tier_client=None,
                  sp_ranks: Optional[int] = None,
                  sp_min_tokens: Optional[int] = None):
-        maybe_enable_compile_cache()
         self.adapter = adapter
         # Multi-model residency (serve/registry.py): named variants
         # sharing this engine's slots and paged pool.  ``adapter`` stays
@@ -1924,7 +1891,7 @@ class InferenceEngine:
         are compiled BEFORE mark_alive reports the replica healthy.
         Only legal against an empty slot table (a busy engine skips: the
         live cache must not see warmup writes); combined with the
-        persistent compile cache (HVD_SERVE_COMPILE_CACHE) a freshly
+        persistent compile cache ``hvd.init()`` enables, a freshly
         grown replica pays disk-cache lookups, not compiles.  Returns
         wall-clock milliseconds spent (0.0 when skipped or failed —
         warmup failure degrades to cold serving, never to a dead

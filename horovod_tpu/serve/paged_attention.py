@@ -8,9 +8,10 @@ for this swap.  These kernels consume the pool and the block tables
 *directly* (vLLM's PagedAttention, Kwon et al. SOSP '23, mapped onto the
 Mosaic pipeline the way ``parallel/flash.py`` maps FlashAttention-2):
 
-* **decode** — grid ``(B, H, num_logical_blocks)`` with the logical-block
-  index as the sequential (``arbitrary``) dimension.  The block tables and
-  positions ride in as **scalar-prefetch** operands
+* **decode** — grid ``(B, num_logical_blocks)`` with the logical-block
+  index as the sequential (``arbitrary``) dimension; a block takes all
+  heads of one pool block and the kernel loops over them.  The block
+  tables and positions ride in as **scalar-prefetch** operands
   (``pltpu.PrefetchScalarGridSpec``), so each K/V block's BlockSpec
   ``index_map`` reads ``tables[b, j]`` and Mosaic double-buffers the
   HBM→VMEM DMA of physical block ``tables[b, j+1]`` against the MXU work
@@ -25,8 +26,8 @@ Mosaic pipeline the way ``parallel/flash.py`` maps FlashAttention-2):
   *in-kernel* position mask zeroes every clamped lane, so correctness
   never depends on a post-hoc ``-1e30`` pass over a gathered copy.
   Blocks entirely past a sequence's length skip their MXU work outright.
-* **chunked prefill** — the same kernel shape with a ``[C, Dh]`` query
-  tile per (sequence, head) and the mask evaluated at *absolute*
+* **chunked prefill** — the same kernel shape with a ``[C, H, Dh]`` query
+  tile per sequence and the mask evaluated at *absolute*
   positions (query ``starts[b] + row`` vs key ``j*block_tokens + col``)
   through the shared ``causal_mask`` mask-mode machinery
   (``MASK_NONE``/``MASK_CAUSAL``/``MASK_STRICT``, ``parallel/flash.py``) —
@@ -54,7 +55,9 @@ pool geometries) and ~1e-7-tight at the attention-output level — the
 same contract the flash kernels pin against their dense reference.
 
 Everything runs under the Pallas interpreter off-TPU (CPU tier-1 tests
-and the hermetic bench), and compiles through Mosaic on TPU.
+and the hermetic bench), and compiles through Mosaic on TPU, where the
+float32 dots go through the MXU as one bf16 pass — the precision XLA's
+default gives the gather path too (chip_smoke.py measures both).
 """
 
 from __future__ import annotations
@@ -66,11 +69,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ..parallel.flash import (LANES, MASK_CAUSAL, MASK_NONE, MASK_STRICT,
                               NEG_INF, block_contributes, causal_mask,
@@ -158,21 +157,25 @@ def _paged_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
                   num_blocks: int, quantized: bool):
     """Shared decode/prefill kernel body.
 
-    ``q_ref`` is ``[1, C, 1, Dh]`` (C = 1 for decode); ``k_ref``/``v_ref``
-    are one physical pool block ``[1, BT, 1, Dh]`` selected by the
-    BlockSpec index_map from the scalar-prefetched table; ``rest`` is
-    ``(k_scale_ref, v_scale_ref, o_ref, acc, m, l)`` when quantized else
-    ``(o_ref, acc, m, l)``.  ``pos_ref[b]`` is the highest key position
-    this row's queries may see (decode: the token's own position;
-    prefill: the chunk's start — each query row adds its offset via the
-    mask-mode machinery).
+    ``q_ref`` is ``[1, C, H, Dh]`` (C = 1 for decode); ``k_ref``/``v_ref``
+    are one physical pool block ``[1, BT, H, Dh]`` selected by the
+    BlockSpec index_map from the scalar-prefetched table — every block
+    takes all heads, so its last two dimensions are whole array
+    dimensions, which the TPU lowering requires of a block that is not a
+    multiple of the (8, 128) tile; the heads are looped over in here.
+    ``rest`` is ``(k_scale_ref, v_scale_ref, o_ref, acc, m, l)`` when
+    quantized else ``(o_ref, acc, m, l)``, the scratch carrying one
+    online-softmax state per head.  ``pos_ref[b]`` is the highest key
+    position this row's queries may see (decode: the token's own
+    position; prefill: the chunk's start — each query row adds its offset
+    via the mask-mode machinery).
     """
     if quantized:
         k_scale_ref, v_scale_ref, o_ref, acc, m, l = rest
     else:
         o_ref, acc, m, l = rest
-    b, j = pl.program_id(0), pl.program_id(2)
-    C = q_ref.shape[1]
+    b, j = pl.program_id(0), pl.program_id(1)
+    C, H = q_ref.shape[1], q_ref.shape[2]
 
     @pl.when(j == 0)
     def _init():
@@ -194,83 +197,76 @@ def _paged_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(contributes)
     def _step():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale   # [C, Dh]
-        if quantized:
-            k = (k_ref[0, :, 0, :].astype(jnp.float32)
-                 * k_scale_ref[0, :, 0].astype(jnp.float32)[:, None])
-            v = (v_ref[0, :, 0, :].astype(jnp.float32)
-                 * v_scale_ref[0, :, 0].astype(jnp.float32)[:, None])
-        else:
-            k = k_ref[0, :, 0, :].astype(jnp.float32)       # [BT, Dh]
-            v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [C, BT]
-        # Absolute-position mask: queries at q_lo + row vs keys at
-        # j*BT + col.  This is what zeroes hole blocks (their clamped
-        # physical block holds positions past the sequence) — the kernel
-        # masks CONTRIBUTIONS, never trusting gathered values.
-        s = causal_mask(s, q_lo, j * block_tokens, mask_mode)
-        online_softmax_block(s, v, m, l, acc)
+        for h in range(H):
+            q = q_ref[0, :, h, :].astype(jnp.float32) * scale   # [C, Dh]
+            k = k_ref[0, :, h, :].astype(jnp.float32)           # [BT, Dh]
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            if quantized:
+                k = k * k_scale_ref[0, :, h:h + 1]
+                v = v * v_scale_ref[0, :, h:h + 1]
+            s = jax.lax.dot_general(
+                q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)             # [C, BT]
+            # Absolute-position mask: queries at q_lo + row vs keys at
+            # j*BT + col.  This is what zeroes hole blocks (their clamped
+            # physical block holds positions past the sequence) — the
+            # kernel masks CONTRIBUTIONS, never trusting gathered values.
+            s = causal_mask(s, q_lo, j * block_tokens, mask_mode)
+            online_softmax_block(s, v, m.at[h], l.at[h], acc.at[h])
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _flush():
-        out, _ = online_softmax_flush(m, l, acc)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
-
-
-def _block_index_maps(num_blocks: int):
-    """index_maps for pool-resident operands: physical block = the
-    scalar-prefetched table entry, clamped onto the last real block for
-    hole sentinels exactly like ``jnp.take(mode="clip")`` (the in-kernel
-    masking skips/zeroes the clamped lanes)."""
-    def kv_map(b, h, j, tables, pos):
-        return (jnp.minimum(tables[b, j], num_blocks - 1), 0, h, 0)
-
-    def scale_map(b, h, j, tables, pos):
-        return (jnp.minimum(tables[b, j], num_blocks - 1), 0, h)
-
-    return kv_map, scale_map
+        for h in range(H):
+            out, _ = online_softmax_flush(m.at[h], l.at[h], acc.at[h])
+            o_ref[0, :, h, :] = out.astype(o_ref.dtype)
 
 
 def _paged_call(q, k_pool, v_pool, tables, positions, k_scale, v_scale,
                 scale, mask_mode, interpret):
-    if pltpu is None:  # pragma: no cover
-        raise ImportError(
-            "paged attention needs jax.experimental.pallas.tpu (VMEM "
-            "scratch + scalar prefetch, used even by the CPU interpreter)")
     B, C, H, Dh = q.shape
     NB, BT = k_pool.shape[0], k_pool.shape[1]
     MB = tables.shape[1]
     quantized = k_scale is not None
-    kv_map, scale_map = _block_index_maps(NB)
+
+    def pool_map(*trailing):
+        # Physical block = the scalar-prefetched table entry, clamped onto
+        # the last real block for hole sentinels exactly like
+        # ``jnp.take(mode="clip")`` (the in-kernel masking skips/zeroes
+        # the clamped lanes).
+        def index_map(b, j, tables, pos):
+            return (jnp.minimum(tables[b, j], NB - 1),) + trailing
+        return index_map
+
+    def q_map(b, j, tables, pos):
+        return (b, 0, 0, 0)
+
     in_specs = [
-        pl.BlockSpec((1, C, 1, Dh), lambda b, h, j, t, p: (b, 0, h, 0)),
-        pl.BlockSpec((1, BT, 1, Dh), kv_map),
-        pl.BlockSpec((1, BT, 1, Dh), kv_map),
+        pl.BlockSpec((1, C, H, Dh), q_map),
+        pl.BlockSpec((1, BT, H, Dh), pool_map(0, 0, 0)),
+        pl.BlockSpec((1, BT, H, Dh), pool_map(0, 0, 0)),
     ]
     args = [q, k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, BT, 1), scale_map),
-                     pl.BlockSpec((1, BT, 1), scale_map)]
-        args += [k_scale, v_scale]
+        in_specs += [pl.BlockSpec((1, BT, H), pool_map(0, 0)),
+                     pl.BlockSpec((1, BT, H), pool_map(0, 0))]
+        # Mosaic has no float16 vector loads; the widening is exact.
+        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H, MB),
+        grid=(B, MB),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, C, 1, Dh),
-                               lambda b, h, j, t, p: (b, 0, h, 0)),
-        scratch_shapes=[pltpu.VMEM((C, Dh), jnp.float32),
-                        pltpu.VMEM((C, LANES), jnp.float32),
-                        pltpu.VMEM((C, LANES), jnp.float32)],
+        out_specs=pl.BlockSpec((1, C, H, Dh), q_map),
+        scratch_shapes=[pltpu.VMEM((H, C, Dh), jnp.float32),
+                        pltpu.VMEM((H, C, LANES), jnp.float32),
+                        pltpu.VMEM((H, C, LANES), jnp.float32)],
     )
     kernel = functools.partial(
         _paged_kernel, scale=scale, mask_mode=mask_mode, block_tokens=BT,
         num_blocks=NB, quantized=quantized)
     compiler_params = None
-    if not interpret and pltpu is not None:
+    if not interpret:
         compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+            dimension_semantics=("parallel", "arbitrary"))
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, C, H, Dh), jnp.float32),

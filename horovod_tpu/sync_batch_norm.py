@@ -64,7 +64,7 @@ def FusedBatchNorm(**kwargs):
     f32 for the normalize chain, so every BN in the net pays full-tensor
     bf16->f32->bf16 converts and an f32 elementwise pass — the
     "convert/multiply_reduce fusions ~0.5-1 ms each" in the round-2
-    profile (artifacts/PERF_r02.md).  ``BatchNorm(dtype=bfloat16)`` fixes
+    profile.  ``BatchNorm(dtype=bfloat16)`` fixes
     the bandwidth but computes the STATISTICS in bf16, which is numerically
     unacceptable.  This layer splits the two concerns:
 

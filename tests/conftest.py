@@ -11,6 +11,9 @@ import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 os.environ.setdefault("HVD_TPU_EMULATE_RANKS", "8")
+# The persistent compile cache hvd.init() sets up is for the chip; a test
+# run (and the workers it spawns) neither reads nor fills the checkout's.
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
 
 import jax  # noqa: E402
 

@@ -9,7 +9,6 @@ import numpy as np
 import pytest
 
 import horovod_tpu as hvd
-from horovod_tpu.compat import has_vma_tracking
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -77,13 +76,6 @@ def test_restore_without_template_single(tmp_path, hvd8):
     np.testing.assert_allclose(np.asarray(restored["a"]), np.ones(3))
 
 
-@pytest.mark.skipif(
-    not has_vma_tracking(),
-    reason="mid-cycle exactness requires vma semantics: only when the "
-           "shard_map transpose pre-reduces replicated-param gradients is "
-           "the accumulator truly replicated — on old jax it is per-device "
-           "local, which a replicated-state checkpoint cannot capture "
-           "(see horovod_tpu/compat.py)")
 def test_load_model_resumes_identical_trajectory(tmp_path, hvd8):
     """save_model/load_model (keras/__init__.py:268 analog): restore the
     wrapped optimizer's FULL state — adam moments AND the local gradient-

@@ -16,7 +16,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
-from horovod_tpu.compat import has_vma_tracking
 from horovod_tpu.ops import collective_ops as C
 
 N = 8
@@ -232,10 +231,6 @@ def test_barrier_in_jit(hvd8):
 # -- gradients: the reference registers these by hand
 #    (tensorflow/mpi_ops.py:115-537); here they fall out of differentiability.
 
-@pytest.mark.skipif(
-    not has_vma_tracking(),
-    reason="psum's transpose is only the Horovod gradient table under vma "
-           "tracking; old jax re-sums the cotangent (see compat.py)")
 def test_allreduce_gradient_is_allreduce(hvd8, per_rank):
     def body(x):
         def loss(t):

@@ -2,8 +2,8 @@
 
 Acceptance coverage (ISSUE 2): a deliberately branch-mismatched
 ``lax.cond`` collective and an undeclared axis name are detected; a clean
-``DistributedOptimizer`` step passes with zero findings on this jax (the
-compat.py-shimmed 0.4.x); the per-step collective census (count + bytes)
+``DistributedOptimizer`` step passes with zero findings; the per-step
+collective census (count + bytes)
 for a DistributedOptimizer step is asserted and surfaced via
 timeline.py's counter events.
 """

@@ -9,20 +9,9 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
-from horovod_tpu.compat import has_vma_tracking
 from tests.test_collective_ops import run_spmd
 
 N = 8
-
-# reduce_axes needs real varying-manual-axes tracking to tell local
-# gradients from pre-summed ones; on a shimmed old jax the optimizer
-# refuses loudly (by design) instead of guessing — the capability, not
-# the code, is absent here.
-requires_vma = pytest.mark.skipif(
-    not has_vma_tracking(),
-    reason="DistributedOptimizer(reduce_axes=...) requires jax vma "
-           "tracking (unavailable on this jax; see horovod_tpu/compat.py)")
-
 
 def test_distributed_optimizer_averages_gradients(hvd8):
     opt = hvd.DistributedOptimizer(optax.sgd(1.0))
@@ -264,7 +253,6 @@ def test_partial_distributed_optimizer(hvd8):
 # 2-D mesh sugar: reduce_axes spans exactly the listed mesh axes
 # ---------------------------------------------------------------------------
 
-@requires_vma
 def test_reduce_axes_2d_mesh_average():
     """DistributedOptimizer(reduce_axes=('dp','sp')) inside a dp×sp
     shard_map: varying grads are averaged over BOTH axes; pre-reduced
@@ -296,7 +284,6 @@ def test_reduce_axes_2d_mesh_average():
                                rtol=1e-5)
 
 
-@requires_vma
 def test_reduce_axes_invariant_leaf_normalized():
     """A gradient that the shard_map transpose already globally summed
     (replicated parameter) must be divided by dp*sp, not psum'd again."""
@@ -338,7 +325,6 @@ def test_reduce_axes_outside_mesh_raises():
                    {"w": jnp.ones((2,))})
 
 
-@requires_vma
 def test_reduce_axes_param_sharded_leaf_not_summed_over_its_axis():
     """A parameter SHARDED over one of the reduce axes (expert/tensor-
     parallel leaf) must have its gradient psum'd only over the remaining

@@ -5,23 +5,13 @@ mesh, forward AND backward."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from horovod_tpu.compat import has_vma_tracking
 from horovod_tpu.parallel.pipeline import gpipe_spmd, stack_stage_params
 from horovod_tpu.parallel.tensor import (column_row_parallel_mlp,
                                          shard_columns, shard_rows)
 
 S = 8  # stages / shards
-
-# Gradients THROUGH in-jit collectives (psum/ppermute chains) follow the
-# Horovod gradient table only under vma tracking; the old-jax transpose
-# re-sums replicated cotangents (see horovod_tpu/compat.py).
-requires_vma_grads = pytest.mark.skipif(
-    not has_vma_tracking(),
-    reason="collective gradient semantics require jax vma tracking "
-           "(unavailable on this jax; see horovod_tpu/compat.py)")
 
 
 def _mesh(axis):
@@ -60,7 +50,6 @@ def test_gpipe_matches_sequential_forward():
                                rtol=1e-5, atol=1e-6)
 
 
-@requires_vma_grads
 def test_gpipe_gradients_match_sequential():
     """jax.grad through the scan/ppermute schedule must equal the serial
     model's per-stage gradients (scan+ppermute transpose = the reverse
@@ -118,7 +107,6 @@ def test_column_row_parallel_mlp_matches_dense():
                                rtol=1e-4, atol=1e-5)
 
 
-@requires_vma_grads
 def test_column_row_parallel_grads_match_dense():
     d, f, b = 4, 16, 3
     rng = np.random.RandomState(6)

@@ -208,21 +208,11 @@ def test_bench_ring_microbench_smoke():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env.update(JAX_PLATFORMS="cpu", BENCH_MODEL="ring", BENCH_SMOKE="1",
-               HVD_TPU_BENCH_TAG="pytestring", HVD_TPU_EMULATE_RANKS="8",
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               BENCH_PROBE_BUDGET_S="120", BENCH_PROBE_TIMEOUT_S="60")
+               HVD_TPU_EMULATE_RANKS="8",
+               XLA_FLAGS="--xla_force_host_platform_device_count=8")
     env.pop("HOROVOD_TIMELINE", None)
-    try:
-        r = subprocess.run([sys.executable, os.path.join(repo, "bench.py")],
-                           env=env, capture_output=True, text=True,
-                           timeout=420)
-    finally:
-        try:  # drop the keyed capture the smoke run persists
-            # (_last_good_path keys BENCH_MODEL=ring + BENCH_SMOKE + tag)
-            os.remove(os.path.join(repo, "artifacts",
-                                   "last_bench_ring_smoke_pytestring.json"))
-        except OSError:
-            pass
+    r = subprocess.run([sys.executable, os.path.join(repo, "bench.py")],
+                       env=env, capture_output=True, text=True, timeout=420)
     assert r.returncode == 0, r.stderr[-2000:]
     records = [json.loads(l) for l in r.stdout.splitlines()
                if l.strip().startswith("{")]

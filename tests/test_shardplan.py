@@ -80,8 +80,7 @@ def test_census_bytes_two_axis_hand_computed():
 
 
 def test_shard_map_census_group_size():
-    """Through the repo's shard_map wrapper (compat shim): the per-shard
-    psum payload is (1, 128) f32 = 512 bytes, wire = 512 x group 8."""
+    """Through jax.shard_map: the per-shard psum payload is (1, 128) f32 = 512 bytes, wire = 512 x group 8."""
     mesh = _mesh((8,), ("hvd",))
 
     def step(x):
@@ -97,23 +96,24 @@ def test_shard_map_census_group_size():
     assert not r.findings
 
 
-def test_rewrite_mode_psum2_counts_as_psum():
-    """shard_map's rewrite mode (check_rep=True) spells psum as the
-    psum2 primitive — the census must normalize it so a modern-jax
-    trace measures identically to the compat-shim trace."""
-    from jax.experimental.shard_map import shard_map as raw_sm
+def test_psum_of_varying_value_counts_as_psum():
+    """Under varying-axes tracking a psum of a varying value traces as
+    the psum_invariant primitive — the census counts it as psum, like
+    the untracked (check_vma=False) trace of the same program."""
     mesh = _mesh((8,), ("hvd",))
 
     def step(x):
         return jax.lax.psum(x, "hvd")
 
-    mapped = raw_sm(step, mesh=mesh, in_specs=P("hvd"),
-                    out_specs=P("hvd"), check_rep=True)
-    closed = jax.make_jaxpr(mapped)(jnp.zeros((8, 128), jnp.float32))
-    r = shardplan.measure_closed_jaxpr_comm(closed, label="sm2", mesh=mesh)
-    assert "psum2" not in r.by_primitive
-    assert r.by_primitive["psum"]["count"] == 1
-    assert r.by_primitive["psum"]["wire_bytes"] == 4096
+    for check_vma in (True, False):
+        mapped = jax.shard_map(step, mesh=mesh, in_specs=P("hvd"),
+                               out_specs=P(), check_vma=check_vma)
+        closed = jax.make_jaxpr(mapped)(jnp.zeros((8, 128), jnp.float32))
+        r = shardplan.measure_closed_jaxpr_comm(closed, label="sm2",
+                                                mesh=mesh)
+        assert "psum_invariant" not in r.by_primitive
+        assert r.by_primitive["psum"]["count"] == 1
+        assert r.by_primitive["psum"]["wire_bytes"] == 4096
 
 
 def test_scan_census_multiplied_and_carried_sharding_clean():

@@ -459,14 +459,34 @@ def test_warmup_failure_degrades_to_cold_serving():
         eng.stop()
 
 
-def test_compile_cache_env_bootstrap(tmp_path, monkeypatch):
-    from horovod_tpu.serve import engine as eng_mod
-    monkeypatch.setenv("HVD_SERVE_COMPILE_CACHE", str(tmp_path / "xc"))
-    monkeypatch.setattr(eng_mod, "_COMPILE_CACHE_ENABLED", False)
-    eng_mod.maybe_enable_compile_cache()
-    assert (tmp_path / "xc").is_dir()
-    assert eng_mod._COMPILE_CACHE_ENABLED
-    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "xc")
+def test_compile_cache_placed_from_outside_or_in_checkout(monkeypatch):
+    """One cache, set up by ``hvd.init()``: JAX_COMPILATION_CACHE_DIR
+    places it from outside (no directory set in code), otherwise it is
+    the checkout's .jax_cache; the persistence floors are zero either
+    way so small serve programs persist."""
+    import os
+    from horovod_tpu import core
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    was = (jax.config.jax_compilation_cache_dir,
+           jax.config.jax_persistent_cache_min_compile_time_secs,
+           jax.config.jax_persistent_cache_min_entry_size_bytes)
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/outside")
+        core._enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        core._enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == \
+            os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
+    finally:
+        for name, value in zip(
+                ("jax_compilation_cache_dir",
+                 "jax_persistent_cache_min_compile_time_secs",
+                 "jax_persistent_cache_min_entry_size_bytes"), was):
+            jax.config.update(name, value)
 
 
 # -- controller interaction --------------------------------------------------
