@@ -137,8 +137,7 @@ def bench_ring():
     overlapped+skip schedule is no slower, the ISSUE 1 acceptance bar).
     Also times a single K/V rotation and a single hop-sized attention fold
     in isolation, attributing step time to transfer vs kernel; with
-    HOROVOD_TIMELINE set those land in the trace as RING_TRANSFER /
-    RING_KERNEL spans next to the traced RING_HOP schedule."""
+    HOROVOD_TIMELINE set the traced RING_HOP schedule lands in the trace."""
     from jax.sharding import PartitionSpec as P2
     from horovod_tpu.parallel import ring as ring_mod
 
@@ -153,7 +152,6 @@ def bench_ring():
     tl = None
     if os.environ.get("HOROVOD_TIMELINE"):
         from horovod_tpu import core as _core
-        from horovod_tpu.timeline import RING_KERNEL, RING_TRANSFER
         # hvd.init() already opened the HOROVOD_TIMELINE writer (rank 0);
         # reuse it — a second Timeline on the same path would interleave
         # two JSON streams.  stop_timeline() below flushes and closes.
@@ -206,14 +204,6 @@ def bench_ring():
     t_kernel = round(timeit(kernel, q, q, q), 4)
 
     if tl is not None:
-        hop_bytes = 2 * B * s_local * H * D * 4
-        cursor = 0.0
-        for hop in range(n):
-            tl.ring_span("ring_microbench", hop, RING_TRANSFER, cursor,
-                         t_transfer * 1e3, bytes_rotated=hop_bytes)
-            tl.ring_span("ring_microbench", hop, RING_KERNEL, cursor,
-                         t_kernel * 1e3)
-            cursor += max(t_transfer, t_kernel) * 1e3
         ring_mod.set_ring_timeline(None)
         hvd.stop_timeline()
 

@@ -203,29 +203,37 @@ class ResNet(nn.Module):
             norm = partial(nn.BatchNorm, use_running_average=not train,
                            momentum=0.9, epsilon=1e-5, dtype=jnp.float32,
                            axis_name=self.axis_name if train else None)
-        x = x.astype(self.dtype)
-        if self.s2d_stem:
-            x = SpaceToDepthStem(self.num_filters, dtype=self.dtype,
-                                 name="conv_init")(x)
-        else:
-            # use_bias=False: the bias feeds straight into BN, which
-            # subtracts it right back out (and it kept the param tree
-            # from matching SpaceToDepthStem's).
-            x = conv(self.num_filters, (7, 7), (2, 2), use_bias=False,
-                     name="conv_init")(x)
-        x = norm(name="bn_init")(x)
-        x = nn.relu(x)
-        if self.eq_pool_grad:
-            x = max_pool_eq_grad(x)
-        else:
-            x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
+        # The named scopes are the model's parts in a device trace: they
+        # enter the ``op_name`` of every operation traced under them,
+        # forward and backward, and leave flax's parameter names alone.
+        with jax.named_scope("stem"):
+            x = x.astype(self.dtype)
+            if self.s2d_stem:
+                x = SpaceToDepthStem(self.num_filters, dtype=self.dtype,
+                                     name="conv_init")(x)
+            else:
+                # use_bias=False: the bias feeds straight into BN, which
+                # subtracts it right back out (and it kept the param tree
+                # from matching SpaceToDepthStem's).
+                x = conv(self.num_filters, (7, 7), (2, 2), use_bias=False,
+                         name="conv_init")(x)
+            x = norm(name="bn_init")(x)
+            x = nn.relu(x)
+        with jax.named_scope("max_pool"):
+            if self.eq_pool_grad:
+                x = max_pool_eq_grad(x)
+            else:
+                x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
         for i, block_size in enumerate(self.stage_sizes):
-            for j in range(block_size):
-                strides = (2, 2) if i > 0 and j == 0 else (1, 1)
-                x = self.block_cls(self.num_filters * 2 ** i,
-                                   strides=strides, conv=conv, norm=norm)(x)
-        x = jnp.mean(x, axis=(1, 2))
-        x = nn.Dense(self.num_classes, dtype=jnp.float32)(x)
+            with jax.named_scope(f"stage{i + 1}"):
+                for j in range(block_size):
+                    strides = (2, 2) if i > 0 and j == 0 else (1, 1)
+                    x = self.block_cls(self.num_filters * 2 ** i,
+                                       strides=strides, conv=conv,
+                                       norm=norm)(x)
+        with jax.named_scope("head"):
+            x = jnp.mean(x, axis=(1, 2))
+            x = nn.Dense(self.num_classes, dtype=jnp.float32)(x)
         return x
 
 

@@ -294,6 +294,29 @@ def _allreduce_tree(grads, op, compression, prescale, postscale, process_set,
     return jax.tree_util.tree_unflatten(treedef, reduced)
 
 
+def _scoped(transformation, scope: str):
+    """``transformation`` with its update traced under
+    ``jax.named_scope(scope)``: the scope lands in the ``op_name`` of every
+    operation the update compiles to, which is how a device trace tells the
+    optimizer from the model.  Metadata only: state and arithmetic are
+    the wrapped transformation's."""
+    inner = optax.with_extra_args_support(transformation)
+
+    def update_fn(updates, state, params=None, **extra_args):
+        with jax.named_scope(scope):
+            return inner.update(updates, state, params, **extra_args)
+
+    return optax.GradientTransformationExtraArgs(inner.init, update_fn)
+
+
+#: Every transformation this module returns traces its update under this
+#: scope (``hvd::<kind>``, the convention of ops/eager.py); inside it,
+#: ``reduce_gradients`` (the gradients' reduction or, where they arrive
+#: already summed, their rescaling) and ``inner_update`` (the wrapped
+#: optimizer).
+_OPTIMIZER_SCOPE = "hvd::optimizer"
+
+
 class DistributedState(NamedTuple):
     inner_state: Any
     acc_grads: Any        # local aggregation buffer (backward_passes_per_step)
@@ -312,6 +335,15 @@ def distributed_gradient_transformation(
     (tensorflow/__init__.py:896 DistributedOptimizer._compute_gradients).
     Local gradient aggregation (``backward_passes_per_step``) lives in
     ``DistributedOptimizer``, which gates the whole chain."""
+    return _scoped(_gradient_reduction(
+        op, compression, gradient_predivide_factor, process_set, groups,
+        reduce_axes), _OPTIMIZER_SCOPE)
+
+
+def _gradient_reduction(op, compression, gradient_predivide_factor,
+                        process_set, groups, reduce_axes):
+    """``distributed_gradient_transformation`` before its scope, for
+    ``DistributedOptimizer`` to put under its own."""
     if optax is None:
         raise ImportError("optax is required for the optimizer layer")
 
@@ -332,9 +364,10 @@ def distributed_gradient_transformation(
         return optax.EmptyState()
 
     def update_fn(updates, state, params=None):
-        reduced = _allreduce_tree(updates, op, compression, prescale,
-                                  postscale, process_set, groups,
-                                  reduce_axes=reduce_axes, params=params)
+        with jax.named_scope("reduce_gradients"):
+            reduced = _allreduce_tree(updates, op, compression, prescale,
+                                      postscale, process_set, groups,
+                                      reduce_axes=reduce_axes, params=params)
         return reduced, state
 
     return optax.GradientTransformation(init_fn, update_fn)
@@ -395,10 +428,10 @@ def DistributedOptimizer(optimizer,
             raise ValueError("compression/groups are not supported with "
                              "reduce_axes (XLA fuses and buckets in-trace "
                              "collectives itself)")
-    allreduce_t = distributed_gradient_transformation(
-        op=op, compression=compression,
-        gradient_predivide_factor=gradient_predivide_factor,
-        process_set=process_set, groups=groups, reduce_axes=reduce_axes)
+    allreduce_t = _gradient_reduction(
+        op, compression, gradient_predivide_factor, process_set, groups,
+        reduce_axes)
+    optimizer = _scoped(optimizer, "inner_update")
     n = max(1, int(backward_passes_per_step))
 
     def _maybe_analyzed(t):
@@ -412,7 +445,8 @@ def DistributedOptimizer(optimizer,
         return t
 
     if n == 1:
-        return _maybe_analyzed(optax.chain(allreduce_t, optimizer))
+        return _maybe_analyzed(_scoped(optax.chain(allreduce_t, optimizer),
+                                       _OPTIMIZER_SCOPE))
 
     def init_fn(params):
         return DistributedState(
@@ -488,7 +522,8 @@ def DistributedOptimizer(optimizer,
         new_counter = jnp.where(sync, 0, counter)
         return new_updates, DistributedState(new_inner, new_acc, new_counter)
 
-    return _maybe_analyzed(optax.GradientTransformation(init_fn, update_fn))
+    return _maybe_analyzed(_scoped(
+        optax.GradientTransformation(init_fn, update_fn), _OPTIMIZER_SCOPE))
 
 
 def PartialDistributedOptimizer(optimizer,
@@ -511,20 +546,24 @@ def PartialDistributedOptimizer(optimizer,
     def init_fn(params):
         return optimizer.init(params)
 
+    optimizer = _scoped(optimizer, "inner_update")
+
     def update_fn(updates, state, params=None):
         flat, treedef = jax.tree_util.tree_flatten_with_path(updates)
         reduced = []
-        for path, leaf in flat:
-            if local_filter(path, leaf):
-                reduced.append(leaf)
-            else:
-                reduced.append(_reduce_grad_leaf(
-                    leaf, op, compression, 1.0, 1.0, process_set))
+        with jax.named_scope("reduce_gradients"):
+            for path, leaf in flat:
+                if local_filter(path, leaf):
+                    reduced.append(leaf)
+                else:
+                    reduced.append(_reduce_grad_leaf(
+                        leaf, op, compression, 1.0, 1.0, process_set))
         synced = jax.tree_util.tree_unflatten(
             jax.tree_util.tree_structure(updates), reduced)
         return optimizer.update(synced, state, params)
 
-    return optax.GradientTransformation(init_fn, update_fn)
+    return _scoped(optax.GradientTransformation(init_fn, update_fn),
+                   _OPTIMIZER_SCOPE)
 
 
 def local_value_and_grad(fun: Callable, **jax_kwargs):

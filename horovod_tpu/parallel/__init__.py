@@ -17,6 +17,7 @@ Submodules:
 
 from __future__ import annotations
 
+import itertools
 from functools import partial
 from typing import Callable, Optional, Sequence, Tuple
 
@@ -94,6 +95,12 @@ def shard_step(fn: Callable,
 
     cache = {}
     analyzed_gen = {}  # arity -> analysis generation it was checked in
+    # The host span of a call, on the profiler's clock: a trace shows what
+    # the wrapper costs the host and, against the k-th execution of the
+    # program on the device, how far the host runs ahead.  Outside a
+    # profiler session it is one check of a flag.
+    span = f"hvd::shard_step::{getattr(fn, '__name__', 'fn')}"
+    calls = itertools.count()
 
     def built(nargs: int):
         if nargs not in cache:
@@ -129,7 +136,8 @@ def shard_step(fn: Callable,
                 # The deployment's actual mesh: hvdshard's comm census
                 # reads axis sizes and the ICI/DCN fabric split off it.
                 mesh=mesh)
-        return jitted(*args)
+        with jax.profiler.TraceAnnotation(span, step=next(calls)):
+            return jitted(*args)
 
     # jit's ahead-of-time door: ``step.lower(*args).compile()`` gives the
     # program the calls run, for its text and its memory analysis.

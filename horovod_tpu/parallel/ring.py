@@ -86,11 +86,10 @@ def set_ring_timeline(timeline, tensor_name: str = "ring") -> None:
     per-hop ring schedule — hop index, bytes rotated, mask rule, schedule,
     and how many shards skip the hop's kernel — whenever a ring collective
     is traced.  The device plane is invisible to the host timeline
-    (docs/timeline.md), so these are trace-time schedule events; measured
-    kernel/transfer spans come from the bench microbench via
-    ``Timeline.ring_span``.  Each distinct ring configuration is emitted
-    once per registration — retraces (grad, checkpoint remat) of the same
-    call do not duplicate the schedule."""
+    (docs/timeline.md), so these are trace-time schedule events.  Each
+    distinct ring configuration is emitted once per registration —
+    retraces (grad, checkpoint remat) of the same call do not duplicate
+    the schedule."""
     global _ring_timeline
     _ring_timeline = None if timeline is None else (timeline, tensor_name)
     _ring_timeline_seen.clear()

@@ -436,7 +436,7 @@ class TransformerAdapter(ModelAdapter):
         scale = 1.0 / math.sqrt(self.head_dim)
         L = self.num_layers
 
-        def fn(params, cache, tokens, lengths, slots):
+        def prefill(params, cache, tokens, lengths, slots):
             # tokens [n, P] int32; lengths [n]; slots [n] (slot >= max_batch
             # marks a padding row: scatter drops out-of-bounds rows, see
             # OOB note below).
@@ -470,7 +470,7 @@ class TransformerAdapter(ModelAdapter):
             logits = self._logits(last, params)
             return {"k": ck, "v": cv}, jnp.argmax(logits, axis=-1)
 
-        return jax.jit(fn, donate_argnums=(1,))
+        return jax.jit(prefill, donate_argnums=(1,))
 
     def prefill(self, cache, prompts, slots):
         import jax.numpy as jnp
@@ -569,12 +569,12 @@ class TransformerAdapter(ModelAdapter):
         import jax
         import jax.numpy as jnp
 
-        def fn(params, cache, tokens, starts, lengths, tables):
+        def prefill_chunk(params, cache, tokens, starts, lengths, tables):
             pool, logits = self._chunk_forward(
                 params, cache, tokens, starts, lengths, tables, NB, c)
             return pool, jnp.argmax(logits, axis=-1)
 
-        return jax.jit(fn, donate_argnums=(1,))
+        return jax.jit(prefill_chunk, donate_argnums=(1,))
 
     def prompt_logits(self, prompt: Sequence[int]) -> np.ndarray:
         """Final-position LM logits for ``prompt`` through the full paged
@@ -675,11 +675,12 @@ class TransformerAdapter(ModelAdapter):
     def _build_prefill_chunk_logits(self, n: int, c: int, NB: int):
         import jax
 
-        def fn(params, cache, tokens, starts, lengths, tables):
+        def prefill_chunk_logits(params, cache, tokens, starts, lengths,
+                                 tables):
             return self._chunk_forward(params, cache, tokens, starts,
                                        lengths, tables, NB, c)
 
-        return jax.jit(fn, donate_argnums=(1,))
+        return jax.jit(prefill_chunk_logits, donate_argnums=(1,))
 
     def prefill_chunk_logits(self, cache, chunks, starts, tables):
         """``prefill_chunk`` returning each row's final-position LM
@@ -702,12 +703,12 @@ class TransformerAdapter(ModelAdapter):
     def _build_verify_chunk(self, n: int, c: int, NB: int):
         import jax
 
-        def fn(params, cache, tokens, starts, lengths, tables):
+        def verify_chunk(params, cache, tokens, starts, lengths, tables):
             pool, x = self._chunk_body(params, cache, tokens, starts,
                                        lengths, tables, NB, c)
             return pool, self._logits(x, params)
 
-        return jax.jit(fn, donate_argnums=(1,))
+        return jax.jit(verify_chunk, donate_argnums=(1,))
 
     def verify_chunk(self, cache, chunks, starts, tables):
         """Speculative verify: run ``chunks[i]`` (the row's last emitted
@@ -751,8 +752,8 @@ class TransformerAdapter(ModelAdapter):
         MB = self.max_blocks_per_seq
         H, Dh = self.cfg.num_heads, self.head_dim
 
-        def fn(params, pool, tokens, q_start, q_len, k_start, ltable,
-               hop_k, hop_v, hop_len):
+        def sp_prefill_chunk(params, pool, tokens, q_start, q_len, k_start,
+                             ltable, hop_k, hop_v, hop_len):
             # tokens [c] — one rank's extent chunk starting at absolute
             # position q_start (q_len real); ltable [MB] maps the
             # rank-LOCAL extent (absolute positions >= k_start) onto the
@@ -813,7 +814,7 @@ class TransformerAdapter(ModelAdapter):
             last = jnp.take(x[0], jnp.maximum(q_len - 1, 0), axis=0)
             return pool, self._logits(last, params)
 
-        return jax.jit(fn, donate_argnums=(1,))
+        return jax.jit(sp_prefill_chunk, donate_argnums=(1,))
 
     def sp_prefill_chunk(self, pool, chunk, q_start, extent_start, ltable,
                          hop_k=None, hop_v=None, hop_len=0):
@@ -863,7 +864,7 @@ class TransformerAdapter(ModelAdapter):
         L, B = self.num_layers, self._max_batch
         S = self.max_len
 
-        def fn(params, cache, tokens, positions):
+        def decode(params, cache, tokens, positions):
             # tokens [B] int32 (last token per slot), positions [B] (the
             # cache index this token's K/V lands at = current length).
             pos = jnp.minimum(positions, S - 1)
@@ -893,7 +894,7 @@ class TransformerAdapter(ModelAdapter):
             logits = self._logits(x, params)
             return {"k": ck, "v": cv}, jnp.argmax(logits, axis=-1)
 
-        return jax.jit(fn, donate_argnums=(1,))
+        return jax.jit(decode, donate_argnums=(1,))
 
     def decode(self, cache, tokens, positions):
         import jax.numpy as jnp
@@ -951,12 +952,12 @@ class TransformerAdapter(ModelAdapter):
         import jax
         import jax.numpy as jnp
 
-        def fn(params, cache, tokens, positions, tables):
+        def decode_paged(params, cache, tokens, positions, tables):
             pool, logits = self._paged_step_body(
                 params, cache, tokens, positions, tables, self.num_layers)
             return pool, jnp.argmax(logits, axis=-1)
 
-        return jax.jit(fn, donate_argnums=(1,))
+        return jax.jit(decode_paged, donate_argnums=(1,))
 
     def decode_paged(self, cache, tokens, positions, tables):
         import jax.numpy as jnp
@@ -978,11 +979,11 @@ class TransformerAdapter(ModelAdapter):
     def _build_paged_decode_logits(self, B: int):
         import jax
 
-        def fn(params, cache, tokens, positions, tables):
+        def decode_paged_logits(params, cache, tokens, positions, tables):
             return self._paged_step_body(
                 params, cache, tokens, positions, tables, self.num_layers)
 
-        return jax.jit(fn, donate_argnums=(1,))
+        return jax.jit(decode_paged_logits, donate_argnums=(1,))
 
     def decode_paged_logits(self, cache, tokens, positions, tables):
         """``decode_paged`` returning each row's raw LM logits ``[B, V]``
@@ -1014,8 +1015,8 @@ class TransformerAdapter(ModelAdapter):
         import jax
         from . import sampling as _sampling
 
-        def fn(params, cache, tokens, positions, tables, keys, temps,
-               top_ks, top_ps):
+        def decode_paged_sampled(params, cache, tokens, positions, tables,
+                                 keys, temps, top_ks, top_ps):
             pool, logits = self._paged_step_body(
                 params, cache, tokens, positions, tables, self.num_layers)
             # The token this step emits OCCUPIES position fed+1 — the
@@ -1024,7 +1025,7 @@ class TransformerAdapter(ModelAdapter):
                 logits, keys, positions + 1, temps, top_ks, top_ps)
             return pool, toks
 
-        return jax.jit(fn, donate_argnums=(1,))
+        return jax.jit(decode_paged_sampled, donate_argnums=(1,))
 
     def decode_paged_sampled(self, cache, tokens, positions, tables,
                              keys, temps, top_ks, top_ps):
@@ -1058,13 +1059,13 @@ class TransformerAdapter(ModelAdapter):
         import jax
         import jax.numpy as jnp
 
-        def fn(params, cache, tokens, positions, tables):
+        def draft_decode(params, cache, tokens, positions, tables):
             pool, logits = self._paged_step_body(
                 params, cache, tokens, positions, tables,
                 self.draft_layers)
             return pool, jnp.argmax(logits, axis=-1)
 
-        return jax.jit(fn, donate_argnums=(1,))
+        return jax.jit(draft_decode, donate_argnums=(1,))
 
     def draft_decode(self, cache, tokens, positions, tables):
         """One draft proposal step (see ``_build_draft_decode``)."""
@@ -1095,9 +1096,9 @@ class TransformerAdapter(ModelAdapter):
         import jax
         import jax.numpy as jnp
         if self._copy_block_fn is None:
-            def fn(c, s, d):
+            def copy_block(c, s, d):
                 return {k: a.at[:, d].set(a[:, s]) for k, a in c.items()}
-            self._copy_block_fn = jax.jit(fn, donate_argnums=(0,))
+            self._copy_block_fn = jax.jit(copy_block, donate_argnums=(0,))
         return self._copy_block_fn(cache, jnp.int32(src), jnp.int32(dst))
 
 
@@ -1135,23 +1136,27 @@ class MLPAdapter(ModelAdapter):
         from . import sampling as _sampling
         self.vocab_size = vocab_size
         self.max_len = max_len
-        self._logits_of = jax.jit(
-            lambda tokens: mlp.apply(
+        # Named functions: a program is ``jit_<function>`` in a device
+        # trace, and a lambda would be ``jit__lambda_``.
+
+        def mlp_logits(tokens):
+            return mlp.apply(
                 {"params": params},
-                jax.nn.one_hot(tokens, vocab_size)).astype(jnp.float32))
-        self._apply = jax.jit(
-            lambda tokens: jax.numpy.argmax(
+                jax.nn.one_hot(tokens, vocab_size)).astype(jnp.float32)
+
+        def mlp_greedy(tokens):
+            return jax.numpy.argmax(
                 mlp.apply({"params": params},
-                          jax.nn.one_hot(tokens, vocab_size)), axis=-1))
+                          jax.nn.one_hot(tokens, vocab_size)), axis=-1)
 
-        def _sampled(tokens, keys, positions, temps, top_ks, top_ps):
-            logits = mlp.apply({"params": params},
-                               jax.nn.one_hot(tokens, vocab_size)
-                               ).astype(jnp.float32)
-            return _sampling.sample_batched(logits, keys, positions + 1,
-                                            temps, top_ks, top_ps)
+        def mlp_sampled(tokens, keys, positions, temps, top_ks, top_ps):
+            return _sampling.sample_batched(
+                mlp_logits(tokens), keys, positions + 1, temps, top_ks,
+                top_ps)
 
-        self._sampled_step = jax.jit(_sampled)
+        self._logits_of = jax.jit(mlp_logits)
+        self._apply = jax.jit(mlp_greedy)
+        self._sampled_step = jax.jit(mlp_sampled)
 
     def init_cache(self, max_batch: int):
         return ()

@@ -43,11 +43,15 @@ def sync_batch_stats(x: jax.Array,
     # Flatten so ANY reduction_axes (stats of any rank) ride the single
     # collective; reshape back after the split.
     shape, k = s.shape, s.size
-    vec = jnp.concatenate([s.ravel(), sq.ravel(),
-                           jnp.full((1,), n_local, x.dtype)])
-    vec = C.allreduce(vec, C.Sum, axis_name=axis_name, members=members)
-    s, sq, cnt = (vec[:k].reshape(shape), vec[k:2 * k].reshape(shape),
-                  vec[-1])
+    # The scope is what tells a statistic's all-reduce (and its transpose
+    # in the backward pass) from a parameter gradient's in the compiled
+    # step: both are ``psum_invariant`` under the same flax module.
+    with jax.named_scope("hvd::sync_bn_stats"):
+        vec = jnp.concatenate([s.ravel(), sq.ravel(),
+                               jnp.full((1,), n_local, x.dtype)])
+        vec = C.allreduce(vec, C.Sum, axis_name=axis_name, members=members)
+        s, sq, cnt = (vec[:k].reshape(shape), vec[k:2 * k].reshape(shape),
+                      vec[-1])
     mean = s / cnt
     # Clamp: the E[x^2]-E[x]^2 form can go epsilon-negative in finite
     # precision, and rsqrt(var + eps) downstream must not see it.
@@ -107,7 +111,10 @@ def _fused_bn_cls():
         bias_init: Callable = nn.initializers.zeros
         scale_init: Callable = nn.initializers.ones
 
+        # The scope is in the ``op_name`` of every operation of the layer,
+        # forward and backward; flax's parameter names do not see it.
         @nn.compact
+        @jax.named_scope("hvd::batch_norm")
         def __call__(self, x, use_running_average: Optional[bool] = None):
             ura = nn.merge_param("use_running_average",
                                  self.use_running_average,

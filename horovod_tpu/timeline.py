@@ -38,12 +38,8 @@ TRACE_COMPILE = "TRACE_COMPILE"
 
 # Ring-collective hop events (no reference analog — the reference has no
 # ring/sequence parallelism).  RING_HOP carries the traced hop schedule
-# (parallel/ring.py set_ring_timeline); RING_KERNEL / RING_TRANSFER carry
-# measured per-hop spans (bench.py ring microbench) so kernel time and ICI
-# transfer time are separable in the trace viewer.
+# (parallel/ring.py set_ring_timeline).
 RING_HOP = "RING_HOP"
-RING_KERNEL = "RING_KERNEL"
-RING_TRANSFER = "RING_TRANSFER"
 
 # Serving-plane counters (no reference analog — the reference is
 # training-only).  serve/metrics.py publishes engine statistics (tokens,
@@ -249,24 +245,13 @@ class Timeline:
         instead of running a kernel.  Emitted at TRACE time by
         parallel/ring.py when a timeline is registered via
         ``set_ring_timeline`` — the device plane inside jit is invisible to
-        the host (module docstring), so these document the schedule, while
-        ``ring_span`` carries measured spans."""
+        the host (module docstring), so these document the schedule."""
         self._put({"name": f"{RING_HOP}_{hop}", "ph": "X",
                    "ts": self._ts_us(), "dur": dur_us,
                    "pid": self.rank, "tid": tensor_name,
                    "args": {"hop": hop, "bytes_rotated": bytes_rotated,
                             "mask": mask, "schedule": schedule,
                             "skipped_shards": skipped_shards}})
-
-    def ring_span(self, tensor_name: str, hop: int, kind: str,
-                  start_us: float, dur_us: float, **args):
-        """Measured span for one ring hop: ``kind`` is RING_KERNEL (per-hop
-        attention/fold compute) or RING_TRANSFER (the K/V ppermute).  Used
-        by the bench ring microbench, which times single-hop programs to
-        attribute step time to kernel vs transfer."""
-        self._put({"name": f"{kind}_{hop}", "ph": "X", "ts": start_us,
-                   "dur": dur_us, "pid": self.rank, "tid": tensor_name,
-                   "args": dict(args, hop=hop)})
 
     def collective_census(self, step_name: str, census: dict):
         """Per-step collective census from the jaxpr checker
