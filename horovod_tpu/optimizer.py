@@ -14,10 +14,22 @@ Reference surface being reproduced:
 
 TPU-native design: the optimizer layer is an **optax gradient
 transformation**, because under jit the "per-parameter hook + async handle"
-machinery is unnecessary — XLA's latency-hiding scheduler overlaps the psum
-with backward compute inside one fused step program, which is the same overlap
-Horovod engineers by hand with hooks (SURVEY.md §7 "Matching the NCCL
-baseline's overlap").  The transformation composes with any optax optimizer
+machinery is unnecessary — the gradients' reduction is part of one compiled
+step program, and cutting it into buckets and hiding them behind compute is
+the compiler's work, not the host's.  It does not do that unasked: left to
+its defaults XLA combines every gradient all-reduce into one or two
+synchronous operations after the backward pass (PERF.md, PR 25).  On a
+multi-chip TPU mesh ``hvd.shard_step`` therefore compiles the step with
+per-program options (``parallel/__init__.py: _ASYNC_BUCKETS``): the
+combiner merges gradients only up to ``_BUCKET_BYTES``, and an all-reduce it
+leaves with one operand (a leaf of that size or more) runs as an
+asynchronous collective behind the optimizer's update loops — as much of
+Horovod's hand-engineered overlap (SURVEY.md §7 "Matching the NCCL
+baseline's overlap") as the chip showed to pay; PERF.md, PR 26, has the
+sweep and what the overlap with backward convolutions cost.  Buckets under
+jit are the compiler's: ``groups=`` stays advisory there, and
+``fusion_threshold_bytes`` (``HOROVOD_FUSION_THRESHOLD``) sizes the eager
+path's buckets only.  The transformation composes with any optax optimizer
 and runs identically:
 
 * inside ``jit``/``shard_map`` (axis bound) — grads reduce via ``lax.psum``;

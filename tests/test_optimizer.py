@@ -180,6 +180,34 @@ def test_shard_step_helper(hvd8):
     np.testing.assert_allclose(np.asarray(out), 2.0 * 16.0)
 
 
+def test_shard_step_passes_no_compiler_option_on_cpu(hvd8, monkeypatch):
+    """The options that cut and hide the gradient buckets are the TPU
+    compiler's: on a CPU mesh, and on a mesh of one device, the wrapper
+    hands ``jax.jit`` none, and the step's sums are what they were."""
+    from horovod_tpu import parallel
+    assert parallel._compiler_options(hvd.mesh()) == {}
+    seen = []
+    jit = jax.jit
+    monkeypatch.setattr(
+        jax, "jit", lambda f, **kw: seen.append(kw) or jit(f, **kw))
+    opt = hvd.DistributedOptimizer(optax.sgd(1.0), op=hvd.Sum)
+
+    def local_step(w, opt_state, xb):
+        grads = jax.grad(lambda w: jnp.sum(xb @ w))(w)
+        updates, opt_state = opt.update(grads, opt_state, w)
+        return optax.apply_updates(w, updates), opt_state
+
+    step = hvd.parallel.shard_step(local_step,
+                                   in_specs=(P(), P(), P("hvd")),
+                                   out_specs=(P(), P()))
+    w = jnp.zeros((3,), jnp.float32)
+    x = jnp.asarray(np.random.RandomState(7).randn(N * 2, 3), jnp.float32)
+    new_w, _ = step(w, opt.init(w), x)
+    np.testing.assert_allclose(np.asarray(new_w), -np.asarray(x).sum(0),
+                               rtol=1e-5)
+    assert [kw.get("compiler_options") for kw in seen] == [None]
+
+
 def test_make_mesh_and_hierarchical(hvd8):
     m = hvd.parallel.make_mesh({"cross": 2, "local": 4})
     assert m.shape == {"cross": 2, "local": 4}

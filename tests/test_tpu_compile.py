@@ -1,4 +1,5 @@
-"""The Pallas kernels of the main path compile for the chip.
+"""The Pallas kernels of the main path compile for the chip, and the
+data-parallel step compiles to gradient all-reduces that run behind it.
 
 Interpret mode (every other kernel test) cannot see what the TPU's
 compiler refuses: a block shape off the (8, 128) tiling, an unsupported
@@ -8,18 +9,28 @@ attached — at the shapes ``chip_smoke.py`` runs on the v5e, with
 ``interpret=False``.  Nothing executes, so they say nothing about results
 or times; ``chip_smoke.py`` checks the numerics on the chip.
 
+The step test reads the compiled text of a small ``hvd.shard_step`` over
+the described chips: what the wrapper's compiler options (``parallel/
+__init__.py``) make of the gradients' all-reduces is a count in that text.
+
 All of them live in this one file and describe the topology inside a
 fixture: only one process at a time may load the TPU library, and under
 pytest-xdist only the worker that is handed this file does.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+import optax
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
+import horovod_tpu as hvd
+from horovod_tpu import parallel
 from horovod_tpu.parallel.flash import flash_attention
 from horovod_tpu.serve.paged_attention import (SCALE_DTYPE,
                                                paged_decode_attention,
@@ -33,14 +44,18 @@ SERVE_B, SERVE_MB, PREFILL_C = 8, 16, 64
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no log files in /tmp
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -107,3 +122,93 @@ def test_paged_attention_compiles_for_v5e(one_chip, no_persistent_cache,
         fn, sds(q_shape, q_dtype), pool, pool,
         sds((SERVE_B, SERVE_MB), jnp.int32), sds((SERVE_B,), jnp.int32),
         *scales)
+
+
+# -- the data-parallel step ---------------------------------------------------
+
+STEP_LAYERS, STEP_WIDTH, STEP_ROWS = 4, 2048, 512
+
+
+def _compiled_step(devices):
+    """``(text, options)`` of a small data-parallel training step through
+    ``hvd.shard_step`` over a mesh of ``devices``: four dense layers of 8
+    MiB of bf16 gradient each (a bucket each, all above the wrapper's
+    bucket size), parameters replicated, SGD with momentum through
+    ``DistributedOptimizer``."""
+    mesh = Mesh(np.asarray(devices), ("hvd",))
+    opt = hvd.DistributedOptimizer(optax.sgd(0.1, momentum=0.9))
+
+    def local_step(params, opt_state, x):
+        def loss(p):
+            h = x.astype(jnp.bfloat16)
+            for w in p:
+                h = jnp.tanh(h @ w.astype(jnp.bfloat16))
+            return (h.astype(jnp.float32) ** 2).mean()
+
+        value, grads = jax.value_and_grad(loss)(params)
+        value = hvd.allreduce(value, op=hvd.Average)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, value
+
+    step = hvd.shard_step(local_step, mesh=mesh,
+                          in_specs=(P(), P(), P("hvd")),
+                          out_specs=(P(), P(), P()), donate_argnums=(0, 1))
+    replicated, rows = NamedSharding(mesh, P()), NamedSharding(mesh, P("hvd"))
+    params = [jax.ShapeDtypeStruct((STEP_WIDTH, STEP_WIDTH), jnp.float32,
+                                   sharding=replicated)] * STEP_LAYERS
+    opt_state = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=replicated),
+        jax.eval_shape(opt.init, params))
+    x = jax.ShapeDtypeStruct((STEP_ROWS * len(devices), STEP_WIDTH),
+                             jnp.float32, sharding=rows)
+    return (step.lower(params, opt_state, x).compile().as_text(),
+            parallel._compiler_options(mesh))
+
+
+def _gradient_all_reduces(text):
+    """``(asynchronous, synchronous)`` all-reduces of the backward pass:
+    an asynchronous one is the ``all-reduce`` in the fused computation that
+    ends in the ``AsyncCollectiveStart`` custom call (the other pieces of
+    the fusion hold copies of it), or an ``all-reduce-start``; a
+    synchronous one is an ``all-reduce`` of the entry computation."""
+    backward = r"[^\n]*op_name=\"[^\"]*transpose\(jvp"
+    starts = 0
+    for computation in re.split(r"\n\n", text):
+        if 'custom_call_target="AsyncCollectiveStart"' in computation:
+            starts += len(re.findall(r" all-reduce\(" + backward,
+                                     computation))
+    entry = text[text.index("\nENTRY "):]
+    starts += len(re.findall(r" all-reduce-start\(" + backward, entry))
+    return starts, len(re.findall(r" all-reduce\(" + backward, entry))
+
+
+def test_dp_step_reduces_its_gradients_in_asynchronous_buckets(
+        topo, no_persistent_cache):
+    text, options = _compiled_step(topo.devices)
+    assert options["xla_jf_crs_combiner_threshold_in_bytes"] == \
+        parallel._BUCKET_BYTES
+    asynchronous, synchronous = _gradient_all_reduces(text)
+    assert asynchronous > 1, (asynchronous, synchronous)
+    assert asynchronous + synchronous == STEP_LAYERS
+
+
+def test_one_device_step_gets_no_option_and_has_no_collective(
+        topo, no_persistent_cache):
+    text, options = _compiled_step(topo.devices[:1])
+    assert options == {}
+    assert not re.search(r"all-reduce|all-gather|reduce-scatter|"
+                         r"collective-permute|all-to-all|AsyncCollective",
+                         text)
+
+
+def test_option_names_the_compiler_refuses_fall_back_to_no_option(
+        topo, monkeypatch):
+    """The names are one libtpu release's: a compiler that has renamed one
+    must cost the overlap, not every multi-chip compile."""
+    mesh = Mesh(np.asarray(topo.devices), ("hvd",))
+    assert parallel._compiler_options(mesh) == parallel._ASYNC_BUCKETS
+    monkeypatch.setattr(parallel, "_ASYNC_BUCKETS", dict(
+        parallel._ASYNC_BUCKETS, xla_tpu_no_such_option_of_any_release=True))
+    with pytest.warns(UserWarning, match="refuses the options"):
+        assert parallel._compiler_options(mesh) == {}
