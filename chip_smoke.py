@@ -10,6 +10,12 @@ repo's own references:
 
 * **kernels** — the four Pallas kernels compiled for the chip
   (``interpret=False``) against their dense / gather references;
+* **sdar** — SDAR-30B-A3B-Chat's block-diffusion step at the benchmark
+  cell's sizes (``benchmarks/configs/sdar-30b-a3b-ep8.json``, 4 sequences of
+  4,096 clean tokens): loss and every gradient leaf of
+  ``models/sdar_moe.py`` (flash kernels under the block-diffusion mask,
+  the dropless expert layer) against the plain float32 reference of
+  ``benchmarks/jobs/sdar_moe.py``;
 * **trainer** — ``horovodrun -np 1 python examples/synthetic_benchmark.py``:
   ResNet-50, 1000 classes, 224², bf16, sync-BN, batch 128, seven steps;
 * **server** — ``hvdserve --model gpt2-small`` answering ``/generate``
@@ -62,6 +68,26 @@ SERVE_BATCH, TABLE_BLOCKS, PREFILL_CHUNK = 8, 16, 64
 # at the highest matmul precision.
 MXU_BF16_TOL = 2.0 ** -7
 
+# -- block diffusion -------------------------------------------------------
+SDAR_CONFIG = "benchmarks/configs/sdar-30b-a3b-ep8.json"
+SDAR_SEQUENCES = 4      # the cell's step
+# The program computes in bf16 on float32 parameters, the reference in
+# float32 at the highest matmul precision.  Loss (about ln 18,992 = 9.85):
+# absolute; measured 3.1e-4.  Gradients: |got - want| / |want| in the
+# 2-norm by leaf, each held to the limit the cell's own check has for it
+# (``correct.gradient_limits`` of the configuration, where the readings
+# behind each limit are given).
+SDAR_LOSS_TOL = 2e-2
+# The expert layer alone, a sequence of the cell (8,192 positions) under
+# independent seeded router columns: once as they fall (about the even
+# load: one buffer) and once with the held experts' columns moved up by
+# SDAR_SKEW standard deviations, which sends several times the even load
+# here: past the buffer, so the chunked path runs.  Against every held
+# expert on every position in float32; bf16 products on both sides of a
+# 768-wide gated unit: a few bf16 ulps of a 2-norm.
+SDAR_SKEW = 1.0
+SDAR_LAYER_TOL = 3e-2
+
 # -- trainer ---------------------------------------------------------------
 TRAINER_CMD = [sys.executable, "-m", "horovod_tpu.runner.launch", "-np", "1",
                sys.executable, "examples/synthetic_benchmark.py",
@@ -86,7 +112,8 @@ DP_LOSS_TOL = 5e-2
 
 # Seconds a phase may take, compilation included; the whole stays inside
 # the 1200 s the contract allows.
-LIMITS = {"kernels": 300, "trainer": 400, "server": 400, "dp4": 900}
+LIMITS = {"kernels": 300, "sdar": 600, "trainer": 400, "server": 400,
+          "dp4": 900}
 
 
 class SmokeFailure(Exception):
@@ -293,7 +320,123 @@ def phase_dp4() -> dict:
     return device
 
 
-CHILD_PHASES = {"kernels": phase_kernels, "dp4": phase_dp4}
+def phase_sdar() -> dict:
+    """Loss and gradients of the block-diffusion step at the published
+    widths and the cell's sizes, program against reference."""
+    device = require_platform()
+    import importlib.util
+    import jax
+    import numpy as np
+    import horovod_tpu as hvd
+    from horovod_tpu.models import sdar_moe
+    hvd.init()  # the compile cache
+    spec = importlib.util.spec_from_file_location(
+        "sdar_job", os.path.join(REPO, "benchmarks/jobs/sdar_moe.py"))
+    job = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(job)
+    with open(os.path.join(REPO, SDAR_CONFIG)) as f:
+        config = json.load(f)
+    cfg = job.model_config(config)
+    params = job.seeded_params(config, SEED)
+    batch = job.seeded_batch(config, SEED, SDAR_SEQUENCES)
+    t0 = time.monotonic()
+    (loss, aux), grads = jax.jit(jax.value_and_grad(
+        lambda p, *b: sdar_moe.loss_fn(p, *b, cfg), has_aux=True))(
+            params, *batch)
+    loss = float(loss)
+    print(f"sdar: program loss {loss:.6f}, pairs routed to the held "
+          f"experts by layer {np.asarray(aux.routed_here).tolist()} "
+          f"({time.monotonic() - t0:.0f} s)", flush=True)
+    t0 = time.monotonic()
+    want_loss, want, chosen = job.ReferenceSteps(
+        config, SDAR_SEQUENCES).loss_and_grads(job.unstacked(params), *batch)
+    print(f"sdar: reference loss {want_loss:.6f} "
+          f"({time.monotonic() - t0:.0f} s); "
+          f"{100 * job.choices_that_differ(aux.chosen, chosen):.3f} % of the "
+          f"program's routing choices are not the reference's", flush=True)
+    check(math.isfinite(loss) and abs(loss - want_loss) <= SDAR_LOSS_TOL,
+          f"sdar: loss {loss} against the reference's {want_loss}")
+    outside = job.leaves_outside(config, job.gradient_errors(grads, want))
+    check(not outside, f"sdar: gradients out of their limits: {outside}")
+    del params, grads, want
+    for skew in (0.0, SDAR_SKEW):
+        sdar_expert_layer(job, config, skew)
+    return device
+
+
+def sdar_expert_layer(job, config: dict, skew: float) -> None:
+    """``dropless_expert_ffn`` on one sequence of the cell, forward and
+    every gradient, against every held expert applied to every position;
+    ``skew`` moves the held experts' router columns up."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from horovod_tpu.parallel.moe import dropless_expert_ffn
+    z = job.sizes(config)
+    n, d, f, held, first = 2 * z["length"], z["d"], z["width"], z["held"], \
+        z["first"]
+    keys = jax.random.split(jax.random.PRNGKey(SEED + 1), 6)
+    normal = lambda key, *shape: jax.random.normal(key, shape, jnp.float32)
+    # Every position shares one direction, as every token's state shares
+    # a mean; the held experts' columns lean on it.
+    shared = normal(keys[0], d)
+    x = (normal(keys[1], n, d) + shared).astype(jnp.bfloat16)
+    cot = normal(keys[1], n, d)
+    router = normal(keys[2], d, z["routed"]) * (4.0 / d ** 0.5)
+    router = router.at[:, first:first + held].add(
+        (skew * 4.0 / d) * shared[:, None])
+    w_gate, w_up = (normal(k, held, d, f) / d ** 0.5 for k in keys[3:5])
+    w_down = normal(keys[5], held, f, d) / f ** 0.5
+
+    def program(x, *weights):
+        out = dropless_expert_ffn(x, router, *weights, top_k=z["top_k"],
+                                  first_expert=first)
+        return jnp.sum(out.out.astype(jnp.float32) * cot), out.routed_here
+
+    @job.highest
+    def plain(x, w_gate, w_up, w_down):
+        h = x.astype(jnp.float32)
+        top_p, chosen = jax.lax.top_k(jax.nn.softmax(h @ router, -1),
+                                      z["top_k"])
+        top_p = top_p / top_p.sum(-1, keepdims=True)
+
+        def add_expert(acc, e_and_weights):
+            e, *weights = e_and_weights
+            gate = jnp.sum(jnp.where(chosen == first + e, top_p, 0.0), -1,
+                           keepdims=True)
+            return acc + gate * job.expert(h, *weights), None
+
+        out, _ = jax.lax.scan(add_expert, jnp.zeros_like(h), (
+            jnp.arange(held), w_gate, w_up, w_down))
+        return jnp.sum(out * cot)
+
+    args = (x, w_gate, w_up, w_down)
+    step = jax.jit(jax.value_and_grad(program, argnums=(0, 1, 2, 3),
+                                      has_aux=True))
+    (got, routed), got_grads = jax.block_until_ready(step(*args))
+    seconds = []
+    for _ in range(3):
+        t0 = time.monotonic()
+        jax.block_until_ready(step(*args))
+        seconds.append(time.monotonic() - t0)
+    want, want_grads = jax.jit(jax.value_and_grad(
+        plain, argnums=(0, 1, 2, 3)))(*args)
+    errors = [abs(float(got) - float(want)) / abs(float(want))] + [
+        float(jnp.linalg.norm((g.astype(jnp.float32) - w).ravel())
+              / jnp.linalg.norm(w.ravel()))
+        for g, w in zip(got_grads, want_grads)]
+    even = n * z["top_k"] * held // z["routed"]
+    print(f"sdar: expert layer, held columns up by {skew}: "
+          f"{int(routed)} pairs routed here ({int(routed) / even:.2f} x the "
+          f"even load), forward and backward {1e3 * min(seconds):.2f} ms; "
+          f"relative errors of the result and the gradients by x, w_gate, "
+          f"w_up, w_down: {[f'{e:.2e}' for e in errors]}", flush=True)
+    check(bool(np.isfinite(errors).all()) and max(errors) <= SDAR_LAYER_TOL,
+          f"sdar: expert layer with held columns up by {skew}: {errors}")
+
+
+CHILD_PHASES = {"kernels": phase_kernels, "dp4": phase_dp4,
+                "sdar": phase_sdar}
 
 
 # ---------------------------------------------------------------------------
@@ -462,6 +605,7 @@ def main(argv=None) -> int:
             device = timed("dp4", child_report, "dp4")
         else:
             device = timed("kernels", child_report, "kernels")
+            timed("sdar", child_report, "sdar")
             timed("trainer", run_trainer)
             timed("server", run_server)
         check(device["platform"] == PLATFORM

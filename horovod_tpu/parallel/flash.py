@@ -5,8 +5,14 @@ math of ring attention, or the full-sequence-per-head-subset attention of
 Ulysses) and the dense encoder attention of BERT/GPT are the hot loops this
 kernel serves.  FlashAttention-2 structure, mapped onto the Mosaic pipeline:
 
-* **Forward** — grid ``(B*H, q_blocks, k_blocks)`` with the K/V block index
-  as an ``arbitrary`` (sequential) grid dimension.  Each K/V block is a
+* **Forward** — grid ``(B*H, q_blocks, j)`` where ``j`` walks the K/V
+  blocks that the mask lets this query block read, an ``arbitrary``
+  (sequential) grid dimension.  Which blocks those are is static
+  (``tile_tables``: numpy tables made from ``block_contributes`` and
+  ``block_full``, handed to the kernel as scalar-prefetch operands that its
+  ``BlockSpec`` index maps read), so a block wholly outside the mask costs
+  no product, no copy and no grid step beyond the longest row, and a block
+  wholly inside skips the mask's arithmetic.  Each K/V block is a
   grid-indexed ``BlockSpec``, so Mosaic double-buffers the HBM→VMEM DMA of
   block *i+1* against the MXU compute of block *i* automatically — the
   whole online-softmax state (running max / sum / accumulator) lives in
@@ -17,6 +23,13 @@ kernel serves.  FlashAttention-2 structure, mapped onto the Mosaic pipeline:
   one accumulates dQ streaming over K/V blocks, one accumulates dK/dV
   streaming over Q blocks; both recompute the probabilities from the saved
   logsumexp instead of materializing them.
+* **Grouped key/value heads** — ``k`` and ``v`` may have fewer heads than
+  ``q``: the index maps send query head ``h`` to key/value head ``h //
+  group``, and the dK/dV kernel's sequential dimension walks the group's
+  query heads as well as the query blocks, so K and V are never repeated
+  in HBM and dK/dV are summed over the group in VMEM.
+* Products run in the operands' dtype (bf16 stays bf16 on the MXU) with
+  float32 accumulation; softmax statistics are float32.
 * ``jax.custom_vjp`` ties them together, so the kernel drops into
   ``jax.grad`` training steps (the BERT/GPT benches) directly.
 
@@ -40,16 +53,41 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+SAVED = ("hvd_flash_out", "hvd_flash_lse")  # checkpoint names, _flash_fwd
 LANES = 128  # VMEM lane width: (block_q, LANES) scratch keeps m/l aligned
 
 # Static mask modes (ring attention's per-hop block masks compile one
 # kernel per mode): NONE = full attend; CAUSAL = q >= k on local indices;
 # STRICT = q > k (the striped ring's off-diagonal rule).
-MASK_NONE, MASK_CAUSAL, MASK_STRICT = 0, 1, 2
+# BLOCK_DIFFUSION is the training mask of block diffusion (BD3-LM,
+# arXiv:2503.09573): the sequence is the noised copy ``[0, L)`` followed by
+# the clean copy ``[L, 2L)`` of ``L`` tokens cut into blocks; a noised query
+# reads the noised keys of its own block and the clean keys of earlier
+# blocks, a clean query reads the clean keys of its own and earlier blocks,
+# and never a noised key.  It needs its block length and ``L``, so the mode
+# is the tuple :func:`block_diffusion_mask` makes.
+MASK_NONE, MASK_CAUSAL, MASK_STRICT, MASK_BLOCK_DIFFUSION = 0, 1, 2, 3
+
+
+def block_diffusion_mask(block_length: int, length: int):
+    """The mask mode for ``[noised ; clean]`` copies of ``length`` tokens in
+    blocks of ``block_length`` (static, hashable: a kernel per value)."""
+    return (MASK_BLOCK_DIFFUSION, int(block_length), int(length))
+
+
+def _half_block(pos, block_length, length):
+    """``(noised?, block index)`` of global positions under block
+    diffusion; arrays or Python ints."""
+    noised = pos < length
+    if isinstance(pos, int):
+        return noised, (pos if noised else pos - length) // block_length
+    return noised, jnp.where(noised, pos, pos - length) // block_length
 
 
 def causal_mask(s, q_offset, k_offset, mode):
@@ -64,21 +102,106 @@ def causal_mask(s, q_offset, k_offset, mode):
     bq, bk = s.shape
     qg = q_offset + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     kg = k_offset + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    keep = qg >= kg if mode == MASK_CAUSAL else qg > kg
+    if isinstance(mode, tuple):
+        _, block_length, length = mode
+        q_noised, q_block = _half_block(qg, block_length, length)
+        k_noised, k_block = _half_block(kg, block_length, length)
+        # Logical operations only: Mosaic has no select over booleans.
+        k_clean = jnp.logical_not(k_noised)
+        keep = (q_noised & k_noised & (q_block == k_block)) \
+            | (q_noised & k_clean & (k_block < q_block)) \
+            | (jnp.logical_not(q_noised) & k_clean & (k_block <= q_block))
+    else:
+        keep = qg >= kg if mode == MASK_CAUSAL else qg > kg
     return jnp.where(keep, s, NEG_INF)
 
 
-def block_contributes(mode, q_lo, q_hi, k_lo):
-    """Whether a key block starting at global position ``k_lo`` can
+def block_contributes(mode, q_lo, q_hi, k_lo, k_hi=None):
+    """Whether a key block spanning global positions ``[k_lo, k_hi]`` can
     contribute to queries spanning ``[q_lo, q_hi]`` under ``mode`` — the
-    compute-skip predicate for blocks entirely outside the mask (their
-    DMA is already in flight; acceptable overfetch).  Static or traced
-    positions, same contract as :func:`causal_mask`."""
+    compute-skip predicate for blocks entirely outside the mask.  Static
+    or traced positions, same contract as :func:`causal_mask`; block
+    diffusion reads ``k_hi`` too and takes static positions only."""
     if mode == MASK_NONE:
         return True
     if mode == MASK_CAUSAL:
         return k_lo <= q_hi
-    return k_lo < q_hi  # STRICT
+    if mode == MASK_STRICT:
+        return k_lo < q_hi
+    # Block diffusion: static positions only (the kernels walk tables made
+    # from this, ``tile_tables``).  The noised and the clean part of each
+    # span, as block indices:
+    _, block_length, length = mode
+    q_has_noised, q_has_clean = q_lo < length, q_hi >= length
+    k_has_noised, k_has_clean = k_lo < length, k_hi >= length
+    q_noised = (q_lo // block_length, min(q_hi, length - 1) // block_length)
+    k_noised = (k_lo // block_length, min(k_hi, length - 1) // block_length)
+    q_last_clean = (q_hi - length) // block_length
+    k_first_clean = (max(k_lo, length) - length) // block_length
+    same_block = q_has_noised and k_has_noised and \
+        k_noised[0] <= q_noised[1] and q_noised[0] <= k_noised[1]
+    earlier_clean = q_has_noised and k_has_clean and \
+        k_first_clean < q_noised[1]
+    block_causal = q_has_clean and k_has_clean and \
+        k_first_clean <= q_last_clean
+    return same_block or earlier_clean or block_causal
+
+
+def block_full(mode, q_lo, q_hi, k_lo, k_hi):
+    """Whether every pair of the tile is kept, so that the kernels need
+    not apply the mask to it.  Static positions only (the tile tables)."""
+    if mode == MASK_NONE:
+        return True
+    if mode == MASK_CAUSAL:
+        return k_hi <= q_lo
+    if mode == MASK_STRICT:
+        return k_hi < q_lo
+    _, block_length, length = mode
+    if (q_lo < length) != (q_hi < length) or \
+            (k_lo < length) != (k_hi < length):
+        return False                      # a span over both copies
+    q_noised, q_first = _half_block(q_lo, block_length, length)
+    _, q_last = _half_block(q_hi, block_length, length)
+    k_noised, k_first = _half_block(k_lo, block_length, length)
+    _, k_last = _half_block(k_hi, block_length, length)
+    if q_noised and k_noised:
+        return q_first == q_last == k_first == k_last
+    if q_noised:
+        return k_last < q_first
+    return not k_noised and k_last <= q_first
+
+
+@functools.lru_cache(maxsize=None)
+def tile_tables(mode, seq: int, block_q: int, block_k: int):
+    """What the kernels walk: for every query tile the key tiles that
+    contribute, and for every key tile the query tiles, as ``int32`` numpy
+    arrays ``(k_of_q [nq, max], k_flag, q_of_k [nk, max], q_flag)``.  A
+    flag is 2 where the tile is wholly inside the mask, 1 where the mask
+    has to be applied, 0 for padding; padding repeats the row's last tile,
+    so that it costs no copy.  Tiles outside the mask are in no row: they
+    cost neither a product nor a grid step beyond the longest row."""
+    nq, nk = seq // block_q, seq // block_k
+    flags = np.zeros((nq, nk), np.int32)
+    for qi in range(nq):
+        q_lo, q_hi = qi * block_q, (qi + 1) * block_q - 1
+        for ki in range(nk):
+            k_lo, k_hi = ki * block_k, (ki + 1) * block_k - 1
+            if block_contributes(mode, q_lo, q_hi, k_lo, k_hi):
+                flags[qi, ki] = 2 if block_full(mode, q_lo, q_hi, k_lo,
+                                                k_hi) else 1
+
+    def rows(flags):
+        width = max(1, int((flags > 0).sum(axis=1).max()))
+        index = np.zeros((flags.shape[0], width), np.int32)
+        flag = np.zeros_like(index)
+        for r, row in enumerate(flags):
+            kept = np.flatnonzero(row)
+            index[r, :len(kept)] = kept
+            index[r, len(kept):] = kept[-1] if len(kept) else 0
+            flag[r, :len(kept)] = row[kept]
+        return index, flag
+
+    return rows(flags) + rows(flags.T)
 
 
 def online_softmax_block(s, v, m_ref, l_ref, acc_ref):
@@ -109,7 +232,7 @@ def online_softmax_block(s, v, m_ref, l_ref, acc_ref):
         l_ref.shape)
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
     acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, dimension_numbers=(((1,), (0,)), ((), ())),
+        p.astype(v.dtype), v, dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
 
@@ -134,114 +257,124 @@ def _row_to_col(row):
     return jnp.broadcast_to(row, (LANES, row.shape[1])).T[:, :1]
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l, *,
-                scale: float, mask_mode: int, block_q: int, block_k: int,
-                num_kb: int):
-    qi, kb = pl.program_id(1), pl.program_id(2)
+def _scaled(q_ref, scale):
+    """The query tile times ``scale``, computed in float32 and handed to
+    the MXU in the tile's own dtype (bf16 stays bf16)."""
+    return (q_ref[0].astype(jnp.float32) * scale).astype(q_ref.dtype)
 
-    @pl.when(kb == 0)
+
+def _scores(q, k):
+    return jax.lax.dot_general(
+        q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)           # [Bq, Bk]
+
+
+def _on_tiles(flag, step):
+    """Run ``step(masked)`` for a tile the tables list: with the mask
+    where the tile crosses its edge (flag 1), without where it lies wholly
+    inside (flag 2), not at all on a row's padding (flag 0)."""
+    pl.when(flag == 1)(functools.partial(step, True))
+    pl.when(flag == 2)(functools.partial(step, False))
+
+
+def _fwd_kernel(kidx_ref, kflag_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                acc, m, l, *, scale: float, mask_mode, block_q: int,
+                block_k: int, num_j: int):
+    qi, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j == 0)
     def _init():
         acc[...] = jnp.zeros_like(acc)
         m[...] = jnp.full_like(m, NEG_INF)
         l[...] = jnp.zeros_like(l)
 
-    contributes = block_contributes(mask_mode, qi * block_q,
-                                    qi * block_q + block_q - 1,
-                                    kb * block_k)
+    def _step(masked):
+        s = _scores(_scaled(q_ref, scale), k_ref[0])
+        if masked:
+            s = causal_mask(s, qi * block_q, kidx_ref[qi, j] * block_k,
+                            mask_mode)
+        online_softmax_block(s, v_ref[0], m, l, acc)
 
-    @pl.when(contributes)
-    def _step():
-        q = q_ref[0].astype(jnp.float32) * scale      # [Bq, D]
-        k = k_ref[0].astype(jnp.float32)              # [Bk, D]
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [Bq, Bk]
-        s = causal_mask(s, qi * block_q, kb * block_k, mask_mode)
-        online_softmax_block(s, v, m, l, acc)
+    _on_tiles(kflag_ref[qi, j], _step)
 
-    @pl.when(kb == num_kb - 1)
+    @pl.when(j == num_j - 1)
     def _flush():
         out, lse = online_softmax_flush(m, l, acc)
         o_ref[0] = out.astype(o_ref.dtype)
         lse_ref[0] = _col_to_row(lse)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_acc, *, scale: float, mask_mode: int, block_q: int,
-                   block_k: int, num_kb: int):
-    qi, kb = pl.program_id(1), pl.program_id(2)
+def _probs_and_ds(q, k_ref, v_ref, do_ref, lse_ref, delta_ref, q_offset,
+                  k_offset, mask_mode, masked):
+    """``(p, ds)`` of one tile, both ``[Bq, Bk]`` float32, recomputed from
+    the saved logsumexp."""
+    s = _scores(q, k_ref[0])
+    if masked:
+        s = causal_mask(s, q_offset, k_offset, mask_mode)
+    p = jnp.exp(s - _row_to_col(lse_ref[0]))
+    dp = jax.lax.dot_general(
+        do_ref[0], v_ref[0], dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return p, p * (dp - _row_to_col(delta_ref[0]))
 
-    @pl.when(kb == 0)
+
+def _bwd_dq_kernel(kidx_ref, kflag_ref, q_ref, k_ref, v_ref, do_ref,
+                   lse_ref, delta_ref, dq_ref, dq_acc, *, scale: float,
+                   mask_mode, block_q: int, block_k: int, num_j: int):
+    qi, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    contributes = block_contributes(mask_mode, qi * block_q,
-                                    qi * block_q + block_q - 1,
-                                    kb * block_k)
-
-    @pl.when(contributes)
-    def _step():
-        q = q_ref[0].astype(jnp.float32) * scale
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s = causal_mask(s, qi * block_q, kb * block_k, mask_mode)
-        p = jnp.exp(s - _row_to_col(lse_ref[0]))      # [Bq, Bk]
-        dp = jax.lax.dot_general(
-            do, v, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - _row_to_col(delta_ref[0]))
+    def _step(masked):
+        _, ds = _probs_and_ds(
+            _scaled(q_ref, scale), k_ref, v_ref, do_ref, lse_ref, delta_ref,
+            qi * block_q, kidx_ref[qi, j] * block_k, mask_mode, masked)
         dq_acc[...] += jax.lax.dot_general(
-            ds, k, dimension_numbers=(((1,), (0,)), ((), ())),
+            ds.astype(k_ref.dtype), k_ref[0],
+            dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(kb == num_kb - 1)
+    _on_tiles(kflag_ref[qi, j], _step)
+
+    @pl.when(j == num_j - 1)
     def _flush():
         dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
-                    mask_mode: int, block_q: int, block_k: int,
-                    num_qb: int):
-    kb, qi = pl.program_id(1), pl.program_id(2)
+def _bwd_dkv_kernel(qidx_ref, qflag_ref, q_ref, k_ref, v_ref, do_ref,
+                    lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                    scale: float, mask_mode, block_q: int, block_k: int,
+                    num_j: int, width: int):
+    # The sequential dimension walks the group's query heads and, for
+    # each, the query tiles that read this key tile: dK and dV of a
+    # key/value head are summed over its query heads here, in VMEM.
+    kb, j = pl.program_id(1), pl.program_id(2)
+    jq = j % width
 
-    @pl.when(qi == 0)
+    @pl.when(j == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    contributes = block_contributes(mask_mode, qi * block_q,
-                                    qi * block_q + block_q - 1,
-                                    kb * block_k)
-
-    @pl.when(contributes)
-    def _step():
-        q = q_ref[0].astype(jnp.float32) * scale
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s = causal_mask(s, qi * block_q, kb * block_k, mask_mode)
-        p = jnp.exp(s - _row_to_col(lse_ref[0]))      # [Bq, Bk]
+    def _step(masked):
+        q = _scaled(q_ref, scale)
+        p, ds = _probs_and_ds(
+            q, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+            qidx_ref[kb, jq] * block_q, kb * block_k, mask_mode, masked)
         dv_acc[...] += jax.lax.dot_general(
-            p, do, dimension_numbers=(((0,), (0,)), ((), ())),
+            p.astype(do_ref.dtype), do_ref[0],
+            dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)       # [Bk, D]
-        dp = jax.lax.dot_general(
-            do, v, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - _row_to_col(delta_ref[0]))
         dk_acc[...] += jax.lax.dot_general(
-            ds, q, dimension_numbers=(((0,), (0,)), ((), ())),
+            ds.astype(q.dtype), q,
+            dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)       # [Bk, D]
 
-    @pl.when(qi == num_qb - 1)
+    _on_tiles(qflag_ref[kb, jq], _step)
+
+    @pl.when(j == num_j - 1)
     def _flush():
         # q was pre-scaled, so dk_acc already carries the scale factor.
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
@@ -267,6 +400,18 @@ def _stat_spec(block_q, index_map):
     return pl.BlockSpec((1, 1, block_q), index_map)
 
 
+def vary_like(x, like):
+    """``x`` typed as varying over every mesh axis ``like`` varies over
+    (inside ``shard_map``): Pallas asks it of every operand of a call, and
+    a ``custom_vjp`` of every cotangent against its primal.  The cast's own
+    transpose is the sum over the axis, which is what a replicated
+    operand's gradient needs."""
+    x = jnp.asarray(x)
+    missing = tuple(a for a in jax.typeof(like).vma
+                    if a not in jax.typeof(x).vma)
+    return jax.lax.pcast(x, missing, to="varying") if missing else x
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, mask_mode, scale, block_q, block_k, interpret):
     out, _ = _flash_fwd(q, k, v, mask_mode, scale, block_q, block_k,
@@ -275,35 +420,51 @@ def _flash(q, k, v, mask_mode, scale, block_q, block_k, interpret):
 
 
 def _flash_fwd(q, k, v, mask_mode, scale, block_q, block_k, interpret):
+    """``q`` is ``[B*H, S, D]``; ``k`` and ``v`` are ``[B*Hkv, S, D]`` with
+    ``Hkv`` dividing ``H``: query head ``h`` reads key/value head
+    ``h // (H / Hkv)`` through the index maps, no copy."""
     BH, S, D = q.shape
-    num_qb, num_kb = S // block_q, S // block_k
+    group = BH // k.shape[0]
+    kidx, kflag, _, _ = tile_tables(mask_mode, S, block_q, block_k)
+    num_j = kidx.shape[1]
     kernel = functools.partial(_fwd_kernel, scale=scale,
                                mask_mode=mask_mode,
                                block_q=block_q, block_k=block_k,
-                               num_kb=num_kb)
+                               num_j=num_j)
+    q_map = lambda bh, qi, j, kidx, kflag: (bh, qi, 0)
+    kv_map = lambda bh, qi, j, kidx, kflag: (bh // group, kidx[qi, j], 0)
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[_out_struct((BH, S, D), q.dtype, q),
                    _out_struct((BH, 1, S), jnp.float32, q)],
-        grid=(BH, num_qb, num_kb),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, kb: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, qi, kb: (bh, kb, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, qi, kb: (bh, kb, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, kb: (bh, qi, 0)),
-            _stat_spec(block_q, lambda bh, qi, kb: (bh, 0, qi)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(BH, S // block_q, num_j),
+            in_specs=[
+                pl.BlockSpec((1, block_q, D), q_map),
+                pl.BlockSpec((1, block_k, D), kv_map),
+                pl.BlockSpec((1, block_k, D), kv_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, D), q_map),
+                _stat_spec(block_q,
+                           lambda bh, qi, j, kidx, kflag: (bh, 0, qi)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, D), jnp.float32),
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+            ]),
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-    )(q, k, v)
-    return out, (q, k, v, out, lse.reshape(BH, S))
+        name="hvd_flash_fwd",
+    )(vary_like(kidx, q), vary_like(kflag, q), q, k, v)
+    # Named for ``jax.checkpoint`` policies: a caller that recomputes a
+    # layer in its backward pass can keep these two (``save_only_these_
+    # names(*SAVED)``) and spare the forward kernel's second run.
+    out = checkpoint_name(out, SAVED[0])
+    lse = checkpoint_name(lse.reshape(BH, S), SAVED[1])
+    return out, (q, k, v, out, lse)
 
 
 def _flash_bwd(mask_mode, scale, block_q, block_k, interpret, res, g):
@@ -323,53 +484,72 @@ def _run_bwd_kernels(q, k, v, do, lse, delta, mask_mode, scale,
     the lse-exposing vjps (the latter folds the lse cotangent into
     ``delta``; see ``_flash_lse_bwd``)."""
     BH, S, D = q.shape
-    num_qb, num_kb = S // block_q, S // block_k
+    group = BH // k.shape[0]
+    kidx, kflag, qidx, qflag = tile_tables(mask_mode, S, block_q, block_k)
     lse, delta = lse.reshape(BH, 1, S), delta.reshape(BH, 1, S)
 
+    q_map = lambda bh, qi, j, kidx, kflag: (bh, qi, 0)
+    kv_map = lambda bh, qi, j, kidx, kflag: (bh // group, kidx[qi, j], 0)
+    stat_map = lambda bh, qi, j, kidx, kflag: (bh, 0, qi)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, mask_mode=mask_mode,
-                          block_q=block_q, block_k=block_k, num_kb=num_kb),
+                          block_q=block_q, block_k=block_k,
+                          num_j=kidx.shape[1]),
         out_shape=_out_struct((BH, S, D), q.dtype, q),
-        grid=(BH, num_qb, num_kb),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, kb: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, qi, kb: (bh, kb, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, qi, kb: (bh, kb, 0)),
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, kb: (bh, qi, 0)),
-            _stat_spec(block_q, lambda bh, qi, kb: (bh, 0, qi)),
-            _stat_spec(block_q, lambda bh, qi, kb: (bh, 0, qi)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D),
-                               lambda bh, qi, kb: (bh, qi, 0)),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(BH, S // block_q, kidx.shape[1]),
+            in_specs=[
+                pl.BlockSpec((1, block_q, D), q_map),
+                pl.BlockSpec((1, block_k, D), kv_map),
+                pl.BlockSpec((1, block_k, D), kv_map),
+                pl.BlockSpec((1, block_q, D), q_map),
+                _stat_spec(block_q, stat_map),
+                _stat_spec(block_q, stat_map),
+            ],
+            out_specs=pl.BlockSpec((1, block_q, D), q_map),
+            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)]),
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+        name="hvd_flash_bwd_dq",
+    )(vary_like(kidx, q), vary_like(kflag, q), q, k, v, do, lse, delta)
 
+    # One program per key/value head and key tile; the sequential
+    # dimension is (query head of the group) x (query tile of the row).
+    width = qidx.shape[1]
+    q_of = lambda bkv, kb, j, qidx, qflag: (
+        bkv * group + j // width, qidx[kb, j % width], 0)
+    kv_of = lambda bkv, kb, j, qidx, qflag: (bkv, kb, 0)
+    stat_of = lambda bkv, kb, j, qidx, qflag: (
+        bkv * group + j // width, 0, qidx[kb, j % width])
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale,
-                          mask_mode=mask_mode,
-                          block_q=block_q, block_k=block_k, num_qb=num_qb),
-        out_shape=[_out_struct((BH, S, D), k.dtype, k),
-                   _out_struct((BH, S, D), v.dtype, v)],
-        grid=(BH, num_kb, num_qb),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, kb, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, kb, qi: (bh, kb, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, kb, qi: (bh, kb, 0)),
-            pl.BlockSpec((1, block_q, D), lambda bh, kb, qi: (bh, qi, 0)),
-            _stat_spec(block_q, lambda bh, kb, qi: (bh, 0, qi)),
-            _stat_spec(block_q, lambda bh, kb, qi: (bh, 0, qi)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda bh, kb, qi: (bh, kb, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, kb, qi: (bh, kb, 0)),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
+                          mask_mode=mask_mode, block_q=block_q,
+                          block_k=block_k, num_j=group * width,
+                          width=width),
+        out_shape=[_out_struct(k.shape, k.dtype, k),
+                   _out_struct(v.shape, v.dtype, v)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(k.shape[0], S // block_k, group * width),
+            in_specs=[
+                pl.BlockSpec((1, block_q, D), q_of),
+                pl.BlockSpec((1, block_k, D), kv_of),
+                pl.BlockSpec((1, block_k, D), kv_of),
+                pl.BlockSpec((1, block_q, D), q_of),
+                _stat_spec(block_q, stat_of),
+                _stat_spec(block_q, stat_of),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_k, D), kv_of),
+                pl.BlockSpec((1, block_k, D), kv_of),
+            ],
+            scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
+                            pltpu.VMEM((block_k, D), jnp.float32)]),
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+        name="hvd_flash_bwd_dkv",
+    )(vary_like(qidx, q), vary_like(qflag, q), q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
@@ -414,14 +594,48 @@ def _flash_lse_bwd(mask_mode, scale, block_q, block_k, interpret,
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
+def _heads_first(x):
+    B, S, H, D = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B * H, S, D)
+
+
+def _checked(name, q, k, v, scale, block_q, block_k, interpret):
+    """Defaults filled in and shapes checked for the two public calls."""
+    B, S, H, D = q.shape
+    if k.shape != v.shape or k.shape[:2] != (B, S) or k.shape[3] != D \
+            or H % k.shape[2]:
+        raise ValueError(
+            f"{name}: keys and values {k.shape}, {v.shape} do not fit "
+            f"queries {q.shape}: the key/value heads must divide the "
+            f"query heads")
+    block_q, block_k = min(block_q, S), min(block_k, S)
+    if S % block_q or S % block_k:
+        raise ValueError(
+            f"{name} requires seq len {S} divisible by block sizes "
+            f"({block_q}, {block_k})")
+    return (scale if scale is not None else 1.0 / math.sqrt(D),
+            block_q, block_k,
+            jax.default_backend() != "tpu" if interpret is None
+            else interpret)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     *,
                     causal: bool = False,
+                    mask_mode=None,
                     scale: Optional[float] = None,
                     block_q: int = 128,
                     block_k: int = 128,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Differentiable flash attention over [B, S, H, D] (full local seq).
+
+    ``k`` and ``v`` may have fewer heads than ``q`` (``[B, S, Hkv, D]``,
+    ``Hkv`` dividing ``H``): query head ``h`` reads key/value head
+    ``h // (H / Hkv)``, the kernels address it through their index maps
+    and sum dK and dV over the group, so K and V are never repeated in
+    HBM.  ``mask_mode`` names the mask where ``causal`` cannot: one of the
+    ``MASK_*`` modes or :func:`block_diffusion_mask`; tiles wholly outside
+    it cost no product.
 
     ``interpret=None`` auto-selects the Pallas interpreter off-TPU so the
     same call works in the CPU-mesh test environment.  In interpret mode
@@ -429,28 +643,18 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     interpreter inlines the kernel, mixing invariant loop indices with
     varying data); the compiled TPU path needs no such escape hatch."""
     B, S, H, D = q.shape
-    scale = scale if scale is not None else 1.0 / math.sqrt(D)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    block_q = min(block_q, S)
-    block_k = min(block_k, S)
-    if S % block_q or S % block_k:
-        raise ValueError(
-            f"flash_attention requires seq len {S} divisible by block sizes "
-            f"({block_q}, {block_k})")
-
-    def reshape_in(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, S, D)
-
-    mode = MASK_CAUSAL if causal else MASK_NONE
-    out = _flash(reshape_in(q), reshape_in(k), reshape_in(v),
-                 mode, scale, block_q, block_k, interpret)
+    scale, block_q, block_k, interpret = _checked(
+        "flash_attention", q, k, v, scale, block_q, block_k, interpret)
+    if mask_mode is None:
+        mask_mode = MASK_CAUSAL if causal else MASK_NONE
+    out = _flash(_heads_first(q), _heads_first(k), _heads_first(v),
+                 mask_mode, scale, block_q, block_k, interpret)
     return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)
 
 
 def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array,
                         *,
-                        mask_mode: int = MASK_NONE,
+                        mask_mode=MASK_NONE,
                         scale: Optional[float] = None,
                         block_q: int = 128,
                         block_k: int = 128,
@@ -461,22 +665,11 @@ def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array,
     (the cross-hop merge weights depend on lse, so its cotangent is
     nonzero).  ``mask_mode`` is one of MASK_NONE / MASK_CAUSAL /
     MASK_STRICT applied on LOCAL block indices (ring hops pick the mode
-    per hop from the block owner)."""
+    per hop from the block owner), or :func:`block_diffusion_mask`."""
     B, S, H, D = q.shape
-    scale = scale if scale is not None else 1.0 / math.sqrt(D)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    block_q = min(block_q, S)
-    block_k = min(block_k, S)
-    if S % block_q or S % block_k:
-        raise ValueError(
-            f"flash_attention_lse requires seq len {S} divisible by block "
-            f"sizes ({block_q}, {block_k})")
-
-    def reshape_in(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, S, D)
-
-    out, lse = _flash_lse(reshape_in(q), reshape_in(k), reshape_in(v),
+    scale, block_q, block_k, interpret = _checked(
+        "flash_attention_lse", q, k, v, scale, block_q, block_k, interpret)
+    out, lse = _flash_lse(_heads_first(q), _heads_first(k), _heads_first(v),
                           mask_mode, scale, block_q, block_k, interpret,
                           out_dtype)
     return (out.reshape(B, H, S, D).transpose(0, 2, 1, 3),

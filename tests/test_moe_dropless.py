@@ -1,0 +1,221 @@
+"""The dropless expert layer (``parallel/moe.py: dropless_expert_ffn``)
+and its grouped products, on the CPU in interpret mode."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import PartitionSpec as P
+
+from horovod_tpu.parallel import grouped
+from horovod_tpu.parallel.moe import dropless_expert_ffn
+
+T, D, F, E, K = 48, 16, 24, 16, 4
+
+
+def weights(seed, experts=E, router_scale=1.0):
+    rng = np.random.RandomState(seed)
+    mk = lambda *shape: jnp.asarray(rng.randn(*shape).astype(np.float32))
+    return {"x": mk(T, D), "router": mk(D, experts) * router_scale,
+            "gate": mk(experts, D, F) * 0.3, "up": mk(experts, D, F) * 0.3,
+            "down": mk(experts, F, D) * 0.3}
+
+
+def per_token_loop(w, first=0, held=None, router=None):
+    """The layer written token by token in numpy: softmax over all
+    experts, the K largest, renormalised; only experts ``first ..
+    first + held - 1`` are applied.  Returns ``(out, pairs here)``."""
+    x = np.asarray(w["x"], np.float64)
+    router = np.asarray(w["router"] if router is None else router,
+                        np.float64)
+    held = router.shape[1] - first if held is None else held
+    out, here = np.zeros_like(x), 0
+    for t in range(x.shape[0]):
+        logits = x[t] @ router
+        p = np.exp(logits - logits.max())
+        p /= p.sum()
+        top = np.argsort(-p, kind="stable")[:K]
+        for e in top:
+            if first <= e < first + held:
+                here += 1
+                g, u, dn = (np.asarray(w[n][e - first], np.float64)
+                            for n in ("gate", "up", "down"))
+                a = x[t] @ g
+                out[t] += p[e] / p[top].sum() * (
+                    (a / (1 + np.exp(-a)) * (x[t] @ u)) @ dn)
+    return out, here
+
+
+def layer(w, **kw):
+    return dropless_expert_ffn(w["x"], w["router"], w["gate"], w["up"],
+                               w["down"], top_k=K, **kw)
+
+
+def test_whole_layer_equals_the_per_token_loop():
+    w = weights(0)
+    got = layer(w)
+    want, here = per_token_loop(w)
+    assert int(got.routed_here) == here == T * K
+    np.testing.assert_allclose(got.out, want, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("held", [1, 2, 4, 8])
+def test_shares_add_up_to_the_uncut_layer(held):
+    """The share test: every share routes over all E experts and computes
+    its own experts' part; the parts of all E / held shares add up to the
+    uncut layer, and their pair counts to T x K."""
+    w = weights(1)
+    total, pairs = 0, 0
+    for first in range(0, E, held):
+        share = dict(w, **{n: w[n][first:first + held]
+                           for n in ("gate", "up", "down")})
+        got = layer(share, first_expert=first)
+        want, here = per_token_loop(share, first, held)
+        assert int(got.routed_here) == here
+        np.testing.assert_allclose(got.out, want, rtol=2e-4, atol=2e-5)
+        total, pairs = total + got.out, pairs + here
+    assert pairs == T * K
+    np.testing.assert_allclose(total, per_token_loop(w)[0], rtol=2e-4,
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("skew", ["one_expert", "held_only", "elsewhere"])
+def test_skewed_routing_drops_nothing(skew):
+    """A share of 2 of 16 experts sized for an even load (a buffer of 48
+    rows for 192 pairs): all tokens choose expert 0 first, all K choices
+    of all tokens are held here (the chunked path), or none is."""
+    w = weights(2)
+    bias = np.zeros((D, E), np.float32)
+    x = np.abs(np.asarray(w["x"])) + 0.5      # so a column's sign decides
+    first, held = 0, 2
+    if skew == "one_expert":
+        bias[:, 0] = 0.3
+    elif skew == "held_only":
+        first, held = 0, 4
+        bias[:, :4] = 0.3
+    else:
+        bias[:, 2:] = 0.3
+    w = dict(w, x=jnp.asarray(x), router=w["router"] * 0.05 + bias)
+    share = dict(w, **{n: w[n][first:first + held]
+                       for n in ("gate", "up", "down")})
+    got = layer(share, first_expert=first)
+    want, here = per_token_loop(share, first, held)
+    assert int(got.routed_here) == here
+    assert {"one_expert": here >= T, "held_only": here == T * K,
+            "elsewhere": here < T}[skew]
+    np.testing.assert_allclose(got.out, want, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("wrt", ["x", "router", "gate", "up", "down"])
+def test_gradients_equal_the_dense_layer(wrt):
+    """Against every held expert applied to every token and weighted by
+    the routing, differentiated by JAX."""
+    w = weights(3)
+    first, held = 4, 8
+    share = dict(w, **{n: w[n][first:first + held]
+                       for n in ("gate", "up", "down")})
+    seed = jnp.asarray(np.random.RandomState(4).randn(T, D), jnp.float32)
+
+    def dense(w):
+        p = jax.nn.softmax(w["x"] @ w["router"], axis=-1)
+        top_p, top = jax.lax.top_k(p, K)
+        gates = jnp.zeros_like(p).at[jnp.arange(T)[:, None], top].set(
+            top_p / top_p.sum(-1, keepdims=True))[:, first:first + held]
+        h = jax.nn.silu(jnp.einsum("td,edf->tef", w["x"], w["gate"])) \
+            * jnp.einsum("td,edf->tef", w["x"], w["up"])
+        return jnp.einsum("te,tef,efd->td", gates, h, w["down"])
+
+    got = jax.grad(lambda w: (layer(w, first_expert=first).out
+                              * seed).sum())(share)[wrt]
+    want = jax.grad(lambda w: (dense(w) * seed).sum())(share)[wrt]
+    np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-4)
+
+
+@pytest.mark.parametrize("skewed", [False, True], ids=["even", "skewed"])
+def test_over_the_mesh_equals_one_device(hvd8, skewed):
+    """``axis_name``: 8 shards of 6 tokens and 2 experts each, every pair
+    through all-to-all and back, equal the single-device layer; skewed,
+    every pair of every shard goes to shard 0."""
+    w = weights(5, router_scale=0.3)
+    if skewed:
+        bias = np.zeros((D, E), np.float32)
+        bias[:, :2] = 0.3
+        w = dict(w, x=jnp.abs(w["x"]) + 0.5, router=w["router"] * 0.05
+                 + bias)
+    mesh = hvd8.mesh()
+
+    def shard(x, router, gate, up, down):
+        got = dropless_expert_ffn(x, router, gate, up, down, top_k=K,
+                                  axis_name="hvd")
+        return got.out, got.routed_here[None]
+
+    out, received = jax.jit(jax.shard_map(
+        shard, mesh=mesh,
+        in_specs=(P("hvd"), P(), P("hvd"), P("hvd"), P("hvd")),
+        out_specs=(P("hvd"), P("hvd")), check_vma=False))(
+            w["x"], w["router"], w["gate"], w["up"], w["down"])
+    want = layer(w)
+    np.testing.assert_allclose(out, want.out, rtol=2e-4, atol=2e-5)
+    assert int(received.sum()) == T * K
+    want_loop, _ = per_token_loop(w)
+    np.testing.assert_allclose(out, want_loop, rtol=2e-4, atol=2e-5)
+
+
+def test_gradients_over_the_mesh_equal_one_device(hvd8):
+    w = weights(6, router_scale=0.3)
+    seed = jnp.asarray(np.random.RandomState(7).randn(T, D), jnp.float32)
+
+    def loss_sharded(x, router, gate, up, down, seed):
+        return (dropless_expert_ffn(x, router, gate, up, down, top_k=K,
+                                    axis_name="hvd").out * seed).sum()[None]
+
+    def sharded(w):
+        return jax.shard_map(
+            loss_sharded, mesh=hvd8.mesh(),
+            in_specs=(P("hvd"), P(), P("hvd"), P("hvd"), P("hvd"),
+                      P("hvd")),
+            out_specs=P("hvd"), check_vma=False)(
+                w["x"], w["router"], w["gate"], w["up"], w["down"],
+                seed).sum()
+
+    got = jax.jit(jax.grad(sharded))(w)
+    want = jax.grad(lambda w: (layer(w).out * seed).sum())(w)
+    for name in ("x", "gate", "up", "down"):
+        np.testing.assert_allclose(got[name], want[name], rtol=2e-3,
+                                   atol=2e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("sizes", [[5, 3, 0, 8], [0, 0, 0, 0],
+                                   [64, 0, 0, 0], [10, 20, 30, 4],
+                                   [0, 1, 0, 40]])
+def test_grouped_product_and_its_gradients(sizes):
+    rng = np.random.RandomState(8)
+    m, k, n = 64, 16, 24
+    lhs = jnp.asarray(rng.randn(m, k), jnp.float32)
+    rhs = jnp.asarray(rng.randn(len(sizes), k, n), jnp.float32)
+    ends = np.cumsum(sizes)
+    total = int(ends[-1])
+    inside = jnp.asarray(np.arange(m) < total, jnp.float32)[:, None]
+    seed = jnp.asarray(rng.randn(m, n), jnp.float32) * inside
+
+    def loop(lhs, rhs):
+        out = jnp.zeros((m, n))
+        for g, size in enumerate(sizes):
+            rows = slice(ends[g] - size, ends[g])
+            out = out.at[rows].set(lhs[rows] @ rhs[g])
+        return out
+
+    sizes = jnp.asarray(sizes, jnp.int32)
+    got = grouped.gmm(lhs, rhs, sizes)
+    np.testing.assert_allclose(got[:total], loop(lhs, rhs)[:total],
+                               rtol=1e-5, atol=1e-5)
+    got = jax.grad(lambda l, r: (grouped.gmm(l, r, sizes) * seed).sum(),
+                   (0, 1))(lhs, rhs)
+    want = jax.grad(lambda l, r: (loop(l, r) * seed).sum(), (0, 1))(lhs, rhs)
+    np.testing.assert_allclose(got[0][:total], want[0][:total], rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_allclose(got[1], want[1], rtol=1e-5, atol=1e-5)
+    transposed = grouped.gmm(lhs, rhs.swapaxes(1, 2), sizes,
+                             transpose_rhs=True)
+    np.testing.assert_allclose(transposed[:total], loop(lhs, rhs)[:total],
+                               rtol=1e-5, atol=1e-5)

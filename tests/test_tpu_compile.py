@@ -31,7 +31,8 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 
 import horovod_tpu as hvd
 from horovod_tpu import parallel
-from horovod_tpu.parallel.flash import flash_attention
+from horovod_tpu.parallel.flash import block_diffusion_mask, flash_attention
+from horovod_tpu.parallel.grouped import gmm
 from horovod_tpu.serve.paged_attention import (SCALE_DTYPE,
                                                paged_decode_attention,
                                                paged_prefill_attention)
@@ -94,6 +95,69 @@ def test_flash_attention_compiles_for_v5e(one_chip, no_persistent_cache,
             argnums=(0, 1, 2))(q, k, v)
 
     _assert_kernel_compiles(fwd_bwd if backward else fwd, qkv, qkv, qkv)
+
+
+# SDAR-30B-A3B-Chat's attention as the benchmark cell runs it: one sequence
+# of 2 x 4,096 positions, 32 query heads on 4 key/value heads of 128, tiles
+# of 512; and its expert products: a sequence's buffer of 16,384 rows
+# through 16 experts of 2,048 x 768.
+SDAR_L, SDAR_H, SDAR_HKV, SDAR_D, SDAR_TILE = 4096, 32, 4, 128, 512
+SDAR_ROWS, SDAR_EXPERTS, SDAR_HIDDEN, SDAR_WIDTH = 16384, 16, 2048, 768
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+def test_block_diffusion_flash_compiles_for_v5e(one_chip,
+                                                no_persistent_cache,
+                                                backward):
+    """The block-diffusion mask and grouped key/value heads, forward and
+    the dQ and dK/dV kernels, at the published head sizes."""
+    def sds(heads):
+        return jax.ShapeDtypeStruct((1, 2 * SDAR_L, heads, SDAR_D),
+                                    jnp.bfloat16, sharding=one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention(
+            q, k, v, mask_mode=block_diffusion_mask(4, SDAR_L),
+            block_q=SDAR_TILE, block_k=SDAR_TILE, interpret=False)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(
+            lambda *a: fwd(*a).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    fn = fwd_bwd if backward else fwd
+    text = jax.jit(fn).lower(sds(SDAR_H), sds(SDAR_HKV),
+                             sds(SDAR_HKV)).compile().as_text()
+    kernels = [line for line in text.split("\n")
+               if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(kernels) == (3 if backward else 1)
+    # K and V go into every kernel as they are, 4 heads: no copy of them
+    # at the 32 query heads' size is made.
+    for line in kernels:
+        assert line.count(f"bf16[{SDAR_HKV},{2 * SDAR_L},{SDAR_D}]") >= 2
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+def test_grouped_product_compiles_for_v5e(one_chip, no_persistent_cache,
+                                          backward):
+    """``parallel/grouped.py``: the gated products' shapes, with the
+    sequential grid dimension as long as the group sizes say."""
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fwd(rows, weights, sizes):
+        return gmm(rows, weights, sizes, interpret=False)
+
+    def fwd_bwd(rows, weights, sizes):
+        return jax.grad(
+            lambda r, w: fwd(r, w, sizes).astype(jnp.float32).sum(),
+            argnums=(0, 1))(rows, weights)
+
+    _assert_kernel_compiles(
+        fwd_bwd if backward else fwd,
+        sds((SDAR_ROWS, SDAR_HIDDEN)),
+        sds((SDAR_EXPERTS, SDAR_HIDDEN, SDAR_WIDTH)),
+        sds((SDAR_EXPERTS,), jnp.int32))
 
 
 @pytest.mark.parametrize("kv", ["f32", "bf16", "int8"])
