@@ -5,28 +5,33 @@ math of ring attention, or the full-sequence-per-head-subset attention of
 Ulysses) and the dense encoder attention of BERT/GPT are the hot loops this
 kernel serves.  FlashAttention-2 structure, mapped onto the Mosaic pipeline:
 
-* **Forward** — grid ``(B*H, q_blocks, j)`` where ``j`` walks the K/V
-  blocks that the mask lets this query block read, an ``arbitrary``
-  (sequential) grid dimension.  Which blocks those are is static
-  (``tile_tables``: numpy tables made from ``block_contributes`` and
-  ``block_full``, handed to the kernel as scalar-prefetch operands that its
-  ``BlockSpec`` index maps read), so a block wholly outside the mask costs
-  no product, no copy and no grid step beyond the longest row, and a block
-  wholly inside skips the mask's arithmetic.  Each K/V block is a
-  grid-indexed ``BlockSpec``, so Mosaic double-buffers the HBM→VMEM DMA of
-  block *i+1* against the MXU compute of block *i* automatically — the
-  whole online-softmax state (running max / sum / accumulator) lives in
-  VMEM scratch that persists across the sequential dimension.  The [S, S]
-  score matrix never touches HBM.  Emits the per-row logsumexp as a
-  residual for the backward pass.
-* **Backward** — two kernels of the same shape (FlashAttention-2 split):
-  one accumulates dQ streaming over K/V blocks, one accumulates dK/dV
-  streaming over Q blocks; both recompute the probabilities from the saved
-  logsumexp instead of materializing them.
+* **Forward** — grid ``(B*H, t)`` where ``t``, an ``arbitrary``
+  (sequential) grid dimension, walks one flat list of exactly the (query
+  tile, key tile) pairs the mask keeps, a query tile's key tiles one after
+  the other.  The list is static (``tile_lists``: a numpy table made from
+  ``block_contributes`` and ``block_full``, handed to the kernel as a
+  scalar-prefetch operand that its ``BlockSpec`` index maps read): for
+  every step the query tile, the key tile, whether the tile crosses the
+  mask's edge, and whether it is the first or the last of its query tile.
+  So a tile wholly outside the mask costs no product, no copy and no grid
+  step, and a tile wholly inside skips the mask's arithmetic.  The output
+  block stays resident while consecutive steps name the same query tile
+  and is written when the tile changes; the scaled query tile and the
+  online-softmax state (running max / sum / accumulator) live in VMEM
+  scratch, set up on a query tile's first step and flushed on its last.
+  Each K/V block is a grid-indexed ``BlockSpec``, so Mosaic double-buffers
+  the HBM→VMEM DMA of step *t+1* against the MXU compute of step *t*
+  automatically.  The [S, S] score matrix never touches HBM.  Emits the
+  per-row logsumexp as a residual for the backward pass.
+* **Backward** — two kernels (FlashAttention-2 split): one accumulates dQ
+  over the same list as the forward pass, one accumulates dK/dV over the
+  list's transpose (a key tile's query tiles one after the other); both
+  recompute the probabilities from the saved logsumexp instead of
+  materializing them.
 * **Grouped key/value heads** — ``k`` and ``v`` may have fewer heads than
   ``q``: the index maps send query head ``h`` to key/value head ``h //
-  group``, and the dK/dV kernel's sequential dimension walks the group's
-  query heads as well as the query blocks, so K and V are never repeated
+  group``, and the dK/dV kernel's list walks, for a key tile, the group's
+  query heads and for each its query tiles, so K and V are never repeated
   in HBM and dK/dV are summed over the group in VMEM.
 * Products run in the operands' dtype (bf16 stays bf16 on the MXU) with
   float32 accumulation; softmax statistics are float32.
@@ -128,8 +133,8 @@ def block_contributes(mode, q_lo, q_hi, k_lo, k_hi=None):
         return k_lo <= q_hi
     if mode == MASK_STRICT:
         return k_lo < q_hi
-    # Block diffusion: static positions only (the kernels walk tables made
-    # from this, ``tile_tables``).  The noised and the clean part of each
+    # Block diffusion: static positions only (the kernels walk lists made
+    # from this, ``tile_lists``).  The noised and the clean part of each
     # span, as block indices:
     _, block_length, length = mode
     q_has_noised, q_has_clean = q_lo < length, q_hi >= length
@@ -149,7 +154,7 @@ def block_contributes(mode, q_lo, q_hi, k_lo, k_hi=None):
 
 def block_full(mode, q_lo, q_hi, k_lo, k_hi):
     """Whether every pair of the tile is kept, so that the kernels need
-    not apply the mask to it.  Static positions only (the tile tables)."""
+    not apply the mask to it.  Static positions only (the tile lists)."""
     if mode == MASK_NONE:
         return True
     if mode == MASK_CAUSAL:
@@ -171,37 +176,73 @@ def block_full(mode, q_lo, q_hi, k_lo, k_hi):
     return not k_noised and k_last <= q_first
 
 
+#: Rows of a tile list (``tile_lists``); a column is one grid step.
+ROW, TILE, EDGE, FIRST, LAST, HEAD = range(6)
+
+
 @functools.lru_cache(maxsize=None)
-def tile_tables(mode, seq: int, block_q: int, block_k: int):
-    """What the kernels walk: for every query tile the key tiles that
-    contribute, and for every key tile the query tiles, as ``int32`` numpy
-    arrays ``(k_of_q [nq, max], k_flag, q_of_k [nk, max], q_flag)``.  A
-    flag is 2 where the tile is wholly inside the mask, 1 where the mask
-    has to be applied, 0 for padding; padding repeats the row's last tile,
-    so that it costs no copy.  Tiles outside the mask are in no row: they
-    cost neither a product nor a grid step beyond the longest row."""
+def _mask_tiles(mode, seq: int, block_q: int, block_k: int):
+    """``(kept, edge)``, boolean ``[nq, nk]``: the tiles that hold a pair
+    the mask keeps, and those of them the mask cuts through.  A query or
+    key tile the mask leaves nothing (``MASK_STRICT`` on tiles of one
+    position) keeps its first tile, on the edge: every pair of it is
+    masked, and its output is written as zeros."""
     nq, nk = seq // block_q, seq // block_k
-    flags = np.zeros((nq, nk), np.int32)
+    kept, edge = np.zeros((nq, nk), bool), np.zeros((nq, nk), bool)
     for qi in range(nq):
         q_lo, q_hi = qi * block_q, (qi + 1) * block_q - 1
         for ki in range(nk):
             k_lo, k_hi = ki * block_k, (ki + 1) * block_k - 1
             if block_contributes(mode, q_lo, q_hi, k_lo, k_hi):
-                flags[qi, ki] = 2 if block_full(mode, q_lo, q_hi, k_lo,
-                                                k_hi) else 1
+                kept[qi, ki] = True
+                edge[qi, ki] = not block_full(mode, q_lo, q_hi, k_lo, k_hi)
+    empty = np.zeros_like(kept)
+    empty[~kept.any(axis=1), 0] = empty[0, ~kept.any(axis=0)] = True
+    return kept | empty, edge | empty
 
-    def rows(flags):
-        width = max(1, int((flags > 0).sum(axis=1).max()))
-        index = np.zeros((flags.shape[0], width), np.int32)
-        flag = np.zeros_like(index)
-        for r, row in enumerate(flags):
-            kept = np.flatnonzero(row)
-            index[r, :len(kept)] = kept
-            index[r, len(kept):] = kept[-1] if len(kept) else 0
-            flag[r, :len(kept)] = row[kept]
-        return index, flag
 
-    return rows(flags) + rows(flags.T)
+@functools.lru_cache(maxsize=None)
+def tile_lists(mode, seq: int, block_q: int, block_k: int, group: int = 1):
+    """What the kernels walk: ``(by_query, by_key)``, two ``int32`` numpy
+    tables with one column for every grid step and the rows ``ROW``,
+    ``TILE``, ``EDGE``, ``FIRST``, ``LAST``, ``HEAD``.
+
+    ``by_query`` (forward and dQ) lists every tile the mask keeps once, in
+    the order of the query tiles: ``ROW`` is the query tile, ``TILE`` the
+    key tile it reads, ``EDGE`` 1 where the mask has to be applied and 0
+    where the tile lies wholly inside it, ``FIRST`` and ``LAST`` 1 on the
+    first and the last step of the query tile; ``HEAD`` is 0.  ``by_key``
+    (dK/dV) is its transpose for ``group`` query heads a key/value head:
+    ``ROW`` is the key tile, and its steps are the heads of the group
+    (``HEAD``) one after the other, for each the query tiles (``TILE``)
+    that read the key tile.  Tiles outside the mask are in neither list:
+    they cost no product, no copy and no grid step."""
+    kept, edge = _mask_tiles(mode, seq, block_q, block_k)
+
+    def walk(kept, edge, heads):
+        steps = []
+        for row in range(kept.shape[0]):
+            visits = [(head, tile) for head in range(heads)
+                      for tile in np.flatnonzero(kept[row])]
+            steps += [(row, tile, edge[row, tile], n == 0,
+                       n == len(visits) - 1, head)
+                      for n, (head, tile) in enumerate(visits)]
+        return np.asarray(steps, np.int32).T
+
+    return walk(kept, edge, 1), walk(kept.T, edge.T, group)
+
+
+def grid_steps(mode, seq: int, block_q: int, block_k: int, heads: int,
+               kv_heads: int):
+    """``(steps, tiles)`` of one sequence through the three kernels: the
+    grid steps they launch along their sequential dimension (the lengths
+    of the lists their grids are sized from) and the tiles they compute
+    (those the mask keeps, once in each kernel for every query head)."""
+    by_query, by_key = tile_lists(mode, seq, block_q, block_k,
+                                  heads // kv_heads)
+    kept, _ = _mask_tiles(mode, seq, block_q, block_k)
+    return (2 * heads * by_query.shape[1] + kv_heads * by_key.shape[1],
+            3 * heads * int(kept.sum()))
 
 
 def online_softmax_block(s, v, m_ref, l_ref, acc_ref):
@@ -251,10 +292,11 @@ def _col_to_row(x):
     return x.T[:1]
 
 
-def _row_to_col(row):
-    """``[1, Bq]`` stored statistic → ``[Bq, 1]`` column that broadcasts
-    against a ``[Bq, Bk]`` score tile."""
-    return jnp.broadcast_to(row, (LANES, row.shape[1])).T[:, :1]
+def _row_to_lanes(row):
+    """``[1, Bq]`` stored statistic → ``[Bq, LANES]``, lane-broadcast;
+    its ``[:, :1]`` is the column that broadcasts against a ``[Bq, Bk]``
+    score tile."""
+    return jnp.broadcast_to(row, (LANES, row.shape[1])).T
 
 
 def _scaled(q_ref, scale):
@@ -269,91 +311,95 @@ def _scores(q, k):
         preferred_element_type=jnp.float32)           # [Bq, Bk]
 
 
-def _on_tiles(flag, step):
-    """Run ``step(masked)`` for a tile the tables list: with the mask
-    where the tile crosses its edge (flag 1), without where it lies wholly
-    inside (flag 2), not at all on a row's padding (flag 0)."""
-    pl.when(flag == 1)(functools.partial(step, True))
-    pl.when(flag == 2)(functools.partial(step, False))
+def _on_tile(edge, step):
+    """Run ``step(masked)`` for the tile of this grid step: with the mask
+    where the tile crosses its edge, without where it lies wholly
+    inside."""
+    pl.when(edge == 1)(functools.partial(step, True))
+    pl.when(edge == 0)(functools.partial(step, False))
 
 
-def _fwd_kernel(kidx_ref, kflag_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc, m, l, *, scale: float, mask_mode, block_q: int,
-                block_k: int, num_j: int):
-    qi, j = pl.program_id(1), pl.program_id(2)
+def _fwd_kernel(tiles_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, qs, acc, m,
+                l, *, scale: float, mask_mode, block_q: int, block_k: int):
+    t = pl.program_id(1)
 
-    @pl.when(j == 0)
+    @pl.when(tiles_ref[FIRST, t] == 1)
     def _init():
+        qs[...] = _scaled(q_ref, scale)
         acc[...] = jnp.zeros_like(acc)
         m[...] = jnp.full_like(m, NEG_INF)
         l[...] = jnp.zeros_like(l)
 
     def _step(masked):
-        s = _scores(_scaled(q_ref, scale), k_ref[0])
+        s = _scores(qs[...], k_ref[0])
         if masked:
-            s = causal_mask(s, qi * block_q, kidx_ref[qi, j] * block_k,
-                            mask_mode)
+            s = causal_mask(s, tiles_ref[ROW, t] * block_q,
+                            tiles_ref[TILE, t] * block_k, mask_mode)
         online_softmax_block(s, v_ref[0], m, l, acc)
 
-    _on_tiles(kflag_ref[qi, j], _step)
+    _on_tile(tiles_ref[EDGE, t], _step)
 
-    @pl.when(j == num_j - 1)
+    @pl.when(tiles_ref[LAST, t] == 1)
     def _flush():
         out, lse = online_softmax_flush(m, l, acc)
         o_ref[0] = out.astype(o_ref.dtype)
         lse_ref[0] = _col_to_row(lse)
 
 
-def _probs_and_ds(q, k_ref, v_ref, do_ref, lse_ref, delta_ref, q_offset,
-                  k_offset, mask_mode, masked):
+def _probs_and_ds(q, k_ref, v_ref, do_ref, lse, delta, q_offset, k_offset,
+                  mask_mode, masked):
     """``(p, ds)`` of one tile, both ``[Bq, Bk]`` float32, recomputed from
-    the saved logsumexp."""
+    the saved logsumexp; ``lse`` and ``delta`` are ``[Bq, 1]`` columns."""
     s = _scores(q, k_ref[0])
     if masked:
         s = causal_mask(s, q_offset, k_offset, mask_mode)
-    p = jnp.exp(s - _row_to_col(lse_ref[0]))
+    p = jnp.exp(s - lse)
     dp = jax.lax.dot_general(
         do_ref[0], v_ref[0], dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    return p, p * (dp - _row_to_col(delta_ref[0]))
+    return p, p * (dp - delta)
 
 
-def _bwd_dq_kernel(kidx_ref, kflag_ref, q_ref, k_ref, v_ref, do_ref,
-                   lse_ref, delta_ref, dq_ref, dq_acc, *, scale: float,
-                   mask_mode, block_q: int, block_k: int, num_j: int):
-    qi, j = pl.program_id(1), pl.program_id(2)
+def _bwd_dq_kernel(tiles_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                   delta_ref, dq_ref, qs, lse_lanes, delta_lanes, dq_acc, *,
+                   scale: float, mask_mode, block_q: int, block_k: int):
+    t = pl.program_id(1)
 
-    @pl.when(j == 0)
+    # What is constant along a query tile's key tiles is made on its
+    # first step: the scaled queries and the two statistics as columns.
+    @pl.when(tiles_ref[FIRST, t] == 1)
     def _init():
+        qs[...] = _scaled(q_ref, scale)
+        lse_lanes[...] = _row_to_lanes(lse_ref[0])
+        delta_lanes[...] = _row_to_lanes(delta_ref[0])
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def _step(masked):
         _, ds = _probs_and_ds(
-            _scaled(q_ref, scale), k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            qi * block_q, kidx_ref[qi, j] * block_k, mask_mode, masked)
+            qs[...], k_ref, v_ref, do_ref, lse_lanes[:, :1],
+            delta_lanes[:, :1], tiles_ref[ROW, t] * block_q,
+            tiles_ref[TILE, t] * block_k, mask_mode, masked)
         dq_acc[...] += jax.lax.dot_general(
             ds.astype(k_ref.dtype), k_ref[0],
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _on_tiles(kflag_ref[qi, j], _step)
+    _on_tile(tiles_ref[EDGE, t], _step)
 
-    @pl.when(j == num_j - 1)
+    @pl.when(tiles_ref[LAST, t] == 1)
     def _flush():
         dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(qidx_ref, qflag_ref, q_ref, k_ref, v_ref, do_ref,
-                    lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                    scale: float, mask_mode, block_q: int, block_k: int,
-                    num_j: int, width: int):
-    # The sequential dimension walks the group's query heads and, for
-    # each, the query tiles that read this key tile: dK and dV of a
-    # key/value head are summed over its query heads here, in VMEM.
-    kb, j = pl.program_id(1), pl.program_id(2)
-    jq = j % width
+def _bwd_dkv_kernel(tiles_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                    delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                    scale: float, mask_mode, block_q: int, block_k: int):
+    # A key tile's steps walk the group's query heads and, for each, the
+    # query tiles that read the key tile: dK and dV of a key/value head
+    # are summed over its query heads here, in VMEM.
+    t = pl.program_id(1)
 
-    @pl.when(j == 0)
+    @pl.when(tiles_ref[FIRST, t] == 1)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -361,8 +407,10 @@ def _bwd_dkv_kernel(qidx_ref, qflag_ref, q_ref, k_ref, v_ref, do_ref,
     def _step(masked):
         q = _scaled(q_ref, scale)
         p, ds = _probs_and_ds(
-            q, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            qidx_ref[kb, jq] * block_q, kb * block_k, mask_mode, masked)
+            q, k_ref, v_ref, do_ref, _row_to_lanes(lse_ref[0])[:, :1],
+            _row_to_lanes(delta_ref[0])[:, :1],
+            tiles_ref[TILE, t] * block_q, tiles_ref[ROW, t] * block_k,
+            mask_mode, masked)
         dv_acc[...] += jax.lax.dot_general(
             p.astype(do_ref.dtype), do_ref[0],
             dimension_numbers=(((0,), (0,)), ((), ())),
@@ -372,9 +420,9 @@ def _bwd_dkv_kernel(qidx_ref, qflag_ref, q_ref, k_ref, v_ref, do_ref,
             dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)       # [Bk, D]
 
-    _on_tiles(qflag_ref[kb, jq], _step)
+    _on_tile(tiles_ref[EDGE, t], _step)
 
-    @pl.when(j == num_j - 1)
+    @pl.when(tiles_ref[LAST, t] == 1)
     def _flush():
         # q was pre-scaled, so dk_acc already carries the scale factor.
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
@@ -389,7 +437,7 @@ def _compiler_params(interpret):
     if interpret:
         return None
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+        dimension_semantics=("parallel", "arbitrary"))
 
 
 def _stat_spec(block_q, index_map):
@@ -412,6 +460,14 @@ def vary_like(x, like):
     return jax.lax.pcast(x, missing, to="varying") if missing else x
 
 
+def _by_query_maps(group):
+    """Index maps of a kernel that walks ``tile_lists``' ``by_query``:
+    ``(query tile, key/value tile, statistic)`` of grid step ``t``."""
+    return (lambda bh, t, tiles: (bh, tiles[ROW, t], 0),
+            lambda bh, t, tiles: (bh // group, tiles[TILE, t], 0),
+            lambda bh, t, tiles: (bh, 0, tiles[ROW, t]))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, mask_mode, scale, block_q, block_k, interpret):
     out, _ = _flash_fwd(q, k, v, mask_mode, scale, block_q, block_k,
@@ -425,21 +481,16 @@ def _flash_fwd(q, k, v, mask_mode, scale, block_q, block_k, interpret):
     ``h // (H / Hkv)`` through the index maps, no copy."""
     BH, S, D = q.shape
     group = BH // k.shape[0]
-    kidx, kflag, _, _ = tile_tables(mask_mode, S, block_q, block_k)
-    num_j = kidx.shape[1]
-    kernel = functools.partial(_fwd_kernel, scale=scale,
-                               mask_mode=mask_mode,
-                               block_q=block_q, block_k=block_k,
-                               num_j=num_j)
-    q_map = lambda bh, qi, j, kidx, kflag: (bh, qi, 0)
-    kv_map = lambda bh, qi, j, kidx, kflag: (bh // group, kidx[qi, j], 0)
+    tiles, _ = tile_lists(mask_mode, S, block_q, block_k, group)
+    q_map, kv_map, stat_map = _by_query_maps(group)
     out, lse = pl.pallas_call(
-        kernel,
+        functools.partial(_fwd_kernel, scale=scale, mask_mode=mask_mode,
+                          block_q=block_q, block_k=block_k),
         out_shape=[_out_struct((BH, S, D), q.dtype, q),
                    _out_struct((BH, 1, S), jnp.float32, q)],
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(BH, S // block_q, num_j),
+            num_scalar_prefetch=1,
+            grid=(BH, tiles.shape[1]),
             in_specs=[
                 pl.BlockSpec((1, block_q, D), q_map),
                 pl.BlockSpec((1, block_k, D), kv_map),
@@ -447,10 +498,10 @@ def _flash_fwd(q, k, v, mask_mode, scale, block_q, block_k, interpret):
             ],
             out_specs=[
                 pl.BlockSpec((1, block_q, D), q_map),
-                _stat_spec(block_q,
-                           lambda bh, qi, j, kidx, kflag: (bh, 0, qi)),
+                _stat_spec(block_q, stat_map),
             ],
             scratch_shapes=[
+                pltpu.VMEM((block_q, D), q.dtype),
                 pltpu.VMEM((block_q, D), jnp.float32),
                 pltpu.VMEM((block_q, LANES), jnp.float32),
                 pltpu.VMEM((block_q, LANES), jnp.float32),
@@ -458,7 +509,7 @@ def _flash_fwd(q, k, v, mask_mode, scale, block_q, block_k, interpret):
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
         name="hvd_flash_fwd",
-    )(vary_like(kidx, q), vary_like(kflag, q), q, k, v)
+    )(vary_like(tiles, q), q, k, v)
     # Named for ``jax.checkpoint`` policies: a caller that recomputes a
     # layer in its backward pass can keep these two (``save_only_these_
     # names(*SAVED)``) and spare the forward kernel's second run.
@@ -485,20 +536,17 @@ def _run_bwd_kernels(q, k, v, do, lse, delta, mask_mode, scale,
     ``delta``; see ``_flash_lse_bwd``)."""
     BH, S, D = q.shape
     group = BH // k.shape[0]
-    kidx, kflag, qidx, qflag = tile_tables(mask_mode, S, block_q, block_k)
+    by_query, by_key = tile_lists(mask_mode, S, block_q, block_k, group)
     lse, delta = lse.reshape(BH, 1, S), delta.reshape(BH, 1, S)
 
-    q_map = lambda bh, qi, j, kidx, kflag: (bh, qi, 0)
-    kv_map = lambda bh, qi, j, kidx, kflag: (bh // group, kidx[qi, j], 0)
-    stat_map = lambda bh, qi, j, kidx, kflag: (bh, 0, qi)
+    q_map, kv_map, stat_map = _by_query_maps(group)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, mask_mode=mask_mode,
-                          block_q=block_q, block_k=block_k,
-                          num_j=kidx.shape[1]),
+                          block_q=block_q, block_k=block_k),
         out_shape=_out_struct((BH, S, D), q.dtype, q),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(BH, S // block_q, kidx.shape[1]),
+            num_scalar_prefetch=1,
+            grid=(BH, by_query.shape[1]),
             in_specs=[
                 pl.BlockSpec((1, block_q, D), q_map),
                 pl.BlockSpec((1, block_k, D), kv_map),
@@ -508,30 +556,31 @@ def _run_bwd_kernels(q, k, v, do, lse, delta, mask_mode, scale,
                 _stat_spec(block_q, stat_map),
             ],
             out_specs=pl.BlockSpec((1, block_q, D), q_map),
-            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((block_q, D), q.dtype),
+                            pltpu.VMEM((block_q, LANES), jnp.float32),
+                            pltpu.VMEM((block_q, LANES), jnp.float32),
+                            pltpu.VMEM((block_q, D), jnp.float32)]),
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
         name="hvd_flash_bwd_dq",
-    )(vary_like(kidx, q), vary_like(kflag, q), q, k, v, do, lse, delta)
+    )(vary_like(by_query, q), q, k, v, do, lse, delta)
 
-    # One program per key/value head and key tile; the sequential
-    # dimension is (query head of the group) x (query tile of the row).
-    width = qidx.shape[1]
-    q_of = lambda bkv, kb, j, qidx, qflag: (
-        bkv * group + j // width, qidx[kb, j % width], 0)
-    kv_of = lambda bkv, kb, j, qidx, qflag: (bkv, kb, 0)
-    stat_of = lambda bkv, kb, j, qidx, qflag: (
-        bkv * group + j // width, 0, qidx[kb, j % width])
+    # One program per key/value head; the sequential dimension is (key
+    # tile) x (query head of the group) x (query tile that reads it).
+    q_of = lambda bkv, t, tiles: (
+        bkv * group + tiles[HEAD, t], tiles[TILE, t], 0)
+    kv_of = lambda bkv, t, tiles: (bkv, tiles[ROW, t], 0)
+    stat_of = lambda bkv, t, tiles: (
+        bkv * group + tiles[HEAD, t], 0, tiles[TILE, t])
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale,
                           mask_mode=mask_mode, block_q=block_q,
-                          block_k=block_k, num_j=group * width,
-                          width=width),
+                          block_k=block_k),
         out_shape=[_out_struct(k.shape, k.dtype, k),
                    _out_struct(v.shape, v.dtype, v)],
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(k.shape[0], S // block_k, group * width),
+            num_scalar_prefetch=1,
+            grid=(k.shape[0], by_key.shape[1]),
             in_specs=[
                 pl.BlockSpec((1, block_q, D), q_of),
                 pl.BlockSpec((1, block_k, D), kv_of),
@@ -549,7 +598,7 @@ def _run_bwd_kernels(q, k, v, do, lse, delta, mask_mode, scale,
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
         name="hvd_flash_bwd_dkv",
-    )(vary_like(qidx, q), vary_like(qflag, q), q, k, v, do, lse, delta)
+    )(vary_like(by_key, q), q, k, v, do, lse, delta)
     return dq, dk, dv
 
 

@@ -98,41 +98,132 @@ def test_heads_that_do_not_divide_are_refused():
 
 
 @pytest.mark.parametrize("mode", [
-    MASK_NONE, MASK_CAUSAL, MASK_STRICT, block_diffusion_mask(4, 48),
-    block_diffusion_mask(4, 64), block_diffusion_mask(16, 64)],
-    ids=["none", "causal", "strict", "bd4x48", "bd4x64", "bd16x64"])
+    block_diffusion_mask(4, 64), MASK_CAUSAL], ids=["bd4x64", "causal"])
+@pytest.mark.parametrize("block_q,block_k", [(32, 16), (16, 32)])
+def test_eight_heads_a_key_value_head_on_rectangular_tiles(mode, block_q,
+                                                           block_k):
+    """The dK/dV walk crosses the group's eight query heads inside every
+    key tile: forward and all three gradients against the dense
+    reference."""
+    q, k, v = qkv(5, 128, kv_heads=1)
+    weight = jnp.asarray(np.random.RandomState(6).randn(
+        B, 128, H, D).astype(np.float32))
+    mask = dense_mask(mode, 128)
+
+    def outputs_and_gradients(attend):
+        def loss(*a):
+            out = attend(*a)
+            return (out * weight).sum(), out
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return out, grads
+
+    out, got = outputs_and_gradients(lambda *a: flash_attention(
+        *a, mask_mode=mode, block_q=block_q, block_k=block_k))
+    want_out, want = outputs_and_gradients(
+        lambda *a: dense_attention(*a, mask))
+    np.testing.assert_allclose(out, want_out, rtol=2e-4, atol=2e-5)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4)
+
+
+MODES = [MASK_NONE, MASK_CAUSAL, MASK_STRICT, block_diffusion_mask(4, 48),
+         block_diffusion_mask(4, 64), block_diffusion_mask(16, 64)]
+MODE_IDS = ["none", "causal", "strict", "bd4x48", "bd4x64", "bd16x64"]
+
+
+def seq_of(mode):
+    return 2 * mode[2] if isinstance(mode, tuple) else 96
+
+
+@pytest.mark.parametrize("mode", MODES, ids=MODE_IDS)
 @pytest.mark.parametrize("block_q,block_k", [(32, 32), (32, 16), (16, 32)])
-def test_tile_tables_hold_the_tiles_the_mask_touches(mode, block_q, block_k):
-    """A tile is in the tables iff the dense mask keeps a pair of it, is
-    flagged full iff it keeps all, and the two tables are each other's
-    transpose."""
-    seq = 2 * mode[2] if isinstance(mode, tuple) else 96
+def test_tile_lists_hold_the_tiles_the_mask_touches(mode, block_q, block_k):
+    """A tile is in each list exactly once iff the dense mask keeps a pair
+    of it, on the edge iff it does not keep all; a list's rows are
+    contiguous, in order, their tiles ascending, their first and last steps
+    marked; the dK/dV list is the transpose of the other, its rows walked
+    once for every head of the group."""
+    seq, group = seq_of(mode), 3
     mask = dense_mask(mode, seq)
-    kidx, kflag, qidx, qflag = flash.tile_tables(mode, seq, block_q,
-                                                 block_k)
-    seen = np.zeros((seq // block_q, seq // block_k), int)
-    for qi in range(seq // block_q):
-        for j in range(kidx.shape[1]):
-            if kflag[qi, j]:
-                seen[qi, kidx[qi, j]] = kflag[qi, j]
-    seen_t = np.zeros_like(seen)
-    for ki in range(seq // block_k):
-        for j in range(qidx.shape[1]):
-            if qflag[ki, j]:
-                seen_t[qidx[ki, j], ki] = qflag[ki, j]
-    np.testing.assert_array_equal(seen, seen_t)
-    for qi in range(seq // block_q):
-        for ki in range(seq // block_k):
+    nq, nk = seq // block_q, seq // block_k
+    want = np.zeros((nq, nk), int)             # 0 out, 1 edge, 2 full
+    for qi in range(nq):
+        for ki in range(nk):
             tile = mask[qi * block_q:(qi + 1) * block_q,
                         ki * block_k:(ki + 1) * block_k]
-            want = 2 if tile.all() else 1 if tile.any() else 0
-            assert seen[qi, ki] == want, (qi, ki)
+            want[qi, ki] = 2 if tile.all() else 1 if tile.any() else 0
+    by_query, by_key = flash.tile_lists(mode, seq, block_q, block_k, group)
+    assert by_query.dtype == by_key.dtype == np.int32
+
+    def check(steps, want, heads):
+        row, tile, edge, first, last, head = steps
+        # Every step in the order (row, head, tile), none twice.
+        order = list(zip(row, head, tile))
+        assert order == sorted(set(order))
+        assert set(edge) <= {0, 1}
+        seen = np.zeros((heads,) + want.shape, int)
+        seen[head, row, tile] = 2 - edge
+        for h in range(heads):
+            np.testing.assert_array_equal(seen[h], want)
+        # First and last mark the ends of a row's run of steps.
+        starts = np.flatnonzero(np.diff(row, prepend=-1))
+        np.testing.assert_array_equal(np.flatnonzero(first), starts)
+        np.testing.assert_array_equal(
+            np.flatnonzero(last),
+            np.append(starts[1:], len(row)) - 1)
+        assert sorted(set(row)) == list(range(want.shape[0]))
+
+    check(by_query, want, 1)
+    check(by_key, want.T, group)
+    assert by_query.shape == (6, (want > 0).sum())
+    assert by_key.shape == (6, group * (want > 0).sum())
+
+
+@pytest.mark.parametrize("mode", MODES, ids=MODE_IDS)
+def test_grid_steps_are_the_lists_lengths_and_the_tiles(mode):
+    """The exported count: the kernels' sequential grid dimensions are the
+    lists' lengths, and no step is left that computes nothing."""
+    seq, heads, kv_heads = seq_of(mode), 8, 2
+    by_query, by_key = flash.tile_lists(mode, seq, 32, 16,
+                                        heads // kv_heads)
+    kept = sum(dense_mask(mode, seq)[qi:qi + 32, ki:ki + 16].any()
+               for qi in range(0, seq, 32) for ki in range(0, seq, 16))
+    steps, tiles = flash.grid_steps(mode, seq, 32, 16, heads, kv_heads)
+    assert steps == 2 * heads * by_query.shape[1] \
+        + kv_heads * by_key.shape[1]
+    assert tiles == 3 * heads * kept == steps
+    if mode == MASK_NONE:                  # the full rectangle, as before
+        assert by_query.shape[1] == (seq // 32) * (seq // 16)
+
+
+def test_a_row_the_mask_leaves_empty_is_written_as_zeros():
+    """``MASK_STRICT`` on tiles of one position: the first query reads no
+    key and the last key is read by no query; each keeps one masked tile
+    (of query 0), so that the row's output is written."""
+    by_query, by_key = flash.tile_lists(MASK_STRICT, 4, 1, 1)
+    assert by_query.shape == by_key.shape == (6, 6 + 2)
+    np.testing.assert_array_equal(
+        by_query[:, :2].T, [[0, 0, 1, 1, 0, 0], [0, 3, 1, 0, 1, 0]])
+    q, k, v = qkv(7, 4)
+    out = flash_attention(q, k, v, mask_mode=MASK_STRICT, block_q=1,
+                          block_k=1)
+    want = dense_attention(q[:, 1:], k, v, dense_mask(MASK_STRICT, 4)[1:])
+    np.testing.assert_array_equal(out[:, 0], 0)
+    np.testing.assert_allclose(out[:, 1:], want, rtol=2e-4, atol=2e-5)
 
 
 def test_block_diffusion_keeps_a_quarter_of_the_tiles():
     """At the benchmark's sizes: 2L = 8,192 positions, blocks of 4, tiles
-    of 512: 80 of 256 tiles hold a kept pair, 24 of them on the edge."""
-    _, kflag, _, _ = flash.tile_tables(block_diffusion_mask(4, 4096), 8192,
-                                       512, 512)
-    assert (kflag > 0).sum() == 80 and (kflag == 1).sum() == 24
-    assert kflag.shape == (16, 9)
+    of 512: 80 of 256 tiles hold a kept pair, 24 of them on the edge, and
+    every kernel takes 80 steps a query head: 2.27 a tile went into the
+    rectangles padded to the longest row ((16, 9) and (16, 16))."""
+    mode = block_diffusion_mask(4, 4096)
+    by_query, by_key = flash.tile_lists(mode, 8192, 512, 512, 8)
+    assert by_query.shape[1] == 80 and by_query[flash.EDGE].sum() == 24
+    assert by_key.shape[1] == 8 * 80
+    assert flash.grid_steps(mode, 8192, 512, 512, 32, 4) == (7680, 7680)
+    per_row = np.bincount(by_query[flash.ROW])
+    np.testing.assert_array_equal(
+        per_row, list(range(2, 10)) + list(range(1, 9)))

@@ -1,0 +1,101 @@
+#!/usr/bin/env python3
+"""The three flash kernels alone on the chip, per call and per tile.
+
+    python3 tools/flash_tile_times.py [--against other/flash.py ...]
+
+One sequence at the ``sdar30b-train-blockdiff-4k`` cell's sizes (8,192
+positions, 32 query heads on 4 key/value heads of 128, tiles of 512)
+through ``flash_attention`` forward and backward under block diffusion,
+``MASK_CAUSAL`` and ``MASK_NONE``; times are the kernels' own events in a
+device trace of ten calls (``benchmarks/harness/trace.py``).  ``--against``
+names further copies of ``parallel/flash.py`` (a parent's, a variant's) to
+time beside this tree's in the same process, and checks their outputs and
+gradients against this tree's bit for bit.  Needs the TPU: a CPU run has
+no device plane and prints no time.  PERF.md section 6, PR 28, has the
+numbers this printed.
+"""
+
+import argparse
+import collections
+import importlib.util
+import os
+import re
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+
+SEQ, HEADS, KV_HEADS, HEAD_DIM, TILE, CALLS = 8192, 32, 4, 128, 512, 10
+
+
+def load(path):
+    name = "flash_" + re.sub(r"\W", "_", os.path.abspath(path))
+    spec = importlib.util.spec_from_file_location(name, path)
+    sys.modules[name] = module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def main():
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from harness import trace as tracing
+
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--against", nargs="*", default=[])
+    paths = [os.path.join(ROOT, "horovod_tpu", "parallel", "flash.py")] \
+        + ap.parse_args().against
+    rng = np.random.RandomState(0)
+    q, k, v, weight = (jnp.asarray(rng.randn(1, SEQ, h, HEAD_DIM),
+                                   jnp.bfloat16)
+                       for h in (HEADS, KV_HEADS, KV_HEADS, HEADS))
+    print("device", jax.devices()[0].device_kind, flush=True)
+    for mode_name in ("block_diffusion", "causal", "none"):
+        mine = None
+        for path in paths:
+            flash = load(path)
+            mode = {"block_diffusion": flash.block_diffusion_mask(
+                        4, SEQ // 2),
+                    "causal": flash.MASK_CAUSAL,
+                    "none": flash.MASK_NONE}[mode_name]
+            call = jax.jit(jax.value_and_grad(
+                lambda q, k, v: (flash.flash_attention(
+                    q, k, v, mask_mode=mode, block_q=TILE, block_k=TILE
+                ).astype(jnp.float32) * weight.astype(jnp.float32)).sum(),
+                argnums=(0, 1, 2)))
+            got = jax.tree.map(np.asarray, call(q, k, v))
+            trace_dir = os.path.join(ROOT, "benchmarks_out", "flash_tiles")
+            tracing.start(trace_dir)
+            for _ in range(CALLS):
+                out = call(q, k, v)
+            jax.block_until_ready(out)
+            tracing.stop()
+            ns, calls = collections.Counter(), collections.Counter()
+            for plane in tracing.load(trace_dir).values():
+                for event, _, duration in plane["ops"]:
+                    kernel = re.search(r"hvd_flash_(fwd|bwd_dq|bwd_dkv)",
+                                       event)
+                    if kernel:
+                        ns[kernel.group(0)] += duration
+                        calls[kernel.group(0)] += 1
+            tiles = HEADS * int(np.sum(
+                [[flash.block_contributes(mode, a, a + TILE - 1, b,
+                                          b + TILE - 1)
+                  for b in range(0, SEQ, TILE)]
+                 for a in range(0, SEQ, TILE)]))
+            times = ", ".join(
+                f"{name} {ns[name] / calls[name] / 1e6:.4f} ms a call, "
+                f"{ns[name] / calls[name] / 1e3 / tiles:.3f} us a tile"
+                for name in sorted(ns))
+            same = "" if mine is None else " bit-equal to this tree: " + str(
+                all(jax.tree.leaves(jax.tree.map(np.array_equal, mine,
+                                                 got))))
+            mine = got if mine is None else mine
+            print(f"{mode_name} {os.path.relpath(path, ROOT)} ({tiles} "
+                  f"tiles a kernel): {times or 'no device plane'}{same}",
+                  flush=True)
+
+
+if __name__ == "__main__":
+    main()
