@@ -45,7 +45,6 @@ KNOB_DEFAULTS = {
     "BENCH_SERVE_REQUESTS": "64",
     "BENCH_SERVE_NEWTOKENS": "32",
     "BENCH_SERVE_REPLICAS": "2",
-    "BENCH_SERVE_SLOT_BATCH": "4",
     "HVD_SERVE_BLOCK_TOKENS": "16",
     "HVD_SERVE_PREFILL_CHUNK": "64",
     "HVD_SERVE_PREFIX_CACHE": "1",
@@ -56,7 +55,6 @@ KNOB_DEFAULTS = {
     "HVD_FAULTLINE_SEED": "0",
     "BENCH_SERVE_STREAM_SESSIONS": "6",
     "BENCH_SERVE_STREAM_TEMP": "0.8",
-    "BENCH_SERVE_SP_RANKS": "4",
 }
 
 
@@ -231,12 +229,12 @@ def bench_serve():
     plane) — aggregate tokens/sec, TTFT / per-output-token latency split,
     achieved batch occupancy.
 
-    Three paged-cache arms (ISSUE 5 acceptance), each with the identical
-    prompts run on both engine configs so exactness is checked in-band:
+    The arms, each with the identical prompts run on both engine configs
+    so exactness is checked in-band:
 
-    * ``paged``   — paged vs slot engine at a FIXED cache-memory budget
-      (``BENCH_SERVE_SLOT_BATCH`` × max_len token positions) on a
-      mixed-length storm: concurrent sequences admitted + tokens/s;
+    * ``paged``   — a mixed-length storm at a FIXED cache-memory budget
+      (4 × max_len token positions): concurrent sequences admitted +
+      tokens/s, batched against each prompt alone;
     * ``chunked`` — decode token_step p99 while max_len prompts prefill,
       chunked (``HVD_SERVE_PREFILL_CHUNK``) vs unchunked;
     * ``prefix``  — shared-prefix storm: prefix-cache hit rate and block
@@ -291,14 +289,13 @@ def bench_serve():
     chunk = int(os.environ.get(
         "HVD_SERVE_PREFILL_CHUNK",
         KNOB_DEFAULTS["HVD_SERVE_PREFILL_CHUNK"]))
-    slot_batch = int(os.environ.get(
-        "BENCH_SERVE_SLOT_BATCH", KNOB_DEFAULTS["BENCH_SERVE_SLOT_BATCH"]))
+    budget_rows = 4  # arm 1's pool: this many full-length sequences
     prefix_on = os.environ.get(
         "HVD_SERVE_PREFIX_CACHE",
         KNOB_DEFAULTS["HVD_SERVE_PREFIX_CACHE"]) not in ("0", "false")
     if smoke:
         n_requests, new_tokens = min(n_requests, 16), min(new_tokens, 8)
-        slot_batch, chunk = min(slot_batch, 2), min(chunk, 8)
+        budget_rows, chunk = 2, min(chunk, 8)
     cfg = TransformerConfig(
         vocab_size=256, causal=True, dtype=jnp.float32, scan_layers=False,
         **({"num_layers": 2, "num_heads": 2, "d_model": 64, "d_ff": 128,
@@ -347,7 +344,6 @@ def bench_serve():
     sched.stop()
     total_tokens = sum(len(o) for o in outs)
     snap = metrics.snapshot()
-    kv_mode = sched.replicas[0].engine.kv_mode
 
     def engine_storm(engine, storm_prompts, toks):
         reqs = [Request(p, max_new_tokens=toks) for p in storm_prompts]
@@ -371,51 +367,39 @@ def bench_serve():
         eng.stop()
         return outs, dt, eng.metrics.snapshot(), stats
 
-    # -- arm 1: paged vs slot at a FIXED cache-memory budget ------------------
-    # Budget = slot_batch × max_len token positions.  The slot engine
-    # spends it on slot_batch full-length reservations; the paged engine
-    # shares the same positions as blocks, so the mixed-(short-)length
-    # storm packs many more concurrent sequences into the same HBM.
-    budget_tokens = slot_batch * cfg.max_len
+    # -- arm 1: a mixed-length storm at a FIXED cache-memory budget -----------
+    # Budget = budget_rows × max_len token positions, shared as blocks:
+    # the mixed-(short-)length storm packs many more concurrent sequences
+    # into it than budget_rows full-length reservations would.
+    budget_tokens = budget_rows * cfg.max_len
     mixed_prompts = [rng.randint(0, 256, size=(
         int(rng.randint(4, max(6, cfg.max_len // 4))),)).tolist()
         for _ in range(n_requests)]
-    slot_adapter = TransformerAdapter(cfg, params)
-    slot_outs, slot_dt, slot_snap, _ = timed_storm(
-        lambda: InferenceEngine(slot_adapter, max_batch=slot_batch,
-                                kv_mode="slot", metrics=ServeMetrics(),
-                                replica_id="bench-slot"),
-        mixed_prompts, new_tokens)
     paged_adapter = TransformerAdapter(cfg, params,
                                        block_tokens=block_tokens)
-    # 4x the slot width: enough rows for the block-bound concurrency the
-    # mixed storm reaches.  (On this CPU harness decode is dense compute,
-    # so tokens/s tracks FLOPs and the paged win is the CONCURRENCY held
-    # in the same HBM budget — the admit_ratio metric; a real TPU decode
-    # is memory-bound and converts that occupancy into throughput.)
-    paged_batch = min(slot_batch * 4, 64)
-    paged_outs, paged_dt, paged_snap, paged_kv = timed_storm(
-        lambda: InferenceEngine(paged_adapter, max_batch=paged_batch,
-                                kv_mode="paged",
-                                num_blocks=budget_tokens // block_tokens,
-                                prefill_chunk=chunk,
-                                prefix_cache=prefix_on,
-                                metrics=ServeMetrics(),
-                                replica_id="bench-paged"),
-        mixed_prompts, new_tokens)
-    slot_tok = sum(len(o) for o in slot_outs)
-    paged_tok = sum(len(o) for o in paged_outs)
+
+    def paged_engine():
+        # 4x the budget's rows: enough for the block-bound concurrency
+        # the mixed storm reaches.
+        return InferenceEngine(paged_adapter,
+                               max_batch=min(budget_rows * 4, 64),
+                               num_blocks=budget_tokens // block_tokens,
+                               prefill_chunk=chunk, prefix_cache=prefix_on,
+                               metrics=ServeMetrics(),
+                               replica_id="bench-paged")
+
+    paged_outs, paged_dt, paged_snap, _ = timed_storm(
+        paged_engine, mixed_prompts, new_tokens)
+    single = paged_engine().start()
+    single_outs = [single.generate(p, max_new_tokens=new_tokens)
+                   for p in mixed_prompts]
+    single.stop()
     arm_paged = {
         "budget_tokens": budget_tokens,
-        "slot_admitted_concurrent": slot_snap["occupancy"]["max"],
         "admitted_concurrent": paged_snap["occupancy"]["max"],
-        "admit_ratio": round(paged_snap["occupancy"]["max"]
-                             / max(slot_snap["occupancy"]["max"], 1), 3),
-        "slot_tokens_per_sec": round(slot_tok / slot_dt, 2),
-        "tokens_per_sec": round(paged_tok / paged_dt, 2),
-        "speedup": round((paged_tok / paged_dt)
-                         / max(slot_tok / slot_dt, 1e-9), 3),
-        "outputs_match": paged_outs == slot_outs,
+        "tokens_per_sec": round(
+            sum(len(o) for o in paged_outs) / paged_dt, 2),
+        "outputs_match": paged_outs == single_outs,
     }
 
     # -- arm 2: chunked vs unchunked under a long-prompt storm ----------------
@@ -429,7 +413,7 @@ def bench_serve():
     n_long = 2 if smoke else 10
     bg_tokens = 40 if smoke else 96
     bg_prompts = [rng.randint(0, 256, size=(4,)).tolist()
-                  for _ in range(max(2, slot_batch))]
+                  for _ in range(max(2, budget_rows))]
     long_len = cfg.max_len - 12
     long_prompts = [rng.randint(0, 256, size=(long_len,)).tolist()
                     for _ in range(n_long)]
@@ -438,17 +422,14 @@ def bench_serve():
     interf_blocks = (len(bg_prompts) + n_long + 2) * \
         chunk_adapter.max_blocks_per_seq
 
-    def interference(prefill_chunk, sp_ranks=0):
+    def interference(prefill_chunk):
         def storm():
-            sp_kw = ({"sp_ranks": sp_ranks, "sp_min_tokens": 32}
-                     if sp_ranks else {})
             eng = InferenceEngine(chunk_adapter, max_batch=8,
-                                  kv_mode="paged", num_blocks=interf_blocks,
+                                  num_blocks=interf_blocks,
                                   prefill_chunk=prefill_chunk,
                                   prefix_cache=False,
                                   metrics=ServeMetrics(),
-                                  replica_id="bench-interf",
-                                  **sp_kw).start()
+                                  replica_id="bench-interf").start()
             bg = [Request(p, max_new_tokens=bg_tokens) for p in bg_prompts]
             for r in bg:
                 eng.batcher.submit(r)
@@ -470,15 +451,8 @@ def bench_serve():
         storm()  # warm: compile this config's chunk buckets
         return storm()
 
-    sp_arm_ranks = int(os.environ.get(
-        "BENCH_SERVE_SP_RANKS", KNOB_DEFAULTS["BENCH_SERVE_SP_RANKS"]))
     chunked_p99, chunked_outs = interference(chunk)
     unchunked_p99, unchunked_outs = interference(0)
-    # SP variant of the SAME storm: the chunked-prefill interference
-    # contract (ISSUE 4) must survive sequence-parallel prefill — SP
-    # runs one emulated-rank chunk per engine iteration, so its decode
-    # p99 has to stay strictly below the unchunked baseline too.
-    sp_interf_p99, sp_interf_outs = interference(chunk, sp_ranks=sp_arm_ranks)
     arm_chunked = {
         "prefill_chunk": chunk,
         "long_prompt_len": long_len,
@@ -486,82 +460,13 @@ def bench_serve():
         "unchunked_token_step_p99_ms": unchunked_p99,
         "p99_ratio": round(unchunked_p99 / max(chunked_p99, 1e-9), 3),
         "outputs_match": chunked_outs == unchunked_outs,
-        "sp_token_step_p99_ms": sp_interf_p99,
-        "sp_p99_bounded": sp_interf_p99 <= unchunked_p99,
-        "sp_outputs_match": sp_interf_outs == chunked_outs,
-    }
-
-    # -- arm 2b: sequence-parallel long-prompt prefill (hvdseqserve) ----------
-    # Hermetic CPU harness: the replica's sp_ranks emulated ranks run on
-    # the engine loop thread, so wall-clock speedup is reported from the
-    # emulation model (max per-rank compute + handoff tail, the quantity
-    # a real multi-host TPU replica would see) against the measured
-    # single-rank prefill stage — tokens must stay EXACTLY equal.
-    sp_prompts = [rng.randint(0, 256, size=(long_len,)).tolist()
-                  for _ in range(3 if smoke else 8)]
-    sp_adapter = TransformerAdapter(cfg, params, block_tokens=block_tokens)
-    sp_blocks = (len(sp_prompts) + 2) * sp_adapter.max_blocks_per_seq
-
-    def sp_storm(ranks):
-        def mk():
-            sp_kw = ({"sp_ranks": ranks, "sp_min_tokens": 32}
-                     if ranks else {})
-            return InferenceEngine(sp_adapter, max_batch=8,
-                                   kv_mode="paged", num_blocks=sp_blocks,
-                                   prefill_chunk=chunk, prefix_cache=False,
-                                   metrics=ServeMetrics(),
-                                   replica_id=f"bench-sp{ranks}", **sp_kw)
-
-        def storm():
-            # Sequential submission: each long prompt's prefill stage is
-            # an isolated sample (no queueing skew in the p50).
-            eng = mk().start()
-            outs, reqs = [], []
-            for p in sp_prompts:
-                r = Request(p, max_new_tokens=4)
-                eng.batcher.submit(r)
-                outs.append(r.result(timeout=600))
-                reqs.append(r)
-            prefill_ms = sorted(r.stage_ms.get("prefill", 0.0)
-                                for r in reqs)
-            snap_ = eng.metrics.snapshot()
-            kv_ = eng.kv_stats()
-            walls = (list(eng.seqpar.walls)
-                     if getattr(eng, "seqpar", None) is not None else [])
-            eng.stop()
-            return outs, prefill_ms, snap_, kv_, walls
-
-        storm()  # warm: compile the single-rank and SP chunk buckets
-        return storm()
-
-    sp_base_outs, sp_base_pf, sp_base_snap, _, _ = sp_storm(0)
-    sp_outs, _, sp_snap, sp_kv, sp_walls = sp_storm(sp_arm_ranks)
-    _p50 = lambda xs: (xs[len(xs) // 2] if xs else 0.0)  # noqa: E731
-    sp_base_p50 = _p50(sp_base_pf)
-    sp_wall_p50 = _p50(sorted(w * 1e3 for w in sp_walls))
-    sp_stats = sp_kv.get("sp", {})
-    arm_sp = {
-        "ranks": sp_arm_ranks,
-        "min_tokens": 32,
-        "emulated": True,
-        "long_prompt_len": long_len,
-        "jobs": sp_stats.get("jobs", 0),
-        "baseline_prefill_p50_ms": round(sp_base_p50, 3),
-        "sp_prefill_wall_p50_ms": round(sp_wall_p50, 3),
-        "speedup": round(sp_base_p50 / max(sp_wall_p50, 1e-9), 3),
-        "baseline_ttft_p50_ms": sp_base_snap["ttft"]["p50_ms"],
-        "ttft_p50_ms": sp_snap["ttft"]["p50_ms"],
-        "handoff_bytes": sp_stats.get("handoff_bytes", 0),
-        "ring_hops": sp_stats.get("ring_hops", 0),
-        "ring_bytes_per_prefill": sp_stats.get("ring_bytes_per_prefill", 0),
-        "outputs_match": sp_outs == sp_base_outs,
     }
 
     # -- arm 3: prefix reuse --------------------------------------------------
     shared = rng.randint(0, 256,
                          size=(cfg.max_len // 2,)).tolist()
     prefix_prompts = [shared + rng.randint(0, 256, size=(3,)).tolist()
-                      for _ in range(max(4, slot_batch * 2))]
+                      for _ in range(max(4, budget_rows * 2))]
     prefix_adapter = TransformerAdapter(cfg, params,
                                         block_tokens=block_tokens)
 
@@ -570,7 +475,7 @@ def bench_serve():
         # cache, then the rest of the storm maps them (a fully-concurrent
         # first wave would look up before anything registered).
         eng = InferenceEngine(prefix_adapter, max_batch=8,
-                              kv_mode="paged", num_blocks=interf_blocks,
+                              num_blocks=interf_blocks,
                               prefill_chunk=chunk, prefix_cache=True,
                               metrics=ServeMetrics(),
                               replica_id="bench-prefix").start()
@@ -605,7 +510,7 @@ def bench_serve():
         ad = TransformerAdapter(cfg, params, max_len=kernel_len,
                                 block_tokens=block_tokens, attn_impl=impl)
         outs, dt, snap, _ = timed_storm(
-            lambda: InferenceEngine(ad, max_batch=4, kv_mode="paged",
+            lambda: InferenceEngine(ad, max_batch=4,
                                     prefill_chunk=chunk,
                                     prefix_cache=False,
                                     metrics=ServeMetrics(),
@@ -652,7 +557,7 @@ def bench_serve():
         # SAME iteration, so occupancy reads the pool's true concurrency
         # bound instead of the chunk budget's staggered ramp-in.
         mk = lambda rid: InferenceEngine(  # noqa: E731
-            ad, max_batch=64, kv_mode="paged", num_blocks=nblocks,
+            ad, max_batch=64, num_blocks=nblocks,
             prefill_chunk=0, prefix_cache=False,
             metrics=ServeMetrics(), replica_id=rid)
         outs, dt, snap, kv = timed_storm(
@@ -854,7 +759,7 @@ def bench_serve():
 
     def spec_storm(sk):
         mk = lambda: InferenceEngine(  # noqa: E731
-            spec_adapter, max_batch=4, kv_mode="paged",
+            spec_adapter, max_batch=4,
             prefill_chunk=chunk, prefix_cache=False,
             metrics=ServeMetrics(), replica_id=f"bench-spec{sk}",
             spec_k=sk)
@@ -913,7 +818,7 @@ def bench_serve():
     sample_seeds = [9000 + i for i in range(len(kernel_prompts))]
 
     def sampled_storm():
-        eng = InferenceEngine(spec_adapter, max_batch=4, kv_mode="paged",
+        eng = InferenceEngine(spec_adapter, max_batch=4,
                               prefill_chunk=chunk, prefix_cache=False,
                               metrics=ServeMetrics(),
                               replica_id="bench-sampled").start()
@@ -937,7 +842,7 @@ def bench_serve():
                                size=(3 * block_tokens + 5,)).tolist()
 
     def nbest_run(n):
-        eng = InferenceEngine(spec_adapter, max_batch=8, kv_mode="paged",
+        eng = InferenceEngine(spec_adapter, max_batch=8,
                               prefill_chunk=chunk, prefix_cache=False,
                               metrics=ServeMetrics(),
                               replica_id=f"bench-nbest{n}").start()
@@ -1093,7 +998,7 @@ def bench_serve():
     for i in range(n_mt):
         eng = InferenceEngine(
             _mt_adapter(3), batcher=DynamicBatcher(tenants=mt_cfg_t),
-            metrics=mt_metrics, max_batch=2, kv_mode="paged",
+            metrics=mt_metrics, max_batch=2,
             replica_id=f"mt-{i}", warmup=True)
         mt_replicas.append(Replica(f"mt-{i}", None, eng))
     mt_sched = ReplicaScheduler(mt_replicas, metrics=mt_metrics)
@@ -1175,7 +1080,6 @@ def bench_serve():
     cold_eng = InferenceEngine(_mt_adapter(11),
                                batcher=DynamicBatcher(),
                                metrics=ServeMetrics(), max_batch=2,
-                               kv_mode="paged",
                                replica_id="mt-cold").start()
     cold_req = Request(list(mt_prompt), max_new_tokens=mt_tokens)
     cold_eng.batcher.submit(cold_req)
@@ -1234,7 +1138,7 @@ def bench_serve():
 
     def untiered_engine():
         return InferenceEngine(tier_adapter, max_batch=12,
-                               kv_mode="paged", num_blocks=tier_pool,
+                               num_blocks=tier_pool,
                                prefill_chunk=chunk,
                                metrics=ServeMetrics(),
                                replica_id="bench-untier")
@@ -1244,7 +1148,7 @@ def bench_serve():
 
     def tiered_engine():
         return InferenceEngine(tier_adapter, max_batch=12,
-                               kv_mode="paged", num_blocks=tier_pool,
+                               num_blocks=tier_pool,
                                prefill_chunk=chunk,
                                tiering=TierConfig(oversub=4.0, quantum=2),
                                metrics=ServeMetrics(),
@@ -1268,7 +1172,7 @@ def bench_serve():
         client = TierClient(KVStoreClient("127.0.0.1", tier_port),
                             replica_id=rid)
         return InferenceEngine(prefix_adapter, max_batch=8,
-                               kv_mode="paged", num_blocks=interf_blocks,
+                               num_blocks=interf_blocks,
                                prefill_chunk=chunk, prefix_cache=True,
                                tiering=TierConfig(quantum=2),
                                tier_client=client,
@@ -1295,7 +1199,7 @@ def bench_serve():
     eng_b.stop()
     tier_srv.stop()
     ref_eng = InferenceEngine(prefix_adapter, max_batch=8,
-                              kv_mode="paged", num_blocks=interf_blocks,
+                              num_blocks=interf_blocks,
                               prefill_chunk=chunk, prefix_cache=True,
                               metrics=ServeMetrics(),
                               replica_id="bench-mig-ref").start()
@@ -1339,7 +1243,7 @@ def bench_serve():
     for i in range(2):
         bsched = build_replicas(
             lambda: prefix_adapter, num_replicas=1,
-            metrics=ServeMetrics(), kv_mode="paged",
+            metrics=ServeMetrics(),
             num_blocks=interf_blocks, prefill_chunk=chunk,
             prefix_cache=True)
         bsrv = ServeServer(bsched)
@@ -1377,7 +1281,6 @@ def bench_serve():
             else:
                 route_outs.setdefault(i, set()).add(tuple(rbody["tokens"]))
     route_ref_eng = InferenceEngine(prefix_adapter, max_batch=8,
-                                    kv_mode="paged",
                                     num_blocks=interf_blocks,
                                     prefill_chunk=chunk, prefix_cache=True,
                                     metrics=ServeMetrics(),
@@ -1457,7 +1360,7 @@ def bench_serve():
     stream_toks = min(new_tokens, 16)
     stream_sched = build_replicas(
         lambda: prefix_adapter, num_replicas=1, metrics=ServeMetrics(),
-        kv_mode="paged", num_blocks=interf_blocks, prefill_chunk=chunk,
+        num_blocks=interf_blocks, prefill_chunk=chunk,
         prefix_cache=True)
     stream_srv = ServeServer(stream_sched)
     stream_port = stream_srv.start(port=0, host="127.0.0.1")
@@ -1612,9 +1515,8 @@ def bench_serve():
                   f"{os.environ.get('HVD_SERVE_MAX_BATCH', '8')}, "
                   f"{n_requests} reqs x {new_tokens} tokens, "
                   f"L{cfg.num_layers} d{cfg.d_model} greedy f32 "
-                  f"{kv_mode} bt{block_tokens} chunk{chunk}"
+                  f"bt{block_tokens} chunk{chunk}"
                   + (" SMOKE" if smoke else ""),
-        "kv_mode": kv_mode,
         "attn_impl": sched.replicas[0].engine.attn_impl,
         "kv_dtype": sched.replicas[0].engine.kv_dtype,
         "block_tokens": block_tokens,
@@ -1630,7 +1532,6 @@ def bench_serve():
         "token_split": snap["token_split"],
         "paged": arm_paged,
         "chunked": arm_chunked,
-        "sp_prefill": arm_sp,
         "prefix": arm_prefix,
         "kernel": arm_kernel,
         "kv_dtype_arm": arm_kv_dtype,

@@ -53,16 +53,10 @@ KINDS = ("kill-rank", "delay-kv", "drop-kv-response", "poison-step",
          "slow-route", "blackhole-endpoint", "stream-disconnect",
          "slow-client")
 
-#: Injection points threaded through the codebase.  ``sp.prefill`` is
-#: the sequence-parallel prefill unit boundary (serve/seqpar.py via
-#: engine._sp_step): consulted once per (rank, chunk) compute unit with
-#: the replica id as the instance — ``kill-rank`` there acts out losing
-#: a rank mid-SP-prefill (every rank's transient extent blocks must
-#: free and the request resubmits whole, falling back to single-rank
-#: prefill on retry).
+#: Injection points threaded through the codebase.
 POINTS = ("engine.step", "replica.route", "kv.request", "preempt.poll",
           "ctl.poll", "registry.roll", "tier.fetch", "router.forward",
-          "stream.emit", "sp.prefill")
+          "stream.emit")
 
 #: Default injection point per kind (a spec may override, e.g. kill-rank
 #: at replica.route fires report_rank_lost directly instead of going

@@ -139,8 +139,7 @@ def _block_scores(q32, k32, scale):
 def online_fold(s, v32, acc, m, l):
     """One online-softmax accumulation of a masked score block into the
     running ``(acc, m, l)`` state — the fold at the heart of
-    ``ring_attention``'s hop loop, shared with the serving engine's
-    sequence-parallel prefill (serve/seqpar.py).
+    ``ring_attention``'s hop loop.
 
     ``s`` is ``[B, H, Sq, Sk]`` with masked entries already at ``-1e30``;
     ``v32`` is ``[B, Sk, H, D]``; ``acc [B, H, Sq, D]`` and ``m, l
@@ -157,72 +156,6 @@ def online_fold(s, v32, acc, m, l):
     l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
     acc_new = acc * corr + jnp.einsum("bhqk,bkhd->bhqd", p, v32)
     return acc_new, m_new, l_new
-
-
-def ragged_fold_init(q32):
-    """Empty online-softmax state for a manual fold sequence over
-    ``q32 [B, Sq, H, D]`` — pair with ``ragged_fold`` per K/V extent and
-    ``ragged_fold_finish`` to normalize."""
-    acc = jnp.einsum("bqhd->bhqd", q32) * 0.0          # [B, H, Sq, D]
-    m = jnp.max(acc, axis=-1, keepdims=True) + jnp.float32(-1e30)
-    l = jnp.zeros_like(m)
-    return acc, m, l
-
-
-def ragged_fold(q32, k32, v32, *, q_start, k_start, k_len,
-                acc, m, l, scale, mask_mode=1):
-    """One ring-style fold of a RAGGED K/V extent with traced
-    per-sequence start offsets.
-
-    ``ring_attention``'s hop fold decides its mask from static ring
-    positions (owner vs my); the serving engine's sequence-parallel
-    prefill folds extents whose global positions are only known at run
-    time (prompts land on arbitrary, non-pow2 boundaries while the
-    buffers stay pow2-bucketed for compile stability).  Here the causal
-    rule is evaluated against traced scalars instead: query row ``i``
-    sits at global position ``q_start + i``, key column ``j`` at
-    ``k_start + j``, and only the first ``k_len`` key columns are real
-    (the rest is bucket padding).
-
-    ``mask_mode`` follows ``parallel/flash.py``: 0 = none (validity bound
-    only), 1 = causal (``q_pos >= k_pos``), 2 = strict (``q_pos >
-    k_pos``).  Same f32-island fold as the ring hops (``online_fold``),
-    so values merge bit-identically with ``ring_attention``'s math.
-    """
-    s = _block_scores(q32, k32, scale)                 # [B, H, Sq, Sk]
-    Sq, Sk = s.shape[-2], s.shape[-1]
-    iq = lax.broadcasted_iota(jnp.int32, (Sq, Sk), 0)
-    ik = lax.broadcasted_iota(jnp.int32, (Sq, Sk), 1)
-    qg = q_start + iq
-    kg = k_start + ik
-    if mask_mode == 1:
-        keep = qg >= kg
-    elif mask_mode == 2:
-        keep = qg > kg
-    else:
-        keep = jnp.ones((Sq, Sk), dtype=bool)
-    keep = keep & (ik < k_len)
-    s = jnp.where(keep[None, None], s, jnp.float32(-1e30))
-    return online_fold(s, v32, acc, m, l)
-
-
-def ragged_fold_finish(acc, m, l, dtype=jnp.float32):
-    """Normalize a manual fold sequence: ``[B, H, Sq, D]`` accumulator
-    back to ``[B, Sq, H, D]`` output (rows that attended nothing come
-    out exactly zero) — the same final step as ``ring_attention``."""
-    out = acc / jnp.maximum(l, 1e-30)
-    return jnp.einsum("bhqd->bqhd", out).astype(dtype)
-
-
-def emit_hop_schedule(kind: str, n: int, bytes_per_hop: int, *,
-                      causal: bool = True, striped: bool = False,
-                      schedule: str = "overlap") -> None:
-    """Public hop-schedule emission for callers that run the ring fold
-    WITHOUT a live ``ppermute`` ring — the serving engine's emulated
-    sequence-parallel prefill world records the n-hop rotation its
-    configuration would run on real chips, with the same timeline dedup
-    and causal-skip accounting as ``ring_attention`` itself."""
-    _emit_hop_schedule(kind, n, bytes_per_hop, causal, striped, schedule)
 
 
 def stripe_sequence(x: jax.Array, n: int, axis: int = 1) -> jax.Array:

@@ -14,8 +14,8 @@ Layers (docs/serving.md has the architecture):
 * :mod:`paged_attention` — fused Pallas paged-attention kernels over the
   block tables + int8/fp8 KV block quantization (``HVD_SERVE_ATTN_IMPL``
   / ``HVD_SERVE_KV_DTYPE``);
-* :mod:`engine`  — paged (default) / slot KV cache, chunked prefill,
-  iteration-level decode loop;
+* :mod:`engine`  — paged KV cache, chunked prefill, iteration-level
+  decode loop;
 * :mod:`batcher` — bounded queue, size/deadline triggers, QoS tiers +
   EDF ordering, shape buckets, block-budget admission;
 * :mod:`replica` — process-set replicas, least-loaded routing, failover;
@@ -56,7 +56,7 @@ _witness.maybe_install_from_env()
 
 from .batcher import (  # noqa: F401,E402
     DeadlineExceededError, DynamicBatcher, QueueFullError, Request,
-    bucket_requests, prompt_bucket,
+    prompt_bucket,
 )
 from .blocks import (  # noqa: F401
     BlockManager, NoFreeBlocksError, chain_hashes,

@@ -19,11 +19,10 @@ Triggers:
   cost of batch formation when traffic is sparse).
 
 Shape bucketing: prompt lengths are padded up to power-of-two buckets
-(floor ``HVD_SERVE_BUCKET_MIN``) so the engine compiles one prefill per
-bucket instead of one per length — ``bucket_requests`` groups an admitted
-set by bucket and the engine runs one prefill per group.
+(floor ``HVD_SERVE_BUCKET_MIN``, ``prompt_bucket``) so the engine compiles
+one chunk-prefill program per bucket instead of one per length.
 
-Block budget (paged engine, docs/serving.md): ``get_admission`` also
+Block budget (docs/serving.md): ``get_admission`` also
 accepts a resource budget + per-request cost — free KV blocks — and
 admits the FIFO prefix that fits, so admission is bounded by actual
 cache memory instead of slot count.
@@ -182,13 +181,6 @@ class Request:
         self.replica_id: Optional[str] = None
         self.requeues = 0
         self.first_token_at: Optional[float] = None
-        # Sequence-parallel prefill admission verdict (serve/seqpar.py):
-        # set by _take when the engine passes an SP budget — True means
-        # admission could NOT reserve transient per-rank extent blocks
-        # for this long prompt (the SP world is busy), so the engine
-        # prefills it on the proven single-rank chunked path instead of
-        # serializing it behind another SP job.
-        self.sp_denied = False
         # Request tracing (obs/tracing.py): ``trace`` is the sampled
         # request's TraceContext — it travels ON the request because the
         # lifecycle crosses threads (HTTP handler → batcher queue →
@@ -308,21 +300,6 @@ class Request:
         return self.temperature > 0
 
 
-def sp_extent_tokens(prompt_len: int, ranks: int,
-                     block_tokens: int) -> int:
-    """Per-rank sequence extent of a sequence-parallel prefill
-    (serve/seqpar.py): ``ceil(prompt_len / ranks)`` rounded UP to a
-    whole block.  Block-aligned extents are what keep the post-prefill
-    handoff whole-block (rank r's extent starts exactly at global block
-    ``r * extent // block_tokens``), so admission costing, the SP world's
-    per-rank allocation, and the handoff all agree on one number."""
-    if ranks < 1:
-        raise ValueError(f"ranks must be >= 1, got {ranks}")
-    ext = -(-int(prompt_len) // int(ranks))
-    bt = max(int(block_tokens), 1)
-    return -(-ext // bt) * bt
-
-
 def prompt_bucket(length: int, *, floor: Optional[int] = None,
                   cap: Optional[int] = None) -> int:
     """Pad a prompt length up to its power-of-two bucket."""
@@ -334,18 +311,6 @@ def prompt_bucket(length: int, *, floor: Optional[int] = None,
     if cap is not None:
         b = min(b, cap)
     return b
-
-
-def bucket_requests(requests: Sequence[Request],
-                    *, floor: Optional[int] = None,
-                    cap: Optional[int] = None) -> Dict[int, List[Request]]:
-    """Group an admitted set by padded prompt-length bucket (one prefill
-    compile/run per group)."""
-    groups: Dict[int, List[Request]] = {}
-    for r in requests:
-        groups.setdefault(
-            prompt_bucket(len(r.prompt), floor=floor, cap=cap), []).append(r)
-    return groups
 
 
 def _order_key(r: Request):
@@ -484,10 +449,7 @@ class DynamicBatcher:
         self._queue = kept
 
     def _take(self, free_slots: int, budget: Optional[int], cost,
-              hard_cap: Optional[int],
-              sp_min_tokens: Optional[int] = None,
-              sp_capacity: Optional[int] = None,
-              sp_cost=None) -> List[Request]:
+              hard_cap: Optional[int]) -> List[Request]:
         # Caller holds the lock.  FIFO prefix bounded by BOTH the free
         # slot count and the caller's resource budget (free KV blocks in
         # the paged engine): the walk stops at the first request the
@@ -496,18 +458,8 @@ class DynamicBatcher:
         # whose cost exceeds ``hard_cap`` (the pool's total capacity) are
         # taken regardless: no amount of waiting helps, and the engine
         # fails them loudly at admission.
-        #
-        # SP admission costing (serve/seqpar.py): long prompts (>=
-        # ``sp_min_tokens``) are ADDITIONALLY charged ``sp_cost(r)``
-        # transient per-rank extent blocks against ``sp_capacity`` — the
-        # sequence-parallel world's free prefill-pool blocks.  Unlike the
-        # owner-pool budget this never blocks admission: a long prompt
-        # the SP pools cannot take is admitted with ``sp_denied`` set and
-        # prefills single-rank (SP is a latency optimization, not a
-        # capacity requirement).
         taken: List[Request] = []
         remaining = budget
-        sp_remaining = sp_capacity
         cap = self.brownout_max_new
         while self._queue and len(taken) < free_slots:
             r = self._queue[0]
@@ -520,45 +472,20 @@ class DynamicBatcher:
             if cost is not None:
                 c = cost(r)
                 if hard_cap is not None and c > hard_cap:
-                    self._sp_charge(r, sp_min_tokens, sp_remaining,
-                                    sp_cost)
                     taken.append(self._queue.pop(0))
                     continue
                 if remaining is not None and c > remaining:
                     break
                 if remaining is not None:
                     remaining -= c
-            sp_remaining = self._sp_charge(r, sp_min_tokens,
-                                           sp_remaining, sp_cost)
             taken.append(self._queue.pop(0))
         return taken
-
-    @staticmethod
-    def _sp_charge(r: Request, sp_min_tokens: Optional[int],
-                   sp_remaining: Optional[int], sp_cost):
-        """Charge one admitted request against the SP extent budget
-        (see _take); returns the remaining capacity.  Prompts below the
-        threshold are untouched (their stale ``sp_denied`` from a prior
-        admission round is reset — requeued requests re-qualify)."""
-        if sp_min_tokens is None or sp_cost is None:
-            return sp_remaining
-        r.sp_denied = False
-        if len(r.prompt) < sp_min_tokens:
-            return sp_remaining
-        c = int(sp_cost(r))
-        if sp_remaining is not None and c > sp_remaining:
-            r.sp_denied = True
-            return sp_remaining
-        return None if sp_remaining is None else sp_remaining - c
 
     def get_admission(self, free_slots: int,
                       block_s: float = 0.0,
                       budget: Optional[int] = None,
                       cost=None,
-                      hard_cap: Optional[int] = None,
-                      sp_min_tokens: Optional[int] = None,
-                      sp_capacity: Optional[int] = None,
-                      sp_cost=None) -> List[Request]:
+                      hard_cap: Optional[int] = None) -> List[Request]:
         """Up to ``free_slots`` requests, honoring the size/deadline
         triggers.  ``block_s`` > 0 waits that long for the triggers when
         the queue cannot fire them yet (the engine blocks when idle and
@@ -567,11 +494,7 @@ class DynamicBatcher:
         ``budget``/``cost``/``hard_cap`` account a second resource beyond
         slots (the paged engine's free KV blocks, docs/serving.md): the
         admitted set is the FIFO prefix whose summed ``cost(request)``
-        fits ``budget`` (see ``_take``).  ``sp_min_tokens``/
-        ``sp_capacity``/``sp_cost`` account a THIRD, advisory resource —
-        the sequence-parallel prefill world's transient extent blocks
-        (serve/seqpar.py): long prompts that do not fit are still
-        admitted, marked ``sp_denied`` (see ``_sp_charge``)."""
+        fits ``budget`` (see ``_take``)."""
         if free_slots <= 0:
             return []
         deadline = time.monotonic() + block_s
@@ -616,8 +539,7 @@ class DynamicBatcher:
                                 self._queue[:] = self._drr.reorder(
                                     self._queue)
                             taken = self._take(free_slots, budget, cost,
-                                               hard_cap, sp_min_tokens,
-                                               sp_capacity, sp_cost)
+                                               hard_cap)
                             if taken:
                                 return taken
                             # Head too expensive for the current budget:

@@ -433,7 +433,7 @@ class FleetController:
             queued += r.engine.batcher.depth()
             active += r.engine.active_count
             kv = r.engine.kv_stats()
-            if kv is not None and "kv_headroom_bytes" in kv:
+            if "kv_headroom_bytes" in kv:
                 h = int(kv["kv_headroom_bytes"])
                 headroom = h if headroom is None else min(headroom, h)
         bounds, counts, total = self.metrics.request_window("latency")

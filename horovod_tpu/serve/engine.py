@@ -5,7 +5,7 @@ is Orca's iteration-level scheduling (OSDI '22) with vLLM's block-paged KV
 storage (Kwon et al., SOSP '23) and Sarathi-Serve's chunked prefill
 (Agrawal et al., OSDI '24):
 
-* **paged KV cache** (default) — the cache is a pool of fixed-size blocks
+* **paged KV cache** — the cache is a pool of fixed-size blocks
   (``HVD_SERVE_BLOCK_TOKENS`` positions each, serve/blocks.BlockManager);
   a sequence holds exactly the blocks its tokens occupy and addresses
   them through a per-sequence block table, so admission is bounded by
@@ -25,10 +25,6 @@ storage (Kwon et al., SOSP '23) and Sarathi-Serve's chunked prefill
 * **prefix caching** — full prompt blocks are content-hashed; a request
   sharing a cached prefix maps the same physical blocks and skips their
   prefill (copy-on-write protects shared blocks from writes);
-* **slot mode** (``kv_mode="slot"``) — the PR-3 contiguous
-  ``[L, max_batch, max_len, H, Dh]`` layout is kept for adapters without
-  a paged interface and as the bench baseline (``BENCH_MODEL=serve``
-  measures paged-vs-slot at a fixed cache-memory budget);
 * **bucketed compilation** — chunk prefill jits once per (padded request
   count, padded chunk length) power-of-two bucket and paged decode jits
   exactly once, so steady-state serving never recompiles.
@@ -40,8 +36,8 @@ block-table holes use an out-of-bounds sentinel (scatter drops the write,
 gather clamps and the mask zeroes the read) — so the tokens a request
 receives are bit-identical whether it ran alone, packed in a full batch,
 prefilled in one shot or in chunks, or resumed on another replica.  Tests
-pin batched==single under every mode, including block-boundary prompt
-lengths.
+pin batched==single, including block-boundary prompt lengths, and hold
+the programs' logits to the flax model's (tests/test_serve_logits.py).
 
 Model support: the ``models/`` Transformer (dense causal attention,
 ``TransformerAdapter`` — stacked ``scan_layers`` checkpoints are unstacked
@@ -65,11 +61,10 @@ import numpy as np
 from ..faultline import runtime as _faultline
 from ..faultline.plan import FaultInjected
 from ..obs import tracing as _obs
-from ..parallel import ring as _ring
 from ..utils import get_logger
 from . import sampling as _sampling
 from .batcher import (DeadlineExceededError, DynamicBatcher, Request,
-                      bucket_requests, prompt_bucket)
+                      prompt_bucket)
 from .blocks import BlockManager, NoFreeBlocksError, chain_hashes
 from .metrics import ServeMetrics
 from .tiering import (TierClient, TierConfig, TieredBlockManager,
@@ -91,11 +86,17 @@ class ModelAdapter:
     """Engine-facing model interface.
 
     The engine owns slot/block bookkeeping; the adapter owns the math and
-    the per-bucket compile caches.  ``prefill``/``decode`` (slot mode) and
-    ``prefill_chunk``/``decode_paged`` (paged mode) take and return the
-    cache pytree so the engine can thread it through jit with donation.
-    An adapter without the paged trio (``init_paged_cache`` /
-    ``prefill_chunk`` / ``decode_paged``) serves in slot mode only.
+    the per-bucket compile caches.  ``prefill_chunk``/``decode_paged``
+    take and return the cache pytree so the engine can thread it through
+    jit with donation.  The engine refuses an adapter without the trio
+    ``init_paged_cache`` / ``prefill_chunk`` / ``decode_paged``; an
+    adapter whose sequences hold blocks (``kv_token_cost > 0``) also
+    needs ``copy_block``.  Optional members, each checked where its
+    feature is asked for: ``prefill_chunk_logits`` +
+    ``decode_paged_sampled`` (sampling, n > 1), ``decode_paged_logits``
+    (schemas, logprobs), ``verify_chunk`` + ``draft_decode`` +
+    ``spec_capable`` (speculative decoding), ``prompt_logits`` /
+    ``score_logits`` (``/score``).
     """
 
     vocab_size: int
@@ -113,22 +114,44 @@ class ModelAdapter:
             return [chr(i) for i in range(self.vocab_size)]
         return None
 
-    def init_cache(self, max_batch: int):
+    def init_paged_cache(self, num_blocks: int, max_batch: int):
+        """The block pool (any pytree) for ``num_blocks`` blocks."""
         raise NotImplementedError
 
-    def prefill(self, cache, prompts: Sequence[Sequence[int]],
-                slots: Sequence[int]):
-        """Run the prompt phase for ``prompts`` into cache rows ``slots``;
+    def prefill_chunk(self, cache, chunks: Sequence[Sequence[int]],
+                      starts: Sequence[int],
+                      tables: Sequence[Sequence[int]]):
+        """Continue sequence i's prompt with ``chunks[i]`` at absolute
+        position ``starts[i]`` into physical blocks ``tables[i]``;
         returns ``(cache, next_tokens)`` where ``next_tokens[i]`` is the
-        greedy first generated token of prompt i."""
+        greedy token after chunk i's last position."""
         raise NotImplementedError
 
-    def decode(self, cache, tokens: np.ndarray, positions: np.ndarray):
+    def decode_paged(self, cache, tokens: np.ndarray,
+                     positions: np.ndarray, tables: np.ndarray):
         """One token step for the whole slot batch: feed ``tokens[b]`` at
-        ``positions[b]``; returns ``(cache, next_tokens[max_batch])``.
-        Rows whose slot is inactive carry token 0 / position 0 and their
-        output is ignored."""
+        ``positions[b]`` through block table ``tables[b]``; returns
+        ``(cache, next_tokens[max_batch])``.  Rows whose slot is inactive
+        carry token 0 / position 0 / an all-hole table and their output
+        is ignored."""
         raise NotImplementedError
+
+    def copy_block(self, cache, src: int, dst: int):
+        """Copy-on-write data move: duplicate physical block ``src`` into
+        ``dst`` across all layers; returns the cache."""
+        raise NotImplementedError
+
+
+#: The members without which an adapter cannot serve at all.
+_PAGED_TRIO = ("init_paged_cache", "prefill_chunk", "decode_paged")
+
+
+def _missing_paged_members(adapter) -> List[str]:
+    """Members of the paged trio ``adapter`` lacks (``ModelAdapter``'s
+    own stubs count as lacking)."""
+    return [m for m in _PAGED_TRIO
+            if not callable(getattr(adapter, m, None))
+            or getattr(type(adapter), m, None) is getattr(ModelAdapter, m)]
 
 
 class TransformerAdapter(ModelAdapter):
@@ -137,8 +160,8 @@ class TransformerAdapter(ModelAdapter):
     Runs the Block math (ln1 → qkv → causal attention → proj residual →
     ln2 → fc1/gelu/fc2 residual; f32 layernorm islands, tied LM head) as
     pure functions over the param pytree, with an explicit per-layer KV
-    cache the flax module doesn't carry — contiguous per-slot rows in slot
-    mode, a block pool addressed through block tables in paged mode.
+    cache the flax module doesn't carry: a block pool addressed through
+    block tables.
     Serving math is forced to f32 (``HVD_SERVE_DTYPE`` may widen
     training bf16 checkpoints) — greedy parity across batch compositions
     is the contract and f32 keeps the argmax far from dtype noise.
@@ -238,22 +261,14 @@ class TransformerAdapter(ModelAdapter):
                 f"draft_layers must be in [0, num_layers), got {dl} "
                 f"(num_layers {self.num_layers})")
         self.draft_layers = dl
-        self._prefill_cache: Dict[Tuple[int, int], object] = {}
         self._chunk_cache: Dict[Tuple[int, int, int], object] = {}
         self._chunk_logits_cache: Dict[Tuple[int, int, int], object] = {}
         self._verify_cache: Dict[Tuple[int, int, int], object] = {}
-        self._decode_fns: Dict[int, object] = {}
         self._paged_decode_fns: Dict[Tuple[int, int], object] = {}
         self._paged_logits_fns: Dict[Tuple[int, int], object] = {}
         self._sampled_decode_fns: Dict[Tuple[int, int], object] = {}
         self._draft_decode_fns: Dict[Tuple[int, int], object] = {}
-        # Sequence-parallel prefill programs (serve/seqpar.py), keyed
-        # (chunk bucket, hop-buffer bucket, pool geometry) — one rank's
-        # extent chunk with prior extents' K/V folded ring-style.
-        self._sp_chunk_cache: Dict[Tuple[int, int, int], object] = {}
         self._copy_block_fn = None
-        self._max_batch = None
-        self._num_blocks = None
 
     @property
     def spec_capable(self) -> bool:
@@ -284,14 +299,6 @@ class TransformerAdapter(ModelAdapter):
     def max_blocks_per_seq(self) -> int:
         return -(-self.max_len // self.block_tokens)
 
-    def init_cache(self, max_batch: int):
-        import jax.numpy as jnp
-        self._max_batch = max_batch
-        shape = (self.num_layers, max_batch, self.max_len,
-                 self.cfg.num_heads, self.head_dim)
-        return {"k": jnp.zeros(shape, self._dtype),
-                "v": jnp.zeros(shape, self._dtype)}
-
     def init_paged_cache(self, num_blocks: int, max_batch: int):
         """Block pool ``[L, num_blocks, block_tokens, H, Dh]``: one
         physical layout shared by every sequence; logical placement lives
@@ -299,14 +306,11 @@ class TransformerAdapter(ModelAdapter):
         storage (int8/fp8) adds per-(block, position, head) scale pools
         ``[L, num_blocks, block_tokens, H]`` written alongside every K/V
         append."""
-        self._num_blocks = num_blocks
-        self._max_batch = max_batch
         return self._pool_arrays(num_blocks)
 
     def _pool_arrays(self, num_blocks: int):
-        """The pool pytree for ``num_blocks`` blocks, no adapter-state
-        mutation (``prompt_logits`` builds throwaway pools through
-        this)."""
+        """The pool pytree for ``num_blocks`` blocks (``prompt_logits``
+        builds throwaway pools through this)."""
         import jax.numpy as jnp
         shape = (self.num_layers, num_blocks, self.block_tokens,
                  self.cfg.num_heads, self.head_dim)
@@ -316,14 +320,6 @@ class TransformerAdapter(ModelAdapter):
             pool["k_scale"] = jnp.zeros(shape[:-1], self._scale_dtype)
             pool["v_scale"] = jnp.zeros(shape[:-1], self._scale_dtype)
         return pool
-
-    def sp_pool(self, num_blocks: int):
-        """A side pool for one sequence-parallel prefill rank
-        (serve/seqpar.py): same pytree as ``init_paged_cache`` but with
-        NO adapter-state mutation — the decode pool's geometry
-        (``_num_blocks`` / ``_max_batch``) must stay whatever the engine
-        initialised, or the decode program would recompile."""
-        return self._pool_arrays(num_blocks)
 
     def paged_block_bytes(self) -> int:
         """HBM bytes one physical block costs across all layers (K + V
@@ -427,82 +423,7 @@ class TransformerAdapter(ModelAdapter):
         return jnp.einsum("...d,vd->...v", x.astype(self._dtype),
                           params["wte"]["embedding"]).astype(jnp.float32)
 
-    # -- prefill (slot mode) -------------------------------------------------
-
-    def _build_prefill(self, n: int, p_len: int):
-        import jax
-        import jax.numpy as jnp
-        from jax import lax
-        scale = 1.0 / math.sqrt(self.head_dim)
-        L = self.num_layers
-
-        def prefill(params, cache, tokens, lengths, slots):
-            # tokens [n, P] int32; lengths [n]; slots [n] (slot >= max_batch
-            # marks a padding row: scatter drops out-of-bounds rows, see
-            # OOB note below).
-            x = params["wte"]["embedding"][tokens] \
-                + params["wpe"]["embedding"][jnp.arange(p_len)][None]
-            ck, cv = cache["k"], cache["v"]
-            iq = lax.broadcasted_iota(jnp.int32, (p_len, p_len), 0)
-            ik = lax.broadcasted_iota(jnp.int32, (p_len, p_len), 1)
-            causal = (iq >= ik)[None, None]
-            for l in range(L):
-                blk = params[f"block_{l}"]
-                q, k, v = self._qkv(x, blk)
-                # Out-of-bounds slot indices (padding rows) are DROPPED by
-                # jax scatter's default FILL_OR_DROP mode — a padding row
-                # must not write anyone's cache.
-                ck = ck.at[l, slots, :p_len].set(k)
-                cv = cv.at[l, slots, :p_len].set(v)
-                s = jnp.einsum("nqhe,nkhe->nhqk",
-                               q.astype(jnp.float32),
-                               k.astype(jnp.float32)) * scale
-                s = jnp.where(causal, s, jnp.float32(-1e30))
-                p = jax.nn.softmax(s, axis=-1)
-                out = jnp.einsum("nhqk,nkhe->nqhe", p,
-                                 v.astype(jnp.float32)).astype(self._dtype)
-                x = self._ffn(self._proj(x, out, blk), blk)
-            # LM head only at each prompt's last real position (padding
-            # tail positions produce garbage that is never read).
-            last = jnp.take_along_axis(
-                x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1
-            )[:, 0]
-            logits = self._logits(last, params)
-            return {"k": ck, "v": cv}, jnp.argmax(logits, axis=-1)
-
-        return jax.jit(prefill, donate_argnums=(1,))
-
-    def prefill(self, cache, prompts, slots):
-        import jax.numpy as jnp
-        n_bucket = _next_pow2(len(prompts))
-        max_p = max(len(p) for p in prompts)
-        # Same bucketing policy as the batcher's admission grouping
-        # (batcher.prompt_bucket) — the compile-cache key must agree with
-        # how bucket_requests grouped the batch.
-        p_bucket = prompt_bucket(max_p, cap=self.max_len)
-        if max_p > self.max_len:
-            raise ValueError(f"prompt length {max_p} exceeds max_len "
-                             f"{self.max_len}")
-        key = (n_bucket, p_bucket)
-        if key not in self._prefill_cache:
-            self._prefill_cache[key] = self._build_prefill(*key)
-        tokens = np.zeros((n_bucket, p_bucket), np.int32)
-        lengths = np.ones((n_bucket,), np.int32)
-        # Padding rows get slot index max_batch: out of range on purpose
-        # (their cache scatter is dropped, their logits discarded).
-        slot_arr = np.full((n_bucket,), self._max_batch, np.int32)
-        for i, p in enumerate(prompts):
-            tokens[i, :len(p)] = p
-            lengths[i] = len(p)
-            slot_arr[i] = slots[i]
-        call_args = (self.params, cache, jnp.asarray(tokens),
-                     jnp.asarray(lengths), jnp.asarray(slot_arr))
-        self._maybe_analyze("prefill", key, self._prefill_cache[key],
-                            call_args)
-        cache, nxt = self._prefill_cache[key](*call_args)
-        return cache, np.asarray(nxt)[:len(prompts)]
-
-    # -- chunked prefill (paged mode) ----------------------------------------
+    # -- chunked prefill -----------------------------------------------------
 
     def _chunk_forward(self, params, cache, tokens, starts, lengths,
                        tables, NB: int, c: int):
@@ -729,185 +650,7 @@ class TransformerAdapter(ModelAdapter):
         cache, logits = self._verify_cache[key](*call_args)
         return cache, np.asarray(logits)[:len(chunks)]
 
-    # -- sequence-parallel prefill (serve/seqpar.py) -------------------------
-
-    def _build_sp_prefill_chunk(self, c: int, KH: int, NB: int):
-        """One SP rank's extent-chunk program: scatter the chunk's K/V
-        into the rank's SIDE pool (geometry ``NB``), then attend with
-        the shared ragged ring fold (parallel/ring.py) — prior extents'
-        K/V arrive in the ``hop_k``/``hop_v`` buffers (the ring-hop
-        payload, ``KH`` rows bucketed pow2), the rank's own extent is
-        gathered back out of its pool through the block table, so the
-        attention INPUTS are exactly what single-rank chunked prefill
-        sees (pool-roundtripped values, quantization included).  No
-        third attention implementation: the mask/online-softmax math is
-        ``ring.ragged_fold`` = flash.py's fold with traced start
-        offsets."""
-        import jax
-        import jax.numpy as jnp
-        from ..parallel import ring as _ring
-        from . import paged_attention as _pa
-        scale = 1.0 / math.sqrt(self.head_dim)
-        BT = self.block_tokens
-        MB = self.max_blocks_per_seq
-        H, Dh = self.cfg.num_heads, self.head_dim
-
-        def sp_prefill_chunk(params, pool, tokens, q_start, q_len, k_start,
-                             ltable, hop_k, hop_v, hop_len):
-            # tokens [c] — one rank's extent chunk starting at absolute
-            # position q_start (q_len real); ltable [MB] maps the
-            # rank-LOCAL extent (absolute positions >= k_start) onto the
-            # side pool (entry NB = hole); hop_k/hop_v [L, KH, H, Dh]
-            # f32 carry prior extents' K/V (hop_len real rows, absolute
-            # positions 0..hop_len).
-            pos = q_start + jnp.arange(c)                      # [c]
-            in_chunk = jnp.arange(c) < q_len
-            x = params["wte"]["embedding"][tokens][None] \
-                + params["wpe"]["embedding"][
-                    jnp.minimum(pos, self.max_len - 1)][None]  # [1, c, d]
-            pool = dict(pool)
-            lidx = pos - k_start
-            wblk = ltable[jnp.minimum(jnp.maximum(lidx, 0) // BT, MB - 1)]
-            wblk = jnp.where(in_chunk, wblk, NB)[None]         # [1, c]
-            woff = (jnp.maximum(lidx, 0) % BT)[None]
-            local_len = q_start + q_len - k_start
-            for l in range(self.num_layers):
-                blk = params[f"block_{l}"]
-                q, k, v = self._qkv(x, blk)                    # [1, c, H, Dh]
-                if self._kv_quantized:
-                    pool = self._quantized_scatter(pool, l, wblk, woff,
-                                                   k, v)
-                else:
-                    pool["k"] = pool["k"].at[l, wblk, woff].set(
-                        k.astype(self._kv_store_dtype))
-                    pool["v"] = pool["v"].at[l, wblk, woff].set(
-                        v.astype(self._kv_store_dtype))
-                q32 = q.astype(jnp.float32)
-                acc, m, l_ = _ring.ragged_fold_init(q32)
-                if KH:
-                    # Hop buffers first, then the local extent — the
-                    # ring schedule's fold order.
-                    acc, m, l_ = _ring.ragged_fold(
-                        q32, hop_k[l][None], hop_v[l][None],
-                        q_start=q_start, k_start=0, k_len=hop_len,
-                        acc=acc, m=m, l=l_, scale=scale)
-                ek = jnp.take(pool["k"][l], ltable, axis=0, mode="clip")
-                ev = jnp.take(pool["v"][l], ltable, axis=0, mode="clip")
-                if self._kv_quantized:
-                    ek = _pa.dequantize_kv(ek, jnp.take(
-                        pool["k_scale"][l], ltable, axis=0, mode="clip"))
-                    ev = _pa.dequantize_kv(ev, jnp.take(
-                        pool["v_scale"][l], ltable, axis=0, mode="clip"))
-                else:
-                    ek = ek.astype(jnp.float32)
-                    ev = ev.astype(jnp.float32)
-                # Clip-mode hole garbage past local_len is masked by
-                # k_len — same validity discipline as _paged_attend.
-                acc, m, l_ = _ring.ragged_fold(
-                    q32, ek.reshape(MB * BT, H, Dh)[None],
-                    ev.reshape(MB * BT, H, Dh)[None],
-                    q_start=q_start, k_start=k_start, k_len=local_len,
-                    acc=acc, m=m, l=l_, scale=scale)
-                out = _ring.ragged_fold_finish(acc, m, l_,
-                                               dtype=self._dtype)
-                x = self._ffn(self._proj(x, out, blk), blk)
-            last = jnp.take(x[0], jnp.maximum(q_len - 1, 0), axis=0)
-            return pool, self._logits(last, params)
-
-        return jax.jit(sp_prefill_chunk, donate_argnums=(1,))
-
-    def sp_prefill_chunk(self, pool, chunk, q_start, extent_start, ltable,
-                         hop_k=None, hop_v=None, hop_len=0):
-        """One sequence-parallel rank's prefill chunk against its side
-        pool.  ``chunk`` continues the rank's extent at absolute
-        position ``q_start``; ``extent_start`` is where the extent (and
-        its block table ``ltable``) begins; ``hop_k``/``hop_v``
-        ``[L, hop_len, H, Dh]`` f32 are the prior extents' dequantized
-        K/V.  Returns ``(pool, logits)`` — RAW final-position logits
-        ``[V]``; the engine argmaxes/samples on the host exactly like
-        the single-rank logits path.  Position scalars are traced, so
-        the compile key is (chunk bucket, hop bucket, pool geometry)
-        only — pow2 buckets, steady state never recompiles."""
-        import jax.numpy as jnp
-        c_bucket = prompt_bucket(len(chunk), cap=self.max_len)
-        NB = int(pool["k"].shape[1])
-        KH = prompt_bucket(int(hop_len), cap=self.max_len) if hop_len else 0
-        key = (c_bucket, KH, NB)
-        if key not in self._sp_chunk_cache:
-            self._sp_chunk_cache[key] = self._build_sp_prefill_chunk(*key)
-        MB = self.max_blocks_per_seq
-        L, H, Dh = self.num_layers, self.cfg.num_heads, self.head_dim
-        tok = np.zeros((c_bucket,), np.int32)
-        tok[:len(chunk)] = chunk
-        tab = np.full((MB,), NB, np.int32)
-        tab[:len(ltable)] = ltable
-        hk = np.zeros((L, max(KH, 1), H, Dh), np.float32)
-        hv = np.zeros((L, max(KH, 1), H, Dh), np.float32)
-        if hop_len:
-            hk[:, :hop_len] = hop_k[:, :hop_len]
-            hv[:, :hop_len] = hop_v[:, :hop_len]
-        call_args = (self.params, pool, jnp.asarray(tok),
-                     np.int32(q_start), np.int32(len(chunk)),
-                     np.int32(extent_start), jnp.asarray(tab),
-                     jnp.asarray(hk), jnp.asarray(hv), np.int32(hop_len))
-        self._maybe_analyze("sp_prefill_chunk", key,
-                            self._sp_chunk_cache[key], call_args)
-        pool, logits = self._sp_chunk_cache[key](*call_args)
-        return pool, np.asarray(logits)
-
-    # -- decode (slot mode) --------------------------------------------------
-
-    def _build_decode(self):
-        import jax
-        import jax.numpy as jnp
-        scale = 1.0 / math.sqrt(self.head_dim)
-        L, B = self.num_layers, self._max_batch
-        S = self.max_len
-
-        def decode(params, cache, tokens, positions):
-            # tokens [B] int32 (last token per slot), positions [B] (the
-            # cache index this token's K/V lands at = current length).
-            pos = jnp.minimum(positions, S - 1)
-            x = params["wte"]["embedding"][tokens] \
-                + params["wpe"]["embedding"][pos]  # [B, d]
-            ck, cv = cache["k"], cache["v"]
-            rows = jnp.arange(B)
-            s_idx = jnp.arange(S)[None, None, :]          # [1, 1, S]
-            valid = s_idx <= pos[:, None, None]           # [B, 1, S]
-            for l in range(L):
-                blk = params[f"block_{l}"]
-                q, k, v = self._qkv(x, blk)               # [B, H, Dh]
-                ck = ck.at[l, rows, pos].set(k)
-                cv = cv.at[l, rows, pos].set(v)
-                s = jnp.einsum("bhe,bshe->bhs",
-                               q.astype(jnp.float32),
-                               ck[l].astype(jnp.float32)) * scale
-                # Cache positions beyond this sequence's length hold other
-                # incarnations' garbage — mask to -1e30 so their softmax
-                # weight is exactly 0 and batched == single bit-for-bit.
-                s = jnp.where(valid, s, jnp.float32(-1e30))
-                p = jax.nn.softmax(s, axis=-1)
-                out = jnp.einsum("bhs,bshe->bhe", p,
-                                 cv[l].astype(jnp.float32)
-                                 ).astype(self._dtype)
-                x = self._ffn(self._proj(x, out, blk), blk)
-            logits = self._logits(x, params)
-            return {"k": ck, "v": cv}, jnp.argmax(logits, axis=-1)
-
-        return jax.jit(decode, donate_argnums=(1,))
-
-    def decode(self, cache, tokens, positions):
-        import jax.numpy as jnp
-        if self._decode_fns.get(self._max_batch) is None:
-            self._decode_fns[self._max_batch] = self._build_decode()
-        call_args = (self.params, cache, jnp.asarray(tokens, jnp.int32),
-                     jnp.asarray(positions, jnp.int32))
-        self._maybe_analyze("decode", (self._max_batch,),
-                            self._decode_fns[self._max_batch], call_args)
-        cache, nxt = self._decode_fns[self._max_batch](*call_args)
-        return cache, np.asarray(nxt)
-
-    # -- decode (paged mode) -------------------------------------------------
+    # -- decode --------------------------------------------------------------
 
     def _paged_step_body(self, params, cache, tokens, positions, tables,
                          num_layers: int):
@@ -1117,8 +860,8 @@ class MLPAdapter(ModelAdapter):
     """Cache-free stand-in model for engine-mechanics tests: the next
     token is ``argmax(MLP(one_hot(token)))`` — a deterministic Markov
     chain over the vocab, so batching/requeue/parity logic is exercised
-    without transformer compile cost.  Serves in both modes: its paged
-    interface consumes zero blocks (``kv_token_cost = 0``).  Sampling
+    without transformer compile cost.  It consumes zero blocks
+    (``kv_token_cost = 0``), so it needs no ``copy_block``.  Sampling
     draws from ``softmax(MLP(one_hot(token)))`` through the same keyed
     sampler as the transformer, and the spec draft is the model ITSELF
     (``draft_decode`` == greedy decode): a perfect proposer, which is
@@ -1158,15 +901,8 @@ class MLPAdapter(ModelAdapter):
         self._apply = jax.jit(mlp_greedy)
         self._sampled_step = jax.jit(mlp_sampled)
 
-    def init_cache(self, max_batch: int):
-        return ()
-
     def init_paged_cache(self, num_blocks: int, max_batch: int):
         return ()
-
-    def prefill(self, cache, prompts, slots):
-        last = np.asarray([p[-1] for p in prompts], np.int32)
-        return cache, np.asarray(self._apply(last))
 
     def prefill_chunk(self, cache, chunks, starts, tables):
         # Next token depends only on the chunk's last token; non-final
@@ -1190,11 +926,8 @@ class MLPAdapter(ModelAdapter):
         flat = np.asarray(self._logits_of(tok.reshape(-1)))
         return cache, flat.reshape(n, c, self.vocab_size)
 
-    def decode(self, cache, tokens, positions):
-        return cache, np.asarray(self._apply(np.asarray(tokens, np.int32)))
-
     def decode_paged(self, cache, tokens, positions, tables):
-        return self.decode(cache, tokens, positions)
+        return cache, np.asarray(self._apply(np.asarray(tokens, np.int32)))
 
     def decode_paged_logits(self, cache, tokens, positions, tables):
         # Host-mode decode (hvdstream): the raw distribution per row.
@@ -1229,24 +962,15 @@ class MLPAdapter(ModelAdapter):
         # The draft IS the target (perfect proposer): greedy spec then
         # accepts every draft and the engine's amortization machinery is
         # exercised at its theoretical ceiling.
-        return self.decode(cache, tokens, positions)
+        return self.decode_paged(cache, tokens, positions, tables)
 
 
 # ---------------------------------------------------------------------------
 # Engine
 # ---------------------------------------------------------------------------
 
-class _Slot:
-    """Slot-mode sequence state (contiguous per-slot cache rows)."""
-    __slots__ = ("request", "length")
-
-    def __init__(self, request: Request, length: int):
-        self.request = request
-        self.length = length  # prompt + generated so far (cache positions)
-
-
 class _Seq:
-    """Paged-mode sequence state.
+    """One sequence's state.
 
     ``generated`` is the authoritative token list for THIS sequence: for
     a plain n==1 request it IS ``request.generated`` (the same list
@@ -1259,7 +983,7 @@ class _Seq:
                  "admit_seq", "published", "generated", "group",
                  "sample_index", "base_key", "parked", "resident",
                  "pending_fetch", "host_kv", "swap_step", "tier_credit",
-                 "gstate", "sp_state")
+                 "gstate")
 
     def __init__(self, request: Request, cached_tokens: int,
                  table: List[int], hashes: List[int], admit_seq: int):
@@ -1293,12 +1017,6 @@ class _Seq:
         # emptied token list.
         self.gstate = (request.grammar.start
                        if request.grammar is not None else None)
-        # Sequence-parallel prefill (serve/seqpar.py): the in-flight
-        # SPJob while this sequence prefills across the SP world's
-        # ranks — _prefill_step skips such sequences, _sp_step drives
-        # them.  None = single-rank prefill (the default and the
-        # fallback).
-        self.sp_state = None
 
     @property
     def decoding(self) -> bool:
@@ -1317,7 +1035,7 @@ class _ForkGroup:
     footprint — the (n-1) fork tails admission COUNTED in its budget
     but did not allocate (the forks grow into them at decode time:
     the CoW copy of the shared partial prompt block plus each fork's
-    decode blocks).  ``_admit_paged`` subtracts the live groups'
+    decode blocks).  ``_admit`` subtracts the live groups'
     reserves from the pool budget so a later admission round can never
     hand those blocks to someone else — which would turn preemption
     from a defensive path into a steady-state tax on every n>1
@@ -1338,10 +1056,9 @@ class InferenceEngine:
     """One continuous-batching decode loop (one per serving replica).
 
     Owns: the model adapter, the slot table, the KV storage (block pool +
-    BlockManager in paged mode, contiguous cache in slot mode), and a
-    worker thread running admit → prefill → decode forever.  Completion is
-    per-request (batcher.Request events); the loop never blocks while any
-    sequence is active.
+    BlockManager), and a worker thread running admit → prefill → decode
+    forever.  Completion is per-request (batcher.Request events); the loop
+    never blocks while any sequence is active.
     """
 
     def __init__(self, adapter: ModelAdapter,
@@ -1349,16 +1066,13 @@ class InferenceEngine:
                  metrics: Optional[ServeMetrics] = None,
                  max_batch: Optional[int] = None,
                  replica_id: str = "replica-0",
-                 kv_mode: Optional[str] = None,
                  num_blocks: Optional[int] = None,
                  prefill_chunk: Optional[int] = None,
                  prefix_cache: Optional[bool] = None,
                  spec_k: Optional[int] = None,
                  warmup: Optional[bool] = None,
                  tiering: Optional[TierConfig] = None,
-                 tier_client=None,
-                 sp_ranks: Optional[int] = None,
-                 sp_min_tokens: Optional[int] = None):
+                 tier_client=None):
         self.adapter = adapter
         # Multi-model residency (serve/registry.py): named variants
         # sharing this engine's slots and paged pool.  ``adapter`` stays
@@ -1389,123 +1103,80 @@ class InferenceEngine:
         # just stops spending draft compute and draft-tail KV blocks
         # under pressure.  The admission-side rungs live on the batcher.
         self.brownout_level = 0
-        mode = (kv_mode or os.environ.get("HVD_SERVE_KV_MODE",
-                                          "auto")).lower()
-        paged_capable = all(
-            hasattr(adapter, m)
-            for m in ("init_paged_cache", "prefill_chunk", "decode_paged"))
-        if mode == "auto":
-            mode = "paged" if paged_capable else "slot"
-        if mode not in ("paged", "slot"):
-            raise ValueError(f"kv_mode must be paged|slot|auto, got {mode}")
-        if mode == "paged" and not paged_capable:
-            raise ValueError(
-                f"{type(adapter).__name__} has no paged interface "
-                f"(prefill_chunk/decode_paged); use kv_mode='slot'")
-        self.kv_mode = mode
+        missing = _missing_paged_members(adapter)
+        if missing:
+            raise TypeError(
+                f"{type(adapter).__name__} cannot serve: it lacks "
+                f"{', '.join(missing)} (the paged interface, ModelAdapter)")
         # Per-replica observability of HOW attention runs (gather vs the
         # Pallas kernel) and how KV is stored — surfaced through
-        # kv_stats()/replica.to_dict()/metrics exposition.  Slot mode
-        # ignores both adapter knobs (dense attention over the
-        # compute-dtype slot cache), so it reports what it actually
-        # runs, not what the adapter was configured with.
-        if mode == "paged":
-            self.attn_impl = getattr(adapter, "attn_impl", "gather")
-            self.kv_dtype = getattr(adapter, "kv_dtype", "native")
-        else:
-            self.attn_impl = "dense"
-            self.kv_dtype = "native"
-        self.blocks: Optional[BlockManager] = None
-        if mode == "paged":
-            self._mb = int(getattr(adapter, "max_blocks_per_seq", 0))
-            bt = int(getattr(adapter, "block_tokens", 1))
-            nb = (num_blocks if num_blocks is not None
-                  else int(os.environ.get("HVD_SERVE_NUM_BLOCKS", "0")))
-            if nb <= 0:
-                # Default pool = the slot layout's HBM footprint
-                # (max_batch × max_len tokens): same budget, but shared,
-                # so mixed-length traffic admits far more sequences.
-                nb = self.max_batch * max(self._mb, 1)
-            pc = (prefix_cache if prefix_cache is not None
-                  else os.environ.get("HVD_SERVE_PREFIX_CACHE", "1")
-                  not in ("0", "false"))
-            bpb_fn = getattr(adapter, "paged_block_bytes", None)
-            bpb = int(bpb_fn()) if callable(bpb_fn) else None
-            # Tiered-KV hierarchy (serve/tiering.py, docs/serving.md):
-            # explicit config wins, else HVD_SERVE_TIER gates the env
-            # path.  Untiered stays a plain BlockManager — zero behavior
-            # change on every existing deployment.
-            self.tiering = (tiering if tiering is not None
-                            else TierConfig.from_env())
-            if self.tiering is not None and not self.tiering.enabled:
-                self.tiering = None
-            self._tier_client: Optional[TierClient] = None
-            if self.tiering is not None:
-                client = tier_client
-                if client is None and self.tiering.kv_addr:
-                    from ..runner.http_server import KVStoreClient
-                    host, _, port = self.tiering.kv_addr.rpartition(":")
-                    client = KVStoreClient(host or "127.0.0.1",
-                                           int(port))
-                if client is not None and not isinstance(client,
-                                                         TierClient):
-                    client = TierClient(client, replica_id=replica_id)
-                self._tier_client = client
-                self.blocks = TieredBlockManager(
-                    nb, bt, self.tiering, prefix_cache=pc,
-                    bytes_per_block=bpb, client=client)
-            else:
-                self.blocks = BlockManager(
-                    nb, bt, prefix_cache=pc, bytes_per_block=bpb)
-            chunk = (prefill_chunk if prefill_chunk is not None
-                     else int(os.environ.get("HVD_SERVE_PREFILL_CHUNK",
-                                             "64")))
-            # <= 0 disables chunking: whole prompts prefill in one
-            # iteration (the unchunked bench/interference baseline).
-            self._chunk_budget = chunk if chunk > 0 else None
-            self._cache = adapter.init_paged_cache(nb, self.max_batch)
-            # Sequence-parallel long-prompt prefill (serve/seqpar.py,
-            # hvdseqserve): an emulated multi-rank world splitting
-            # prompts past sp_min_tokens by sequence extent.  Built
-            # BEFORE _verify_pool_budget so the plan verdict attributes
-            # the ring's per-prefill wire bytes (HVD401).
-            from .seqpar import SPConfig, SPWorld
-            sp_cfg = SPConfig(ranks=sp_ranks, min_tokens=sp_min_tokens)
-            self.seqpar: Optional[SPWorld] = None
-            if sp_cfg.enabled and hasattr(adapter, "sp_prefill_chunk"):
-                self.seqpar = SPWorld(adapter, sp_cfg.ranks,
-                                      sp_cfg.min_tokens,
-                                      replica_id=replica_id)
-                self.seqpar.prime(self)
-            self._verify_pool_budget(nb)
-            if self.tiering is not None:
-                # Device IO pair + tier worker + loop-side arrival
-                # plumbing.  Arrivals are (worker → loop) messages; the
-                # deque is appended under no lock (worker) and drained
-                # at iteration top (loop) — deque.append/popleft are
-                # atomic, and _tier_event lets a stalled loop wake the
-                # moment a fetch lands instead of polling.
-                self.blocks.set_device_io(*make_block_io(self))
-                self._tier_arrivals: deque = deque()
-                self._tier_event = threading.Event()
-                self._tier_worker: Optional[TierWorker] = None
-                if self._tier_client is not None:
-                    self._tier_worker = TierWorker(
-                        self.blocks, self._tier_client,
-                        self._tier_notify, replica_id=replica_id)
-                self._tier_stall_anchor: Optional[float] = None
-                self.tier_faults = 0
-                self.inflight_peak = 0
-                self._tier_peeked: set = set()
-        else:
-            self._mb = 0
-            self._cache = adapter.init_cache(self.max_batch)
-            self.pool_bytes = self.weight_bytes = 0
-            self.kv_headroom_bytes: Optional[int] = None
-            self.plan_verdict = None
+        # kv_stats()/replica.to_dict()/metrics exposition.
+        self.attn_impl = getattr(adapter, "attn_impl", "gather")
+        self.kv_dtype = getattr(adapter, "kv_dtype", "native")
+        self._mb = int(getattr(adapter, "max_blocks_per_seq", 0))
+        bt = int(getattr(adapter, "block_tokens", 1))
+        nb = (num_blocks if num_blocks is not None
+              else int(os.environ.get("HVD_SERVE_NUM_BLOCKS", "0")))
+        if nb <= 0:
+            # Default pool = max_batch × max_len tokens, shared, so
+            # mixed-length traffic admits far more than max_batch
+            # sequences of full length would.
+            nb = self.max_batch * max(self._mb, 1)
+        pc = (prefix_cache if prefix_cache is not None
+              else os.environ.get("HVD_SERVE_PREFIX_CACHE", "1")
+              not in ("0", "false"))
+        bpb_fn = getattr(adapter, "paged_block_bytes", None)
+        bpb = int(bpb_fn()) if callable(bpb_fn) else None
+        # Tiered-KV hierarchy (serve/tiering.py, docs/serving.md):
+        # explicit config wins, else HVD_SERVE_TIER gates the env
+        # path.  Untiered stays a plain BlockManager — zero behavior
+        # change on every existing deployment.
+        self.tiering = (tiering if tiering is not None
+                        else TierConfig.from_env())
+        if self.tiering is not None and not self.tiering.enabled:
             self.tiering = None
-            self._tier_client = None
-            self.seqpar = None
+        self._tier_client: Optional[TierClient] = None
+        if self.tiering is not None:
+            client = tier_client
+            if client is None and self.tiering.kv_addr:
+                from ..runner.http_server import KVStoreClient
+                host, _, port = self.tiering.kv_addr.rpartition(":")
+                client = KVStoreClient(host or "127.0.0.1", int(port))
+            if client is not None and not isinstance(client, TierClient):
+                client = TierClient(client, replica_id=replica_id)
+            self._tier_client = client
+            self.blocks = TieredBlockManager(
+                nb, bt, self.tiering, prefix_cache=pc,
+                bytes_per_block=bpb, client=client)
+        else:
+            self.blocks = BlockManager(
+                nb, bt, prefix_cache=pc, bytes_per_block=bpb)
+        chunk = (prefill_chunk if prefill_chunk is not None
+                 else int(os.environ.get("HVD_SERVE_PREFILL_CHUNK", "64")))
+        # <= 0 disables chunking: whole prompts prefill in one
+        # iteration (the unchunked bench/interference baseline).
+        self._chunk_budget = chunk if chunk > 0 else None
+        self._cache = adapter.init_paged_cache(nb, self.max_batch)
+        self._verify_pool_budget(nb)
+        if self.tiering is not None:
+            # Device IO pair + tier worker + loop-side arrival
+            # plumbing.  Arrivals are (worker → loop) messages; the
+            # deque is appended under no lock (worker) and drained
+            # at iteration top (loop) — deque.append/popleft are
+            # atomic, and _tier_event lets a stalled loop wake the
+            # moment a fetch lands instead of polling.
+            self.blocks.set_device_io(*make_block_io(self))
+            self._tier_arrivals: deque = deque()
+            self._tier_event = threading.Event()
+            self._tier_worker: Optional[TierWorker] = None
+            if self._tier_client is not None:
+                self._tier_worker = TierWorker(
+                    self.blocks, self._tier_client,
+                    self._tier_notify, replica_id=replica_id)
+            self._tier_stall_anchor: Optional[float] = None
+            self.tier_faults = 0
+            self.inflight_peak = 0
+            self._tier_peeked: set = set()
         # Decode-algorithm layer (docs/serving.md sampling/spec): seeded
         # sampling + n>1 forking need the logits/sampled adapter
         # programs; speculative decoding additionally needs the
@@ -1514,26 +1185,20 @@ class InferenceEngine:
         # admission (_fail_doomed) so a legacy adapter keeps serving
         # greedy n==1 exactly as before.
         self._sample_capable = (
-            mode == "paged"
-            and hasattr(adapter, "decode_paged_sampled")
+            hasattr(adapter, "decode_paged_sampled")
             and hasattr(adapter, "prefill_chunk_logits"))
         sk = (spec_k if spec_k is not None
               else int(os.environ.get("HVD_SERVE_SPEC_K", "0")))
         if sk < 0:
             raise ValueError(f"spec_k must be >= 0, got {sk}")
-        if sk > 0:
-            if mode != "paged":
-                raise ValueError(
-                    "speculative decoding requires kv_mode='paged' "
-                    "(the draft shares the paged pool)")
-            if not (hasattr(adapter, "verify_chunk")
-                    and hasattr(adapter, "draft_decode")
-                    and getattr(adapter, "spec_capable", False)):
-                raise ValueError(
-                    f"{type(adapter).__name__} has no usable draft for "
-                    f"speculative decoding (verify_chunk/draft_decode + "
-                    f"spec_capable — transformer adapters need "
-                    f"HVD_SERVE_DRAFT_LAYERS >= 1)")
+        if sk > 0 and not (hasattr(adapter, "verify_chunk")
+                           and hasattr(adapter, "draft_decode")
+                           and getattr(adapter, "spec_capable", False)):
+            raise ValueError(
+                f"{type(adapter).__name__} has no usable draft for "
+                f"speculative decoding (verify_chunk/draft_decode + "
+                f"spec_capable — transformer adapters need "
+                f"HVD_SERVE_DRAFT_LAYERS >= 1)")
         self.spec_k = sk
         # n>1 fork observability (/metrics + kv_stats/healthz): total
         # forked sequences created (n-1 per forked group) and requests
@@ -1619,21 +1284,12 @@ class InferenceEngine:
         # the comm half passes trivially; a tensor/pipeline-sharded
         # adapter declares its measured per-decode-step wire bytes.
         from ..analysis import shardplan as _shardplan
-        # Sequence-parallel prefill adds a REAL per-prefill wire cost
-        # (the ring's K/V rotation, serve/seqpar.py) on an otherwise
-        # zero-collective replica: attribute its worst-case bytes into
-        # the comm half so plan_go on healthz reflects the multi-rank
-        # prefill's budget.
-        self.sp_comm_bytes = (self.seqpar.ring_bytes_per_prefill()
-                              if getattr(self, "seqpar", None) is not None
-                              else 0)
         self.plan_verdict = _shardplan.check_replica_plan(
             f"serve:{self.replica_id}:plan",
             pool_bytes=self.pool_bytes,
             weight_bytes=self.weight_bytes,
             step_comm_bytes=int(getattr(self.adapter,
-                                        "step_comm_bytes", 0) or 0)
-            + self.sp_comm_bytes,
+                                        "step_comm_bytes", 0) or 0),
             step_dcn_bytes=int(getattr(self.adapter,
                                        "step_dcn_bytes", 0) or 0))
         if not self.plan_verdict.go:
@@ -1647,11 +1303,11 @@ class InferenceEngine:
         the default adapter's — checked loudly at add/swap time, not at
         the first mismatched gather."""
         base = self.adapter
-        if not all(hasattr(adapter, m) for m in
-                   ("init_paged_cache", "prefill_chunk", "decode_paged")):
+        missing = _missing_paged_members(adapter)
+        if missing:
             raise ValueError(
-                f"{type(adapter).__name__} has no paged interface; "
-                f"multi-model residency is paged-only")
+                f"{type(adapter).__name__} cannot be resident: it lacks "
+                f"{', '.join(missing)}")
         for attr in ("max_len", "block_tokens", "max_blocks_per_seq",
                      "kv_token_cost"):
             a, b = getattr(adapter, attr, None), getattr(base, attr, None)
@@ -1683,18 +1339,9 @@ class InferenceEngine:
     def add_model(self, name: str, adapter, version: int = 0) -> None:
         """Make variant ``name`` resident: it shares the slot table and
         the paged pool with the default model (requests partition by
-        model per iteration, _prefill_step/_decode_once_paged).
-
-        Paged-only BY DESIGN: the slot-mode decode program writes K/V at
-        position 0 of every INACTIVE row (masked reads make that
-        harmless single-model), so interleaving a second model's decode
-        would corrupt the other group's live caches.  The paged
-        programs address exclusively through block tables — an all-hole
-        row touches nothing."""
-        if self.kv_mode != "paged":
-            raise ValueError(
-                "multi-model residency requires kv_mode='paged' "
-                "(slot-mode decode clobbers inactive rows)")
+        model per iteration, _prefill_step/_decode_once).  The programs
+        address exclusively through block tables: an all-hole row (a
+        row of another model's group) touches nothing."""
         if name == self.default_model or name in self._adapters:
             raise ValueError(f"model {name!r} already resident; use "
                              "swap_model to change its weights")
@@ -1735,8 +1382,7 @@ class InferenceEngine:
         self._model_versions[name] = int(version)
         if name == self.default_model:
             self.adapter = adapter
-        if self.kv_mode == "paged":
-            self._verify_pool_budget(self.blocks.num_blocks)
+        self._verify_pool_budget(self.blocks.num_blocks)
 
     def _adapter_for(self, model: Optional[str]):
         return self._adapters[model or self.default_model]
@@ -1779,8 +1425,8 @@ class InferenceEngine:
         ad = self._adapter_for(model)
         if not hasattr(ad, "score_logits"):
             raise ValueError(
-                f"{type(ad).__name__} has no score_logits program; "
-                f"/score needs a paged-capable adapter")
+                f"{type(ad).__name__} has no score_logits program, "
+                f"which /score needs")
         tokens = [int(t) for t in tokens]
         for t in tokens:
             if not 0 <= t < ad.vocab_size:
@@ -1820,13 +1466,11 @@ class InferenceEngine:
         """Routing load: in-flight sequences + queued requests."""
         return self.active_count + self.batcher.depth()
 
-    def kv_stats(self) -> Optional[dict]:
-        """Block-pool utilization / prefix-cache statistics (None in slot
-        mode) — sampled by metrics render and replica healthz.  Carries
-        the engine's attention impl + KV storage dtype so both are
-        visible per replica on every export surface."""
-        if self.blocks is None:
-            return None
+    def kv_stats(self) -> dict:
+        """Block-pool utilization / prefix-cache statistics — sampled by
+        metrics render and replica healthz.  Carries the engine's
+        attention impl + KV storage dtype so both are visible per
+        replica on every export surface."""
         stats = self.blocks.stats()
         stats["attn_impl"] = self.attn_impl
         stats["kv_dtype"] = self.kv_dtype
@@ -1849,21 +1493,14 @@ class InferenceEngine:
         # combined with the per-step comm budget (HVD401) — rides
         # kv_stats so healthz + /metrics show whether this replica's
         # plan was admitted and with how much headroom.
-        verdict = getattr(self, "plan_verdict", None)
-        if verdict is not None:
-            stats["plan_go"] = verdict.go
-            stats["plan_findings"] = len(verdict.findings)
+        stats["plan_go"] = self.plan_verdict.go
+        stats["plan_findings"] = len(self.plan_verdict.findings)
         if self.tiering is not None and "tier" in stats:
             # Loop-side tier counters next to the manager's: stall
             # episodes and the oversubscription high-water mark (the
             # tiered admit-ratio numerator in the bench).
             stats["tier"]["faults"] = self.tier_faults
             stats["tier"]["inflight_peak"] = self.inflight_peak
-        if self.seqpar is not None:
-            # Sequence-parallel prefill world (serve/seqpar.py): rank
-            # count, thresholds, and the job/handoff/ring counters —
-            # rides kv_stats onto healthz + /metrics like the tier's.
-            stats["sp"] = self.seqpar.stats()
         return stats
 
     def tier_unpublish(self) -> int:
@@ -1908,10 +1545,7 @@ class InferenceEngine:
                 return 0.0
         t0 = time.monotonic()
         try:
-            if self.kv_mode == "paged":
-                self._warmup_paged()
-            else:
-                self._warmup_slot()
+            self._warmup_lattice()
         except Exception as exc:
             get_logger().warning(
                 "%s: warmup failed (%s: %s); serving cold",
@@ -1925,10 +1559,10 @@ class InferenceEngine:
                           self.replica_id, self.warmup_runs, ms)
         return ms
 
-    def _warmup_paged(self) -> None:
+    def _warmup_lattice(self) -> None:
         """Drive every resident adapter (id-deduped: variants sharing
-        one adapter object compile once) through the paged bucket
-        lattice.  Chunks are all-hole — empty block tables map every
+        one adapter object compile once) through the bucket lattice.
+        Chunks are all-hole — empty block tables map every
         K/V write onto the dropped sentinel row — so retained prefix
         blocks and pool accounting are untouched; only the compile
         caches change.  Decode warms at its single runtime shape:
@@ -1957,33 +1591,6 @@ class InferenceEngine:
             tables = np.full((self.max_batch, self._mb), nb, np.int32)
             self._cache, _ = ad.decode_paged(
                 self._cache, tokens, positions, tables)
-        if self.seqpar is not None:
-            # SP bucket lattice (serve/seqpar.py): every (chunk, hop)
-            # bucket an eligible long prompt can hit, so a revived
-            # multi-rank replica pays zero first-long-prompt compiles.
-            self.seqpar.warmup(self._chunk_budget)
-
-    def _warmup_slot(self) -> None:
-        """Slot-mode ladder (single adapter — add_model refuses slot
-        engines).  Writes land in real cache rows, which is safe only
-        because the empty-slot guard in warmup() held: the first real
-        prefill into any slot overwrites position 0 wholesale."""
-        ad = self.adapter
-        lens: List[int] = []
-        c = prompt_bucket(1, cap=ad.max_len)
-        while True:
-            lens.append(c)
-            if c >= ad.max_len:
-                break
-            c = min(c * 2, ad.max_len)
-        for n in self._warmup_counts():
-            slots = list(range(n))
-            for c in lens:
-                self._cache, _ = ad.prefill(
-                    self._cache, [[0] * c for _ in range(n)], slots)
-        self._cache, _ = ad.decode(
-            self._cache, np.zeros((self.max_batch,), np.int32),
-            np.zeros((self.max_batch,), np.int32))
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -2050,8 +1657,7 @@ class InferenceEngine:
             for i, s in enumerate(self._slots):
                 if s is None:
                     continue
-                if self.blocks is not None:
-                    self.blocks.free_table(s.table)
+                self.blocks.free_table(s.table)
                 self._slots[i] = None
                 r = s.request
                 if id(r) in seen:
@@ -2064,10 +1670,9 @@ class InferenceEngine:
                     r.token_logprobs = []
                 if r.samples is not None:
                     r.samples = [None] * r.n
-                group = getattr(s, "group", None)  # slot mode holds _Slot
-                if group is not None:
-                    group.completed = 0
-                    group.forked = False
+                if s.group is not None:
+                    s.group.completed = 0
+                    s.group.forked = False
                 r.requeues += 1
                 # Failover bookkeeping: the next admission (on the
                 # survivor) emits the resubmission span from here.
@@ -2082,18 +1687,8 @@ class InferenceEngine:
             return [i for i, s in enumerate(self._slots) if s is None]
 
     @staticmethod
-    def _finished(r: Request, token: int) -> bool:
-        if r.eos_id is not None and token == r.eos_id:
-            r.finish_reason = "stop"
-            return True
-        if len(r.generated) >= r.max_new_tokens:
-            r.finish_reason = "length"
-            return True
-        return False
-
-    @staticmethod
     def _seq_finished(s: "_Seq", token: int) -> bool:
-        """Per-sequence finish check (paged mode): a fork finishes on
+        """Per-sequence finish check: a fork finishes on
         its OWN stream, not the request's sample-0 mirror.  Finish
         decisions record ``finish_reason`` on n==1 requests (hvdstream:
         the terminal event / response field): ``stop`` (EOS), ``length``
@@ -2153,8 +1748,7 @@ class InferenceEngine:
         LAST fork retires (each fork's stream lands in
         ``request.samples[sample_index]``; ``request.generated`` mirrors
         sample 0).  Caller holds ``self._lock``."""
-        if self.blocks is not None:
-            self.blocks.free_table(s.table)
+        self.blocks.free_table(s.table)
         # The table is FREED now; clear it so group-level paths that
         # walk ``group.seqs`` later (a pool-exhaustion preempt of a
         # surviving member, expiry) can never free it a second time — a
@@ -2208,9 +1802,8 @@ class InferenceEngine:
         for f in group.seqs:
             if f is not s:
                 f.table = list(s.table[:shared])
-                if self.blocks is not None:
-                    for bid in f.table:
-                        self.blocks.ref(bid)
+                for bid in f.table:
+                    self.blocks.ref(bid)
                 f.length = s.length
                 f.prompt_pos = P
                 f.parked = False
@@ -2253,7 +1846,7 @@ class InferenceEngine:
         now = time.monotonic()
         if r.finish_reason is None:
             # The engine-cap retirement paths (s.length >= max_len)
-            # complete without a _finished verdict — the client-visible
+            # complete without a _seq_finished verdict — the client-visible
             # reason is the same as exhausting max_new_tokens.
             r.finish_reason = "length"
         if r.first_token_at is not None:
@@ -2375,16 +1968,14 @@ class InferenceEngine:
                 f"max_len {ad.max_len}"))
             self.metrics.count_request("error", tenant=r.tenant)
             return True
-        # Sampling / n>1 need the logits + sampled adapter programs and
-        # the paged engine (fork tables are CoW block tables; the slot
-        # layout has nothing to fork) — fail loudly instead of silently
-        # serving a greedy single answer to a sampled n-best request.
+        # Sampling / n>1 need the logits + sampled adapter programs —
+        # fail loudly instead of silently serving a greedy single answer
+        # to a sampled n-best request.
         if (r.sampled or r.n > 1) and not self._sample_capable:
             r.fail(ValueError(
-                f"{r.request_id}: sampling/n>1 needs a paged engine and "
-                f"an adapter with prefill_chunk_logits/"
-                f"decode_paged_sampled (kv_mode={self.kv_mode}, "
-                f"adapter {type(self.adapter).__name__})"))
+                f"{r.request_id}: sampling/n>1 needs an adapter with "
+                f"prefill_chunk_logits/decode_paged_sampled "
+                f"(adapter {type(self.adapter).__name__})"))
             self.metrics.count_request("error", tenant=r.tenant)
             return True
         if r.n > self.max_batch:
@@ -2394,17 +1985,16 @@ class InferenceEngine:
             self.metrics.count_request("error", tenant=r.tenant)
             return True
         # hvdstream structured decoding / per-token logprobs need the
-        # paged engine's host-mode decode step (raw logits on the host:
+        # host-mode decode step (raw logits on the host:
         # decode_paged_logits) — fail loudly rather than silently drop
         # the mask or the logprobs (serve/structured.py, docs/serving.md).
         if r.schema is not None or r.logprobs is not None:
-            if (self.kv_mode != "paged" or not self._sample_capable
+            if (not self._sample_capable
                     or not hasattr(ad, "decode_paged_logits")):
                 r.fail(ValueError(
-                    f"{r.request_id}: schema/logprobs need a paged "
-                    f"engine and an adapter with decode_paged_logits + "
-                    f"prefill_chunk_logits (kv_mode={self.kv_mode}, "
-                    f"adapter {type(ad).__name__})"))
+                    f"{r.request_id}: schema/logprobs need an adapter "
+                    f"with decode_paged_logits + prefill_chunk_logits "
+                    f"(adapter {type(ad).__name__})"))
                 self.metrics.count_request("error", tenant=r.tenant)
                 return True
         if r.schema is not None and r.grammar is None:
@@ -2418,8 +2008,7 @@ class InferenceEngine:
         # kv_token_cost and the n>1 shared-prompt + n-tails shape) — a
         # mismatch would let _take's hard_cap bypass pop a request this
         # check then declines to fail: an infinite requeue livelock.
-        if self.blocks is not None and self._mb and \
-                self._request_cost_blocks(r) > self.blocks.capacity:
+        if self._mb and self._request_cost_blocks(r) > self.blocks.capacity:
             r.fail(ValueError(
                 f"{r.request_id}: needs "
                 f"{self._request_cost_blocks(r)} KV blocks but the "
@@ -2447,11 +2036,7 @@ class InferenceEngine:
                 # member in turn — only the first fails the request).
                 if id(s.request) not in failed:
                     failed.add(id(s.request))
-                    # Slot-mode _Slot has no per-sequence stream; the
-                    # request's own list is the authority there.
-                    gen = getattr(s, "generated", None)
-                    ntokens = len(gen if gen is not None
-                                  else s.request.generated)
+                    ntokens = len(s.generated)
                     if s.request.expired(now):
                         s.request.fail(DeadlineExceededError(
                             f"{s.request.request_id} deadline expired "
@@ -2481,9 +2066,7 @@ class InferenceEngine:
                                       self.replica_id,
                                       args={"tokens": ntok}, t=now)
                         self._trace_emits.append(emit)
-                table = getattr(s, "table", None)
-                if self.blocks is not None and table is not None:
-                    self.blocks.free_table(table)
+                self.blocks.free_table(s.table)
                 self._slots[i] = None
                 expired += 1
         self._flush_trace_emits()
@@ -2503,113 +2086,16 @@ class InferenceEngine:
             if f.kind == "slow-decode":
                 time.sleep(f.param or 0.02)
             elif f.kind == "pool-corrupt-block":
-                if self.blocks is not None:
-                    n = self.blocks.invalidate_retained(
-                        max(int(f.param), 1))
-                    get_logger().warning(
-                        "%s: faultline scrubbed %d retained KV block(s)",
-                        self.replica_id, n)
+                n = self.blocks.invalidate_retained(max(int(f.param), 1))
+                get_logger().warning(
+                    "%s: faultline scrubbed %d retained KV block(s)",
+                    self.replica_id, n)
             elif f.kind == "poison-step":
                 raise FaultInjected(
                     f"faultline: poisoned step on {self.replica_id} "
                     f"(step {self.steps})")
 
-    # -- slot-mode loop ------------------------------------------------------
-
-    def _admit(self, block_s: float) -> int:
-        free = self._free_slots()
-        if not free:
-            return 0
-        admitted = self.batcher.get_admission(len(free), block_s=block_s)
-        if not admitted:
-            return 0
-        self._observe_admission(admitted)
-        cursor = 0
-        for p_bucket, group in sorted(
-                bucket_requests(admitted, cap=self.adapter.max_len).items()):
-            # One prefill per shape bucket (batcher module doc); requests
-            # whose prompt would overflow the cache fail loudly here.
-            runnable = [r for r in group if not self._fail_doomed(r)]
-            if not runnable:
-                continue
-            slots = free[cursor:cursor + len(runnable)]
-            cursor += len(runnable)
-            t0 = time.monotonic()
-            self._cache, first = self.adapter.prefill(
-                self._cache, [r.prompt for r in runnable], slots)
-            now = time.monotonic()
-            with self._lock:
-                for r, slot, tok in zip(runnable, slots, first):
-                    r.replica_id = self.replica_id
-                    r.first_token_at = now
-                    r.generated.append(int(tok))
-                    self._publish_stream(r, r.generated)
-                    r.stage_add("prefill", now)
-                    self.metrics.observe_ttft((now - r.submitted_at) * 1e3)
-                    if r.trace is not None and _obs.TRACER is not None:
-                        def emit(t=_obs.TRACER, r=r, t0=t0, now=now,
-                                 p_bucket=p_bucket, n=len(runnable)):
-                            t.emit_span(r.trace, "prefill", t0, now,
-                                        self.replica_id,
-                                        args={"bucket": p_bucket,
-                                              "batch": n})
-                        self._trace_emits.append(emit)
-                        self._defer_flow(r)
-                    if self._finished(r, int(tok)):
-                        self._complete(r)
-                    else:
-                        # Cache holds positions 0..P-1; the first decode
-                        # feeds the prefill's token at position P.
-                        self._slots[slot] = _Slot(r, len(r.prompt))
-            self._flush_trace_emits()
-            get_logger().debug(
-                "%s: admitted %d (bucket %d) in %.1f ms", self.replica_id,
-                len(runnable), p_bucket, (now - t0) * 1e3)
-        return cursor
-
-    def _decode_once(self) -> int:
-        with self._lock:
-            active = [(i, s) for i, s in enumerate(self._slots)
-                      if s is not None]
-        if not active:
-            self._step_anchor = None
-            return 0
-        tokens = np.zeros((self.max_batch,), np.int32)
-        positions = np.zeros((self.max_batch,), np.int32)
-        for i, s in active:
-            tokens[i] = s.request.generated[-1]
-            positions[i] = s.length  # next cache index = current length
-        t0 = time.monotonic()
-        self._cache, nxt = self.adapter.decode(self._cache, tokens,
-                                               positions)
-        now = time.monotonic()
-        # token_step is the INTER-decode-step latency while the engine
-        # stays busy: everything between two decode completions (prefill,
-        # admission) counts, so a prefill stalling decodes shows up in the
-        # p99 — the statistic chunked prefill is built to hold flat.
-        dt_ms = (now - (self._step_anchor if self._step_anchor is not None
-                        else t0)) * 1e3
-        self._step_anchor = now
-        with self._lock:
-            for i, s in active:
-                if self._slots[i] is not s:
-                    continue  # drained concurrently
-                tok = int(nxt[i])
-                s.request.generated.append(tok)
-                self._publish_stream(s.request, s.request.generated)
-                s.length += 1
-                self._defer_flow(s.request)
-                if self._finished(s.request, tok) \
-                        or s.length >= self.adapter.max_len:
-                    self._complete(s.request)
-                    self._slots[i] = None
-        self.steps += 1
-        self._flush_trace_emits()
-        self.metrics.observe_decode_step(dt_ms, len(active), len(active))
-        self.metrics.maybe_emit_timeline()
-        return len(active)
-
-    # -- paged-mode loop -----------------------------------------------------
+    # -- admission, prefill and decode ---------------------------------------
 
     def _blocks_for_tokens(self, tokens: int) -> int:
         if not self._mb:
@@ -2640,7 +2126,7 @@ class InferenceEngine:
         seen, total = set(), 0
         with self._lock:
             for s in self._slots:
-                g = getattr(s, "group", None) if s is not None else None
+                g = s.group if s is not None else None
                 if g is not None and id(g) not in seen:
                     seen.add(id(g))
                     total += g.reserve
@@ -3064,14 +2550,14 @@ class InferenceEngine:
             self._tier_stall_anchor = time.monotonic()
         self._tier_event.wait(timeout=0.002)
 
-    def _admit_paged(self, block_s: float) -> int:
+    def _admit(self, block_s: float) -> int:
         free = self._free_slots()
         if not free:
             return 0
-        use_blocks = self.blocks is not None and self._mb > 0
+        use_blocks = self._mb > 0
         # A sequence's whole lifetime fits prompt + max_new_tokens cache
-        # positions, so admission reserves exactly that (the paged win
-        # over slot mode is not reserving max_len) — no decode-time
+        # positions, so admission reserves exactly that, not max_len —
+        # no decode-time
         # growth can exhaust the pool, so preemption stays a defensive
         # path instead of a steady-state tax.  n>1 fork tails are
         # reserved, not allocated (the forks grow into them at decode
@@ -3091,16 +2577,11 @@ class InferenceEngine:
         elif use_blocks:
             budget = max(self.blocks.available()
                          - self._reserved_blocks(), 0)
-        sp = self.seqpar
         admitted = self.batcher.get_admission(
             len(free), block_s=block_s,
             budget=budget if use_blocks else None,
             cost=self._request_cost_blocks if use_blocks else None,
-            hard_cap=self.blocks.capacity if use_blocks else None,
-            sp_min_tokens=sp.min_tokens if sp is not None else None,
-            sp_capacity=sp.free_extent_blocks() if sp is not None else None,
-            sp_cost=((lambda r: sp.extent_cost_blocks(len(r.prompt)))
-                     if sp is not None else None))
+            hard_cap=self.blocks.capacity if use_blocks else None)
         if not admitted:
             return 0
         self._observe_admission(admitted)
@@ -3231,8 +2712,7 @@ class InferenceEngine:
             pending = [(i, s) for i, s in enumerate(self._slots)
                        if s is not None and not s.parked
                        and not s.decoding and s.resident
-                       and s.pending_fetch is None
-                       and s.sp_state is None]
+                       and s.pending_fetch is None]
         if not pending:
             return 0
         pending.sort(key=lambda t: t[1].admit_seq)
@@ -3303,7 +2783,7 @@ class InferenceEngine:
                 except Exception:
                     pass
         total = 0
-        bt = self.blocks.block_tokens if self.blocks is not None else 1
+        bt = self.blocks.block_tokens
         tiered = self.tiering is not None
         publishing = (tiered and self._tier_worker is not None
                       and self.tiering.publish)
@@ -3381,170 +2861,6 @@ class InferenceEngine:
             self._tier_publish(pub_jobs)
         return total
 
-    # -- sequence-parallel prefill (serve/seqpar.py) -------------------------
-
-    def _sp_eligible(self, s: "_Seq") -> bool:
-        """May this pending sequence prefill through the SP world?
-        Conservative by design — everything here falls back to the
-        proven single-rank chunked path, bit-identically:
-
-        * plain n==1 greedy/sampled requests only (grammar and logprob
-          requests need per-chunk host rows; fork groups prefill once
-          through their primary);
-        * not requeued (a kill-rank resubmission MUST make progress —
-          retrying through the component that just died would spin);
-        * not admission-denied (``sp_denied``, batcher._sp_charge);
-        * prompt untouched (``prompt_pos == 0`` — a prefix-cache hit
-          already skipped ahead) with its WHOLE block table allocated
-          (excludes tiered lazy admission — SP+tiering is future work);
-        * long enough to pay for the ring."""
-        r = s.request
-        bt = self.adapter.block_tokens
-        return (s.sp_state is None and not s.parked and s.resident
-                and s.pending_fetch is None and s.group is None
-                and r.n == 1 and r.grammar is None
-                and r.logprobs is None and r.requeues == 0
-                and not getattr(r, "sp_denied", False)
-                and s.prompt_pos == 0
-                and len(r.prompt) >= self.seqpar.min_tokens
-                and len(s.table) * bt >= len(r.prompt))
-
-    def _sp_step(self) -> int:
-        """Drive the SP world one emulated-rank chunk: claim the oldest
-        eligible pending sequence when the world is idle, advance the
-        active job otherwise.  Returns prompt tokens processed (the
-        iteration-observability twin of _prefill_step's)."""
-        sp = self.seqpar
-        job = sp.job
-        if job is None:
-            with self._lock:
-                cand = [(i, s) for i, s in enumerate(self._slots)
-                        if s is not None and self._sp_eligible(s)]
-            if not cand:
-                return 0
-            cand.sort(key=lambda t: t[1].admit_seq)
-            slot, s = cand[0]
-            job = sp.begin(s, slot)
-            if job is None:
-                return 0
-            s.sp_state = job
-            self._sp_wire_timeline()
-            _ring.emit_hop_schedule("sp_prefill", sp.ranks,
-                                    sp._hop_bytes())
-        # Faultline kill-rank drill (docs/serving.md): a rank dying
-        # mid-SP-prefill aborts the job — every rank's blocks free and
-        # the request resubmits whole through the preemption path.
-        for f in _faultline.fire("sp.prefill", self.replica_id):
-            if f.kind == "kill-rank":
-                get_logger().warning(
-                    "%s: faultline kill-rank at sp.prefill (rank %d)",
-                    self.replica_id, job.rank)
-                self._sp_abort(job)
-                return 0
-        with self._lock:
-            alive = self._slots[job.slot] is job.seq
-        if not alive:
-            # Drained/expired under us: the slot owner already released
-            # the main table; only the rank-side blocks remain.
-            sp.abort(job)
-            job.seq.sp_state = None
-            return 0
-        before = sp.sp_tokens_total
-        sp.step(self, self._chunk_budget)
-        took = sp.sp_tokens_total - before
-        self._sp_emit(job)
-        if job.done:
-            self._sp_complete(job)
-        return took
-
-    def _sp_wire_timeline(self) -> None:
-        """Route the ring layer's RING_HOP schedule events at the
-        tracer's timeline (PR 1's ``set_ring_timeline``), re-armed per
-        job so every SP prefill documents its hop schedule."""
-        tl = (getattr(_obs.TRACER, "_timeline", None)
-              if _obs.TRACER is not None else None)
-        if tl is not None:
-            _ring.set_ring_timeline(
-                tl, tensor_name=f"serve:{self.replica_id}:sp")
-
-    def _sp_emit(self, job) -> None:
-        """Drain the job's collected span records (per-extent chunk
-        compute + handoff) into the tracer as children of the request's
-        root — they all fall inside the prefill stage window, so
-        ``hvd_serve_stage_ms{stage=prefill}`` still partitions
-        exactly."""
-        spans, job.spans = job.spans, []
-        r = job.seq.request
-        if r.trace is None or _obs.TRACER is None:
-            return
-        for name, t0, t1, args in spans:
-            try:
-                _obs.TRACER.emit_span(r.trace, name, t0, t1,
-                                      self.replica_id, args=args)
-            except Exception:
-                pass
-
-    def _sp_complete(self, job) -> None:
-        """SP prefill done: every extent's blocks already sit in the
-        main pool (ahead-of-decode handoff), so this is _prefill_step's
-        completion block for one sequence — publish prefix blocks, draw
-        the first token from the final extent's logits on the host,
-        stamp TTFT, and hand the sequence to the proven single-rank
-        decode path."""
-        sp = self.seqpar
-        s = job.seq
-        r = s.request
-        now = time.monotonic()
-        with self._lock:
-            if self._slots[job.slot] is not s:
-                sp.abort(job)
-                s.sp_state = None
-                return
-            P = len(r.prompt)
-            s.prompt_pos = P
-            s.length = max(s.length, P)
-            bt = self.blocks.block_tokens
-            if self._mb and s.hashes:
-                for b in range(s.published, P // bt):
-                    self.blocks.register(s.hashes[b], s.table[b])
-                s.published = max(s.published, P // bt)
-            raw = job.final_logits
-            if r.sampled:
-                tok = _sampling.sample_host(raw, s.base_key, P,
-                                            r.temperature, r.top_k,
-                                            r.top_p)
-            else:
-                tok = int(np.argmax(raw))
-            r.first_token_at = now
-            s.generated.append(tok)
-            self._publish_stream(r, s.generated, None)
-            r.stage_add("prefill", now)
-            self.metrics.observe_ttft((now - r.submitted_at) * 1e3)
-            self.metrics.count_sp_prefill(P, job.handoff_bytes,
-                                          job.ring_hops)
-            self._defer_flow(r)
-            s.sp_state = None
-            sp.finish(job)
-            if self._seq_finished(s, tok):
-                self._retire_seq(job.slot, s)
-        self._flush_trace_emits()
-
-    def _sp_abort(self, job) -> None:
-        """kill-rank / lost-slot abort: free the rank-side extent blocks
-        (sp world) AND the sequence's main-pool table, then resubmit the
-        request whole — the standard preemption discipline, plus the SP
-        bookkeeping.  The resubmission re-admits with ``requeues > 0``,
-        which _sp_eligible rejects: the retry prefills single-rank, so
-        the drill always makes progress."""
-        s = job.seq
-        self.seqpar.abort(job)
-        s.sp_state = None
-        self.metrics.count_sp_abort()
-        with self._lock:
-            alive = self._slots[job.slot] is s
-        if alive:
-            self._preempt(job.slot, s)
-
     def _preempt(self, slot: int, s: "_Seq") -> None:
         """Victim path for pool exhaustion: release the sequence's blocks
         and requeue its request at the FRONT of this engine's own queue —
@@ -3554,11 +2870,6 @@ class InferenceEngine:
         cache).  An n>1 fork family is preempted as ONE unit: every
         member's blocks are released, every member slot cleared, and the
         request requeued once — half a fork group can never restart."""
-        if s.sp_state is not None and self.seqpar is not None:
-            # An SP-prefilling victim also holds transient extent blocks
-            # on every SP rank — release those first (zero leaks).
-            self.seqpar.abort(s.sp_state)
-            s.sp_state = None
         members = s.group.seqs if s.group is not None else [s]
         with self._lock:
             if s.group is None:
@@ -3679,7 +2990,7 @@ class InferenceEngine:
                         placed = True  # s itself evicted; skip this step
         return ok
 
-    def _decode_once_paged(self) -> int:
+    def _decode_once(self) -> int:
         with self._lock:
             active = [(i, s) for i, s in enumerate(self._slots)
                       if s is not None and s.decoding and s.resident]
@@ -3691,7 +3002,7 @@ class InferenceEngine:
             if not active:
                 self._step_anchor = None
                 return 0
-        nb = self.blocks.capacity if self.blocks is not None else 0
+        nb = self.blocks.capacity
         # Multi-model partition: one decode call per resident variant
         # with decoding rows, threading the shared pool sequentially
         # (the prefill partition's discipline).  Non-member rows in each
@@ -3784,8 +3095,10 @@ class InferenceEngine:
             for i, _ in members:
                 nxt_by_slot[i] = int(nxt[i])
         now = time.monotonic()
-        # Inter-decode-step latency (see _decode_once): prefill chunks
-        # between two decode steps land in this statistic by design.
+        # token_step is the INTER-decode-step latency while the engine
+        # stays busy: everything between two decode completions (prefill,
+        # admission) counts, so a prefill stalling decodes shows up in the
+        # p99 — the statistic chunked prefill is built to hold flat.
         dt_ms = (now - (self._step_anchor if self._step_anchor is not None
                         else t0)) * 1e3
         self._step_anchor = now
@@ -3816,13 +3129,10 @@ class InferenceEngine:
         self.steps += 1
         self._flush_trace_emits()
         self.metrics.observe_decode_step(dt_ms, len(active), len(active))
-        if self.blocks is not None:
-            self.metrics.maybe_emit_timeline(kv_stats=self.blocks.stats())
-        else:
-            self.metrics.maybe_emit_timeline()
+        self.metrics.maybe_emit_timeline(kv_stats=self.blocks.stats())
         return len(active)
 
-    # -- speculative decoding (paged mode, HVD_SERVE_SPEC_K > 0) -------------
+    # -- speculative decoding (HVD_SERVE_SPEC_K > 0) -------------------------
 
     def _spec_once(self) -> int:
         """One speculative iteration (Leviathan et al. 2023 / Chen et
@@ -3863,7 +3173,7 @@ class InferenceEngine:
             if not active:
                 self._step_anchor = None
                 return 0
-        nb = self.blocks.capacity if self.blocks is not None else 0
+        nb = self.blocks.capacity
         B = self.max_batch
         t0 = time.monotonic()
         drafts: Dict[int, List[int]] = {i: [] for i, _ in active}
@@ -4010,10 +3320,7 @@ class InferenceEngine:
         self._flush_trace_emits()
         self.metrics.observe_decode_step(dt_ms, len(active), emitted_total)
         self.metrics.observe_spec(drafted, accepted, rejected)
-        if self.blocks is not None:
-            self.metrics.maybe_emit_timeline(kv_stats=self.blocks.stats())
-        else:
-            self.metrics.maybe_emit_timeline()
+        self.metrics.maybe_emit_timeline(kv_stats=self.blocks.stats())
         return len(active)
 
     # -- the loop ------------------------------------------------------------
@@ -4031,7 +3338,7 @@ class InferenceEngine:
 
     def _recover(self, e: BaseException) -> None:
         """Poisoned-batch recovery: fail the in-flight requests NOW with
-        the real error and keep serving.  Paged mode frees ONLY the
+        the real error and keep serving.  It frees ONLY the
         failed iteration's block references — the pool arrays and the
         prefix registry survive (shared/registered blocks were written by
         previously-successful iterations; the failed sequences' private
@@ -4039,16 +3346,9 @@ class InferenceEngine:
         had already consumed its DONATED cache buffers (XLA runtime
         failure mid-step), the pool is rebuilt and the prefix registry
         reset with it — retained hashes must never describe zeroed
-        blocks.  Slot mode re-inits the whole cache (its contents are
-        suspect and per-slot rows aren't individually reclaimable)."""
+        blocks."""
         get_logger().exception(
             "%s: engine step failed: %s", self.replica_id, e)
-        if self.seqpar is not None and self.seqpar.job is not None:
-            # The in-flight SP job's rank blocks must not leak across a
-            # recovery; its request fails with everything else below.
-            job = self.seqpar.job
-            job.seq.sp_state = None
-            self.seqpar.abort(job)
         with self._lock:
             failed = set()
             for i, s in enumerate(self._slots):
@@ -4060,13 +3360,10 @@ class InferenceEngine:
                         s.request.fail(e)
                         self.metrics.count_request(
                             "error", tenant=s.request.tenant)
-                    if self.blocks is not None:
-                        self.blocks.free_table(s.table)
+                    self.blocks.free_table(s.table)
                     self._slots[i] = None
         self._flush_trace_emits()  # leftovers from the crashed helper
-        if self.kv_mode == "slot":
-            self._cache = self.adapter.init_cache(self.max_batch)
-        elif self._cache_deleted():
+        if self._cache_deleted():
             get_logger().warning(
                 "%s: donated KV pool was consumed by the failed step; "
                 "rebuilding pool and prefix registry", self.replica_id)
@@ -4098,13 +3395,12 @@ class InferenceEngine:
 
     def _run(self) -> None:
         idle_block_s = float(os.environ.get("HVD_SERVE_IDLE_POLL_S", "0.05"))
-        paged = self.kv_mode == "paged"
         while not self._stop.is_set():
             try:
                 if _faultline.PLAN is not None:
                     self._faultline_step()
                 self._expire_inflight()
-                if paged and self.tiering is not None:
+                if self.tiering is not None:
                     # Tier bookkeeping at the iteration top: apply
                     # worker arrivals, time out dead fetches, rotate
                     # swapped sequences back in, issue demotes and
@@ -4115,50 +3411,35 @@ class InferenceEngine:
                 # Iteration-level scheduling: admission happens BETWEEN
                 # decode steps — non-blocking while sequences are active,
                 # blocking (bounded) when idle.
-                block = 0.0 if busy else idle_block_s
-                if paged:
-                    self._admit_paged(block)
-                    pre = 0
-                    if self.seqpar is not None:
-                        # Sequence-parallel long-prompt prefill: one
-                        # emulated-rank chunk per iteration, so decode
-                        # keeps interleaving under the same chunk
-                        # budget (the interference contract).  BEFORE
-                        # _prefill_step: SP claims eligible prompts at
-                        # position 0, the single-rank walk takes the
-                        # rest.
-                        pre += self._sp_step()
-                    pre += self._prefill_step()
-                    # Speculative decoding is single-model (the draft is
-                    # the DEFAULT adapter's): any non-default decoding
-                    # row falls back to the per-model greedy path —
-                    # bit-identical output, just no draft amortization
-                    # that iteration.
-                    spec_ok = self.spec_k > 0 and self.brownout_level < 3
-                    if spec_ok:
-                        # Grammar/logprob rows decode on the host
-                        # (decode_paged_logits) — the fused spec
-                        # draft/verify pair has no logits or mask seam,
-                        # so any such active row falls the whole
-                        # iteration back to the plain per-model path
-                        # (bit-identical output, hvdstream contract).
-                        with self._lock:
-                            spec_ok = all(
-                                (s.request.model is None
-                                 or s.request.model == self.default_model
-                                 or len(self._adapters) == 1)
-                                and s.request.grammar is None
-                                and s.request.logprobs is None
-                                for s in self._slots if s is not None)
-                    dec = (self._spec_once() if spec_ok
-                           else self._decode_once_paged())
-                    if pre or dec:
-                        self.metrics.observe_iteration(pre, dec)
-                    if self.tiering is not None:
-                        self._tier_idle_wait(pre, dec)
-                else:
-                    self._admit(block)
-                    self._decode_once()
+                self._admit(0.0 if busy else idle_block_s)
+                pre = self._prefill_step()
+                # Speculative decoding is single-model (the draft is
+                # the DEFAULT adapter's): any non-default decoding
+                # row falls back to the per-model greedy path —
+                # bit-identical output, just no draft amortization
+                # that iteration.
+                spec_ok = self.spec_k > 0 and self.brownout_level < 3
+                if spec_ok:
+                    # Grammar/logprob rows decode on the host
+                    # (decode_paged_logits) — the fused spec
+                    # draft/verify pair has no logits or mask seam,
+                    # so any such active row falls the whole
+                    # iteration back to the plain per-model path
+                    # (bit-identical output, hvdstream contract).
+                    with self._lock:
+                        spec_ok = all(
+                            (s.request.model is None
+                             or s.request.model == self.default_model
+                             or len(self._adapters) == 1)
+                            and s.request.grammar is None
+                            and s.request.logprobs is None
+                            for s in self._slots if s is not None)
+                dec = (self._spec_once() if spec_ok
+                       else self._decode_once())
+                if pre or dec:
+                    self.metrics.observe_iteration(pre, dec)
+                if self.tiering is not None:
+                    self._tier_idle_wait(pre, dec)
             except Exception as e:
                 # A dying loop thread would hang every in-flight request
                 # until its client timeout — recover instead: one

@@ -168,15 +168,6 @@ class ServeMetrics:
         self.tier_demote_bytes = 0
         self.tier_migrated_tokens = 0
         self.tier_migrations_total = 0
-        # Sequence-parallel prefill plane (serve/seqpar.py): jobs that
-        # prefilled across the SP world's ranks, prompt tokens they
-        # covered, bit-exact handoff bytes shipped to the decode owner,
-        # ring hops folded, and kill-rank/preemption aborts.
-        self.sp_prefills_total = 0
-        self.sp_tokens_total = 0
-        self.sp_handoff_bytes = 0
-        self.sp_ring_hops_total = 0
-        self.sp_aborts_total = 0
         # Batch occupancy: sequences active per decode step.
         self.occupancy_last = 0
         self.occupancy_max = 0
@@ -244,24 +235,6 @@ class ServeMetrics:
             self.spec_accepted_total += accepted
             self.spec_rejected_total += rejected
             self.spec_steps_total += 1
-
-    def count_sp_prefill(self, tokens: int, handoff_bytes: int,
-                         ring_hops: int) -> None:
-        """One completed sequence-parallel prefill job
-        (engine._sp_complete): prompt tokens covered, bit-exact handoff
-        bytes shipped to the decode owner, and ring hops folded."""
-        with self._lock:
-            self.sp_prefills_total += 1
-            self.sp_tokens_total += int(tokens)
-            self.sp_handoff_bytes += int(handoff_bytes)
-            self.sp_ring_hops_total += int(ring_hops)
-
-    def count_sp_abort(self) -> None:
-        """One SP job abort (kill-rank drill / preemption / lost slot —
-        engine._sp_abort); the request itself resubmits whole and is
-        ALSO counted preempted by the standard path."""
-        with self._lock:
-            self.sp_aborts_total += 1
 
     def observe_stage(self, stage: str, ms: float) -> None:
         """One completed request's time in ``stage`` (queue / prefill /
@@ -413,9 +386,8 @@ class ServeMetrics:
             self._queue_depth_fns[replica_id] = fn
 
     def register_kv_stats(self, replica_id: str, fn) -> None:
-        """``fn`` returns the replica engine's BlockManager ``stats()``
-        dict (or None in slot mode); sampled at render time like queue
-        depth."""
+        """``fn`` returns the replica engine's ``kv_stats()`` dict;
+        sampled at render time like queue depth."""
         with self._lock:
             self._kv_stats_fns[replica_id] = fn
 
@@ -523,13 +495,6 @@ class ServeMetrics:
                     "demote_bytes": self.tier_demote_bytes,
                     "migrations": self.tier_migrations_total,
                     "migrated_tokens": self.tier_migrated_tokens,
-                },
-                "sp": {
-                    "prefills": self.sp_prefills_total,
-                    "tokens": self.sp_tokens_total,
-                    "handoff_bytes": self.sp_handoff_bytes,
-                    "ring_hops": self.sp_ring_hops_total,
-                    "aborts": self.sp_aborts_total,
                 },
                 "seq_forks": sum(s.get("seq_forks", 0)
                                  for s in kv.values()),
@@ -717,24 +682,6 @@ class ServeMetrics:
             rate = (self.spec_accepted_total / self.spec_drafted_total
                     if self.spec_drafted_total else 0.0)
             lines.append(f"hvd_serve_spec_acceptance_rate {rate:g}")
-            # Sequence-parallel prefill plane (serve/seqpar.py): job /
-            # token / handoff-byte / ring-hop / abort counters — the
-            # bench sp_prefill arm and the kill-rank drill read these.
-            lines.append("# TYPE hvd_serve_sp_prefills_total counter")
-            lines.append(
-                f"hvd_serve_sp_prefills_total {self.sp_prefills_total}")
-            lines.append("# TYPE hvd_serve_sp_tokens_total counter")
-            lines.append(
-                f"hvd_serve_sp_tokens_total {self.sp_tokens_total}")
-            lines.append("# TYPE hvd_serve_sp_handoff_bytes_total counter")
-            lines.append(f"hvd_serve_sp_handoff_bytes_total "
-                         f"{self.sp_handoff_bytes}")
-            lines.append("# TYPE hvd_serve_sp_ring_hops_total counter")
-            lines.append(
-                f"hvd_serve_sp_ring_hops_total {self.sp_ring_hops_total}")
-            lines.append("# TYPE hvd_serve_sp_aborts_total counter")
-            lines.append(
-                f"hvd_serve_sp_aborts_total {self.sp_aborts_total}")
             # Tiered-KV plane (serve/tiering.py): fault-stall histogram
             # (part of the inter-decode-step p99 contract), bytes moved
             # per direction, migration hits, and per-replica tier

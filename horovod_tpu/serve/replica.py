@@ -84,39 +84,33 @@ class Replica:
                "ranks": self.ranks, "load": self.load(),
                "active": self.engine.active_count,
                "queued": self.engine.batcher.depth(),
-               "kv_mode": self.engine.kv_mode,
                "attn_impl": self.engine.attn_impl,
                "kv_dtype": self.engine.kv_dtype,
                "rolling": self.rolling,
                "models": {name: self.engine._model_versions.get(name, 0)
                           for name in sorted(self.engine._adapters)}}
         kv = self.engine.kv_stats()
-        if kv is not None:
-            out["kv_blocks"] = {k: kv[k] for k in
-                                ("total", "used", "free", "retained")}
-            if "bytes_per_block" in kv:
-                out["kv_blocks"]["bytes_per_block"] = kv["bytes_per_block"]
-            # hvdmem budget plan: pool + weight bytes, and the headroom
-            # against HVD_MEM_BUDGET_BYTES / probed HBM when known —
-            # surfaced on healthz so an operator sees a mis-sized
-            # BlockManager before it OOMs (docs/serving.md).
-            # n>1 CoW fork + speculative observability (ISSUE 11): the
-            # fork counters and spec config ride healthz next to the
-            # block stats, so the n-best path is visible per replica
-            # from the first forked request.
-            # hvdshard go/no-go (ISSUE 17): the static replica-plan
-            # verdict (pool budget x comm budget) rides the same
-            # surface, so healthz shows plan_go per replica.
-            # hvdseqserve (serve/seqpar.py): the SP prefill world's
-            # geometry + counters ride the same surface — a multi-rank
-            # replica's healthz shows its ring comm budget and job
-            # history next to plan_go.
-            for extra in ("pool_bytes", "weight_bytes",
-                          "kv_headroom_bytes", "seq_forks",
-                          "forked_requests", "spec_k",
-                          "plan_go", "plan_findings", "sp"):
-                if extra in kv:
-                    out["kv_blocks"][extra] = kv[extra]
+        out["kv_blocks"] = {k: kv[k] for k in
+                            ("total", "used", "free", "retained")}
+        if "bytes_per_block" in kv:
+            out["kv_blocks"]["bytes_per_block"] = kv["bytes_per_block"]
+        # hvdmem budget plan: pool + weight bytes, and the headroom
+        # against HVD_MEM_BUDGET_BYTES / probed HBM when known —
+        # surfaced on healthz so an operator sees a mis-sized
+        # BlockManager before it OOMs (docs/serving.md).
+        # n>1 CoW fork + speculative observability (ISSUE 11): the
+        # fork counters and spec config ride healthz next to the
+        # block stats, so the n-best path is visible per replica
+        # from the first forked request.
+        # hvdshard go/no-go (ISSUE 17): the static replica-plan
+        # verdict (pool budget x comm budget) rides the same
+        # surface, so healthz shows plan_go per replica.
+        for extra in ("pool_bytes", "weight_bytes",
+                      "kv_headroom_bytes", "seq_forks",
+                      "forked_requests", "spec_k",
+                      "plan_go", "plan_findings"):
+            if extra in kv:
+                out["kv_blocks"][extra] = kv[extra]
         return out
 
 
@@ -468,7 +462,7 @@ def build_replicas(adapter_factory: Callable[[], ModelAdapter],
     and stand up one engine per set (adapter_factory is called per replica
     — each replica owns its model arrays and KV block pool).
 
-    ``engine_kwargs`` pass through to each ``InferenceEngine`` (kv_mode /
+    ``engine_kwargs`` pass through to each ``InferenceEngine`` (
     num_blocks / prefill_chunk / prefix_cache — the paged-cache knobs,
     docs/serving.md); unset ones fall back to their ``HVD_SERVE_*`` envs.
 

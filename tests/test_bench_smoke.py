@@ -69,42 +69,18 @@ def test_serve_bench_smoke_emits_throughput_and_latency():
     assert last["occupancy_max"] > 1
     assert last["requests"]["ok"] >= 16
     # ISSUE 5: the paged-cache config keys and the three arms.
-    assert last["kv_mode"] == "paged"
     assert last["block_tokens"] == 16
     assert last["prefill_chunk"] > 0
     assert last["prefix_cache"] is True
     paged = last["paged"]
-    for key in ("budget_tokens", "admitted_concurrent",
-                "slot_admitted_concurrent", "admit_ratio",
-                "tokens_per_sec", "slot_tokens_per_sec"):
+    for key in ("budget_tokens", "admitted_concurrent", "tokens_per_sec"):
         assert key in paged, f"paged.{key} missing: {paged}"
-    assert paged["outputs_match"] is True  # batched==single==slot
+    assert paged["outputs_match"] is True  # batched == single
     chunked = last["chunked"]
     for key in ("prefill_chunk", "token_step_p99_ms",
                 "unchunked_token_step_p99_ms"):
         assert key in chunked, f"chunked.{key} missing: {chunked}"
     assert chunked["outputs_match"] is True
-    # ISSUE 20: the SP variant of the interference storm stays
-    # bit-exact (its decode tail is a time: recorded, not judged here).
-    for key in ("sp_token_step_p99_ms", "sp_p99_bounded",
-                "sp_outputs_match"):
-        assert key in chunked, f"chunked.{key} missing: {chunked}"
-    assert chunked["sp_outputs_match"] is True
-    # ISSUE 20: the sequence-parallel prefill arm — emulated
-    # multi-rank long-prompt prefill with token-exact outputs, the
-    # emulation-model speedup, and the handoff/ring accounting.
-    sp = last["sp_prefill"]
-    for key in ("ranks", "emulated", "jobs", "speedup",
-                "baseline_prefill_p50_ms", "sp_prefill_wall_p50_ms",
-                "baseline_ttft_p50_ms", "ttft_p50_ms",
-                "handoff_bytes", "ring_hops",
-                "ring_bytes_per_prefill", "outputs_match"):
-        assert key in sp, f"sp_prefill.{key} missing: {sp}"
-    assert sp["outputs_match"] is True  # SP ≡ single-rank, exact
-    assert sp["emulated"] is True       # CPU-hermetic emulation
-    assert sp["jobs"] >= 1              # the SP path really engaged
-    assert sp["handoff_bytes"] > 0
-    assert sp["ring_hops"] > 0
     prefix = last["prefix"]
     for key in ("enabled", "hit_rate", "hit_tokens", "cow_copies"):
         assert key in prefix, f"prefix.{key} missing: {prefix}"

@@ -61,13 +61,9 @@ class _SlowMLP(MLPAdapter):
 
     delay_s = 0.02
 
-    def decode(self, cache, tokens, positions):
-        time.sleep(self.delay_s)
-        return MLPAdapter.decode(self, cache, tokens, positions)
-
     def decode_paged(self, cache, tokens, positions, tables):
         time.sleep(self.delay_s)
-        return MLPAdapter.decode(self, cache, tokens, positions)
+        return super().decode_paged(cache, tokens, positions, tables)
 
 
 def _slow_adapter(seed=3, vocab=VOCAB):
@@ -264,7 +260,7 @@ def test_pool_corrupt_block_scrubs_prefix_cache_and_stays_exact():
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, 8), jnp.int32))["params"]
     ad = TransformerAdapter(_TINY, params, block_tokens=8)
-    eng = _engine(ad, kv_mode="paged", prefill_chunk=16).start()
+    eng = _engine(ad, prefill_chunk=16).start()
     try:
         prompt = list(range(1, 25))  # 3 full blocks of 8
         first = eng.generate(prompt, max_new_tokens=4, timeout_s=60)

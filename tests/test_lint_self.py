@@ -11,6 +11,7 @@ To silence a deliberate pattern, add ``# hvdlint: disable=HVDxxx`` on
 the flagged line WITH a reasoned comment (docs/static_analysis.md).
 """
 
+import glob
 import os
 
 from horovod_tpu.analysis import lint_paths, unsuppressed
@@ -39,47 +40,11 @@ def test_lint_covers_the_whole_tree():
     assert len(files) > 50
     assert any(f.endswith("optimizer.py") for f in files)
     assert any(f.endswith("mnist_mlp.py") for f in files)
-    # The serve/ subsystem (ISSUE 4) must stay inside the gate's walk —
-    # a skip-list regression here would let serving-path antipatterns
-    # land unlinted.
-    serve_files = [f for f in files
-                   if os.sep + os.path.join("serve", "") in f]
-    # sampling.py (ISSUE 11) carries the serving PRNG discipline the new
-    # HVD010 rule audits — it must stay inside the gate's walk.
-    # controller.py (ISSUE 13) holds the fleet control plane — the
-    # autoscale/brownout decision loop must stay under the same lint.
-    # tenancy.py / registry.py (ISSUE 15) carry the fairness scheduler
-    # and the hot-swap walk — same deal.
-    # router.py / router_server.py (ISSUE 18) carry the front-door
-    # retry/hedge/health machinery — same deal.
-    # seqpar.py (ISSUE 20) carries the sequence-parallel prefill world
-    # — the rank-block/handoff machinery must stay under the same lint.
-    for mod in ("engine.py", "batcher.py", "blocks.py", "replica.py",
-                "server.py", "metrics.py", "paged_attention.py",
-                "sampling.py", "controller.py", "tenancy.py",
-                "registry.py", "tiering.py", "router.py",
-                "router_server.py", "seqpar.py"):
-        assert any(f.endswith(os.path.join("serve", mod))
-                   for f in serve_files), f"serve/{mod} not linted"
-    # Same for faultline/ (ISSUE 6): the injection layer must stay under
-    # the swallowed-fault rule it motivated (HVD009).
-    for mod in ("plan.py", "runtime.py"):
-        assert any(f.endswith(os.path.join("faultline", mod))
-                   for f in files), f"faultline/{mod} not linted"
-    # And obs/ (ISSUE 9): the tracing plane threads through the serve
-    # hot paths and the KV client — it must stay inside the gate.
-    for mod in ("tracing.py", "merge.py", "cli.py"):
-        assert any(f.endswith(os.path.join("obs", mod))
-                   for f in files), f"obs/{mod} not linted"
-    # And the hvdmem analyzer itself (ISSUE 10): memplan.py must pass
-    # the lint the rest of the repo is held to.
-    assert any(f.endswith(os.path.join("analysis", "memplan.py"))
-               for f in files), "analysis/memplan.py not linted"
-    # And the hvdshard analyzer (ISSUE 17): shardplan.py must pass the
-    # same lint — including the HVD011 sync-under-lock rule it shipped
-    # beside.
-    assert any(f.endswith(os.path.join("analysis", "shardplan.py"))
-               for f in files), "analysis/shardplan.py not linted"
+    # Every module of the package, found by a walk of its own: a
+    # skip-list regression would let a subsystem land unlinted.
+    missing = set(glob.glob(os.path.join(_REPO, "horovod_tpu", "**", "*.py"),
+                            recursive=True)) - set(files)
+    assert not missing, f"not linted: {sorted(missing)}"
     assert not any("__pycache__" in f for f in files)
 
 

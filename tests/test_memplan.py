@@ -503,7 +503,7 @@ def _small_engine(**kw):
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, 8), jnp.int32))["params"]
     adapter = TransformerAdapter(cfg, params, block_tokens=8)
-    engine = InferenceEngine(adapter, max_batch=2, kv_mode="paged",
+    engine = InferenceEngine(adapter, max_batch=2,
                              metrics=ServeMetrics(),
                              replica_id="memplan-test", **kw)
     return adapter, engine
@@ -582,7 +582,7 @@ def test_hvd302_flags_pool_past_1gib_budget(monkeypatch):
 
     adapter = _FatBlockAdapter(mlp, params, vocab_size=vocab)
     metrics = ServeMetrics()
-    engine = InferenceEngine(adapter, max_batch=2, kv_mode="paged",
+    engine = InferenceEngine(adapter, max_batch=2,
                              num_blocks=32,  # 32 x 64 MiB = 2 GiB
                              metrics=metrics, replica_id="fat-pool")
     # HVD302 published at construction.

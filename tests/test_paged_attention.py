@@ -65,7 +65,7 @@ def _engine(params, impl, **kw):
     kw.setdefault("prefill_chunk", 5)  # deliberately unaligned with BT
     ad = TransformerAdapter(_TINY, params, block_tokens=BT, attn_impl=impl,
                             kv_dtype=kw.pop("kv_dtype", None))
-    return InferenceEngine(ad, kv_mode="paged",
+    return InferenceEngine(ad,
                            replica_id=f"pa-{impl}", **kw)
 
 
@@ -331,7 +331,7 @@ def test_kernel_engine_poisoned_batch_recovery():
 
     ad = _PoisonOnce(TransformerAdapter(_TINY, params, block_tokens=BT,
                                         attn_impl="kernel"))
-    eng = InferenceEngine(ad, kv_mode="paged", max_batch=4,
+    eng = InferenceEngine(ad, max_batch=4,
                           prefill_chunk=64, replica_id="k-poison").start()
     try:
         shared = list(range(2 * BT))
@@ -356,7 +356,7 @@ def test_kernel_engine_pool_exhaustion_preempts_youngest():
     _, params = _tiny()
     ad = TransformerAdapter(_TINY, params, block_tokens=BT,
                             attn_impl="kernel")
-    eng = InferenceEngine(ad, kv_mode="paged", max_batch=4, num_blocks=2,
+    eng = InferenceEngine(ad, max_batch=4, num_blocks=2,
                           prefill_chunk=64, replica_id="k-exhaust")
     from horovod_tpu.serve.engine import _Seq
     old_req = Request([1] * BT, max_new_tokens=4)
@@ -371,7 +371,7 @@ def test_kernel_engine_pool_exhaustion_preempts_youngest():
     young.prompt_pos = BT
     eng._slots[0] = old
     eng._slots[1] = young
-    eng._decode_once_paged()
+    eng._decode_once()
     assert eng._slots[1] is None
     assert young_req.generated == [] and young_req.requeues == 1
     assert eng.metrics.snapshot()["requests"]["preempted"] == 1
@@ -399,7 +399,7 @@ def test_int8_engine_error_bounds_and_batched_equals_single():
                     / (np.linalg.norm(l8) * np.linalg.norm(l16)))
         assert cos > 0.999, cos
         assert float(np.max(np.abs(l8 - l16))) < 0.05
-    eng = InferenceEngine(ad8, kv_mode="paged", max_batch=4,
+    eng = InferenceEngine(ad8, max_batch=4,
                           prefill_chunk=5, replica_id="int8").start()
     try:
         singles = [eng.generate(p, max_new_tokens=5) for p in prompts]
@@ -456,7 +456,7 @@ def test_paged_block_bytes_matches_pool_and_manager():
                                 kv_dtype=kvd)
         expect = _TINY.num_layers * 2 * BT * _TINY.num_heads * per_tok_head
         assert ad.paged_block_bytes() == expect, kvd
-        eng = InferenceEngine(ad, kv_mode="paged", max_batch=2,
+        eng = InferenceEngine(ad, max_batch=2,
                               num_blocks=4, replica_id=f"bytes-{kvd}")
         stats = eng.kv_stats()
         assert stats["bytes_per_block"] == expect
@@ -537,19 +537,3 @@ def test_replica_to_dict_carries_impl_and_dtype():
     assert d["kv_dtype"] == "int8"
     assert d["kv_blocks"]["bytes_per_block"] == \
         eng.adapter.paged_block_bytes()
-
-
-def test_slot_mode_reports_what_it_runs_not_adapter_config():
-    """Review finding: slot mode ignores attn_impl/kv_dtype (dense
-    attention over the compute-dtype slot cache), so its export
-    surfaces must say so instead of echoing knobs it never applies."""
-    from horovod_tpu.serve import Replica
-    _, params = _tiny()
-    ad = TransformerAdapter(_TINY, params, block_tokens=BT,
-                            attn_impl="kernel", kv_dtype="int8")
-    eng = InferenceEngine(ad, kv_mode="slot", max_batch=2,
-                          replica_id="slot-r")
-    assert eng.attn_impl == "dense"
-    assert eng.kv_dtype == "native"
-    d = Replica("slot-r", None, eng).to_dict()
-    assert d["attn_impl"] == "dense" and d["kv_dtype"] == "native"

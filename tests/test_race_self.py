@@ -12,6 +12,7 @@ flagged line WITH a reasoned comment, or declare the intended order with
 ``# hvdrace: order=A<B`` (docs/static_analysis.md).
 """
 
+import glob
 import os
 
 from horovod_tpu.analysis import lint_paths, race_paths, unsuppressed
@@ -55,51 +56,12 @@ def test_race_walk_covers_the_threaded_tree():
     analyzer = _Analyzer()
     files = iter_python_files(_PATHS)
     assert len(files) > 50
-    # The Pallas paged-attention module (ISSUE 8) must stay inside the
-    # race walk: it is lock-free BY DESIGN (pure kernels), and that
-    # property is only checked if the walker actually visits it.
-    assert any(f.endswith(os.path.join("serve", "paged_attention.py"))
-               for f in files), "serve/paged_attention.py not analyzed"
-    # The tracing plane (ISSUE 9) holds its own lock while called from
-    # under the engine/batcher locks — its ordering must stay analyzed.
-    for mod in ("tracing.py", "merge.py"):
-        assert any(f.endswith(os.path.join("obs", mod))
-                   for f in files), f"obs/{mod} not analyzed"
-    # The hvdmem analyzer (ISSUE 10) is lock-free by design (pure AST +
-    # jaxpr walks) — a property only checked if the walk visits it.
-    assert any(f.endswith(os.path.join("analysis", "memplan.py"))
-               for f in files), "analysis/memplan.py not analyzed"
-    # The sampling layer (ISSUE 11) is lock-free by design (pure key
-    # derivation + filtering called from under the engine's loop) —
-    # checked only if the walker visits it.
-    assert any(f.endswith(os.path.join("serve", "sampling.py"))
-               for f in files), "serve/sampling.py not analyzed"
-    # The fleet controller (ISSUE 13) polls replica locks from its own
-    # thread — the walker must see it for the registry check below.
-    assert any(f.endswith(os.path.join("serve", "controller.py"))
-               for f in files), "serve/controller.py not analyzed"
-    # The registry's roll walk (ISSUE 15) drains replicas while holding
-    # its own lock; tenancy's DRR is called under the batcher's.
-    assert any(f.endswith(os.path.join("serve", "registry.py"))
-               for f in files), "serve/registry.py not analyzed"
-    assert any(f.endswith(os.path.join("serve", "tenancy.py"))
-               for f in files), "serve/tenancy.py not analyzed"
-    assert any(f.endswith(os.path.join("serve", "tiering.py"))
-               for f in files), "serve/tiering.py not analyzed"
-    # The SP world (ISSUE 20) is lock-FREE by design — every mutation
-    # happens on the engine loop thread; that property only holds if
-    # the race walker actually visits it.
-    assert any(f.endswith(os.path.join("serve", "seqpar.py"))
-               for f in files), "serve/seqpar.py not analyzed"
-    # The hvdroute front door (ISSUE 18) runs forwards, hedges, and the
-    # active health poller on their own threads over the router lock.
-    for mod in ("router.py", "router_server.py"):
-        assert any(f.endswith(os.path.join("serve", mod))
-                   for f in files), f"serve/{mod} not analyzed"
-    # The hvdshard analyzer (ISSUE 17) is lock-free by design (pure AST
-    # + jaxpr walks) — checked only if the walker visits it.
-    assert any(f.endswith(os.path.join("analysis", "shardplan.py"))
-               for f in files), "analysis/shardplan.py not analyzed"
+    # Every module of the package, found by a walk of its own: modules
+    # that are lock-free by design (the Pallas kernels, sampling, the
+    # analyzers) hold that property only if the walker visits them.
+    missing = set(glob.glob(os.path.join(_REPO, "horovod_tpu", "**", "*.py"),
+                            recursive=True)) - set(files)
+    assert not missing, f"not analyzed: {sorted(missing)}"
     for path in files:
         with open(path, "rb") as fh:
             src = fh.read().decode("utf-8", errors="replace")

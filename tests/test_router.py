@@ -361,9 +361,9 @@ class _SlowPrefillAdapter(MLPAdapter):
     """Holds each request in flight long enough for the drain tests to
     observe it."""
 
-    def prefill(self, cache, prompts, slots):
+    def prefill_chunk(self, cache, chunks, starts, tables):
         time.sleep(0.4)
-        return super().prefill(cache, prompts, slots)
+        return super().prefill_chunk(cache, chunks, starts, tables)
 
 
 def _post(port, payload, headers=(), timeout=10):

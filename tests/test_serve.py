@@ -20,21 +20,21 @@ from horovod_tpu.models.transformer import (Transformer, TransformerConfig,
                                             stack_block_params)
 from horovod_tpu.serve import (DeadlineExceededError, DynamicBatcher,
                                Histogram, InferenceEngine, MLPAdapter,
-                               NoHealthyReplicaError, QueueFullError,
-                               Replica, ReplicaScheduler, Request,
-                               ServeMetrics, TransformerAdapter,
-                               bucket_requests, prompt_bucket)
+                               ModelAdapter, NoHealthyReplicaError,
+                               QueueFullError, Replica, ReplicaScheduler,
+                               Request, ServeMetrics, TransformerAdapter,
+                               prompt_bucket)
 
 VOCAB = 31
 
 
 # -- shared tiny models ------------------------------------------------------
 
-def _mlp_adapter(seed=3, vocab=VOCAB, max_len=128):
+def _mlp_adapter(seed=3, vocab=VOCAB, max_len=128, cls=MLPAdapter):
     mlp = create_mlp(features=(16, vocab))
     params = mlp.init(jax.random.PRNGKey(seed),
                       jnp.zeros((1, vocab)))["params"]
-    return MLPAdapter(mlp, params, vocab_size=vocab, max_len=max_len)
+    return cls(mlp, params, vocab_size=vocab, max_len=max_len)
 
 
 def _mlp_chain(adapter, prompt, n):
@@ -66,10 +66,8 @@ def test_prompt_bucketing_pow2_with_floor_and_cap():
     assert prompt_bucket(8, floor=8) == 8
     assert prompt_bucket(9, floor=8) == 16
     assert prompt_bucket(100, floor=8, cap=64) == 64
-    groups = bucket_requests([Request([1] * n) for n in (3, 8, 9, 30)],
-                             floor=8)
-    assert sorted(groups) == [8, 16, 32]
-    assert len(groups[8]) == 2
+    assert [prompt_bucket(n, floor=8) for n in (3, 8, 9, 30)] == \
+        [8, 8, 16, 32]
 
 
 def test_batcher_backpressure_sheds_at_capacity():
@@ -209,29 +207,19 @@ def test_engine_batched_equals_single_and_occupancy_exceeds_one():
         eng.stop()
 
 
-class _SlowAdapter:
-    """Delegating adapter whose decode steps take ~5 ms — keeps requests
+class _SlowAdapter(MLPAdapter):
+    """MLP adapter whose decode steps take ~5 ms — keeps requests
     demonstrably in-flight for drain/failover tests."""
 
-    def __init__(self, inner, delay_s=0.005):
-        self._inner = inner
-        self._delay = delay_s
-        self.vocab_size = inner.vocab_size
-        self.max_len = inner.max_len
+    delay_s = 0.005
 
-    def init_cache(self, max_batch):
-        return self._inner.init_cache(max_batch)
-
-    def prefill(self, cache, prompts, slots):
-        return self._inner.prefill(cache, prompts, slots)
-
-    def decode(self, cache, tokens, positions):
-        time.sleep(self._delay)
-        return self._inner.decode(cache, tokens, positions)
+    def decode_paged(self, cache, tokens, positions, tables):
+        time.sleep(self.delay_s)
+        return super().decode_paged(cache, tokens, positions, tables)
 
 
 def test_engine_drain_returns_inflight_with_cleared_progress():
-    ad = _SlowAdapter(_mlp_adapter())
+    ad = _mlp_adapter(cls=_SlowAdapter)
     eng = InferenceEngine(ad, max_batch=4, replica_id="t").start()
     reqs = [Request([3], max_new_tokens=120) for _ in range(3)]
     for r in reqs:
@@ -253,17 +241,16 @@ def test_engine_survives_poisoned_batch():
     engine serving — one poisoned batch must not take the replica down."""
 
     class _PoisonOnce(_SlowAdapter):
-        def __init__(self, inner):
-            super().__init__(inner, delay_s=0.0)
-            self.armed = True
+        delay_s = 0.0
+        armed = True
 
-        def decode(self, cache, tokens, positions):
+        def decode_paged(self, cache, tokens, positions, tables):
             if self.armed:
                 self.armed = False
                 raise RuntimeError("simulated device fault")
-            return super().decode(cache, tokens, positions)
+            return super().decode_paged(cache, tokens, positions, tables)
 
-    ad = _PoisonOnce(_mlp_adapter())
+    ad = _mlp_adapter(cls=_PoisonOnce)
     eng = InferenceEngine(ad, max_batch=2, replica_id="t").start()
     try:
         doomed = Request([5], max_new_tokens=8)
@@ -276,6 +263,29 @@ def test_engine_survives_poisoned_batch():
         assert eng.metrics.snapshot()["requests"]["error"] == 1
     finally:
         eng.stop()
+
+
+def test_engine_refuses_an_adapter_without_the_paged_trio():
+    """No second cache layout to fall back to: the constructor names the
+    members of the paged interface that are missing."""
+
+    class _HalfAdapter(ModelAdapter):
+        vocab_size, max_len = VOCAB, 16
+
+        def init_paged_cache(self, num_blocks, max_batch):
+            return ()
+
+    with pytest.raises(TypeError) as e:
+        InferenceEngine(_HalfAdapter(), max_batch=2)
+    assert "_HalfAdapter" in str(e.value)
+    assert "prefill_chunk, decode_paged" in str(e.value)
+    assert "init_paged_cache" not in str(e.value)
+
+
+@pytest.mark.parametrize("gone", ["kv_mode", "sp_ranks"])
+def test_engine_has_no_cache_layout_or_emulated_rank_option(gone):
+    with pytest.raises(TypeError, match=gone):
+        InferenceEngine(_mlp_adapter(), max_batch=2, **{gone: None})
 
 
 def test_engine_rejects_overlong_request():
@@ -294,13 +304,13 @@ def test_engine_rejects_overlong_request():
 
 def test_transformer_prefill_matches_flax_apply():
     model, params = _tiny_transformer()
-    ad = TransformerAdapter(_TINY, params)
-    ad._max_batch = 4
-    cache = ad.init_cache(4)
+    ad = TransformerAdapter(_TINY, params, block_tokens=8)
+    cache = ad.init_paged_cache(4, 4)
     tokens = np.random.RandomState(0).randint(0, 61, (1, 12))
     ref = model.apply({"params": params},
                       jnp.asarray(tokens, jnp.int32))  # [1, 12, V]
-    cache, first = ad.prefill(cache, [tokens[0].tolist()], [0])
+    cache, first = ad.prefill_chunk(cache, [tokens[0].tolist()], [0],
+                                    [[2, 0]])
     assert int(first[0]) == int(jnp.argmax(ref[0, -1]))
 
 
@@ -355,19 +365,20 @@ def test_transformer_adapter_rejects_training_mesh_configs():
 
 def test_transformer_prefill_compile_cache_buckets():
     """Same-bucket shapes reuse the compiled prefill; only new (count,
-    length) buckets compile — steady-state serving never recompiles."""
+    length) buckets compile — steady-state serving never recompiles.
+    The third key is the pool's size, which the program bakes in."""
     _, params = _tiny_transformer()
-    ad = TransformerAdapter(_TINY, params)
-    ad._max_batch = 8
-    cache = ad.init_cache(8)
-    cache, _ = ad.prefill(cache, [[1, 2, 3]], [0])
-    assert set(ad._prefill_cache) == {(1, 8)}
-    cache, _ = ad.prefill(cache, [[4] * 7], [1])  # same buckets
-    assert set(ad._prefill_cache) == {(1, 8)}
-    cache, _ = ad.prefill(cache, [[5] * 9], [2])  # longer prompt bucket
-    assert set(ad._prefill_cache) == {(1, 8), (1, 16)}
-    cache, _ = ad.prefill(cache, [[6]] * 3, [3, 4, 5])  # wider count bucket
-    assert set(ad._prefill_cache) == {(1, 8), (1, 16), (4, 8)}
+    ad = TransformerAdapter(_TINY, params, block_tokens=16)
+    cache = ad.init_paged_cache(8, 8)
+    cache, _ = ad.prefill_chunk(cache, [[1, 2, 3]], [0], [[0]])
+    assert set(ad._chunk_cache) == {(1, 8, 8)}
+    cache, _ = ad.prefill_chunk(cache, [[4] * 7], [0], [[1]])  # same
+    assert set(ad._chunk_cache) == {(1, 8, 8)}
+    cache, _ = ad.prefill_chunk(cache, [[5] * 9], [0], [[2]])  # longer
+    assert set(ad._chunk_cache) == {(1, 8, 8), (1, 16, 8)}
+    cache, _ = ad.prefill_chunk(cache, [[6]] * 3, [0] * 3,
+                                [[3], [4], [5]])  # wider count bucket
+    assert set(ad._chunk_cache) == {(1, 8, 8), (1, 16, 8), (4, 8, 8)}
 
 
 # -- process-set partitioning ------------------------------------------------
@@ -415,7 +426,7 @@ def test_scheduler_routes_least_loaded():
 def test_scheduler_mark_dead_requeues_to_survivor():
     replicas, metrics = [], ServeMetrics()
     for i in range(2):
-        eng = InferenceEngine(_SlowAdapter(_mlp_adapter()), max_batch=4,
+        eng = InferenceEngine(_mlp_adapter(cls=_SlowAdapter), max_batch=4,
                               metrics=metrics, replica_id=f"replica-{i}")
         replicas.append(Replica(f"replica-{i}", None, eng))
     sched = ReplicaScheduler(replicas, metrics=metrics).start()
@@ -446,7 +457,7 @@ def test_mark_dead_requeues_past_full_survivor_queue():
     metrics = ServeMetrics()
     replicas = []
     for i in range(2):
-        eng = InferenceEngine(_SlowAdapter(_mlp_adapter()),
+        eng = InferenceEngine(_mlp_adapter(cls=_SlowAdapter),
                               batcher=DynamicBatcher(max_queue=1),
                               max_batch=2, metrics=metrics,
                               replica_id=f"replica-{i}")
@@ -478,7 +489,7 @@ def test_scheduler_stop_fails_inflight_promptly():
     """Review finding: stop() must fail in-flight requests immediately —
     not leave their waiters parked until the request timeout."""
     metrics = ServeMetrics()
-    eng = InferenceEngine(_SlowAdapter(_mlp_adapter()), max_batch=2,
+    eng = InferenceEngine(_mlp_adapter(cls=_SlowAdapter), max_batch=2,
                           metrics=metrics, replica_id="replica-0")
     sched = ReplicaScheduler([Replica("replica-0", None, eng)],
                              metrics=metrics).start()

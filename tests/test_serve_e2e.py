@@ -4,7 +4,7 @@ multi-replica process-set world, on CPU, under real concurrent load.
 Pins the three acceptance properties in one scenario:
 
 (a) batched decode output EXACTLY matches single-request decode — greedy
-    decoding over a masked slot cache is batch-composition-invariant
+    decoding over a masked cache is batch-composition-invariant
     (engine.py module doc), so 64 concurrent requests answer identically
     to the same prompts served alone;
 (b) continuous batching actually batched: /metrics reports max batch

@@ -59,7 +59,7 @@ def _paged_engine(params, **kw):
     kw.setdefault("max_batch", 8)
     kw.setdefault("prefill_chunk", 5)  # deliberately unaligned with BT
     ad = TransformerAdapter(_TINY, params, block_tokens=BT)
-    return InferenceEngine(ad, kv_mode="paged", replica_id="paged-t", **kw)
+    return InferenceEngine(ad, replica_id="paged-t", **kw)
 
 
 # -- BlockManager ------------------------------------------------------------
@@ -214,14 +214,11 @@ def test_paged_chunked_matches_flax_at_block_boundaries():
         eng.stop()
 
 
-def test_paged_batched_equals_single_and_slot_engine():
-    """The three-way exactness pin: a concurrent storm on the paged
-    engine == the same prompts served alone == the slot engine."""
+def test_paged_batched_equals_single():
+    """The exactness pin: a concurrent storm on the engine == the same
+    prompts served alone."""
     model, params = _tiny()
     eng = _paged_engine(params).start()
-    slot_eng = InferenceEngine(TransformerAdapter(_TINY, params),
-                               kv_mode="slot", max_batch=8,
-                               replica_id="slot-t").start()
     try:
         prompts = [np.random.RandomState(i).randint(
             0, 61, (3 + (i * 5) % (3 * BT),)).tolist() for i in range(12)]
@@ -238,12 +235,9 @@ def test_paged_batched_equals_single_and_slot_engine():
         for t in threads:
             t.join()
         assert results == singles
-        assert [slot_eng.generate(p, max_new_tokens=8) for p in prompts] \
-            == singles
         assert eng.metrics.snapshot()["occupancy"]["max"] > 1
     finally:
         eng.stop()
-        slot_eng.stop()
 
 
 def test_paged_engine_eos_and_requeue_semantics():
@@ -302,7 +296,7 @@ def _interference_run(params, prefill_chunk):
                         ms_per_token=2.0)
 
     def run():
-        eng = InferenceEngine(ad, kv_mode="paged", max_batch=4,
+        eng = InferenceEngine(ad, max_batch=4,
                               prefill_chunk=prefill_chunk,
                               metrics=ServeMetrics(),
                               replica_id="interf").start()
@@ -385,7 +379,7 @@ def test_prefix_reuse_allocates_fewer_blocks_and_matches_single():
         # the no-cache reference — cached K/V is bit-equal by content.
         cold = InferenceEngine(
             TransformerAdapter(_TINY, params, block_tokens=BT),
-            kv_mode="paged", max_batch=8, prefix_cache=False,
+            max_batch=8, prefix_cache=False,
             replica_id="cold").start()
         try:
             assert cold.generate(p2, max_new_tokens=6) == ref2
@@ -430,7 +424,7 @@ def test_paged_poisoned_batch_frees_only_failed_blocks():
             return super().decode_paged(cache, tokens, positions, tables)
 
     ad = _PoisonOnce(TransformerAdapter(_TINY, params, block_tokens=BT))
-    eng = InferenceEngine(ad, kv_mode="paged", max_batch=4,
+    eng = InferenceEngine(ad, max_batch=4,
                           prefill_chunk=64, replica_id="poison").start()
     try:
         shared = list(range(2 * BT))
@@ -463,7 +457,7 @@ def test_pool_exhaustion_preempts_youngest_and_requeues():
     the front of the engine's own queue, counted, never corrupted."""
     _, params = _tiny()
     ad = TransformerAdapter(_TINY, params, block_tokens=BT)
-    eng = InferenceEngine(ad, kv_mode="paged", max_batch=4, num_blocks=2,
+    eng = InferenceEngine(ad, max_batch=4, num_blocks=2,
                           prefill_chunk=64, replica_id="exhaust")
     from horovod_tpu.serve.engine import _Seq
     # Hand-build two decoding sequences that together exceed the 2-block
@@ -481,7 +475,7 @@ def test_pool_exhaustion_preempts_youngest_and_requeues():
     young.prompt_pos = BT
     eng._slots[0] = old
     eng._slots[1] = young
-    eng._decode_once_paged()
+    eng._decode_once()
     # The youngest lost its slot and sits at the front of the queue with
     # progress reset; the old sequence decoded on.
     assert eng._slots[1] is None
@@ -496,7 +490,7 @@ def test_pool_exhaustion_preempts_youngest_and_requeues():
 def test_paged_steady_state_never_recompiles():
     _, params = _tiny()
     ad = TransformerAdapter(_TINY, params, block_tokens=BT)
-    eng = InferenceEngine(ad, kv_mode="paged", max_batch=4,
+    eng = InferenceEngine(ad, max_batch=4,
                           prefill_chunk=8, replica_id="compile").start()
     try:
         for i in range(3):
@@ -527,7 +521,7 @@ def test_shared_adapter_across_pool_sizes_stays_exact():
     ref = _flax_greedy(model, params, prompt, 6)
     # INTERLEAVED engines on one adapter: geometry must come from each
     # call's own cache, not from whichever engine initialized last.
-    engines = [InferenceEngine(ad, kv_mode="paged", max_batch=4,
+    engines = [InferenceEngine(ad, max_batch=4,
                                num_blocks=nb, prefill_chunk=5,
                                replica_id=f"pool-{nb}").start()
                for nb in (16, 48)]
@@ -550,7 +544,7 @@ def test_recovery_rebuilds_pool_when_donated_cache_was_consumed():
     zeroed blocks); the replica keeps serving exactly."""
     model, params = _tiny()
     ad = TransformerAdapter(_TINY, params, block_tokens=BT)
-    eng = InferenceEngine(ad, kv_mode="paged", max_batch=4,
+    eng = InferenceEngine(ad, max_batch=4,
                           prefill_chunk=64, replica_id="donated").start()
     try:
         shared = list(range(2 * BT))
@@ -591,7 +585,7 @@ def test_prefix_registration_is_watermarked_not_quadratic():
     prompt length."""
     _, params = _tiny()
     ad = TransformerAdapter(_TINY, params, block_tokens=BT)
-    eng = InferenceEngine(ad, kv_mode="paged", max_batch=4,
+    eng = InferenceEngine(ad, max_batch=4,
                           prefill_chunk=BT, replica_id="wm").start()
     calls = []
     orig = eng.blocks.register
@@ -673,17 +667,13 @@ def test_replica_to_dict_and_build_replicas_kwargs(hvd8):
     try:
         sched.start()
         for r in sched.replicas:
-            assert r.engine.kv_mode == "paged"
             assert r.engine.blocks.capacity == 16
-            d = r.to_dict()
-            assert d["kv_mode"] == "paged"
-            assert d["kv_blocks"]["total"] == 16
+            assert r.to_dict()["kv_blocks"]["total"] == 16
     finally:
         sched.stop()
-    # MLP adapters serve paged-mode with a zero-block footprint.
+    # MLP adapters serve with a zero-block footprint.
     meng = InferenceEngine(MLPAdapter(mlp, mp, vocab_size=31),
                            max_batch=4, replica_id="mlp")
-    assert meng.kv_mode == "paged"
     assert meng.kv_stats()["block_tokens"] == 1
 
 
@@ -703,11 +693,11 @@ def test_mark_dead_during_chunked_prefill_requeues_and_frees_blocks():
     victim_eng = InferenceEngine(
         _CostedAdapter(TransformerAdapter(_TINY, params, block_tokens=BT),
                        ms_per_token=5.0),
-        kv_mode="paged", prefill_chunk=5, max_batch=8, metrics=metrics,
+        prefill_chunk=5, max_batch=8, metrics=metrics,
         replica_id="victim")
     survivor_eng = InferenceEngine(
         TransformerAdapter(_TINY, params, block_tokens=BT),
-        kv_mode="paged", prefill_chunk=5, max_batch=8, metrics=metrics,
+        prefill_chunk=5, max_batch=8, metrics=metrics,
         replica_id="survivor")
     sched = ReplicaScheduler(
         [Replica("victim", None, victim_eng),
@@ -756,11 +746,11 @@ def test_mark_dead_idle_replica_refunds_reserves_and_requeues_nothing():
     metrics = ServeMetrics()
     victim_eng = InferenceEngine(
         TransformerAdapter(_TINY, params, block_tokens=BT),
-        kv_mode="paged", prefill_chunk=5, max_batch=8, metrics=metrics,
+        prefill_chunk=5, max_batch=8, metrics=metrics,
         replica_id="victim")
     survivor_eng = InferenceEngine(
         TransformerAdapter(_TINY, params, block_tokens=BT),
-        kv_mode="paged", prefill_chunk=5, max_batch=8, metrics=metrics,
+        prefill_chunk=5, max_batch=8, metrics=metrics,
         replica_id="survivor")
     sched = ReplicaScheduler(
         [Replica("victim", None, victim_eng),

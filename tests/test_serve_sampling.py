@@ -85,7 +85,7 @@ def _engine(params=None, **kw):
                                  draft_layers=draft)
               if params is not None else _shared_adapter())
     kw.setdefault("replica_id", "sampling-t")
-    return InferenceEngine(ad, kv_mode="paged", **kw)
+    return InferenceEngine(ad, **kw)
 
 
 # -- validation (the /generate payload contract) -----------------------------
@@ -279,28 +279,6 @@ def test_fork_primary_finishing_first_never_aliases_blocks():
     eng.stop()
 
 
-def test_slot_mode_expiry_reports_request_tokens():
-    """Review regression: slot-mode ``_Slot`` carries no per-sequence
-    stream — mid-flight expiry must read the request's own token list
-    (an AttributeError here would poison-fail EVERY in-flight request
-    through _recover instead of expiring one)."""
-    import time as _time
-    from horovod_tpu.serve import DeadlineExceededError
-    from horovod_tpu.serve.engine import _Slot
-    eng = InferenceEngine(_mlp_adapter(), max_batch=2, kv_mode="slot",
-                          metrics=ServeMetrics(), replica_id="slot-exp")
-    req = Request([1, 2], max_new_tokens=8, timeout_s=0.001)
-    req.generated = [5, 6]
-    _time.sleep(0.01)
-    eng._slots[0] = _Slot(req, 4)
-    assert eng._expire_inflight() == 1
-    assert eng._slots[0] is None
-    with pytest.raises(DeadlineExceededError) as e:
-        req.result(timeout=5)
-    assert "2 token(s)" in str(e.value)
-    assert eng.metrics.snapshot()["requests"]["expired"] == 1
-
-
 def test_retired_member_table_never_double_freed_on_group_preempt():
     """Review regression: a fork member that retires (EOS) leaves its
     FREED table cleared — a later pool-exhaustion preempt of a surviving
@@ -390,7 +368,7 @@ def test_pool_exhaustion_preempts_whole_fork_group():
     eng._slots[1], eng._slots[2] = members
     group.forked = True
     fork_req.samples = [None, None]
-    eng._decode_once_paged()
+    eng._decode_once()
     # The whole family lost its slots and its block; the request sits
     # requeued ONCE with progress reset; the old sequence decoded on.
     assert eng._slots[1] is None and eng._slots[2] is None
@@ -470,7 +448,7 @@ def test_spec_sampled_matches_nonspec_sampled_distribution():
 
     def storm(spec_k):
         from horovod_tpu.serve import DynamicBatcher
-        eng = InferenceEngine(ad, max_batch=8, kv_mode="paged",
+        eng = InferenceEngine(ad, max_batch=8,
                               batcher=DynamicBatcher(max_queue=1024),
                               metrics=ServeMetrics(), spec_k=spec_k,
                               replica_id=f"dist-{spec_k}").start()
@@ -501,7 +479,7 @@ def test_spec_sampled_matches_nonspec_sampled_distribution():
 # -- HTTP surface ------------------------------------------------------------
 
 def _serve_http():
-    eng = InferenceEngine(_mlp_adapter(), max_batch=4, kv_mode="paged",
+    eng = InferenceEngine(_mlp_adapter(), max_batch=4,
                           metrics=ServeMetrics(), replica_id="replica-0")
     sched = ReplicaScheduler([Replica("replica-0", None, eng)],
                              metrics=eng.metrics).start()

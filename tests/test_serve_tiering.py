@@ -71,7 +71,7 @@ def _engine(params, rid, tier=None, client=None, **kw):
     kw.setdefault("num_blocks", 32)
     ad = TransformerAdapter(_TINY, params, block_tokens=BT,
                             kv_dtype=kw.pop("kv_dtype", None))
-    return InferenceEngine(ad, kv_mode="paged", replica_id=rid,
+    return InferenceEngine(ad, replica_id=rid,
                            tiering=tier, tier_client=client, **kw)
 
 

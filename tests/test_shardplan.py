@@ -408,7 +408,7 @@ def _small_engine(**kw):
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, 8), jnp.int32))["params"]
     adapter = TransformerAdapter(cfg, params, block_tokens=8)
-    engine = InferenceEngine(adapter, max_batch=2, kv_mode="paged",
+    engine = InferenceEngine(adapter, max_batch=2,
                              metrics=ServeMetrics(),
                              replica_id="shardplan-test", **kw)
     return adapter, engine
@@ -446,7 +446,7 @@ def test_engine_plan_rejects_dcn_heavy_adapter(monkeypatch):
     adapter = TransformerAdapter(cfg, params, block_tokens=8)
     adapter.step_comm_bytes = 1 << 20
     adapter.step_dcn_bytes = 1 << 20
-    engine = InferenceEngine(adapter, max_batch=2, kv_mode="paged",
+    engine = InferenceEngine(adapter, max_batch=2,
                              metrics=ServeMetrics(),
                              replica_id="shardplan-dcn")
     stats = engine.kv_stats()

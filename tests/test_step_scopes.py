@@ -286,16 +286,12 @@ def test_serving_programs_have_names_of_their_own():
     params = Transformer(cfg).init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
     ad = TransformerAdapter(cfg, params, block_tokens=8)
-    ad.init_cache(4)
     cache = ad.init_paged_cache(6, 4)
     ad.copy_block(cache, 0, 1)
     programs = {
-        "prefill": ad._build_prefill(2, 8),
         "prefill_chunk": ad._build_prefill_chunk(2, 8, 6),
         "prefill_chunk_logits": ad._build_prefill_chunk_logits(2, 8, 6),
         "verify_chunk": ad._build_verify_chunk(2, 8, 6),
-        "sp_prefill_chunk": ad._build_sp_prefill_chunk(8, 8, 6),
-        "decode": ad._build_decode(),
         "decode_paged": ad._build_paged_decode(4),
         "decode_paged_logits": ad._build_paged_decode_logits(4),
         "decode_paged_sampled": ad._build_paged_decode_sampled(4),
@@ -310,6 +306,8 @@ def test_serving_programs_have_names_of_their_own():
                     mlp_sampled=toy._sampled_step)
     names = {want: fn.__name__ for want, fn in programs.items()}
     assert names == {want: want for want in programs}
+    # Seven transformer program families, and the pool's only other writer.
+    assert len(programs) == 7 + 1 + 3
     assert len(set(names.values())) == len(programs)
     # The name of the function is the name of the lowered program.
     lowered = toy._apply.lower(jnp.zeros((2,), jnp.int32)).as_text()

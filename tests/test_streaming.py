@@ -92,7 +92,7 @@ class _SlowMLP(MLPAdapter):
 
     def decode_paged(self, cache, tokens, positions, tables):
         time.sleep(self.delay_s)
-        return MLPAdapter.decode(self, cache, tokens, positions)
+        return super().decode_paged(cache, tokens, positions, tables)
 
 
 def _slow_adapter(seed=3, vocab=VOCAB):
@@ -106,7 +106,7 @@ def _fleet_server(adapter_fn=_mlp_adapter, n=1, request_timeout_s=60,
                   **engine_kw):
     engine_kw.setdefault("max_batch", 4)
     replicas = [Replica(f"replica-{i}", None,
-                        InferenceEngine(adapter_fn(), kv_mode="paged",
+                        InferenceEngine(adapter_fn(),
                                         metrics=ServeMetrics(),
                                         replica_id=f"replica-{i}",
                                         **engine_kw))

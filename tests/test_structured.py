@@ -55,7 +55,7 @@ def _mlp256(seed=3, max_len=512):
 
 def _paged_engine(adapter=None, **kw):
     kw.setdefault("max_batch", 4)
-    return InferenceEngine(adapter or _mlp256(), kv_mode="paged",
+    return InferenceEngine(adapter or _mlp256(),
                            metrics=ServeMetrics(),
                            replica_id="structured-t", **kw)
 
@@ -343,15 +343,25 @@ def test_engine_exhausted_grammar_finishes_with_reason_grammar():
         eng.stop()
 
 
-def test_engine_schema_needs_paged_sampling_capable_stack():
-    eng = InferenceEngine(_mlp256(), max_batch=2, kv_mode="slot",
+def test_engine_schema_needs_sampling_capable_adapter():
+    class _GreedyOnly:
+        """The paged trio and nothing else: no logits, no sampler."""
+
+        def __init__(self, inner):
+            self.vocab_size, self.max_len = inner.vocab_size, inner.max_len
+            self.init_paged_cache = inner.init_paged_cache
+            self.prefill_chunk = inner.prefill_chunk
+            self.decode_paged = inner.decode_paged
+
+    eng = InferenceEngine(_GreedyOnly(_mlp256()), max_batch=2,
                           metrics=ServeMetrics(),
-                          replica_id="slot-t").start()
+                          replica_id="greedy-t").start()
     try:
+        assert eng.generate([65], max_new_tokens=2)  # greedy still serves
         r = Request([65], max_new_tokens=8, eos_id=EOS,
                     schema=BOOL_SCHEMA)
         eng.batcher.submit(r)
-        with pytest.raises(ValueError, match="paged"):
+        with pytest.raises(ValueError, match="decode_paged_logits"):
             r.result(timeout=30)
     finally:
         eng.stop()

@@ -71,7 +71,7 @@ def _engine(adapter=None, replica_id="replica-t", warmup=False, **kw):
     return InferenceEngine(adapter or _mlp_adapter(),
                            batcher=DynamicBatcher(),
                            metrics=ServeMetrics(), max_batch=4,
-                           kv_mode="paged", replica_id=replica_id,
+                           replica_id=replica_id,
                            warmup=warmup, **kw)
 
 
@@ -82,7 +82,7 @@ def _fleet(n=2, warmup=False, tenants=None, metrics=None):
         eng = InferenceEngine(
             _mlp_adapter(3),
             batcher=DynamicBatcher(tenants=tenants),
-            metrics=metrics, max_batch=4, kv_mode="paged",
+            metrics=metrics, max_batch=4,
             replica_id=f"replica-{i}", warmup=warmup)
         replicas.append(Replica(f"replica-{i}", None, eng))
     return ReplicaScheduler(replicas, metrics=metrics)
@@ -303,12 +303,7 @@ def test_engine_fails_unknown_model_request():
         eng.stop()
 
 
-def test_add_model_refuses_slot_mode_and_bad_geometry():
-    slot_eng = InferenceEngine(_mlp_adapter(), batcher=DynamicBatcher(),
-                               metrics=ServeMetrics(), max_batch=2,
-                               kv_mode="slot", replica_id="slot-t")
-    with pytest.raises(ValueError, match="slot"):
-        slot_eng.add_model("alt", _mlp_adapter(7))
+def test_add_model_refuses_bad_geometry_and_duplicate_names():
     eng = _engine()
     with pytest.raises(ValueError, match="max_len"):
         eng.add_model("alt", _mlp_adapter(7, max_len=32))
@@ -577,7 +572,7 @@ def test_e2e_weighted_goodput_tracks_weights():
     metrics = ServeMetrics()
     eng = InferenceEngine(_mlp_adapter(3),
                           batcher=DynamicBatcher(tenants=cfg),
-                          metrics=metrics, max_batch=2, kv_mode="paged",
+                          metrics=metrics, max_batch=2,
                           replica_id="fair-0")
     reqs = []
     for _ in range(8):
