@@ -19,6 +19,9 @@ kernel serves.  FlashAttention-2 structure, mapped onto the Mosaic pipeline:
   and is written when the tile changes; the scaled query tile and the
   online-softmax state (running max / sum / accumulator) live in VMEM
   scratch, set up on a query tile's first step and flushed on its last.
+  The state is dense across the lanes, a row's maximum and sum the same in
+  every lane of the row, so a step pays its two lane reductions and no
+  lane broadcast.
   Each K/V block is a grid-indexed ``BlockSpec``, so Mosaic double-buffers
   the HBM→VMEM DMA of step *t+1* against the MXU compute of step *t*
   automatically.  The [S, S] score matrix never touches HBM.  Emits the
@@ -65,7 +68,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 SAVED = ("hvd_flash_out", "hvd_flash_lse")  # checkpoint names, _flash_fwd
-LANES = 128  # VMEM lane width: (block_q, LANES) scratch keeps m/l aligned
+LANES = 128  # VMEM lane width: per-row statistics are kept this wide
 
 # Static mask modes (ring attention's per-hop block masks compile one
 # kernel per mode): NONE = full attend; CAUSAL = q >= k on local indices;
@@ -249,8 +252,12 @@ def online_softmax_block(s, v, m_ref, l_ref, acc_ref):
     """One FlashAttention-2 online-softmax accumulation step: fold score
     tile ``s`` [Bq, Bk] and value block ``v`` [Bk, D] into the running
     (max ``m_ref``, sum ``l_ref``, accumulator ``acc_ref``) VMEM scratch
-    carried across the sequential K-block grid dimension.  Shared by the
-    training flash kernels and serve/paged_attention.
+    carried across the sequential K-block grid dimension.  For
+    serve/paged_attention alone, whose score tiles are one block of the
+    pool wide, a fraction of a lane group: the statistics are ``[:, :1]``
+    columns broadcast over the lanes.  The training forward kernel has its own step
+    (``_fwd_step``), which keeps them dense over tiles of whole lane
+    groups.
 
     The running max is floored at ``NEG_INF / 2`` so a row with EVERY
     key masked contributes ``p = exp(NEG_INF - NEG_INF/2) = 0`` instead
@@ -281,7 +288,8 @@ def online_softmax_flush(m_ref, l_ref, acc_ref):
     """Finalize the online softmax: returns ``(out [Bq, D], lse
     [Bq, LANES])`` from the scratch state after the last contributing
     block; the logsumexp stays lane-broadcast like the state it is made
-    from."""
+    from.  :func:`online_softmax_block`'s flush, for
+    serve/paged_attention alone."""
     l_final = jnp.maximum(l_ref[...], 1e-30)
     return acc_ref[...] / l_final[:, :1], m_ref[...] + jnp.log(l_final)
 
@@ -319,6 +327,61 @@ def _on_tile(edge, step):
     pl.when(edge == 0)(functools.partial(step, False))
 
 
+def _state_lanes(block_k):
+    """Width of the forward kernel's per-row statistics: the lane width
+    where the key tile is whole lane groups, else the key tile's own."""
+    return LANES if block_k % LANES == 0 else block_k
+
+
+def _at_width(x, width):
+    """A ``[Bq, lanes]`` statistic that is the same in every lane, at
+    ``width`` lanes (the accumulator's head size)."""
+    if width <= x.shape[1]:
+        return x[:, :width]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
+
+
+def _fwd_step(s, v, m_ref, l_ref, acc_ref):
+    """One online-softmax step of the forward kernel: fold score tile ``s``
+    [Bq, Bk] and value block ``v`` [Bk, D] into the running state, all of it
+    float32 VMEM scratch ``_state_lanes(Bk)`` wide and dense across the
+    lanes from load to store: ``m_ref`` holds a row's maximum and ``l_ref``
+    its sum in every lane.  The maximum (the sum) is the elementwise
+    maximum (sum) of the tile's lane groups, then one lane reduction whose
+    result is in every lane, so the exponent's argument, the correction and
+    the stores are register for register: no column, no lane broadcast.
+
+    The running maximum starts at the floor ``NEG_INF / 2``
+    (``_fwd_kernel``), so a row with EVERY key masked contributes
+    ``p = exp(NEG_INF - NEG_INF/2) = 0`` and its output is written as
+    zeros; see :func:`online_softmax_block`, whose results these are, bit
+    for bit."""
+    lanes = m_ref.shape[1]
+    groups = [s[:, c:c + lanes] for c in range(0, s.shape[1], lanes)]
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(
+        functools.reduce(jnp.maximum, groups), axis=1, keepdims=True))
+    p = [jnp.exp(group - m_new) for group in groups]
+    corr = jnp.exp(m_prev - m_new)
+    m_ref[...] = m_new
+    l_ref[...] = l_ref[...] * corr + jnp.sum(
+        functools.reduce(jnp.add, p), axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * _at_width(corr, acc_ref.shape[1]) \
+        + jax.lax.dot_general(
+            jnp.concatenate(p, axis=1).astype(v.dtype), v,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+
+def _fwd_flush(m_ref, l_ref, acc_ref):
+    """``(out [Bq, D], lse [Bq, lanes])`` from ``_fwd_step``'s state after
+    a query tile's last key tile; the (natural) logsumexp is lane-broadcast
+    like the state it is made from."""
+    l_final = jnp.maximum(l_ref[...], 1e-30)
+    return (acc_ref[...] / _at_width(l_final, acc_ref.shape[1]),
+            m_ref[...] + jnp.log(l_final))
+
+
 def _fwd_kernel(tiles_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, qs, acc, m,
                 l, *, scale: float, mask_mode, block_q: int, block_k: int):
     t = pl.program_id(1)
@@ -327,7 +390,7 @@ def _fwd_kernel(tiles_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, qs, acc, m,
     def _init():
         qs[...] = _scaled(q_ref, scale)
         acc[...] = jnp.zeros_like(acc)
-        m[...] = jnp.full_like(m, NEG_INF)
+        m[...] = jnp.full_like(m, NEG_INF / 2)
         l[...] = jnp.zeros_like(l)
 
     def _step(masked):
@@ -335,13 +398,13 @@ def _fwd_kernel(tiles_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, qs, acc, m,
         if masked:
             s = causal_mask(s, tiles_ref[ROW, t] * block_q,
                             tiles_ref[TILE, t] * block_k, mask_mode)
-        online_softmax_block(s, v_ref[0], m, l, acc)
+        _fwd_step(s, v_ref[0], m, l, acc)
 
     _on_tile(tiles_ref[EDGE, t], _step)
 
     @pl.when(tiles_ref[LAST, t] == 1)
     def _flush():
-        out, lse = online_softmax_flush(m, l, acc)
+        out, lse = _fwd_flush(m, l, acc)
         o_ref[0] = out.astype(o_ref.dtype)
         lse_ref[0] = _col_to_row(lse)
 
@@ -503,8 +566,8 @@ def _flash_fwd(q, k, v, mask_mode, scale, block_q, block_k, interpret):
             scratch_shapes=[
                 pltpu.VMEM((block_q, D), q.dtype),
                 pltpu.VMEM((block_q, D), jnp.float32),
-                pltpu.VMEM((block_q, LANES), jnp.float32),
-                pltpu.VMEM((block_q, LANES), jnp.float32),
+                pltpu.VMEM((block_q, _state_lanes(block_k)), jnp.float32),
+                pltpu.VMEM((block_q, _state_lanes(block_k)), jnp.float32),
             ]),
         compiler_params=_compiler_params(interpret),
         interpret=interpret,

@@ -227,3 +227,87 @@ def test_block_diffusion_keeps_a_quarter_of_the_tiles():
     per_row = np.bincount(by_query[flash.ROW])
     np.testing.assert_array_equal(
         per_row, list(range(2, 10)) + list(range(1, 9)))
+
+
+# -- the forward kernel's state over whole lane groups ------------------------
+# Key tiles of one, two and four lane groups of 128: a row's running maximum
+# and sum are kept in every lane of the row, made from the elementwise
+# maximum and sum of the groups (``flash._fwd_step``, ``flash._fwd_flush``).
+
+WIDE_SEQ, WIDE_H, WIDE_HKV, WIDE_D = 512, 2, 1, 32
+WIDE_MODES = [MASK_NONE, MASK_CAUSAL, MASK_STRICT,
+              block_diffusion_mask(4, WIDE_SEQ // 2)]
+WIDE_IDS = ["none", "causal", "strict", "bd4x256"]
+
+
+def wide_qkv(seed, dtype):
+    rng = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rng.randn(1, WIDE_SEQ, h, WIDE_D), dtype)
+                 for h in (WIDE_H, WIDE_HKV, WIDE_HKV))
+
+
+def dense_out_and_lse(q, k, v, mask):
+    """Float32 ``(out [B,S,H,D], lse [B,H,S])`` of masked softmax attention;
+    a row with no key reads zeros and ``-inf``."""
+    q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+    k, v = (jnp.repeat(t, q.shape[2] // k.shape[2], axis=2) for t in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   precision="highest") / np.sqrt(q.shape[-1])
+    s = jnp.where(mask[None, None], s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    p = jnp.where(mask[None, None], jnp.exp(s - jnp.where(
+        jnp.isfinite(lse), lse, 0.0)[..., None]), 0.0)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v, precision="highest"), lse
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16"])
+@pytest.mark.parametrize("block_k", [128, 256, 512])
+@pytest.mark.parametrize("mode", WIDE_MODES, ids=WIDE_IDS)
+def test_forward_and_logsumexp_over_lane_groups(mode, block_k, dtype):
+    q, k, v = wide_qkv(11, dtype)
+    out, lse = flash.flash_attention_lse(q, k, v, mask_mode=mode,
+                                         block_q=128, block_k=block_k)
+    want, want_lse = dense_out_and_lse(q, k, v, dense_mask(mode, WIDE_SEQ))
+    assert out.dtype == dtype and lse.dtype == jnp.float32
+    assert lse.shape == (1, WIDE_H, WIDE_SEQ)
+    reads = np.asarray(jnp.isfinite(want_lse))[0, 0]       # rows with a key
+    assert reads.sum() == WIDE_SEQ - (mode == MASK_STRICT)
+    # A row with every key masked: zeros, and a logsumexp that is finite.
+    np.testing.assert_array_equal(np.asarray(out, np.float32)[:, ~reads], 0)
+    assert np.isfinite(np.asarray(lse)).all()
+    # bf16: the scaled queries, the probabilities and the output are
+    # rounded to bf16 in the kernel and not in the reference.
+    tol = 1e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(np.asarray(lse)[..., reads],
+                               np.asarray(want_lse)[..., reads],
+                               rtol=0, atol=tol)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want), rtol=0, atol=tol)
+
+
+@pytest.mark.parametrize("block_k", [128, 256, 512])
+@pytest.mark.parametrize("mode", WIDE_MODES, ids=WIDE_IDS)
+def test_gradients_recomputed_from_the_lane_groups_logsumexp(mode, block_k):
+    """The backward kernels recompute the probabilities from the forward
+    kernel's logsumexp; the loss reads the logsumexp too, as ring
+    attention's merge does."""
+    q, k, v = wide_qkv(12, jnp.float32)
+    mask = dense_mask(mode, WIDE_SEQ)
+    reads = jnp.asarray(mask.any(axis=1))
+    weight = jnp.asarray(np.random.RandomState(13).randn(
+        1, WIDE_SEQ, WIDE_H, WIDE_D).astype(np.float32))
+
+    def loss(attend):
+        def f(q, k, v):
+            out, lse = attend(q, k, v)
+            return (out * weight).sum() + jnp.where(reads, lse, 0.0).sum()
+        return f
+
+    got = jax.grad(loss(lambda q, k, v: flash.flash_attention_lse(
+        q, k, v, mask_mode=mode, block_q=128, block_k=block_k)),
+        argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: dense_out_and_lse(q, k, v, mask)),
+                    argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4)

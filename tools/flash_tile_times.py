@@ -9,10 +9,11 @@ through ``flash_attention`` forward and backward under block diffusion,
 ``MASK_CAUSAL`` and ``MASK_NONE``; times are the kernels' own events in a
 device trace of ten calls (``benchmarks/harness/trace.py``).  ``--against``
 names further copies of ``parallel/flash.py`` (a parent's, a variant's) to
-time beside this tree's in the same process, and checks their outputs and
-gradients against this tree's bit for bit.  Needs the TPU: a CPU run has
-no device plane and prints no time.  PERF.md section 6, PR 28, has the
-numbers this printed.
+time beside this tree's in the same process, and compares their output,
+logsumexp and three gradients with this tree's: bit-equal, or the largest
+absolute and relative difference of each.  Needs the TPU: a CPU run has
+no device plane and prints no time.  PERF.md section 6, PR 28 and PR 30,
+has the numbers this printed.
 """
 
 import argparse
@@ -36,6 +37,30 @@ def load(path):
     return module
 
 
+def mask_modes(flash):
+    """The three masks the tools run, by name, as ``flash`` spells them."""
+    return {"block_diffusion": flash.block_diffusion_mask(4, SEQ // 2),
+            "causal": flash.MASK_CAUSAL, "none": flash.MASK_NONE}
+
+
+def differences(mine, theirs):
+    """``name abs rel of-largest`` for each array of ``theirs`` that is not
+    bit-equal to ``mine``'s: the largest absolute difference, the largest
+    relative one (over the larger of an element's two magnitudes: a unit
+    in the last place of bf16 reads up to 0.0078) and the absolute one over
+    the array's largest magnitude (what a sum that cancels is held to)."""
+    import numpy as np
+    found = []
+    for name in mine:
+        a, b = (np.asarray(x[name], np.float64) for x in (mine, theirs))
+        if not np.array_equal(a, b):
+            gap, size = np.abs(a - b), np.maximum(np.abs(a), np.abs(b))
+            found.append(f"{name} {gap.max():.3g} "
+                         f"{(gap[size > 0] / size[size > 0]).max():.3g} "
+                         f"{gap.max() / size.max():.3g}")
+    return found
+
+
 def main():
     import jax
     import jax.numpy as jnp
@@ -55,21 +80,25 @@ def main():
         mine = None
         for path in paths:
             flash = load(path)
-            mode = {"block_diffusion": flash.block_diffusion_mask(
-                        4, SEQ // 2),
-                    "causal": flash.MASK_CAUSAL,
-                    "none": flash.MASK_NONE}[mode_name]
-            call = jax.jit(jax.value_and_grad(
-                lambda q, k, v: (flash.flash_attention(
-                    q, k, v, mask_mode=mode, block_q=TILE, block_k=TILE
-                ).astype(jnp.float32) * weight.astype(jnp.float32)).sum(),
-                argnums=(0, 1, 2)))
-            got = jax.tree.map(np.asarray, call(q, k, v))
+            mode = mask_modes(flash)[mode_name]
+            # ``weight`` is an argument: closed over, it would be compiled
+            # into the executable (400 MB, half a minute a compile).
+            def loss(q, k, v, weight):
+                out, lse = flash.flash_attention_lse(
+                    q, k, v, mask_mode=mode, block_q=TILE, block_k=TILE)
+                return (out.astype(jnp.float32)
+                        * weight.astype(jnp.float32)).sum(), (out, lse)
+
+            call = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                              has_aux=True))
+            (_, (out, lse)), grads = call(q, k, v, weight)
+            got = dict(zip(("out", "lse", "dq", "dk", "dv"),
+                           (out, lse) + grads))
             trace_dir = os.path.join(ROOT, "benchmarks_out", "flash_tiles")
             tracing.start(trace_dir)
             for _ in range(CALLS):
-                out = call(q, k, v)
-            jax.block_until_ready(out)
+                last = call(q, k, v, weight)
+            jax.block_until_ready(last)
             tracing.stop()
             ns, calls = collections.Counter(), collections.Counter()
             for plane in tracing.load(trace_dir).values():
@@ -88,9 +117,10 @@ def main():
                 f"{name} {ns[name] / calls[name] / 1e6:.4f} ms a call, "
                 f"{ns[name] / calls[name] / 1e3 / tiles:.3f} us a tile"
                 for name in sorted(ns))
-            same = "" if mine is None else " bit-equal to this tree: " + str(
-                all(jax.tree.leaves(jax.tree.map(np.array_equal, mine,
-                                                 got))))
+            same = "" if mine is None else (
+                "; largest difference from this tree's (absolute, relative"
+                ", over the largest): "
+                + ("; ".join(differences(mine, got)) or "none, bit-equal"))
             mine = got if mine is None else mine
             print(f"{mode_name} {os.path.relpath(path, ROOT)} ({tiles} "
                   f"tiles a kernel): {times or 'no device plane'}{same}",
