@@ -16,6 +16,15 @@ repo's own references:
   ``models/sdar_moe.py`` (flash kernels under the block-diffusion mask,
   the dropless expert layer) against the plain float32 reference of
   ``benchmarks/jobs/sdar_moe.py``;
+* **afmoe** — Trinity-Mini's next-token step at the benchmark cell's sizes
+  (``benchmarks/configs/trinity-mini-ep8.json``, 4 sequences of 8,192
+  tokens): loss
+  and every gradient leaf of ``models/afmoe.py`` (window and full attention
+  in one stack, the gated attention output, the sigmoid router with a
+  shared expert, a leading dense layer) against the plain float32 reference
+  of ``benchmarks/jobs/afmoe.py``; then the three flash kernels' time a tile
+  under the window, ``MASK_CAUSAL`` and ``MASK_NONE``
+  (``tools/flash_tile_times.py``);
 * **trainer** — ``horovodrun -np 1 python examples/synthetic_benchmark.py``:
   ResNet-50, 1000 classes, 224², bf16, sync-BN, batch 128, seven steps;
 * **server** — ``hvdserve --model gpt2-small`` answering ``/generate``
@@ -88,6 +97,15 @@ SDAR_LOSS_TOL = 2e-2
 SDAR_SKEW = 1.0
 SDAR_LAYER_TOL = 3e-2
 
+# -- window and full attention, a shared expert ------------------------------
+AFMOE_CONFIG = "benchmarks/configs/trinity-mini-ep8.json"
+AFMOE_SEQUENCES = 4     # the cell's step, which its limits were read on
+AFMOE_LOSS_TOL = 2e-2   # about ln 25,024 = 10.1; as SDAR_LOSS_TOL
+# The three flash kernels alone under the window, the causal mask and no
+# mask (us a tile, from a device trace): run after the phase, by the parent.
+AFMOE_TILE_TIMES = [sys.executable, "tools/flash_tile_times.py", "--masks",
+                    "window", "causal", "none"]
+
 # -- trainer ---------------------------------------------------------------
 TRAINER_CMD = [sys.executable, "-m", "horovod_tpu.runner.launch", "-np", "1",
                sys.executable, "examples/synthetic_benchmark.py",
@@ -112,8 +130,8 @@ DP_LOSS_TOL = 5e-2
 
 # Seconds a phase may take, compilation included; the whole stays inside
 # the 1200 s the contract allows.
-LIMITS = {"kernels": 300, "sdar": 600, "trainer": 400, "server": 400,
-          "dp4": 900}
+LIMITS = {"kernels": 300, "sdar": 600, "afmoe": 600, "tile_times": 300,
+          "trainer": 400, "server": 400, "dp4": 900}
 
 
 class SmokeFailure(Exception):
@@ -435,8 +453,59 @@ def sdar_expert_layer(job, config: dict, skew: float) -> None:
           f"sdar: expert layer with held columns up by {skew}: {errors}")
 
 
+def phase_afmoe() -> dict:
+    """Loss and every gradient leaf of the Trinity-Mini cell's step at the
+    published widths, program against reference, each leaf held to the
+    limit the cell's own check has for it."""
+    device = require_platform()
+    import jax
+    import numpy as np
+    import horovod_tpu as hvd
+    from horovod_tpu.models import afmoe
+    hvd.init()  # the compile cache
+    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+    from harness import manifest as mf
+    job = mf.load_module("jobs", "afmoe")
+    with open(os.path.join(REPO, AFMOE_CONFIG)) as f:
+        config = json.load(f)
+    cfg = job.model_config(config)
+    params = job.seeded_params(config, SEED)
+    batch = job.seeded_batch(config, SEED, AFMOE_SEQUENCES)
+    t0 = time.monotonic()
+    (loss, aux), grads = jax.jit(jax.value_and_grad(
+        lambda p, *b: afmoe.loss_fn(p, *b, cfg), has_aux=True))(
+            params, *batch)
+    loss = float(loss)
+    # To the host, and the stacked tree gone, before the reference's own
+    # 5.6 GB of parameters and gradients.
+    grads, chosen = jax.tree_util.tree_map(np.asarray, (grads, aux.chosen))
+    print(f"afmoe: program loss {loss:.6f}, pairs routed to the held "
+          f"experts by expert layer {np.asarray(aux.routed_here).tolist()} "
+          f"({time.monotonic() - t0:.0f} s)", flush=True)
+    layers = job.unstacked(params)
+    del params, aux
+    t0 = time.monotonic()
+    # As the cell's check: the reference follows the program's choices, and
+    # those are held to its own by their own limit.
+    want_loss, want, want_chosen = job.ReferenceSteps(
+        config, AFMOE_SEQUENCES).loss_and_grads(layers, *batch,
+                                                imposed=chosen)
+    differ = job.choices_that_differ(chosen, want_chosen)
+    print(f"afmoe: reference loss {want_loss:.6f} "
+          f"({time.monotonic() - t0:.0f} s); {100 * differ:.3f} % of "
+          f"the program's routing choices are not the reference's",
+          flush=True)
+    check(math.isfinite(loss) and abs(loss - want_loss) <= AFMOE_LOSS_TOL,
+          f"afmoe: loss {loss} against the reference's {want_loss}")
+    check(differ <= config["correct"]["choices_limit"],
+          f"afmoe: {differ} of the routing choices are not the reference's")
+    outside = job.leaves_outside(config, job.gradient_errors(grads, want))
+    check(not outside, f"afmoe: gradients out of their limits: {outside}")
+    return device
+
+
 CHILD_PHASES = {"kernels": phase_kernels, "dp4": phase_dp4,
-                "sdar": phase_sdar}
+                "sdar": phase_sdar, "afmoe": phase_afmoe}
 
 
 # ---------------------------------------------------------------------------
@@ -606,6 +675,8 @@ def main(argv=None) -> int:
         else:
             device = timed("kernels", child_report, "kernels")
             timed("sdar", child_report, "sdar")
+            timed("afmoe", child_report, "afmoe")
+            timed("tile_times", run_child, "tile_times", AFMOE_TILE_TIMES)
             timed("trainer", run_trainer)
             timed("server", run_server)
         check(device["platform"] == PLATFORM

@@ -136,6 +136,16 @@ def _layer(cfg: SdarMoeConfig, positions, mask_mode, x, p):
     return x, (moe.routed_here, moe.chosen)
 
 
+def through_layers(layer, x, stacked):
+    """``x [batch, S, hidden]`` through the layers whose parameters are
+    ``stacked`` on a leading axis: the layers under ``lax.scan`` and, inside
+    a layer, the sequences one after the other.  ``layer(x [S, hidden], p)
+    -> (x, aux)`` comes with its own ``jax.checkpoint``; returns ``(x, aux
+    [layers, batch, ...])``."""
+    return lax.scan(lambda x, p: lax.map(lambda xs: layer(xs, p), x),
+                    x, stacked)
+
+
 def hidden_states(params: dict, tokens, cfg: SdarMoeConfig, mask_mode):
     """``(final hidden states [batch, S, hidden] before the last norm,
     Aux)`` for ``tokens [batch, S]`` under ``mask_mode`` (a mode of
@@ -160,9 +170,7 @@ def hidden_states(params: dict, tokens, cfg: SdarMoeConfig, mask_mode):
         lambda x, p: _layer(cfg, positions, mask_mode, x, p),
         policy=jax.checkpoint_policies.save_only_these_names(*SAVED))
     with jax.named_scope("decoder"):
-        x, (routed_here, chosen) = lax.scan(
-            lambda x, p: lax.map(lambda xs: one(xs, p), x),
-            x, params["layers"])
+        x, (routed_here, chosen) = through_layers(one, x, params["layers"])
     return x, Aux(routed_here.sum(axis=1),
                   chosen.reshape(chosen.shape[0], batch * seq, -1))
 

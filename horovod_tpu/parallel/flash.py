@@ -36,6 +36,14 @@ kernel serves.  FlashAttention-2 structure, mapped onto the Mosaic pipeline:
   group``, and the dK/dV kernel's list walks, for a key tile, the group's
   query heads and for each its query tiles, so K and V are never repeated
   in HBM and dK/dV are summed over the group in VMEM.
+* **Masks** — static modes, a kernel per mode: ``MASK_NONE``,
+  ``MASK_CAUSAL``, ``MASK_STRICT``, the causal window of
+  :func:`window_mask` (``0 <= q - k < window``: a row walks at most
+  ``window / block_k + 1`` key tiles, the diagonal one and the one the
+  window's far side cuts with the mask, those between without) and the
+  block-diffusion mask of :func:`block_diffusion_mask`.  One predicate pair
+  (``block_contributes``, ``block_full``) decides for every mode which tiles
+  the lists hold and which of them need the mask's arithmetic.
 * Products run in the operands' dtype (bf16 stays bf16 on the MXU) with
   float32 accumulation; softmax statistics are float32.
 * ``jax.custom_vjp`` ties them together, so the kernel drops into
@@ -80,13 +88,26 @@ LANES = 128  # VMEM lane width: per-row statistics are kept this wide
 # blocks, a clean query reads the clean keys of its own and earlier blocks,
 # and never a noised key.  It needs its block length and ``L``, so the mode
 # is the tuple :func:`block_diffusion_mask` makes.
-MASK_NONE, MASK_CAUSAL, MASK_STRICT, MASK_BLOCK_DIFFUSION = 0, 1, 2, 3
+# WINDOW is the causal sliding window: a query reads itself and the
+# ``window - 1`` keys before it, ``0 <= q - k < window``; the mode is the
+# tuple :func:`window_mask` makes.
+MASK_NONE, MASK_CAUSAL, MASK_STRICT, MASK_BLOCK_DIFFUSION, MASK_WINDOW = \
+    0, 1, 2, 3, 4
 
 
 def block_diffusion_mask(block_length: int, length: int):
     """The mask mode for ``[noised ; clean]`` copies of ``length`` tokens in
     blocks of ``block_length`` (static, hashable: a kernel per value)."""
     return (MASK_BLOCK_DIFFUSION, int(block_length), int(length))
+
+
+def window_mask(window: int):
+    """The mask mode of a causal window: pair ``(q, k)`` kept iff ``0 <= q -
+    k < window`` (static, hashable: a kernel per value).  At or over the
+    sequence it is ``MASK_CAUSAL``, tile lists and results bit for bit."""
+    if window < 1:
+        raise ValueError(f"a window of {window} positions keeps nothing")
+    return (MASK_WINDOW, int(window))
 
 
 def _half_block(pos, block_length, length):
@@ -110,7 +131,10 @@ def causal_mask(s, q_offset, k_offset, mode):
     bq, bk = s.shape
     qg = q_offset + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     kg = k_offset + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    if isinstance(mode, tuple):
+    if isinstance(mode, tuple) and mode[0] == MASK_WINDOW:
+        ahead = qg - kg
+        keep = (ahead >= 0) & (ahead < mode[1])
+    elif isinstance(mode, tuple):
         _, block_length, length = mode
         q_noised, q_block = _half_block(qg, block_length, length)
         k_noised, k_block = _half_block(kg, block_length, length)
@@ -128,14 +152,17 @@ def block_contributes(mode, q_lo, q_hi, k_lo, k_hi=None):
     """Whether a key block spanning global positions ``[k_lo, k_hi]`` can
     contribute to queries spanning ``[q_lo, q_hi]`` under ``mode`` — the
     compute-skip predicate for blocks entirely outside the mask.  Static
-    or traced positions, same contract as :func:`causal_mask`; block
-    diffusion reads ``k_hi`` too and takes static positions only."""
+    or traced positions, same contract as :func:`causal_mask`; the window
+    and block diffusion read ``k_hi`` too, and block diffusion takes static
+    positions only."""
     if mode == MASK_NONE:
         return True
     if mode == MASK_CAUSAL:
         return k_lo <= q_hi
     if mode == MASK_STRICT:
         return k_lo < q_hi
+    if mode[0] == MASK_WINDOW:
+        return (k_lo <= q_hi) & (q_lo - k_hi < mode[1])
     # Block diffusion: static positions only (the kernels walk lists made
     # from this, ``tile_lists``).  The noised and the clean part of each
     # span, as block indices:
@@ -164,6 +191,8 @@ def block_full(mode, q_lo, q_hi, k_lo, k_hi):
         return k_hi <= q_lo
     if mode == MASK_STRICT:
         return k_hi < q_lo
+    if mode[0] == MASK_WINDOW:
+        return k_hi <= q_lo and q_hi - k_lo < mode[1]
     _, block_length, length = mode
     if (q_lo < length) != (q_hi < length) or \
             (k_lo < length) != (k_hi < length):
@@ -746,8 +775,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``h // (H / Hkv)``, the kernels address it through their index maps
     and sum dK and dV over the group, so K and V are never repeated in
     HBM.  ``mask_mode`` names the mask where ``causal`` cannot: one of the
-    ``MASK_*`` modes or :func:`block_diffusion_mask`; tiles wholly outside
-    it cost no product.
+    ``MASK_*`` modes, :func:`window_mask` or :func:`block_diffusion_mask`;
+    tiles wholly outside it cost no product, no copy and no grid step.
 
     ``interpret=None`` auto-selects the Pallas interpreter off-TPU so the
     same call works in the CPU-mesh test environment.  In interpret mode
@@ -777,7 +806,8 @@ def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array,
     (the cross-hop merge weights depend on lse, so its cotangent is
     nonzero).  ``mask_mode`` is one of MASK_NONE / MASK_CAUSAL /
     MASK_STRICT applied on LOCAL block indices (ring hops pick the mode
-    per hop from the block owner), or :func:`block_diffusion_mask`."""
+    per hop from the block owner), :func:`window_mask` or
+    :func:`block_diffusion_mask`."""
     B, S, H, D = q.shape
     scale, block_q, block_k, interpret = _checked(
         "flash_attention_lse", q, k, v, scale, block_q, block_k, interpret)

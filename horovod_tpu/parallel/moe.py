@@ -47,16 +47,33 @@ class MoEOutput(NamedTuple):
     # dropped by capacity — monitor; raise capacity_factor if high
 
 
-def _top_k_gating(logits: jax.Array, top_k: int):
-    """Top-k router: returns (indices [T, k], weights [T, k], probs [T, E]).
+def _top_k_gating(logits: jax.Array, top_k: int, score_func: str = "softmax",
+                  selection_bias: Optional[jax.Array] = None,
+                  route_scale: float = 1.0):
+    """Top-k router: returns (indices [T, k], weights [T, k], scores [T, E]).
 
-    Weights are the softmax probabilities of the chosen experts,
-    renormalized over the k choices (GShard convention)."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    weights, indices = lax.top_k(probs, top_k)
-    weights = weights / jnp.maximum(
-        weights.sum(axis=-1, keepdims=True), 1e-9)
-    return indices, weights, probs
+    The scores are the float32 ``logits``' softmax (GShard convention) or,
+    each output alone, their sigmoid.  The ``top_k`` largest of ``scores +
+    selection_bias`` are chosen: the bias picks, weighs nothing and takes no
+    gradient.  A chosen expert's weight is its score over the chosen
+    scores' sum (plus 1e-20, which a sum above 1e-12 does not feel), times
+    ``route_scale``."""
+    logits = logits.astype(jnp.float32)
+    if score_func == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif score_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"score_func {score_func!r}: softmax or sigmoid")
+    if selection_bias is None:
+        weights, indices = lax.top_k(scores, top_k)
+    else:
+        _, indices = lax.top_k(scores + lax.stop_gradient(
+            selection_bias.astype(jnp.float32)), top_k)
+        weights = jnp.take_along_axis(scores, indices, axis=-1)
+    weights = route_scale * weights / (
+        weights.sum(axis=-1, keepdims=True) + 1e-20)
+    return indices, weights, scores
 
 
 def _dispatch_combine(indices, weights, probs, num_experts: int,
@@ -365,6 +382,9 @@ def dropless_expert_ffn(x: jax.Array,
                         top_k: int,
                         first_expert=0,
                         axis_name: Optional[str] = None,
+                        score_func: str = "softmax",
+                        selection_bias: Optional[jax.Array] = None,
+                        route_scale: float = 1.0,
                         interpret: Optional[bool] = None) -> DroplessOutput:
     """Mixture-of-experts FFN that is told which experts it holds, routes
     over all of them and drops nothing.
@@ -377,7 +397,13 @@ def dropless_expert_ffn(x: jax.Array,
 
     Each token takes its ``top_k`` experts by softmax probability, the
     chosen probabilities divided by their sum (``norm_topk_prob``).  The
-    (token, choice) pairs whose expert is held here, experts
+    router's variants (``_top_k_gating``; scores, top-k and weights in
+    float32 at the highest precision whatever the variant):
+    ``score_func="sigmoid"`` scores every expert alone; ``selection_bias
+    [E]`` is added to the scores for the choice only, never to a weight, and
+    takes no gradient; ``route_scale`` multiplies the weights.  Nothing
+    after the routing knows which variant chose.  The (token, choice) pairs
+    whose expert is held here, experts
     ``first_expert .. first_expert + E_held - 1``, are sorted by expert
     into one buffer, the three expert products run as grouped matrix
     products whose work follows the group sizes (``parallel/grouped.py``),
@@ -410,7 +436,8 @@ def dropless_expert_ffn(x: jax.Array,
     with jax.named_scope("hvd::moe::route"):
         logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
-        chosen, weights, _ = _top_k_gating(logits, top_k)
+        chosen, weights, _ = _top_k_gating(logits, top_k, score_func,
+                                           selection_bias, route_scale)
         chosen = chosen.astype(jnp.int32)
 
     if not axis_name:

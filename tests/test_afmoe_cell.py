@@ -1,0 +1,163 @@
+"""The AFMoE job of ``benchmarks/jobs/afmoe.py`` around its model: the
+program through ``hvd.shard_step`` and ``DistributedOptimizer``, the
+reference's judgement of a first step, and the rehearsal cell of
+``benchmarks/tests/test_trinity_cell.py``; the rehearsal configuration (a
+dense layer, a window and a full expert layer) on the CPU, float32."""
+
+import importlib.util
+import json
+import math
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu.models import afmoe
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmarks")
+SEED, BATCH = 11, 2
+
+
+@pytest.fixture(scope="module")
+def job():
+    sys.path.insert(0, BENCH)       # the job finds ``harness`` by name
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "bench_jobs_afmoe", os.path.join(BENCH, "jobs", "afmoe.py"))
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+    finally:
+        sys.path.remove(BENCH)
+    return module
+
+
+@pytest.fixture(scope="module")
+def config():
+    with open(os.path.join(BENCH, "tests", "cells", "configs",
+                           "trinity-tiny.json")) as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def seeded(job, config):
+    return (job.seeded_params(config, SEED),
+            job.seeded_batch(config, SEED, BATCH))
+
+
+@pytest.fixture(scope="module")
+def first(job, config, seeded):
+    """The model's gradients and routing on the seeded state."""
+    params, batch = seeded
+    (_, aux), grads = jax.value_and_grad(
+        lambda p: afmoe.loss_fn(p, *batch, job.model_config(config)),
+        has_aux=True)(params)
+    return jax.tree_util.tree_map(np.asarray, grads), aux
+
+
+def leaves(tree):
+    return {jax.tree_util.keystr(path): leaf for path, leaf in
+            jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+def test_step_through_shard_step_and_distributed_optimizer(hvd8, job,
+                                                           config):
+    """The job's program on the 8-device CPU mesh, a sequence a slot,
+    against the reference's AdamW step on one device."""
+    program = job.Program(config, 1, SEED)
+    state = program.fresh_state()
+    got = []
+    for _ in range(2):
+        *state, loss = program.step(*state, *program.batch)
+        got.append(float(loss))
+    want = job.reference_losses(config, SEED, program.global_batch, 2)
+    np.testing.assert_allclose(got, want, rtol=1e-4)
+    assert got[1] < got[0]
+    routed, chosen, gradients = program.first
+    z = job.sizes(config)
+    assert routed.shape == (2,) and chosen.shape == (
+        2, 8 * z["length"], z["top_k"])
+    assert sorted(leaves(gradients)) == sorted(leaves(
+        jax.eval_shape(lambda: job.seeded_params(config, SEED))))
+    assert len(gradients["runs"]) == 3 and "mlp_up" in gradients["runs"][0]
+
+
+def test_the_references_update_by_leaf_is_adamws_first_step(job, config,
+                                                            seeded):
+    import optax
+    params = job.unstacked(seeded[0])
+    grads = jax.tree_util.tree_map(
+        lambda a: jnp.asarray(np.random.RandomState(8).randn(*a.shape),
+                              jnp.float32), params)
+    opt = job.make_optimizer(config)
+    updates, _ = opt.update(grads, opt.init(params), params)
+    want = optax.apply_updates(params, updates)
+    keep = jax.tree_util.tree_map(jnp.copy, (params, grads))
+    got = job.update_by_leaf(opt, *keep)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-8)
+    with pytest.raises(ValueError, match="first step"):
+        job.reference_losses(config, SEED, BATCH, 3)
+
+
+@pytest.mark.parametrize("fault,leaf", [
+    (None, None), ("scaled", "head"), ("scaled", "router"),
+    ("zero", "shared_down"), ("zero", "mlp_up"), ("absent", "wg"),
+    ("choices", None)])
+def test_a_gradient_outside_its_limit_fails_the_loss_comparison(
+        job, config, first, fault, leaf):
+    """The runner compares losses only: a first step with a gradient leaf
+    outside ``correct.gradient_limits`` gets ``inf`` to agree with, and so
+    does one whose choices of experts are not the reference's (the
+    reference follows them, so with no limit on the gradients the choices'
+    own limit is what fails)."""
+    grads, aux = first
+    grads = dict(grads, runs=[dict(run) for run in grads["runs"]])
+    for holder in [grads] + grads["runs"]:
+        if leaf in holder and fault == "scaled":
+            holder[leaf] = 1.01 * holder[leaf]
+        elif leaf in holder and fault == "zero":
+            holder[leaf] = np.zeros_like(holder[leaf])
+    limits = dict(config["correct"]["gradient_limits"])
+    if fault == "absent":
+        del limits[leaf]
+    chosen = np.asarray(aux.chosen)
+    if fault == "choices":
+        limits = dict.fromkeys(limits, math.inf)
+        chosen = chosen.copy()
+        chosen[:, ::50] = (chosen[:, ::50] + 1) % job.sizes(config)["routed"]
+    config = dict(config, correct=dict(config["correct"],
+                                       gradient_limits=limits))
+    job._first_steps[SEED, BATCH] = job.FirstStep(
+        np.asarray(aux.routed_here), chosen, grads)
+    losses = job.reference_losses(config, SEED, BATCH, 2)
+    assert (SEED, BATCH) not in job._first_steps
+    assert math.isfinite(losses[1])
+    assert math.isinf(losses[0]) == (fault is not None)
+
+
+@pytest.mark.parametrize("test", [
+    "test_cell_and_its_reference",
+    "test_cell_traced_reports_counts_but_no_device_metric",
+    "test_control_in_a_lower_precision_comes_out_not_correct",
+    "test_operations_count_the_pairs_the_masks_keep",
+    "test_forward_kernel_time_over_the_tile_runs_of_a_mixed_stack",
+    "test_every_new_reader_has_its_file_and_its_entry"])
+def test_rehearsal_cell_through_the_train_runner(test):
+    """``benchmarks/tests/test_trinity_cell.py`` (the rehearsal cell of
+    ``benchmarks/tests/cells/`` through ``runners/train.py``, in a child
+    process) as counted cases of this suite."""
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "HVD_TPU_EMULATE_RANKS")}
+    proc = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-x", "-p",
+         "no:cacheprovider", "-p", "no:xdist",
+         f"benchmarks/tests/test_trinity_cell.py::{test}"],
+        cwd=ROOT, env=dict(env, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=900)
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-2000:]

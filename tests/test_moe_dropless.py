@@ -219,3 +219,144 @@ def test_grouped_product_and_its_gradients(sizes):
                              transpose_rhs=True)
     np.testing.assert_allclose(transposed[:total], loop(lhs, rhs)[:total],
                                rtol=1e-5, atol=1e-5)
+
+
+# -- the router's variants ------------------------------------------------------
+# A sigmoid router with a selection bias, the chosen scores divided by their
+# sum plus 1e-20 and scaled (``score_func``, ``selection_bias``,
+# ``route_scale``), against the layer written token by token.
+
+SCALE, EPS = 2.826, 1e-20
+SIGMOID = dict(score_func="sigmoid", route_scale=SCALE)
+
+
+def sigmoid_loop(w, first=0, held=None, bias=None, shared=None):
+    """Sigmoid scores, the K largest of ``score + bias``, a chosen score
+    over the chosen scores' sum times ``SCALE``; experts ``first .. first +
+    held - 1`` applied, and ``shared`` (gate, up, down) once a token."""
+    x = np.asarray(w["x"], np.float64)
+    router = np.asarray(w["router"], np.float64)
+    held = router.shape[1] - first if held is None else held
+    bias = np.zeros(router.shape[1]) if bias is None else np.asarray(bias)
+    ffn = lambda v, g, u, dn: ((v @ g) / (1 + np.exp(-(v @ g)))
+                               * (v @ u)) @ dn
+    out, here = np.zeros_like(x), 0
+    for t in range(x.shape[0]):
+        s = 1 / (1 + np.exp(-(x[t] @ router)))
+        top = np.argsort(-(s + bias), kind="stable")[:K]
+        for e in top:
+            if first <= e < first + held:
+                here += 1
+                out[t] += SCALE * s[e] / (s[top].sum() + EPS) * ffn(
+                    x[t], *(np.asarray(w[n][e - first], np.float64)
+                            for n in ("gate", "up", "down")))
+        if shared is not None:
+            out[t] += ffn(x[t], *(np.asarray(m, np.float64)
+                                  for m in shared))
+    return out, here
+
+
+def test_the_default_router_is_the_parents_bit_for_bit():
+    """The one router's defaults against the parent's formula written out:
+    softmax, top-k, the chosen probabilities over their sum floored at
+    1e-9, which a top-k sum (at least ``K / E``) never reaches."""
+    from horovod_tpu.parallel import moe
+    logits = jnp.asarray(np.random.RandomState(20).randn(T, E) * 3,
+                         jnp.float32)
+    chosen, gates, scores = moe._top_k_gating(logits, K)
+    want_gates, want_chosen = jax.lax.top_k(jax.nn.softmax(logits, -1), K)
+    want_gates = want_gates / jnp.maximum(
+        want_gates.sum(axis=-1, keepdims=True), 1e-9)
+    np.testing.assert_array_equal(chosen, want_chosen)
+    np.testing.assert_array_equal(gates, want_gates)
+    np.testing.assert_array_equal(scores, jax.nn.softmax(logits, -1))
+    w = weights(21)
+    np.testing.assert_array_equal(
+        layer(w).out, layer(w, score_func="softmax", selection_bias=None,
+                            route_scale=1.0).out)
+
+
+def test_sigmoid_scores_renormalised_and_scaled():
+    w = weights(22)
+    got = layer(w, **SIGMOID)
+    want, here = sigmoid_loop(w)
+    assert int(got.routed_here) == here == T * K
+    np.testing.assert_allclose(got.out, want, rtol=2e-4, atol=2e-5)
+    # The scale is a factor of the result.
+    unscaled = layer(w, score_func="sigmoid")
+    np.testing.assert_allclose(got.out, SCALE * unscaled.out, rtol=1e-5,
+                               atol=1e-6)
+    with pytest.raises(ValueError, match="score_func"):
+        layer(w, score_func="tanh")
+
+
+def test_selection_bias_changes_the_choice_and_not_the_weight():
+    """A bias of 10 on two experts puts them among every token's choices;
+    a weight is still the plain score over the chosen plain scores."""
+    w = weights(23)
+    bias = np.zeros(E, np.float32)
+    bias[[3, 11]] = 10.0
+    got = layer(w, selection_bias=jnp.asarray(bias), **SIGMOID)
+    plain = layer(w, **SIGMOID)
+    assert all({3, 11} <= set(row) for row in np.asarray(got.chosen))
+    assert not all({3, 11} <= set(row) for row in np.asarray(plain.chosen))
+    want, _ = sigmoid_loop(w, bias=bias)
+    np.testing.assert_allclose(got.out, want, rtol=2e-4, atol=2e-5)
+    # No gradient reaches the bias.
+    grad = jax.grad(lambda b: layer(w, selection_bias=b, **SIGMOID)
+                    .out.sum())(jnp.asarray(bias))
+    np.testing.assert_array_equal(grad, 0)
+
+
+@pytest.mark.parametrize("wrt", ["x", "router", "gate", "up", "down"])
+def test_sigmoid_router_gradients_equal_the_dense_layer(wrt):
+    w = weights(24)
+    first, held = 4, 8
+    share = dict(w, **{n: w[n][first:first + held]
+                       for n in ("gate", "up", "down")})
+    seed = jnp.asarray(np.random.RandomState(25).randn(T, D), jnp.float32)
+
+    def dense(w):
+        s = jax.nn.sigmoid(w["x"] @ w["router"])
+        top_s, top = jax.lax.top_k(s, K)
+        gates = jnp.zeros_like(s).at[jnp.arange(T)[:, None], top].set(
+            SCALE * top_s / (top_s.sum(-1, keepdims=True) + EPS)
+        )[:, first:first + held]
+        h = jax.nn.silu(jnp.einsum("td,edf->tef", w["x"], w["gate"])) \
+            * jnp.einsum("td,edf->tef", w["x"], w["up"])
+        return jnp.einsum("te,tef,efd->td", gates, h, w["down"])
+
+    got = jax.grad(lambda w: (layer(w, first_expert=first, **SIGMOID).out
+                              * seed).sum())(share)[wrt]
+    want = jax.grad(lambda w: (dense(w) * seed).sum())(share)[wrt]
+    np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-4)
+
+
+def test_eight_chips_shares_and_the_shared_expert_once_are_the_uncut_layer():
+    """The share test of a layer with a shared expert: each of 8 chips
+    routes over all 16 experts and computes its 2; their parts, with the
+    shared expert (which every chip computes alike) counted ONCE, add up to
+    the uncut reference layer, and their pair counts to T x K."""
+    w = weights(26)
+    rng = np.random.RandomState(27)
+    shared = tuple(jnp.asarray(rng.randn(*shape).astype(np.float32) * 0.3)
+                   for shape in ((D, F), (D, F), (F, D)))
+    bias = rng.randn(E).astype(np.float32) * 0.2
+    held = E // 8
+    total, pairs = 0, 0
+    for first in range(0, E, held):
+        share = dict(w, **{n: w[n][first:first + held]
+                           for n in ("gate", "up", "down")})
+        got = layer(share, first_expert=first,
+                    selection_bias=jnp.asarray(bias), **SIGMOID)
+        want, here = sigmoid_loop(share, first, held, bias=bias)
+        assert int(got.routed_here) == here
+        np.testing.assert_allclose(got.out, want, rtol=2e-4, atol=2e-5)
+        total, pairs = total + got.out, pairs + here
+    assert pairs == T * K
+    gate, up, down = shared
+    total = total + (jax.nn.silu(w["x"] @ gate) * (w["x"] @ up)) @ down
+    uncut, _ = sigmoid_loop(w, bias=bias, shared=shared)
+    np.testing.assert_allclose(total, uncut, rtol=2e-4, atol=2e-5)
+    # Counted on every chip, the shared expert would be there 8 times.
+    assert float(np.abs(uncut - sigmoid_loop(w, bias=bias)[0]).max()) > 0.1

@@ -31,7 +31,8 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 
 import horovod_tpu as hvd
 from horovod_tpu import parallel
-from horovod_tpu.parallel.flash import block_diffusion_mask, flash_attention
+from horovod_tpu.parallel.flash import (block_diffusion_mask,
+                                        flash_attention, window_mask)
 from horovod_tpu.parallel.grouped import gmm
 from horovod_tpu.serve.paged_attention import (SCALE_DTYPE,
                                                paged_decode_attention,
@@ -135,6 +136,33 @@ def test_block_diffusion_flash_compiles_for_v5e(one_chip,
     # at the 32 query heads' size is made.
     for line in kernels:
         assert line.count(f"bf16[{SDAR_HKV},{2 * SDAR_L},{SDAR_D}]") >= 2
+
+
+@pytest.mark.parametrize("window", [2048, 8192], ids=["w2048", "w8192"])
+def test_window_flash_compiles_for_v5e(one_chip, no_persistent_cache,
+                                       window):
+    """Trinity-Mini's attention as its cell runs it: one sequence of 8,192
+    positions, 32 query heads on 4 key/value heads of 128, tiles of 512,
+    forward and both backward kernels under the causal window (2,048, and
+    the sequence's own length, which keeps what ``MASK_CAUSAL`` keeps)."""
+    def sds(heads):
+        return jax.ShapeDtypeStruct((1, 2 * SDAR_L, heads, SDAR_D),
+                                    jnp.bfloat16, sharding=one_chip)
+
+    text = jax.jit(jax.grad(
+        lambda *a: flash_attention(
+            *a, mask_mode=window_mask(window), block_q=SDAR_TILE,
+            block_k=SDAR_TILE, interpret=False).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))).lower(
+            sds(SDAR_H), sds(SDAR_HKV), sds(SDAR_HKV)).compile().as_text()
+    kernels = [line for line in text.split("\n")
+               if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(kernels) == 3
+    # The tile list is an operand: 70 (or 136) steps a query head in the
+    # forward and dQ kernels, eight heads' worth a key/value head in dK/dV.
+    steps = 70 if window == 2048 else 136
+    assert sum(f"s32[6,{steps}]" in line for line in kernels) == 2
+    assert sum(f"s32[6,{8 * steps}]" in line for line in kernels) == 1
 
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])

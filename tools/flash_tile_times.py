@@ -1,12 +1,15 @@
 #!/usr/bin/env python3
 """The three flash kernels alone on the chip, per call and per tile.
 
-    python3 tools/flash_tile_times.py [--against other/flash.py ...]
+    python3 tools/flash_tile_times.py [--masks NAME ...] [--against other/flash.py ...]
 
-One sequence at the ``sdar30b-train-blockdiff-4k`` cell's sizes (8,192
-positions, 32 query heads on 4 key/value heads of 128, tiles of 512)
-through ``flash_attention`` forward and backward under block diffusion,
-``MASK_CAUSAL`` and ``MASK_NONE``; times are the kernels' own events in a
+One sequence at the two language-model cells' sizes (8,192 positions, 32
+query heads on 4 key/value heads of 128, tiles of 512) through
+``flash_attention`` forward and backward under block diffusion,
+``MASK_CAUSAL``, ``MASK_NONE`` and the causal window of 2,048
+(``--masks``: any of ``block_diffusion``, ``causal``, ``none``, ``window``;
+all four by default; a copy of ``flash.py`` that lacks a mode is left out
+under it); times are the kernels' own events in a
 device trace of ten calls (``benchmarks/harness/trace.py``).  ``--against``
 names further copies of ``parallel/flash.py`` (a parent's, a variant's) to
 time beside this tree's in the same process, and compares their output,
@@ -37,10 +40,17 @@ def load(path):
     return module
 
 
+WINDOW = 2048
+
+
 def mask_modes(flash):
-    """The three masks the tools run, by name, as ``flash`` spells them."""
-    return {"block_diffusion": flash.block_diffusion_mask(4, SEQ // 2),
-            "causal": flash.MASK_CAUSAL, "none": flash.MASK_NONE}
+    """The masks the tools run, by name, as ``flash`` spells them; the
+    window where ``flash`` has one."""
+    modes = {"block_diffusion": flash.block_diffusion_mask(4, SEQ // 2),
+             "causal": flash.MASK_CAUSAL, "none": flash.MASK_NONE}
+    if hasattr(flash, "window_mask"):
+        modes["window"] = flash.window_mask(WINDOW)
+    return modes
 
 
 def differences(mine, theirs):
@@ -69,18 +79,23 @@ def main():
 
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--against", nargs="*", default=[])
+    ap.add_argument("--masks", nargs="*", default=[
+        "block_diffusion", "causal", "none", "window"])
+    args = ap.parse_args()
     paths = [os.path.join(ROOT, "horovod_tpu", "parallel", "flash.py")] \
-        + ap.parse_args().against
+        + args.against
     rng = np.random.RandomState(0)
     q, k, v, weight = (jnp.asarray(rng.randn(1, SEQ, h, HEAD_DIM),
                                    jnp.bfloat16)
                        for h in (HEADS, KV_HEADS, KV_HEADS, HEADS))
     print("device", jax.devices()[0].device_kind, flush=True)
-    for mode_name in ("block_diffusion", "causal", "none"):
+    for mode_name in args.masks:
         mine = None
         for path in paths:
             flash = load(path)
-            mode = mask_modes(flash)[mode_name]
+            mode = mask_modes(flash).get(mode_name)
+            if mode is None:
+                continue
             # ``weight`` is an argument: closed over, it would be compiled
             # into the executable (400 MB, half a minute a compile).
             def loss(q, k, v, weight):
