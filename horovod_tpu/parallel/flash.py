@@ -43,7 +43,11 @@ kernel serves.  FlashAttention-2 structure, mapped onto the Mosaic pipeline:
   window's far side cuts with the mask, those between without) and the
   block-diffusion mask of :func:`block_diffusion_mask`.  One predicate pair
   (``block_contributes``, ``block_full``) decides for every mode which tiles
-  the lists hold and which of them need the mask's arithmetic.
+  the lists hold and which of them need the mask's arithmetic.  That
+  arithmetic (:func:`causal_mask`) runs on the tile's positions as a
+  ``[Bq, 1]`` column and a ``[1, Bk]`` row, 512 values each and not
+  262,144: an edge tile pays one or two comparisons of the column with the
+  row, their conjunction and a select.
 * Products run in the operands' dtype (bf16 stays bf16 on the MXU) with
   float32 accumulation; softmax statistics are float32.
 * ``jax.custom_vjp`` ties them together, so the kernel drops into
@@ -112,11 +116,20 @@ def window_mask(window: int):
 
 def _half_block(pos, block_length, length):
     """``(noised?, block index)`` of global positions under block
-    diffusion; arrays or Python ints."""
+    diffusion; arrays or Python ints.  Positions are never negative, so an
+    array's division is a shift where the block length is a power of two
+    and truncates where it is not."""
     noised = pos < length
     if isinstance(pos, int):
         return noised, (pos if noised else pos - length) // block_length
-    return noised, jnp.where(noised, pos, pos - length) // block_length
+    local = jnp.where(noised, pos, pos - length)
+    if block_length & (block_length - 1) == 0:
+        return noised, jax.lax.shift_right_logical(
+            local, jnp.int32(block_length.bit_length() - 1))
+    return noised, jax.lax.div(local, jnp.int32(block_length))
+
+
+_NEVER = np.iinfo(np.int32).max  # above every position and every rank
 
 
 def causal_mask(s, q_offset, k_offset, mode):
@@ -125,24 +138,36 @@ def causal_mask(s, q_offset, k_offset, mode):
     Offsets may be static ints (the dense flash kernels pass block-index
     multiples) or traced scalars (the paged serving kernels pass each
     sequence's absolute chunk start / block-table slot).  Shared by the
-    training flash kernels and serve/paged_attention."""
+    training flash kernels and serve/paged_attention.
+
+    What depends on a query's position alone is computed on a ``[Bq, 1]``
+    column and what depends on a key's alone on a ``[1, Bk]`` row: the
+    positions, the window's far side, block diffusion's halves, block
+    indices (its one division) and ranks.  The tile sees the column
+    compared with the row (once under ``MASK_CAUSAL`` and ``MASK_STRICT``,
+    twice and the two results' conjunction under the window and block
+    diffusion) and the one select: no arithmetic of the tile's shape."""
     if mode == MASK_NONE:
         return s
     bq, bk = s.shape
-    qg = q_offset + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    kg = k_offset + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    qg = q_offset + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+    kg = k_offset + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
     if isinstance(mode, tuple) and mode[0] == MASK_WINDOW:
-        ahead = qg - kg
-        keep = (ahead >= 0) & (ahead < mode[1])
+        keep = (kg <= qg) & (kg > qg - mode[1])
     elif isinstance(mode, tuple):
         _, block_length, length = mode
         q_noised, q_block = _half_block(qg, block_length, length)
         k_noised, k_block = _half_block(kg, block_length, length)
-        # Logical operations only: Mosaic has no select over booleans.
-        k_clean = jnp.logical_not(k_noised)
-        keep = (q_noised & k_noised & (q_block == k_block)) \
-            | (q_noised & k_clean & (k_block < q_block)) \
-            | (jnp.logical_not(q_noised) & k_clean & (k_block <= q_block))
+        # Rank the blocks of both copies in one order, noised block b at
+        # 2b and clean block b at 2b + 1.  A clean key is kept iff its
+        # rank is at most the query's (a noised query's own clean block
+        # ranks one above it), a noised key iff its rank is the query's
+        # (only a noised query of its block has it).  Comparisons and
+        # logical operations only: Mosaic has no select over booleans.
+        q_rank = 2 * q_block + jnp.where(q_noised, 0, 1)
+        k_rank = 2 * k_block + jnp.where(k_noised, 0, 1)
+        keep = (k_rank <= q_rank) \
+            & (q_rank <= jnp.where(k_noised, k_rank, _NEVER))
     else:
         keep = qg >= kg if mode == MASK_CAUSAL else qg > kg
     return jnp.where(keep, s, NEG_INF)

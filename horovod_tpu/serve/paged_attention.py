@@ -17,9 +17,10 @@ Mosaic pipeline the way ``parallel/flash.py`` maps FlashAttention-2):
   HBM→VMEM DMA of physical block ``tables[b, j+1]`` against the MXU work
   on block ``tables[b, j]`` — no gathered copy ever exists in HBM.  The
   online-softmax state (running max / sum / accumulator) lives in VMEM
-  scratch persisting across the block dimension, via the same
-  ``online_softmax_block``/``online_softmax_flush`` helpers the training
-  flash kernels use.
+  scratch persisting across the block dimension, via
+  ``online_softmax_block``/``online_softmax_flush`` of ``parallel/flash.py``,
+  which this kernel alone calls (the training forward kernel keeps its
+  state dense across the lanes, ``flash._fwd_step``).
 * **hole masking** — table holes carry the out-of-bounds sentinel
   (``num_blocks``); the index_map clamps them onto the last real block
   (exactly what ``jnp.take(mode="clip")`` does in the gather path) and the
@@ -29,8 +30,9 @@ Mosaic pipeline the way ``parallel/flash.py`` maps FlashAttention-2):
 * **chunked prefill** — the same kernel shape with a ``[C, H, Dh]`` query
   tile per sequence and the mask evaluated at *absolute*
   positions (query ``starts[b] + row`` vs key ``j*block_tokens + col``)
-  through the shared ``causal_mask`` mask-mode machinery
-  (``MASK_NONE``/``MASK_CAUSAL``/``MASK_STRICT``, ``parallel/flash.py``) —
+  through ``causal_mask``, the one function shared with the training
+  flash kernels (``MASK_NONE``/``MASK_CAUSAL``/``MASK_STRICT``,
+  ``parallel/flash.py``; positions on a column and a row) —
   the engine scatters the chunk's K/V into the pool before the call, so
   intra-chunk causality falls out of the positional mask exactly as in
   the gather path.

@@ -8,8 +8,8 @@ import pytest
 
 from horovod_tpu.parallel import flash
 from horovod_tpu.parallel.flash import (MASK_CAUSAL, MASK_NONE, MASK_STRICT,
-                                        block_diffusion_mask,
-                                        flash_attention)
+                                        MASK_WINDOW, block_diffusion_mask,
+                                        flash_attention, window_mask)
 
 B, H, HKV, D = 2, 8, 2, 16
 
@@ -25,6 +25,8 @@ def dense_mask(mode, seq):
         return q >= k
     if mode == MASK_STRICT:
         return q > k
+    if mode[0] == MASK_WINDOW:
+        return (q >= k) & (q - k < mode[1])
     _, block, length = mode
     qn, kn = q < length, k < length
     qb, kb = (q % length) // block, (k % length) // block
@@ -310,4 +312,127 @@ def test_gradients_recomputed_from_the_lane_groups_logsumexp(mode, block_k):
     want = jax.grad(loss(lambda q, k, v: dense_out_and_lse(q, k, v, mask)),
                     argnums=(0, 1, 2))(q, k, v)
     for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4)
+
+
+# -- the mask of one tile: positions on a column and a row --------------------
+# ``causal_mask`` computes what a query's position decides on a [Bq, 1]
+# column and what a key's decides on a [1, Bk] row; the tile sees them
+# compared, the results' conjunction and the select.
+
+# (block length, clean tokens L, query tile, key tile): the cell's sizes,
+# then block lengths that are no power of two with L no multiple of the
+# tile, so that a tile spans the end of the noised copy and the start of
+# the clean one.
+TILE_CASES = [(4, 4096, 512, 512), (3, 60, 8, 8), (6, 60, 24, 24),
+              (6, 60, 8, 24), (3, 48, 32, 16)]
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["static", "traced"])
+@pytest.mark.parametrize("block,length,block_q,block_k", TILE_CASES)
+def test_causal_mask_is_the_rule_on_every_edge_tile(block, length, block_q,
+                                                    block_k, traced):
+    mode = block_diffusion_mask(block, length)
+    seq = 2 * length
+    want = dense_mask(mode, seq)
+    _, edge = flash._mask_tiles(mode, seq, block_q, block_k)
+    if length % block_q:           # the tiles that span both copies
+        assert edge[length // block_q].any()
+        assert edge[:, length // block_k].any()
+    # Off the edge the kernels skip the mask; it is right there too (the
+    # paged kernel runs it on every block): every tile at the small sizes.
+    tiles = np.argwhere(edge if seq > 1024 else np.ones_like(edge))
+    assert seq <= 1024 or len(tiles) == 24
+
+    def kept(q_offset, k_offset):
+        return flash.causal_mask(jnp.zeros((block_q, block_k)), q_offset,
+                                 k_offset, mode) == 0
+
+    got = jax.jit(kept) if traced else kept
+    for qi, ki in tiles:
+        q_lo, k_lo = int(qi * block_q), int(ki * block_k)
+        np.testing.assert_array_equal(
+            got(q_lo, k_lo),
+            want[q_lo:q_lo + block_q, k_lo:k_lo + block_k],
+            err_msg=f"tile ({qi}, {ki})")
+
+
+def tile_shaped(jaxpr, shape):
+    """The primitives of the equations of ``jaxpr``, and of those inside
+    them, whose output has ``shape``, in order."""
+    found = []
+    for eqn in jaxpr.eqns:
+        inner = [v for v in eqn.params.values()
+                 if hasattr(v, "jaxpr") or hasattr(v, "eqns")]
+        if inner:
+            for sub in inner:
+                found += tile_shaped(getattr(sub, "jaxpr", sub), shape)
+        elif any(getattr(v.aval, "shape", None) == shape
+                 for v in eqn.outvars):
+            found.append(eqn.primitive.name)
+    return found
+
+
+COMPARISONS = {"lt", "le", "gt", "ge", "eq", "ne"}
+
+
+@pytest.mark.parametrize("mode,comparisons", [
+    (block_diffusion_mask(4, 4096), 2), (window_mask(2048), 2),
+    (MASK_CAUSAL, 1), (MASK_STRICT, 1)],
+    ids=["block_diffusion", "window", "causal", "strict"])
+def test_the_tile_sees_comparisons_and_one_select(mode, comparisons):
+    """The CPU's reading of what ``tools/flash_bundles.py`` counts: on a
+    [512, 512] edge tile no division, remainder, subtraction, iota or
+    select has an output of the tile's shape but the final select; the
+    tile-shaped equations are the comparisons of the column with the row,
+    their conjunction, the masked value's broadcast and that select."""
+    tile = (512, 512)
+    jaxpr = jax.make_jaxpr(
+        lambda s, q_offset, k_offset: flash.causal_mask(
+            s, q_offset, k_offset, mode))(jnp.zeros(tile), 3584, 3584)
+    found = tile_shaped(jaxpr.jaxpr, tile)
+    assert found[-1] == "select_n" and found.count("select_n") == 1
+    assert sum(name in COMPARISONS for name in found) == comparisons
+    assert set(found) <= COMPARISONS | {"broadcast_in_dim", "and",
+                                        "select_n"}
+    assert found.count("and") == comparisons - 1
+
+
+# The five masks where a block-diffusion tile spans both copies and the
+# block length is no power of two: 120 positions, L = 60.
+SPAN_SEQ = 120
+SPAN_MODES = [MASK_NONE, MASK_CAUSAL, MASK_STRICT,
+              block_diffusion_mask(6, SPAN_SEQ // 2), window_mask(20)]
+SPAN_IDS = ["none", "causal", "strict", "bd6x60", "window20"]
+
+
+@pytest.mark.parametrize("block_q,block_k", [(24, 8), (8, 24)])
+@pytest.mark.parametrize("mode", SPAN_MODES, ids=SPAN_IDS)
+def test_outputs_logsumexp_and_gradients_under_the_five_masks(
+        mode, block_q, block_k):
+    q, k, v = qkv(21, SPAN_SEQ)
+    mask = dense_mask(mode, SPAN_SEQ)
+    reads = jnp.asarray(mask.any(axis=1))
+    weight = jnp.asarray(np.random.RandomState(22).randn(
+        B, SPAN_SEQ, H, D).astype(np.float32))
+
+    def everything(attend):
+        def loss(q, k, v):
+            out, lse = attend(q, k, v)
+            return ((out * weight).sum()
+                    + jnp.where(reads, lse, 0.0).sum()), (out, lse)
+        (_, outs), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return outs, grads
+
+    (out, lse), got = everything(lambda q, k, v: flash.flash_attention_lse(
+        q, k, v, mask_mode=mode, block_q=block_q, block_k=block_k))
+    (want_out, want_lse), want = everything(
+        lambda q, k, v: dense_out_and_lse(q, k, v, mask))
+    np.testing.assert_allclose(out, want_out, rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse)[..., np.asarray(reads)],
+                               np.asarray(want_lse)[..., np.asarray(reads)],
+                               rtol=0, atol=1e-5)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
         np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4)

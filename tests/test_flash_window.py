@@ -197,3 +197,36 @@ def test_traced_offsets_take_the_window_too():
                                   dense_mask(24, 5)[16:24, 8:16])
     assert bool(contributes)
     assert not bool(masked(24, 8)[1]) and not bool(masked(8, 16)[1])
+
+
+# (positions, window, query tile, key tile): a window narrower than the
+# tile, the tile's own width, a tile and a half, and the cell's sizes.
+TILE_CASES = [(128, 8, 32, 32), (128, 32, 32, 32), (128, 48, 32, 32),
+              (96, 20, 32, 16), (8192, 2048, 512, 512)]
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["static", "traced"])
+@pytest.mark.parametrize("seq,window,block_q,block_k", TILE_CASES)
+def test_causal_mask_is_the_band_on_every_edge_tile(seq, window, block_q,
+                                                    block_k, traced):
+    """``causal_mask`` on a tile against the dense band, with the offsets
+    as Python ints and traced: every tile at the small sizes (off the edge
+    too, where the kernels skip the mask), the 28 edge tiles at the
+    cell's."""
+    mode = window_mask(window)
+    want = dense_mask(seq, window)
+    _, edge = flash._mask_tiles(mode, seq, block_q, block_k)
+    tiles = np.argwhere(edge if seq > 1024 else np.ones_like(edge))
+    assert seq <= 1024 or len(tiles) == 28
+
+    def kept(q_offset, k_offset):
+        return flash.causal_mask(jnp.zeros((block_q, block_k)), q_offset,
+                                 k_offset, mode) == 0
+
+    got = jax.jit(kept) if traced else kept
+    for qi, ki in tiles:
+        q_lo, k_lo = int(qi * block_q), int(ki * block_k)
+        np.testing.assert_array_equal(
+            got(q_lo, k_lo),
+            want[q_lo:q_lo + block_q, k_lo:k_lo + block_k],
+            err_msg=f"tile ({qi}, {ki})")

@@ -3,15 +3,20 @@
 
     python3 tools/flash_bundles.py [--against other/flash.py ...]
 
-Compiles ``flash_attention`` forward and backward at the
-``sdar30b-train-blockdiff-4k`` cell's sizes for a *described* v5e (no chip:
+Compiles ``flash_attention`` forward and backward at the two language-model
+cells' sizes under block diffusion, the causal window of 2,048,
+``MASK_CAUSAL`` and ``MASK_NONE`` for a *described* v5e (no chip:
 ``tests/test_tpu_compile.py`` has the method), has the TPU compiler write
 each kernel's final schedule (``--xla_jf_dump_llo_text``) and prints, for
 every region of a kernel over 30 bundles (in the forward kernel: the set-up
 of a query tile, the step on a tile the mask cuts, the step on a full tile,
 the flush), its VLIW bundles and what they hold: stores, loads, MXU pushes,
 exponents, lane reductions (``xlane``) and lane permutes (``vperm``: a
-``[:, :1]`` column broadcast over the lanes costs one a register).
+``[:, :1]`` column broadcast over the lanes costs one a register).  After
+each mask, one line a kernel with the bundles of its two steps (on a tile
+the mask cuts / on a full tile) for this tree's ``flash.py`` and every
+``--against`` copy side by side: the difference of the two is what the mask
+costs an edge tile.
 
 A count, not a time: a bundle issues in a cycle at best (1.5 GHz), and the
 kernels measure 1.3 to 1.7 cycles a bundle (PERF.md section 6, PR 30).  It
@@ -33,7 +38,7 @@ import tempfile
 import flash_tile_times as sizes  # beside this file: sizes, load, masks
 
 ROOT = sizes.ROOT
-MODES = ("block_diffusion", "causal", "none")
+MODES = ("block_diffusion", "window", "causal", "none")
 COUNTED = re.compile(  # the lane units' pushes, not their ``vpop``s
     r"= (vst|vld|vmatmul|vmatpush|vpow2|vperm|v(?:max|add)\.xlane)")
 
@@ -79,6 +84,31 @@ def regions(path):
     return found
 
 
+def schedules_of(path, mode):
+    """``{kernel: regions}`` of the three kernels compiled through the copy
+    of ``flash.py`` at ``path`` under ``mode``; empty where the compiler
+    wrote no schedule."""
+    with tempfile.TemporaryDirectory() as dump:
+        subprocess.run(
+            [sys.executable, __file__, "--child", path, mode],
+            env=dict(os.environ, LIBTPU_INIT_ARGS=(
+                f"--xla_jf_dump_to={dump} --xla_jf_dump_llo_text=true"
+                " --xla_jf_dump_llo_pass_label_regex=final_bundles")),
+            capture_output=True)
+        return {re.search(r"hvd_flash_\w+?(?=_*\.)", name).group(0):
+                regions(name)
+                for name in glob.glob(os.path.join(
+                    dump, "*hvd_flash*-final_bundles.txt"))}
+
+
+def steps(found):
+    """``cut / full``: the bundles of a kernel's two steps, which are its
+    regions that push to the MXU, in ``_on_tile``'s order: the step on a
+    tile the mask cuts, then the step on a full tile."""
+    return " / ".join(str(bundles) for bundles, ops in found
+                      if "vmatmul" in ops)
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--against", nargs="*", default=[])
@@ -89,26 +119,20 @@ def main():
     paths = [os.path.join(ROOT, "horovod_tpu", "parallel", "flash.py")] \
         + args.against
     for mode in MODES:
-        for path in paths:
-            with tempfile.TemporaryDirectory() as dump:
-                subprocess.run(
-                    [sys.executable, __file__, "--child", path, mode],
-                    env=dict(os.environ, LIBTPU_INIT_ARGS=(
-                        f"--xla_jf_dump_to={dump} --xla_jf_dump_llo_text=true"
-                        " --xla_jf_dump_llo_pass_label_regex=final_bundles")),
-                    capture_output=True)
-                schedules = sorted(
-                    glob.glob(os.path.join(
-                        dump, "*hvd_flash*-final_bundles.txt")),
-                    key=lambda name: name.split("hvd_flash")[1])
-                print(f"{mode} {os.path.relpath(path, ROOT)}"
-                      + ("" if schedules else ": no schedule was written"))
-                for schedule in schedules:
-                    kernel = re.search(r"hvd_flash_\w+?(?=_*\.)", schedule)
-                    for bundles, ops in regions(schedule):
-                        print(f"  {kernel.group(0)} {bundles} bundles: "
-                              + ", ".join(f"{op} {n}"
-                                          for op, n in ops.items()))
+        found = {path: schedules_of(path, mode) for path in paths}
+        for path, kernels in found.items():
+            print(f"{mode} {os.path.relpath(path, ROOT)}"
+                  + ("" if kernels else ": no schedule was written"))
+            for kernel, parts in sorted(kernels.items()):
+                for bundles, ops in parts:
+                    print(f"  {kernel} {bundles} bundles: "
+                          + ", ".join(f"{op} {n}" for op, n in ops.items()))
+        print(f"{mode}: a step on a tile the mask cuts / on a full tile, "
+              "bundles, by copy in the order above")
+        for kernel in sorted(set().union(*found.values())):
+            print(f"  {kernel} " + " | ".join(
+                steps(kernels.get(kernel, [])) or "-"
+                for kernels in found.values()))
 
 
 if __name__ == "__main__":

@@ -6,10 +6,10 @@
 One sequence at the two language-model cells' sizes (8,192 positions, 32
 query heads on 4 key/value heads of 128, tiles of 512) through
 ``flash_attention`` forward and backward under block diffusion,
-``MASK_CAUSAL``, ``MASK_NONE`` and the causal window of 2,048
-(``--masks``: any of ``block_diffusion``, ``causal``, ``none``, ``window``;
-all four by default; a copy of ``flash.py`` that lacks a mode is left out
-under it); times are the kernels' own events in a
+``MASK_CAUSAL``, ``MASK_STRICT``, ``MASK_NONE`` and the causal window of
+2,048 (``--masks``: any of ``block_diffusion``, ``causal``, ``strict``,
+``none``, ``window``; all five by default; a copy of ``flash.py`` that lacks
+a mode is left out under it); times are the kernels' own events in a
 device trace of ten calls (``benchmarks/harness/trace.py``).  ``--against``
 names further copies of ``parallel/flash.py`` (a parent's, a variant's) to
 time beside this tree's in the same process, and compares their output,
@@ -47,7 +47,8 @@ def mask_modes(flash):
     """The masks the tools run, by name, as ``flash`` spells them; the
     window where ``flash`` has one."""
     modes = {"block_diffusion": flash.block_diffusion_mask(4, SEQ // 2),
-             "causal": flash.MASK_CAUSAL, "none": flash.MASK_NONE}
+             "causal": flash.MASK_CAUSAL, "strict": flash.MASK_STRICT,
+             "none": flash.MASK_NONE}
     if hasattr(flash, "window_mask"):
         modes["window"] = flash.window_mask(WINDOW)
     return modes
@@ -80,7 +81,7 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--against", nargs="*", default=[])
     ap.add_argument("--masks", nargs="*", default=[
-        "block_diffusion", "causal", "none", "window"])
+        "block_diffusion", "causal", "strict", "none", "window"])
     args = ap.parse_args()
     paths = [os.path.join(ROOT, "horovod_tpu", "parallel", "flash.py")] \
         + args.against
