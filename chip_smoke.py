@@ -25,6 +25,13 @@ repo's own references:
   of ``benchmarks/jobs/afmoe.py``; then the three flash kernels' time a tile
   under the window, ``MASK_CAUSAL`` and ``MASK_NONE``
   (``tools/flash_tile_times.py``);
+* **joyai** — JoyAI-LLM-Flash's step (next-token loss plus the depth-1
+  multi-token-prediction loss) at the benchmark cell's sizes
+  (``benchmarks/configs/joyai-llm-flash-ep16.json``, 4 sequences of 8,192
+  tokens): both losses and every gradient leaf of ``models/joyai_flash.py``
+  (latent attention through the flash kernels with keys of 192 and values
+  of 128, the MTP module on the shared embedding and head) against the
+  plain float32 reference of ``benchmarks/jobs/joyai_flash.py``;
 * **trainer** — ``horovodrun -np 1 python examples/synthetic_benchmark.py``:
   ResNet-50, 1000 classes, 224², bf16, sync-BN, batch 128, seven steps;
 * **server** — ``hvdserve --model gpt2-small`` answering ``/generate``
@@ -106,6 +113,11 @@ AFMOE_LOSS_TOL = 2e-2   # about ln 25,024 = 10.1; as SDAR_LOSS_TOL
 AFMOE_TILE_TIMES = [sys.executable, "tools/flash_tile_times.py", "--masks",
                     "window", "causal", "none"]
 
+# -- latent attention, a multi-token-prediction module -----------------------
+JOYAI_CONFIG = "benchmarks/configs/joyai-llm-flash-ep16.json"
+JOYAI_SEQUENCES = 4     # the cell's step, which its limits were read on
+JOYAI_LOSS_TOL = 2e-2   # about ln 16,160 = 9.7; as SDAR_LOSS_TOL
+
 # -- trainer ---------------------------------------------------------------
 TRAINER_CMD = [sys.executable, "-m", "horovod_tpu.runner.launch", "-np", "1",
                sys.executable, "examples/synthetic_benchmark.py",
@@ -130,7 +142,8 @@ DP_LOSS_TOL = 5e-2
 
 # Seconds a phase may take, compilation included; the whole stays inside
 # the 1200 s the contract allows.
-LIMITS = {"kernels": 300, "sdar": 600, "afmoe": 600, "tile_times": 300,
+LIMITS = {"kernels": 300, "sdar": 600, "afmoe": 600, "joyai": 600,
+          "tile_times": 300,
           "trainer": 400, "server": 400, "dp4": 900}
 
 
@@ -504,8 +517,64 @@ def phase_afmoe() -> dict:
     return device
 
 
+def phase_joyai() -> dict:
+    """Both losses and every gradient leaf of the JoyAI-LLM-Flash cell's
+    step at the published widths, program against reference, each leaf held
+    to the limit the cell's own check has for it."""
+    device = require_platform()
+    import jax
+    import numpy as np
+    import horovod_tpu as hvd
+    from horovod_tpu.models import joyai_flash
+    hvd.init()  # the compile cache
+    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+    from harness import manifest as mf
+    job = mf.load_module("jobs", "joyai_flash")
+    with open(os.path.join(REPO, JOYAI_CONFIG)) as f:
+        config = json.load(f)
+    cfg = job.model_config(config)
+    params = job.seeded_params(config, SEED)
+    batch = job.seeded_batch(config, SEED, JOYAI_SEQUENCES)
+    t0 = time.monotonic()
+    (_, (aux, *got)), grads = jax.jit(jax.value_and_grad(
+        lambda p, *b: joyai_flash.loss_fn(p, *b, cfg), has_aux=True))(
+            params, *batch)
+    got = [float(x) for x in got]
+    # To the host, and the stacked tree gone, before the reference's own
+    # 5.4 GB of parameters and gradients.
+    grads, chosen = jax.tree_util.tree_map(np.asarray, (grads, aux.chosen))
+    print(f"joyai: program L_main {got[0]:.6f} L_mtp {got[1]:.6f}, pairs "
+          f"routed to the held experts by expert layer, the MTP block last, "
+          f"{np.asarray(aux.routed_here).tolist()} "
+          f"({time.monotonic() - t0:.0f} s)", flush=True)
+    layers = job.unstacked(params)
+    del params, aux
+    t0 = time.monotonic()
+    # As the cell's check: the reference follows the program's choices, and
+    # those are held to its own by their own limit.
+    want, want_grads, want_chosen = job.ReferenceSteps(
+        config, JOYAI_SEQUENCES).loss_and_grads(layers, *batch,
+                                                imposed=chosen)
+    want = [want[0], want[1] / cfg.mtp_loss_weight]
+    differ = job.choices_that_differ(chosen, want_chosen)
+    print(f"joyai: reference L_main {want[0]:.6f} L_mtp {want[1]:.6f} "
+          f"({time.monotonic() - t0:.0f} s); {100 * differ:.3f} % of "
+          f"the program's routing choices are not the reference's",
+          flush=True)
+    check(all(math.isfinite(mine) and abs(mine - theirs) <= JOYAI_LOSS_TOL
+              for mine, theirs in zip(got, want)),
+          f"joyai: losses {got} against the reference's {want}")
+    check(differ <= config["correct"]["choices_limit"],
+          f"joyai: {differ} of the routing choices are not the reference's")
+    outside = job.leaves_outside(config,
+                                 job.gradient_errors(grads, want_grads))
+    check(not outside, f"joyai: gradients out of their limits: {outside}")
+    return device
+
+
 CHILD_PHASES = {"kernels": phase_kernels, "dp4": phase_dp4,
-                "sdar": phase_sdar, "afmoe": phase_afmoe}
+                "sdar": phase_sdar, "afmoe": phase_afmoe,
+                "joyai": phase_joyai}
 
 
 # ---------------------------------------------------------------------------
@@ -676,6 +745,7 @@ def main(argv=None) -> int:
             device = timed("kernels", child_report, "kernels")
             timed("sdar", child_report, "sdar")
             timed("afmoe", child_report, "afmoe")
+            timed("joyai", child_report, "joyai")
             timed("tile_times", run_child, "tile_times", AFMOE_TILE_TIMES)
             timed("trainer", run_trainer)
             timed("server", run_server)

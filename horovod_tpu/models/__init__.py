@@ -5,12 +5,15 @@ Mirrors the reference's benchmark surface (SURVEY.md §6): ResNet-50/101/152
 (keras mnist examples), and transformer families (BERT-large / GPT-2) for the
 BASELINE.json north-star configs.
 
-Two further models are plain functions over a dict of arrays, each a module
-imported by name (``from horovod_tpu.models import afmoe``): ``sdar_moe``
-(SDAR-MoE trained by block diffusion) and ``afmoe`` (Trinity-Mini's block:
-window and full attention in one stack, a gated attention output, a sigmoid
-router with a shared expert, leading dense layers); the benchmark's jobs
-(``benchmarks/jobs/``) train both.
+Three further models are plain functions over a dict of arrays, each a
+module imported by name (``from horovod_tpu.models import afmoe``):
+``sdar_moe`` (SDAR-MoE trained by block diffusion), ``afmoe`` (Trinity-Mini's
+block: window and full attention in one stack, a gated attention output, a
+sigmoid router with a shared expert, leading dense layers) and
+``joyai_flash`` (JoyAI-LLM-Flash: latent attention with one rotary key
+shared by all heads, and a multi-token-prediction module on the shared
+embedding and head); the benchmark's jobs (``benchmarks/jobs/``) train all
+three.
 """
 
 from .resnet import (  # noqa: F401

@@ -36,6 +36,17 @@ kernel serves.  FlashAttention-2 structure, mapped onto the Mosaic pipeline:
   group``, and the dK/dV kernel's list walks, for a key tile, the group's
   query heads and for each its query tiles, so K and V are never repeated
   in HBM and dK/dV are summed over the group in VMEM.
+* **Two widths** — queries and keys are ``Dqk`` wide, values ``Dv``
+  (``v.shape[-1]``; latent attention attends with keys of 192, 128 without
+  positions and 64 rotary, and values of 128).  ``q``, ``k``, dQ and dK and
+  their blocks and scratch are ``Dqk`` wide; ``v``, the output, its
+  accumulator, dO and dV ``Dv``; the softmax scale defaults to ``1 /
+  sqrt(Dqk)``.  One product over the whole key: a key of one and a half
+  lane widths costs the MXU the two passes that 256 would, and so would a
+  second product over its 64 rotary dimensions alone, so a rotary key that
+  all heads share is broadcast by the caller (``models/joyai_flash.py``)
+  and its gradient is the sum of dK's rotary columns over the heads.  With
+  ``Dv == Dqk`` the kernels are the programs they were.
 * **Masks** — static modes, a kernel per mode: ``MASK_NONE``,
   ``MASK_CAUSAL``, ``MASK_STRICT``, the causal window of
   :func:`window_mask` (``0 <= q - k < window``: a row walks at most
@@ -60,9 +71,9 @@ against the dense reference implementation in tests (CPU interpret mode),
 the kernels are compiled for the chip in tests/test_tpu_compile.py and run
 against the same reference on it by chip_smoke.py.
 
-Layout: [B, S, H, D] public API; internally [B*H, S, D], per-row
-statistics [B*H, 1, S].  Block sizes default to 128 (MXU tile) and clamp
-to the sequence length.
+Layout: [B, S, H, D] public API (``v``: [B, S, Hkv, Dv]); internally
+[B*H, S, D], per-row statistics [B*H, 1, S].  Block sizes default to 128
+(MXU tile) and clamp to the sequence length.
 """
 
 from __future__ import annotations
@@ -531,7 +542,7 @@ def _bwd_dkv_kernel(tiles_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dv_acc[...] += jax.lax.dot_general(
             p.astype(do_ref.dtype), do_ref[0],
             dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [Bk, D]
+            preferred_element_type=jnp.float32)       # [Bk, Dv]
         dk_acc[...] += jax.lax.dot_general(
             ds.astype(q.dtype), q,
             dimension_numbers=(((0,), (0,)), ((), ())),
@@ -593,17 +604,19 @@ def _flash(q, k, v, mask_mode, scale, block_q, block_k, interpret):
 
 
 def _flash_fwd(q, k, v, mask_mode, scale, block_q, block_k, interpret):
-    """``q`` is ``[B*H, S, D]``; ``k`` and ``v`` are ``[B*Hkv, S, D]`` with
-    ``Hkv`` dividing ``H``: query head ``h`` reads key/value head
-    ``h // (H / Hkv)`` through the index maps, no copy."""
+    """``q`` is ``[B*H, S, D]``, ``k`` ``[B*Hkv, S, D]`` and ``v`` ``[B*Hkv,
+    S, Dv]`` with ``Hkv`` dividing ``H``: query head ``h`` reads key/value
+    head ``h // (H / Hkv)`` through the index maps, no copy.  The output
+    and its accumulator are ``Dv`` wide."""
     BH, S, D = q.shape
+    Dv = v.shape[2]
     group = BH // k.shape[0]
     tiles, _ = tile_lists(mask_mode, S, block_q, block_k, group)
     q_map, kv_map, stat_map = _by_query_maps(group)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, mask_mode=mask_mode,
                           block_q=block_q, block_k=block_k),
-        out_shape=[_out_struct((BH, S, D), q.dtype, q),
+        out_shape=[_out_struct((BH, S, Dv), q.dtype, q),
                    _out_struct((BH, 1, S), jnp.float32, q)],
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -611,15 +624,15 @@ def _flash_fwd(q, k, v, mask_mode, scale, block_q, block_k, interpret):
             in_specs=[
                 pl.BlockSpec((1, block_q, D), q_map),
                 pl.BlockSpec((1, block_k, D), kv_map),
-                pl.BlockSpec((1, block_k, D), kv_map),
+                pl.BlockSpec((1, block_k, Dv), kv_map),
             ],
             out_specs=[
-                pl.BlockSpec((1, block_q, D), q_map),
+                pl.BlockSpec((1, block_q, Dv), q_map),
                 _stat_spec(block_q, stat_map),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_q, D), q.dtype),
-                pltpu.VMEM((block_q, D), jnp.float32),
+                pltpu.VMEM((block_q, Dv), jnp.float32),
                 pltpu.VMEM((block_q, _state_lanes(block_k)), jnp.float32),
                 pltpu.VMEM((block_q, _state_lanes(block_k)), jnp.float32),
             ]),
@@ -652,6 +665,7 @@ def _run_bwd_kernels(q, k, v, do, lse, delta, mask_mode, scale,
     the lse-exposing vjps (the latter folds the lse cotangent into
     ``delta``; see ``_flash_lse_bwd``)."""
     BH, S, D = q.shape
+    Dv = v.shape[2]
     group = BH // k.shape[0]
     by_query, by_key = tile_lists(mask_mode, S, block_q, block_k, group)
     lse, delta = lse.reshape(BH, 1, S), delta.reshape(BH, 1, S)
@@ -667,8 +681,8 @@ def _run_bwd_kernels(q, k, v, do, lse, delta, mask_mode, scale,
             in_specs=[
                 pl.BlockSpec((1, block_q, D), q_map),
                 pl.BlockSpec((1, block_k, D), kv_map),
-                pl.BlockSpec((1, block_k, D), kv_map),
-                pl.BlockSpec((1, block_q, D), q_map),
+                pl.BlockSpec((1, block_k, Dv), kv_map),
+                pl.BlockSpec((1, block_q, Dv), q_map),
                 _stat_spec(block_q, stat_map),
                 _stat_spec(block_q, stat_map),
             ],
@@ -701,17 +715,17 @@ def _run_bwd_kernels(q, k, v, do, lse, delta, mask_mode, scale,
             in_specs=[
                 pl.BlockSpec((1, block_q, D), q_of),
                 pl.BlockSpec((1, block_k, D), kv_of),
-                pl.BlockSpec((1, block_k, D), kv_of),
-                pl.BlockSpec((1, block_q, D), q_of),
+                pl.BlockSpec((1, block_k, Dv), kv_of),
+                pl.BlockSpec((1, block_q, Dv), q_of),
                 _stat_spec(block_q, stat_of),
                 _stat_spec(block_q, stat_of),
             ],
             out_specs=[
                 pl.BlockSpec((1, block_k, D), kv_of),
-                pl.BlockSpec((1, block_k, D), kv_of),
+                pl.BlockSpec((1, block_k, Dv), kv_of),
             ],
             scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                            pltpu.VMEM((block_k, D), jnp.float32)]),
+                            pltpu.VMEM((block_k, Dv), jnp.float32)]),
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
         name="hvd_flash_bwd_dkv",
@@ -768,12 +782,13 @@ def _heads_first(x):
 def _checked(name, q, k, v, scale, block_q, block_k, interpret):
     """Defaults filled in and shapes checked for the two public calls."""
     B, S, H, D = q.shape
-    if k.shape != v.shape or k.shape[:2] != (B, S) or k.shape[3] != D \
-            or H % k.shape[2]:
+    if k.shape[:3] != v.shape[:3] or k.shape[:2] != (B, S) \
+            or k.shape[3] != D or H % k.shape[2]:
         raise ValueError(
             f"{name}: keys and values {k.shape}, {v.shape} do not fit "
-            f"queries {q.shape}: the key/value heads must divide the "
-            f"query heads")
+            f"queries {q.shape}: keys as wide as the queries, as many value "
+            f"heads as key heads, and their number must divide the query "
+            f"heads")
     block_q, block_k = min(block_q, S), min(block_k, S)
     if S % block_q or S % block_k:
         raise ValueError(
@@ -795,6 +810,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Differentiable flash attention over [B, S, H, D] (full local seq).
 
+    ``v`` may be narrower or wider than ``q`` and ``k`` (``[B, S, Hkv,
+    Dv]``): the output is ``[B, S, H, Dv]``.
     ``k`` and ``v`` may have fewer heads than ``q`` (``[B, S, Hkv, D]``,
     ``Hkv`` dividing ``H``): query head ``h`` reads key/value head
     ``h // (H / Hkv)``, the kernels address it through their index maps
@@ -815,7 +832,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         mask_mode = MASK_CAUSAL if causal else MASK_NONE
     out = _flash(_heads_first(q), _heads_first(k), _heads_first(v),
                  mask_mode, scale, block_q, block_k, interpret)
-    return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)
+    return out.reshape(B, H, S, v.shape[3]).transpose(0, 2, 1, 3)
 
 
 def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -839,5 +856,5 @@ def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array,
     out, lse = _flash_lse(_heads_first(q), _heads_first(k), _heads_first(v),
                           mask_mode, scale, block_q, block_k, interpret,
                           out_dtype)
-    return (out.reshape(B, H, S, D).transpose(0, 2, 1, 3),
+    return (out.reshape(B, H, S, v.shape[3]).transpose(0, 2, 1, 3),
             lse.reshape(B, H, S))

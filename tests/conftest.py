@@ -49,6 +49,30 @@ def hvd8():
     hvd.shutdown()
 
 
+@pytest.fixture(scope="session")
+def bench_job():
+    """``bench_job(name)``: the module ``benchmarks/jobs/<name>.py``, loaded
+    as the benchmark loads it (it finds ``harness`` by name)."""
+    import importlib.util
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+
+    def load(name):
+        sys.path.insert(0, bench)
+        try:
+            spec = importlib.util.spec_from_file_location(
+                "bench_jobs_" + name, os.path.join(bench, "jobs",
+                                                   name + ".py"))
+            module = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(module)
+        finally:
+            sys.path.remove(bench)
+        return module
+
+    return load
+
+
 @pytest.fixture(scope="session", autouse=True)
 def _lock_witness_session():
     """HVD_SANITIZE=1 runs the whole suite under the lock-witness

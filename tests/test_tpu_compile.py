@@ -165,6 +165,33 @@ def test_window_flash_compiles_for_v5e(one_chip, no_persistent_cache,
     assert sum(f"s32[6,{8 * steps}]" in line for line in kernels) == 1
 
 
+def test_latent_attention_flash_compiles_for_v5e(one_chip,
+                                                 no_persistent_cache):
+    """JoyAI-LLM-Flash's attention as its cell runs it: one sequence of
+    8,192 positions, 32 heads with keys of 192 (128 and the shared rotary
+    64) and values of 128, tiles of 512, forward and both backward kernels
+    under the causal mask: blocks one and a half lane widths wide."""
+    def sds(width):
+        return jax.ShapeDtypeStruct((1, 2 * SDAR_L, SDAR_H, width),
+                                    jnp.bfloat16, sharding=one_chip)
+
+    text = jax.jit(jax.grad(
+        lambda *a: flash_attention(
+            *a, causal=True, block_q=SDAR_TILE, block_k=SDAR_TILE,
+            interpret=False).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))).lower(
+            sds(192), sds(192), sds(SDAR_D)).compile().as_text()
+    kernels = [line for line in text.split("\n")
+               if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(kernels) == 3
+    wide, narrow = (f"bf16[{SDAR_H},{2 * SDAR_L},{width}]"
+                    for width in (192, SDAR_D))
+    # Forward: q, k in at 192, v in and the output at 128; dQ: dO in at 128,
+    # dQ out at 192; dK/dV: one of each out.
+    assert sorted((line.count(wide), line.count(narrow))
+                  for line in kernels) == [(2, 2), (3, 2), (3, 3)]
+
+
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
 def test_grouped_product_compiles_for_v5e(one_chip, no_persistent_cache,
                                           backward):

@@ -3,8 +3,9 @@
 
     python3 tools/flash_bundles.py [--against other/flash.py ...]
 
-Compiles ``flash_attention`` forward and backward at the two language-model
-cells' sizes under block diffusion, the causal window of 2,048,
+Compiles ``flash_attention`` forward and backward at the language-model
+cells' sizes (``--widths 192 128 --kv-heads 32``: latent attention's, keys
+wider than values) under block diffusion, the causal window of 2,048,
 ``MASK_CAUSAL`` and ``MASK_NONE`` for a *described* v5e (no chip:
 ``tests/test_tpu_compile.py`` has the method), has the TPU compiler write
 each kernel's final schedule (``--xla_jf_dump_llo_text``) and prints, for
@@ -57,14 +58,16 @@ def compile_in_child(path, mode_name):
     mode = sizes.mask_modes(flash)[mode_name]
     chip = SingleDeviceSharding(topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices[0])
-    q, k = (jax.ShapeDtypeStruct((1, sizes.SEQ, heads, sizes.HEAD_DIM),
-                                 jnp.bfloat16, sharding=chip)
-            for heads in (sizes.HEADS, sizes.KV_HEADS))
+    q, k, v = (jax.ShapeDtypeStruct((1, sizes.SEQ, heads, width),
+                                    jnp.bfloat16, sharding=chip)
+               for heads, width in ((sizes.HEADS, sizes.HEAD_DIM),
+                                    (sizes.KV_HEADS, sizes.HEAD_DIM),
+                                    (sizes.KV_HEADS, sizes.V_DIM)))
     jax.jit(jax.grad(
         lambda q, k, v: flash.flash_attention(
             q, k, v, mask_mode=mode, block_q=sizes.TILE, block_k=sizes.TILE,
             interpret=False).astype(jnp.float32).sum(),
-        argnums=(0, 1, 2))).lower(q, k, k).compile()
+        argnums=(0, 1, 2))).lower(q, k, v).compile()
 
 
 def regions(path):
@@ -90,7 +93,9 @@ def schedules_of(path, mode):
     wrote no schedule."""
     with tempfile.TemporaryDirectory() as dump:
         subprocess.run(
-            [sys.executable, __file__, "--child", path, mode],
+            [sys.executable, __file__, "--child", path, mode, "--widths",
+             str(sizes.HEAD_DIM), str(sizes.V_DIM), "--kv-heads",
+             str(sizes.KV_HEADS)],
             env=dict(os.environ, LIBTPU_INIT_ARGS=(
                 f"--xla_jf_dump_to={dump} --xla_jf_dump_llo_text=true"
                 " --xla_jf_dump_llo_pass_label_regex=final_bundles")),
@@ -113,7 +118,9 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--against", nargs="*", default=[])
     ap.add_argument("--child", nargs=2, help=argparse.SUPPRESS)
+    sizes.add_size_arguments(ap)
     args = ap.parse_args()
+    sizes.set_sizes(args)
     if args.child:
         return compile_in_child(*args.child)
     paths = [os.path.join(ROOT, "horovod_tpu", "parallel", "flash.py")] \

@@ -3,8 +3,10 @@
 
     python3 tools/flash_tile_times.py [--masks NAME ...] [--against other/flash.py ...]
 
-One sequence at the two language-model cells' sizes (8,192 positions, 32
-query heads on 4 key/value heads of 128, tiles of 512) through
+One sequence at the language-model cells' sizes (8,192 positions, 32
+query heads on 4 key/value heads of 128, tiles of 512; ``--widths 192 128
+--kv-heads 32`` for latent attention's, whose keys are wider than its
+values) through
 ``flash_attention`` forward and backward under block diffusion,
 ``MASK_CAUSAL``, ``MASK_STRICT``, ``MASK_NONE`` and the causal window of
 2,048 (``--masks``: any of ``block_diffusion``, ``causal``, ``strict``,
@@ -30,6 +32,21 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
 
 SEQ, HEADS, KV_HEADS, HEAD_DIM, TILE, CALLS = 8192, 32, 4, 128, 512, 10
+V_DIM = HEAD_DIM  # the values' width; the queries' and keys' is HEAD_DIM
+
+
+def add_size_arguments(ap):
+    ap.add_argument("--widths", nargs=2, type=int, metavar=("QK", "V"),
+                    default=[HEAD_DIM, V_DIM],
+                    help="head size of queries and keys, and of values "
+                    "(latent attention: 192 128)")
+    ap.add_argument("--kv-heads", type=int, default=KV_HEADS,
+                    help="key/value heads (latent attention: 32)")
+
+
+def set_sizes(args):
+    global HEAD_DIM, V_DIM, KV_HEADS
+    (HEAD_DIM, V_DIM), KV_HEADS = args.widths, args.kv_heads
 
 
 def load(path):
@@ -82,13 +99,15 @@ def main():
     ap.add_argument("--against", nargs="*", default=[])
     ap.add_argument("--masks", nargs="*", default=[
         "block_diffusion", "causal", "strict", "none", "window"])
+    add_size_arguments(ap)
     args = ap.parse_args()
+    set_sizes(args)
     paths = [os.path.join(ROOT, "horovod_tpu", "parallel", "flash.py")] \
         + args.against
     rng = np.random.RandomState(0)
-    q, k, v, weight = (jnp.asarray(rng.randn(1, SEQ, h, HEAD_DIM),
-                                   jnp.bfloat16)
-                       for h in (HEADS, KV_HEADS, KV_HEADS, HEADS))
+    q, k, v, weight = (jnp.asarray(rng.randn(1, SEQ, h, d), jnp.bfloat16)
+                       for h, d in ((HEADS, HEAD_DIM), (KV_HEADS, HEAD_DIM),
+                                    (KV_HEADS, V_DIM), (HEADS, V_DIM)))
     print("device", jax.devices()[0].device_kind, flush=True)
     for mode_name in args.masks:
         mine = None
