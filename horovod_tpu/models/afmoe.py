@@ -64,6 +64,7 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..utils import get_logger
 from .sdar_moe import Aux, head_loss, rms_norm, rotary, through_layers
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -73,11 +74,13 @@ SLIDING, FULL = "sliding_attention", "full_attention"
 #: The rule: keep the type whose rerun costs more per byte saved, and as
 #: many types as the step has room for.  A full layer's output spares twice
 #: the tiles a window layer's does (136 against 70 a head at 8,192
-#: positions); with both kept the training step of one chip's share of an
-#: eight-way split misses a v5e's memory by 220 MB (ROADMAP S13: both, once
-#: the bf16 copies of the stacked expert weights are no longer made for all
-#: layers ahead of the scan).
-KEPT_ATTENTION = (FULL,)
+#: positions).  Both fit: with the five layers' kept at four sequences
+#: (1.3 GB) the training step of one chip's share of an eight-way split
+#: takes 14.77 GiB of a v5e's 15.75 by the compiler's report, since the
+#: grouped products read the float32 expert matrices themselves and the
+#: expert half keeps only its products' operands and results (PR 34;
+#: before, 15.96 with both and 14.74 with the full layer's alone).
+KEPT_ATTENTION = (SLIDING, FULL)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -200,7 +203,12 @@ def _layer(cfg: AfmoeConfig, dense: bool, window: bool, positions):
     aux)``, under its ``jax.checkpoint``."""
     from ..parallel.flash import SAVED
     mlp = _dense_half if dense else _expert_half
-    keep = (SLIDING if window else FULL) in KEPT_ATTENTION
+    kind = SLIDING if window else FULL
+    keep = kind in KEPT_ATTENTION
+    get_logger().info(
+        "afmoe: a run of %s layers over %s %s its flash output across the "
+        "recomputation", "dense" if dense else "expert", kind,
+        "keeps" if keep else "computes again")
     return jax.checkpoint(
         lambda x, p: mlp(cfg, _attention_half(cfg, window, positions, x, p),
                          p),

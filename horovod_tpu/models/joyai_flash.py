@@ -80,15 +80,19 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from ..utils import get_logger
 from .afmoe import gated_mlp
 from .sdar_moe import Aux, head_loss, rms_norm, through_layers
 
 #: Whether a layer's flash output and logsumexp are kept across the
 #: recomputation of its (layer, sequence): 68 MB each at the published
 #: sizes, 1.6 GB over the six attention layers of one chip's share at four
-#: sequences, which the step has no room for beside 10.9 GB of state and
-#: gradient.  The forward kernel runs again in the backward pass.
-KEEP_ATTENTION = False
+#: sequences, beside 10.9 GB of state and gradient.  They fit: 14.54 GiB of
+#: a v5e's 15.75 by the compiler's report, since the grouped products read
+#: the float32 expert matrices themselves and the expert half keeps only
+#: its products' operands and results (PR 34; before, 15.51 with them and
+#: 14.25 without).  Off, the forward kernel runs again in the backward pass.
+KEEP_ATTENTION = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -208,6 +212,10 @@ def _layer(cfg: JoyaiFlashConfig, dense: bool, positions):
     aux)``, under its ``jax.checkpoint``."""
     from ..parallel.flash import SAVED
     mlp = _dense_half if dense else _expert_half
+    get_logger().info(
+        "joyai_flash: a run of %s layers %s its flash output across the "
+        "recomputation", "dense" if dense else "expert",
+        "keeps" if KEEP_ATTENTION else "computes again")
     return jax.checkpoint(
         lambda x, p: mlp(cfg, _attention_half(cfg, positions, x, p), p),
         policy=jax.checkpoint_policies.save_only_these_names(*SAVED)

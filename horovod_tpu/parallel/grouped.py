@@ -15,9 +15,25 @@ their output is left unwritten.  Callers read only rows inside a group.
   ``tgmm`` (``lhs[rows of g]^T @ dout[rows of g]`` for every group) for
   ``rhs``.
 
-Products run in the operands' dtype with float32 accumulation.  Unlike
-megablox the calls carry their varying-axes type, so they run inside
-``shard_map(check_vma=True)``, which ``hvd.shard_step`` and
+Dtypes: ``rhs`` comes as it is stored (a model's float32 parameters) and
+is never converted outside the kernel.  The products run in ``lhs``'s
+dtype with float32 accumulation: a block of ``rhs`` is cast to it in VMEM,
+the rounding ``rhs.astype(lhs.dtype)`` would make.  ``out`` and the
+gradient of ``lhs`` come in ``lhs``'s dtype, the gradient of ``rhs`` in
+``rhs``'s, straight from ``tgmm``'s float32 sums.
+
+One fetch a group: where a group's whole ``(k, tn)`` slab of ``rhs`` is at
+most ``SLAB_BYTES``, it is the block, ``tn`` as wide as that allows.  Its
+index then follows the step's group alone, consecutive visits of a group
+reuse the copy Pallas holds, and the cast is made once a group into a
+scratch buffer.  A wider slab is walked in tiles of the contraction,
+fetched and cast again at every visit.  The choice follows ``k``, ``n`` and
+``rhs``'s dtype; ``fetches`` counts what it comes to.  ``tgmm``'s block of
+a group's gradient follows the same rule, and a float32 one is summed in
+place.
+
+Unlike megablox the calls carry their varying-axes type, so they run
+inside ``shard_map(check_vma=True)``, which ``hvd.shard_step`` and
 ``DistributedOptimizer`` rely on.
 """
 
@@ -33,9 +49,18 @@ from jax.experimental.pallas import tpu as pltpu
 from .flash import _out_struct, vary_like
 
 #: Row, contraction and column tile: targets, cut to a divisor of the
-#: dimension.  512 rows of 1,024 bf16 and a 1,024 x 1,024 block of weights,
-#: double-buffered, with a float32 accumulator: about 9 MiB of VMEM.
+#: dimension.  The contraction's is for a slab beyond ``SLAB_BYTES`` only.
 TILES = (512, 1024, 1024)
+
+#: The largest ``(k, tn)`` slab of one group's matrix, in bytes of ``rhs``'s
+#: dtype, that ``gmm`` takes as one block: 2,048 x 1,024 float32.
+SLAB_BYTES = 8 * 2 ** 20
+
+#: What a call may use of VMEM (a v5e core has 128 MiB, the compiler's
+#: default is 16): a float32 slab double-buffered (16 MiB) and its cast
+#: copy (4), 512 rows of 2,048 bf16 in and out, double-buffered (8), the
+#: product in float32 (4), and 24 for the compiler's own.
+VMEM_LIMIT_BYTES = 56 * 2 ** 20
 
 
 def _tile(dim: int, target: int) -> int:
@@ -55,19 +80,21 @@ def row_tile(rows: int) -> int:
     return min(TILES[0], -(-rows // 8) * 8)
 
 
-def group_tiles(group_sizes, m: int, tm: int):
+def group_tiles(group_sizes, m: int, tm: int, visit_empty: bool = False):
     """``(starts, ends, group_ids, tile_ids, count)``: the first and one
     past the last row of every group, and for each step of the sequential
     grid dimension the group and the row tile it works on.  A tile that
     holds rows of two groups is visited once for each, one after the
-    other; an empty group is not visited.  ``count`` is the number of
+    other; an empty group is not visited, or with ``visit_empty`` once, on
+    a tile none of whose rows are its own.  ``count`` is the number of
     steps in use, at most ``m // tm + G - 1``."""
     num_groups = group_sizes.shape[0]
     group_sizes = group_sizes.astype(jnp.int32)
     ends = jnp.cumsum(group_sizes)
     starts = ends - group_sizes
     first = starts // tm
-    tiles = jnp.where(group_sizes > 0, (ends - 1) // tm - first + 1, 0)
+    tiles = jnp.where(group_sizes > 0, (ends - 1) // tm - first + 1,
+                      int(visit_empty))
     steps = m // tm + num_groups - 1
     group_ids = jnp.repeat(jnp.arange(num_groups, dtype=jnp.int32), tiles,
                            total_repeat_length=steps)
@@ -87,17 +114,48 @@ def _rows_of_group(starts, ends, group_ids, tile_ids, step, tm, width):
     return jnp.logical_and(rows >= starts[group], rows < ends[group])
 
 
-def _params(interpret):
+def _params(interpret, *semantics):
     if interpret:
         return None
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary", "arbitrary"))
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def _gmm_tiles(m: int, k: int, n: int, rhs_dtype):
+    """``(tm, tk, tn)`` of a product of ``[m, k]`` rows with ``[k, n]``
+    matrices of ``rhs_dtype``: the column tile as wide as a slab of at
+    most ``SLAB_BYTES`` allows, and ``tk == k``, the group's slab whole,
+    where the slab is no larger."""
+    size = jnp.dtype(rhs_dtype).itemsize
+    tn = _tile(n, max(TILES[2], SLAB_BYTES // (k * size)))
+    return (row_tile(m),
+            k if k * tn * size <= SLAB_BYTES else _tile(k, TILES[1]), tn)
+
+
+def fetches(group_sizes, m: int, k: int, n: int, rhs_dtype):
+    """``(visits, fetches)`` of one ``gmm`` call at these sizes: the grid
+    steps along its sequential dimension (a row tile once for every group
+    with rows in it) and the blocks of ``rhs`` it copies into VMEM.  A
+    slab is fetched once for every group with rows and column tile, ``n //
+    tn`` times a group; a matrix walked in tiles ``k // tk`` times a visit
+    and column tile.  A count: the same on any backend."""
+    tm, tk, tn = _gmm_tiles(m, k, n, rhs_dtype)
+    group_sizes = jnp.asarray(group_sizes)
+    visits = int(group_tiles(group_sizes, m, tm)[-1])
+    groups = int((group_sizes > 0).sum())
+    return visits, n // tn * (groups if tk == k else visits * (k // tk))
+
+
+def _new_group(group_ids, step):
+    """Whether the step's group is another than the step's before."""
+    return jnp.logical_or(
+        step == 0, group_ids[jnp.maximum(step - 1, 0)] != group_ids[step])
 
 
 def _gmm_call(lhs, rhs, group_sizes, transpose_rhs, interpret):
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    tm, tk, tn = row_tile(m), _tile(k, TILES[1]), _tile(n, TILES[2])
+    tm, tk, tn = _gmm_tiles(m, k, n, rhs.dtype)
     if m % tm:
         raise ValueError(f"gmm: {m} rows are no multiple of the row tile "
                          f"{tm} (pad to row_tile(rows))")
@@ -105,28 +163,51 @@ def _gmm_call(lhs, rhs, group_sizes, transpose_rhs, interpret):
     tiles_k = k // tk
     contract = (((1,), (1,)), ((), ())) if transpose_rhs else \
         (((1,), (0,)), ((), ()))
+    rhs_block = (tn, tk) if transpose_rhs else (tk, tn)
+    # The slab's cast copy lives from a group's first visit to its last;
+    # a tile of the contraction is cast as it is used.
+    cast_once = tiles_k == 1 and rhs.dtype != lhs.dtype
+    scratch = ([pltpu.VMEM(rhs_block, lhs.dtype)] if cast_once else []) + \
+        ([pltpu.VMEM((tm, tn), jnp.float32)] if tiles_k > 1 else [])
 
     def kernel(starts, ends, group_ids, tile_ids, lhs_ref, rhs_ref, out_ref,
-               acc):
+               *scratch):
         step, k_i = pl.program_id(1), pl.program_id(2)
 
-        @pl.when(k_i == 0)
-        def _init():
-            acc[...] = jnp.zeros_like(acc)
+        if cast_once:
+            @pl.when(_new_group(group_ids, step))
+            def _cast():
+                scratch[0][...] = rhs_ref[...].astype(lhs_ref.dtype)
 
-        acc[...] += jax.lax.dot_general(
-            lhs_ref[...], rhs_ref[...], contract,
-            preferred_element_type=jnp.float32)
+            matrix = scratch[0][...]
+        else:
+            matrix = rhs_ref[...].astype(lhs_ref.dtype)
+        part = jax.lax.dot_general(lhs_ref[...], matrix, contract,
+                                   preferred_element_type=jnp.float32)
+
+        def among(total):
+            """``total`` on the group's rows, the block as it is elsewhere."""
+            mine = _rows_of_group(starts, ends, group_ids, tile_ids, step,
+                                  tm, tn)
+            return jnp.where(mine, total, out_ref[...].astype(jnp.float32)
+                             ).astype(out_ref.dtype)
+
+        if tiles_k == 1:
+            out_ref[...] = among(part)
+            return
+        acc = scratch[-1]
+
+        @pl.when(k_i == 0)
+        def _first():
+            acc[...] = part
+
+        @pl.when(k_i > 0)
+        def _add():
+            acc[...] += part
 
         @pl.when(k_i == tiles_k - 1)
         def _store():
-            mine = _rows_of_group(starts, ends, group_ids, tile_ids, step,
-                                  tm, tn)
-            out_ref[...] = jnp.where(
-                mine, acc[...], out_ref[...].astype(jnp.float32)
-            ).astype(out_ref.dtype)
-
-    rhs_block = (None, tn, tk) if transpose_rhs else (None, tk, tn)
+            out_ref[...] = among(acc[...])
 
     def rhs_map(n_i, step, k_i, starts, ends, group_ids, tile_ids):
         return (group_ids[step],) + ((n_i, k_i) if transpose_rhs
@@ -141,38 +222,39 @@ def _gmm_call(lhs, rhs, group_sizes, transpose_rhs, interpret):
             in_specs=[
                 pl.BlockSpec((tm, tk), lambda n_i, step, k_i, starts, ends,
                              group_ids, tile_ids: (tile_ids[step], k_i)),
-                pl.BlockSpec(rhs_block, rhs_map),
+                pl.BlockSpec((None,) + rhs_block, rhs_map),
             ],
             out_specs=pl.BlockSpec(
                 (tm, tn), lambda n_i, step, k_i, starts, ends, group_ids,
                 tile_ids: (tile_ids[step], n_i)),
-            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
-        compiler_params=_params(interpret),
+            scratch_shapes=scratch),
+        compiler_params=_params(interpret, "parallel", "arbitrary",
+                                "arbitrary"),
         interpret=interpret,
         name="hvd_gmm",
     )(*(vary_like(x, lhs) for x in meta), lhs, rhs)
 
 
-def _tgmm_call(lhs, dout, group_sizes, interpret):
+def _tgmm_call(lhs, dout, group_sizes, dtype, interpret):
     """``out[g] = lhs[rows of g]^T @ dout[rows of g]``: ``[G, k, n]`` in
-    ``lhs``'s dtype; an empty group's matrix is zero."""
+    ``dtype`` from float32 sums.  An empty group is visited once, with no
+    row of its own, so that its matrix is written too, as zeros.  The
+    block of ``out`` follows ``gmm``'s rule for a matrix of ``dtype``, a
+    group's slab whole where that fits; a float32 block is summed in
+    place."""
     m, k = lhs.shape
     n = dout.shape[1]
     num_groups = group_sizes.shape[0]
-    tm, tk, tn = row_tile(m), _tile(k, TILES[1]), _tile(n, TILES[2])
-    *meta, count = group_tiles(group_sizes, m, tm)
+    tm, tk, tn = _gmm_tiles(m, k, n, dtype)
+    *meta, count = group_tiles(group_sizes, m, tm, visit_empty=True)
+    in_place = jnp.dtype(dtype) == jnp.float32
 
     def kernel(starts, ends, group_ids, tile_ids, steps, lhs_ref, dout_ref,
-               out_ref, acc):
+               out_ref, *scratch):
+        acc = out_ref if in_place else scratch[0]
         step = pl.program_id(2)
-        group = group_ids[step]
-        first = jnp.logical_or(
-            step == 0, group_ids[jnp.maximum(step - 1, 0)] != group)
-        last = jnp.logical_or(
-            step == steps[0] - 1,
-            group_ids[jnp.minimum(step + 1, steps[0] - 1)] != group)
 
-        @pl.when(first)
+        @pl.when(_new_group(group_ids, step))
         def _init():
             acc[...] = jnp.zeros_like(acc)
 
@@ -188,13 +270,19 @@ def _tgmm_call(lhs, dout, group_sizes, interpret):
             mine(lhs_ref), mine(dout_ref), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-        @pl.when(last)
-        def _store():
-            out_ref[...] = acc[...].astype(out_ref.dtype)
+        if not in_place:
+            last = jnp.logical_or(
+                step == steps[0] - 1,
+                group_ids[jnp.minimum(step + 1, steps[0] - 1)]
+                != group_ids[step])
 
-    out = pl.pallas_call(
+            @pl.when(last)
+            def _store():
+                out_ref[...] = acc[...].astype(out_ref.dtype)
+
+    return pl.pallas_call(
         kernel,
-        out_shape=_out_struct((num_groups, k, n), lhs.dtype, lhs),
+        out_shape=_out_struct((num_groups, k, n), dtype, lhs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(k // tk, n // tn, count),
@@ -207,14 +295,13 @@ def _tgmm_call(lhs, dout, group_sizes, interpret):
             out_specs=pl.BlockSpec(
                 (None, tk, tn), lambda k_i, n_i, step, starts, ends,
                 group_ids, tile_ids, steps: (group_ids[step], k_i, n_i)),
-            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            scratch_shapes=[] if in_place else
+            [pltpu.VMEM((tk, tn), jnp.float32)]),
+        compiler_params=_params(interpret, "parallel", "parallel",
+                                "arbitrary"),
         interpret=interpret,
         name="hvd_tgmm",
     )(*(vary_like(x, lhs) for x in meta + [count[None]]), lhs, dout)
-    return jnp.where((group_sizes > 0)[:, None, None], out,
-                     jnp.zeros_like(out))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -230,7 +317,7 @@ def _gmm_fwd(lhs, rhs, group_sizes, transpose_rhs, interpret):
 def _gmm_bwd(transpose_rhs, interpret, res, dout):
     lhs, rhs, group_sizes = res
     dlhs = _gmm_call(dout, rhs, group_sizes, not transpose_rhs, interpret)
-    drhs = _tgmm_call(lhs, dout, group_sizes, interpret)
+    drhs = _tgmm_call(lhs, dout, group_sizes, rhs.dtype, interpret)
     return dlhs, (drhs.swapaxes(1, 2) if transpose_rhs else drhs), None
 
 
@@ -243,10 +330,11 @@ def gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
     ``transpose_rhs``) for every group ``g``; differentiable in ``lhs``
     and ``rhs``.  ``lhs`` is ``[m, k]`` with ``m`` a multiple of
     ``row_tile(m)``, sorted by group; ``sum(group_sizes) <= m``, and rows
-    beyond it are neither read nor written."""
+    beyond it are neither read nor written.  ``rhs`` is taken in the dtype
+    it is stored in and cast to ``lhs``'s inside the kernel: the result and
+    ``lhs``'s gradient have ``lhs``'s dtype, ``rhs``'s gradient ``rhs``'s
+    (the module's text has the rule of the slab)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if lhs.dtype != rhs.dtype:
-        raise ValueError(f"gmm: lhs is {lhs.dtype}, rhs is {rhs.dtype}")
     return _gmm(lhs, vary_like(rhs, lhs), vary_like(group_sizes, lhs),
                 transpose_rhs, interpret)

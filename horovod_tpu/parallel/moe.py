@@ -38,6 +38,7 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 
 class MoEOutput(NamedTuple):
@@ -293,6 +294,12 @@ def _slots(key, key_bound: int, num_slots: int, slot_of_rank, rank_of_slot):
             sorted_key)
 
 
+#: ``jax.checkpoint`` name of what ``_held_experts`` needs again in its
+#: backward pass and cannot make from its inputs for an elementwise pass:
+#: the sorted buffer, the three products' results, the slots and sizes.
+KEPT = "hvd_moe_kept"
+
+
 def _held_experts(x, expert, weights, w_gate, w_up, w_down, rows: int,
                   interpret):
     """``sum_j weights[t, j] * FFN_{expert[t, j]}(x[t])`` over the pairs
@@ -300,6 +307,7 @@ def _held_experts(x, expert, weights, w_gate, w_up, w_down, rows: int,
     here), in a buffer of ``rows`` rows sorted by expert, which the caller
     has shown to be enough.  Gated SiLU experts as grouped products."""
     from .grouped import gmm
+    keep = functools.partial(checkpoint_name, name=KEPT)
     held = w_gate.shape[0]
     n, k = expert.shape
     flat = expert.reshape(-1).astype(jnp.int32)
@@ -310,16 +318,17 @@ def _held_experts(x, expert, weights, w_gate, w_up, w_down, rows: int,
         lambda rank, key: jnp.where(key < held, rank, rows),
         lambda slot, key: (slot, slot < here))
     ends = _count_below(sorted_expert, range(1, held + 1))
-    group_sizes = ends - jnp.concatenate([jnp.zeros(1, jnp.int32),
-                                          ends[:-1]])
-    slot_of_pair = slot_of_pair.reshape(n, k)
+    group_sizes = keep(ends - jnp.concatenate([jnp.zeros(1, jnp.int32),
+                                               ends[:-1]]))
+    slot_of_pair, pair_of_slot, in_use = (
+        keep(slot_of_pair.reshape(n, k)), keep(pair_of_slot), keep(in_use))
     with jax.named_scope("hvd::moe::experts"):
-        xs = _dispatch(x, slot_of_pair, pair_of_slot)
-        gate = gmm(xs, w_gate, group_sizes, interpret=interpret)
-        up = gmm(xs, w_up, group_sizes, interpret=interpret)
+        xs = keep(_dispatch(x, slot_of_pair, pair_of_slot))
+        gate = keep(gmm(xs, w_gate, group_sizes, interpret=interpret))
+        up = keep(gmm(xs, w_up, group_sizes, interpret=interpret))
         hidden = (jax.nn.silu(gate.astype(jnp.float32))
                   * up.astype(jnp.float32)).astype(xs.dtype)
-        ys = gmm(hidden, w_down, group_sizes, interpret=interpret)
+        ys = keep(gmm(hidden, w_down, group_sizes, interpret=interpret))
     with jax.named_scope("hvd::moe::combine"):
         return _combine(ys, weights, slot_of_pair, pair_of_slot, in_use)
 
@@ -332,8 +341,8 @@ def _held_experts(x, expert, weights, w_gate, w_up, w_down, rows: int,
 #: 0.95 to 1.1 even loads here (PERF.md, PR 27), a buffer for the worst
 #: routing, 8 even loads, would add some 15 % to that cell's step (those
 #: passes take 50 ms of its second at 2 even loads), and more than this
-#: goes a chunk at a time (29.8 ms a layer and sequence at 3.3 even loads,
-#: 12.3 at 1.35: ``chip_smoke.py``, phase ``sdar``).
+#: goes a chunk at a time (31.6 ms a layer and sequence at 3.3 even loads,
+#: 10.4 at 1.35: ``chip_smoke.py``, phase ``sdar``).
 BUFFER_LOADS = 2
 
 
@@ -369,7 +378,12 @@ def _held_experts_any_load(x, expert, weights, w_gate, w_up, w_down,
                        chunks(weights, 0)))
         return out.reshape(n + pad, x.shape[1])[:n]
 
-    return lax.cond(jnp.sum(expert < held) <= rows, fits, by_chunks,
+    # The two branches hand the backward pass the union of what each
+    # keeps, the other's part as zeros: keep the products' operands and
+    # results alone (``KEPT``), not every float32 value of the gated unit.
+    kept = jax.checkpoint(
+        fits, policy=jax.checkpoint_policies.save_only_these_names(KEPT))
+    return lax.cond(jnp.sum(expert < held) <= rows, kept, by_chunks,
                     x, expert, weights)
 
 
@@ -393,7 +407,11 @@ def dropless_expert_ffn(x: jax.Array,
       x:       [T, d]  tokens (bf16 or float32; the products run in it)
       router:  [d, E]  the router over ALL ``E`` experts
       w_gate, w_up:    [E_held, d, f]   the held experts' gated SiLU
-      w_down:          [E_held, f, d]   and down projections
+      w_down:          [E_held, f, d]   and down projections, in the dtype
+                       they are stored in (float32 parameters beside bf16
+                       tokens): ``grouped.gmm`` rounds a matrix to ``x``'s
+                       dtype inside its kernel, and their gradients come
+                       in their own dtype from float32 sums
 
     Each token takes its ``top_k`` experts by softmax probability, the
     chosen probabilities divided by their sum (``norm_topk_prob``).  The
@@ -431,7 +449,9 @@ def dropless_expert_ffn(x: jax.Array,
         raise ValueError(
             f"router over {num_experts} experts, but {shards} shards hold "
             f"{held} each")
-    experts = tuple(w.astype(x.dtype) for w in (w_gate, w_up, w_down))
+    # As they are stored: the grouped products cast a group's matrix in
+    # VMEM, so no copy of the stack in ``x``'s dtype is ever made.
+    experts = (w_gate, w_up, w_down)
 
     with jax.named_scope("hvd::moe::route"):
         logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),

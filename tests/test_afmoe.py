@@ -287,13 +287,14 @@ def test_keeping_the_flash_output_or_not_changes_nothing(job, small,
         return jax.value_and_grad(
             lambda p: afmoe.loss_fn(p, *batch, cfg), has_aux=True)(params)
 
-    assert afmoe.KEPT_ATTENTION == (F,)
-    (loss, _), grads = step((F,))
-    (got, _), got_grads = step((S,))
-    assert float(got) == pytest.approx(float(loss), rel=1e-6)
-    for name, leaf in leaves(got_grads).items():
-        np.testing.assert_allclose(leaf, leaves(grads)[name], rtol=1e-4,
-                                   atol=1e-7, err_msg=name)
+    assert afmoe.KEPT_ATTENTION == (S, F)
+    (loss, _), grads = step((S, F))
+    for kept in [(F,), (S,)]:
+        (got, _), got_grads = step(kept)
+        assert float(got) == pytest.approx(float(loss), rel=1e-6)
+        for name, leaf in leaves(got_grads).items():
+            np.testing.assert_allclose(leaf, leaves(grads)[name], rtol=1e-4,
+                                       atol=1e-7, err_msg=f"{kept} {name}")
 
 
 def published_config():

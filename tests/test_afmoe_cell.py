@@ -10,6 +10,7 @@ import math
 import os
 import subprocess
 import sys
+import types
 
 import jax
 import jax.numpy as jnp
@@ -146,7 +147,6 @@ def test_a_gradient_outside_its_limit_fails_the_loss_comparison(
     "test_cell_traced_reports_counts_but_no_device_metric",
     "test_control_in_a_lower_precision_comes_out_not_correct",
     "test_operations_count_the_pairs_the_masks_keep",
-    "test_forward_kernel_time_over_the_tile_runs_of_a_mixed_stack",
     "test_every_new_reader_has_its_file_and_its_entry"])
 def test_rehearsal_cell_through_the_train_runner(test):
     """``benchmarks/tests/test_trinity_cell.py`` (the rehearsal cell of
@@ -161,3 +161,45 @@ def test_rehearsal_cell_through_the_train_runner(test):
         cwd=ROOT, env=dict(env, JAX_PLATFORMS="cpu"), capture_output=True,
         text=True, timeout=900)
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-2000:]
+
+
+@pytest.mark.parametrize("kept,tile_runs", [
+    ((afmoe.SLIDING, afmoe.FULL), 53_248), ((afmoe.FULL,), 89_088)])
+def test_forward_kernel_time_over_the_tile_runs_the_program_keeps(
+        monkeypatch, kept, tile_runs):
+    """``mixed_flash_fwd_tile_us.train`` on a hand-built trace under the
+    cell's own files, as ``benchmarks/tests/test_trinity_cell.py::
+    test_forward_kernel_time_over_the_tile_runs_of_a_mixed_stack`` builds
+    it (that case pins ``KEPT_ATTENTION == (FULL,)``): a layer's forward
+    kernel is counted twice where its type is not kept across the
+    recomputation, so the reader follows the program's constant."""
+    monkeypatch.syspath_prepend(BENCH)
+    from harness import manifest as mf
+    from harness import scope_times
+    monkeypatch.setattr(afmoe, "KEPT_ATTENTION", kept)
+    with open(os.path.join(BENCH, "configs", "trinity-mini-ep8.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(BENCH, "workloads",
+                           "trinity-mini-train-8k.json")) as f:
+        cell = json.load(f)
+    step = "jit(local_step)/shard_map/decoder/hvd::window_attention/"
+    names = {"custom-call.7": step + "hvd_flash_fwd/pallas_call",
+             "custom-call.9": step + "hvd_flash_bwd_dq/pallas_call"}
+    codes = dict.fromkeys(names, "custom-call")
+    event = "%{0} = f32[8]{{0}} custom-call(%x)".format
+    devices = {"/device:TPU:0": {
+        "ops": [(event("custom-call.7"), 0, 150_000),
+                (event("custom-call.9"), 100, 70_000),
+                (event("custom-call.7"), 200, 90_000)],
+        "modules": [("jit_local_step(5)", 0, 1000)] * 2}}
+    table = scope_times.reduce(devices, names, codes, scope_times.KERNELS)
+    read = mf.load_module("layer_metrics",
+                          "mixed_flash_fwd_tile_us.train").read
+    run = types.SimpleNamespace(scopes={"scope_times": table},
+                                config=config, cell=cell)
+    # 4 sequences x 32 heads x (4 window layers x 70 tiles + the full
+    # layer's 136), a type's tiles twice where it is not kept.
+    window, full = (2 - (kind in kept) for kind in (afmoe.SLIDING,
+                                                    afmoe.FULL))
+    assert 4 * 32 * (4 * 70 * window + 136 * full) == tile_runs
+    assert read(run) == pytest.approx(240_000 / 1e3 / (2 * tile_runs))

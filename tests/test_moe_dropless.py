@@ -221,6 +221,128 @@ def test_grouped_product_and_its_gradients(sizes):
                                rtol=1e-5, atol=1e-5)
 
 
+def _by_group(sizes, lhs, rhs):
+    """``lhs[rows of g] @ rhs[g]`` group by group in float32 numpy; rows
+    beyond the last group zero."""
+    lhs, rhs = np.asarray(lhs, np.float32), np.asarray(rhs, np.float32)
+    out, ends = np.zeros((lhs.shape[0], rhs.shape[2]), np.float32), \
+        np.cumsum(sizes)
+    for g, size in enumerate(sizes):
+        rows = slice(ends[g] - size, ends[g])
+        out[rows] = lhs[rows] @ rhs[g]
+    return out
+
+
+@pytest.mark.parametrize("sizes", [[5, 3, 0, 8], [10, 20, 30, 4],
+                                   [0, 1, 0, 40]])
+def test_grouped_product_of_bf16_rows_with_stored_float32_matrices(sizes):
+    """The dtype contract: ``rhs`` as it is stored, rounded to ``lhs``'s
+    dtype inside the kernel; the result and ``dlhs`` in ``lhs``'s dtype,
+    ``drhs`` in ``rhs``'s, from float32 sums that no bf16 rounds."""
+    rng = np.random.RandomState(9)
+    m, k, n = 64, 16, 24
+    bf16 = jnp.bfloat16
+    lhs = jnp.asarray(rng.randn(m, k), bf16)
+    rhs = jnp.asarray(rng.randn(len(sizes), k, n), jnp.float32)
+    total = int(np.sum(sizes))
+    seed = jnp.asarray(rng.randn(m, n) * (np.arange(m) < total)[:, None],
+                       bf16)
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+
+    got = grouped.gmm(lhs, rhs, group_sizes)
+    rounded = grouped.gmm(lhs, rhs.astype(bf16), group_sizes)
+    assert got.dtype == bf16
+    np.testing.assert_array_equal(np.asarray(got[:total], np.float32),
+                                  np.asarray(rounded[:total], np.float32))
+    np.testing.assert_allclose(
+        np.asarray(got[:total], np.float32),
+        _by_group(sizes, lhs, rhs.astype(bf16))[:total], rtol=1e-2,
+        atol=1e-2)
+
+    def loss(l, r):
+        return (grouped.gmm(l, r, group_sizes).astype(jnp.float32)
+                * seed.astype(jnp.float32)).sum()
+
+    dlhs, drhs = jax.grad(loss, (0, 1))(lhs, rhs)
+    assert dlhs.dtype == bf16 and drhs.dtype == jnp.float32
+    # drhs[g] = lhs[rows of g]^T @ dout[rows of g]: exact sums of the bf16
+    # inputs' products, which a result rounded to bf16 (4e-3) is not.
+    rows, dout = np.asarray(lhs, np.float32), np.asarray(seed, np.float32)
+    want = np.stack([rows[end - size:end].T @ dout[end - size:end]
+                     for size, end in zip(sizes, np.cumsum(sizes))])
+    np.testing.assert_allclose(drhs, want, rtol=1e-5, atol=1e-5)
+    want_dlhs = _by_group(sizes, seed,
+                          np.asarray(rhs.astype(bf16),
+                                     np.float32).swapaxes(1, 2))
+    np.testing.assert_allclose(np.asarray(dlhs[:total], np.float32),
+                               want_dlhs[:total], rtol=1e-2, atol=2e-2)
+    # A caller that stores bf16 matrices gets bf16 gradients, as before.
+    assert jax.grad(loss, 1)(lhs, rhs.astype(bf16)).dtype == bf16
+
+
+@pytest.mark.parametrize("rows_dtype", ["float32", "bfloat16"])
+def test_a_slab_beyond_the_budget_is_walked_in_tiles(rows_dtype):
+    """``k x tn`` float32 beyond ``SLAB_BYTES``: the contraction goes in
+    tiles of 1,024, each fetched and cast at every visit; the transposed
+    product of the gradient (``k`` and ``n`` swapped) fits a slab."""
+    m, k, n, sizes = 16, 4096, 1024, [5, 9]
+    assert k * n * 4 > grouped.SLAB_BYTES
+    assert grouped._gmm_tiles(m, k, n, jnp.float32) == (16, 1024, 1024)
+    assert grouped._gmm_tiles(m, n, k, jnp.float32) == (16, 1024, 2048)
+    assert grouped._gmm_tiles(m, 2048, n, jnp.float32) == (16, 2048, 1024)
+    rng = np.random.RandomState(10)
+    lhs = jnp.asarray(rng.randn(m, k), rows_dtype)
+    rhs = jnp.asarray(rng.randn(2, k, n) / k ** 0.5, jnp.float32)
+    seed = jnp.asarray(rng.randn(m, n) * (np.arange(m) < 14)[:, None],
+                       rows_dtype)
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    exact = rows_dtype == "float32"
+    tol = dict(rtol=1e-4, atol=1e-4) if exact else dict(rtol=2e-2, atol=2e-2)
+    matrices = rhs if exact else rhs.astype(rows_dtype)
+
+    def loss(l, r):
+        return (grouped.gmm(l, r, group_sizes).astype(jnp.float32)
+                * seed.astype(jnp.float32)).sum()
+
+    got = grouped.gmm(lhs, rhs, group_sizes)
+    np.testing.assert_allclose(np.asarray(got[:14], np.float32),
+                               _by_group(sizes, lhs, matrices)[:14], **tol)
+    dlhs, drhs = jax.grad(loss, (0, 1))(lhs, rhs)
+    np.testing.assert_allclose(
+        np.asarray(dlhs[:14], np.float32),
+        _by_group(sizes, seed, np.asarray(matrices, np.float32)
+                  .swapaxes(1, 2))[:14], **tol)
+    rows, dout = np.asarray(lhs, np.float32), np.asarray(seed, np.float32)
+    np.testing.assert_allclose(
+        drhs, np.stack([rows[:5].T @ dout[:5], rows[5:14].T @ dout[5:14]]),
+        rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("cell,rows,pairs,width", [
+    ("sdar", 16384, 512, 768), ("trinity", 16384, 512, 1024),
+    ("joyai", 8192, 256, 768)])
+def test_a_groups_matrix_is_fetched_once_a_column_tile(cell, rows, pairs,
+                                                       width):
+    """``grouped.fetches`` at the cells' sizes (a layer and sequence, 16
+    held experts a little off the row tile, as a routing leaves them): a
+    tile of rows is visited once for every group in it, and a group's
+    float32 slab is copied ``n // tn`` times whatever its visits; walked in
+    tiles of the contraction it would be copied at every visit."""
+    sizes = np.full(16, pairs - 12)
+    visits, fetched = grouped.fetches(sizes, rows, 2048, width, jnp.float32)
+    straddling = sum(a // 512 != (b - 1) // 512 for a, b in
+                     zip(np.cumsum(sizes) - sizes, np.cumsum(sizes)))
+    assert visits == 16 + straddling == {512: 31, 256: 23}[pairs]
+    assert fetched == 16                    # tn is the whole width
+    assert grouped.fetches(sizes, rows, width, 2048,
+                           jnp.float32) == (visits, 16)   # and here: 2,048
+    # The same walk of a slab beyond the budget: every visit, every tile.
+    assert grouped.fetches(sizes, rows, 4096, 1024,
+                           jnp.float32) == (visits, visits * 4)
+    sizes[3:] = 0                           # empty groups are not fetched
+    assert grouped.fetches(sizes, rows, 2048, width, jnp.float32)[1] == 3
+
+
 # -- the router's variants ------------------------------------------------------
 # A sigmoid router with a selection bias, the chosen scores divided by their
 # sum plus 1e-20 and scaled (``score_func``, ``selection_bias``,

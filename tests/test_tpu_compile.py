@@ -104,6 +104,7 @@ def test_flash_attention_compiles_for_v5e(one_chip, no_persistent_cache,
 # through 16 experts of 2,048 x 768.
 SDAR_L, SDAR_H, SDAR_HKV, SDAR_D, SDAR_TILE = 4096, 32, 4, 128, 512
 SDAR_ROWS, SDAR_EXPERTS, SDAR_HIDDEN, SDAR_WIDTH = 16384, 16, 2048, 768
+TRINITY_WIDTH = 1024             # moe_intermediate_size of models/afmoe.py
 
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
@@ -193,10 +194,16 @@ def test_latent_attention_flash_compiles_for_v5e(one_chip,
 
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("stored", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("width", [SDAR_WIDTH, TRINITY_WIDTH])
 def test_grouped_product_compiles_for_v5e(one_chip, no_persistent_cache,
-                                          backward):
-    """``parallel/grouped.py``: the gated products' shapes, with the
-    sequential grid dimension as long as the group sizes say."""
+                                          width, stored, backward):
+    """``parallel/grouped.py``: the gated products' shapes at the three
+    cells' widths, the matrices in the rows' dtype or as a model stores
+    them (float32: a group's 2,048 x ``width`` slab is one block, 6 and 8
+    MiB, cast in VMEM), with the sequential grid dimension as long as the
+    group sizes say."""
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
@@ -211,8 +218,42 @@ def test_grouped_product_compiles_for_v5e(one_chip, no_persistent_cache,
     _assert_kernel_compiles(
         fwd_bwd if backward else fwd,
         sds((SDAR_ROWS, SDAR_HIDDEN)),
-        sds((SDAR_EXPERTS, SDAR_HIDDEN, SDAR_WIDTH)),
+        sds((SDAR_EXPERTS, SDAR_HIDDEN, width), stored),
         sds((SDAR_EXPERTS,), jnp.int32))
+
+
+def test_dropless_layers_under_scan_make_no_copy_of_the_stacked_weights(
+        one_chip, no_persistent_cache):
+    """Three expert layers under ``lax.scan`` over their stacked float32
+    parameters, bf16 tokens, forward and gradients: the compiled text
+    holds the experts' matrices in float32 alone.  A conversion in the
+    layer is moved out of the scan by XLA and made for every layer at
+    once, a bf16 copy of the stack that lives through the whole step."""
+    from horovod_tpu.parallel.moe import dropless_expert_ffn
+    layers, tokens, experts = 3, 2048, 128
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, stacked):
+        def layer(x, p):
+            return x + dropless_expert_ffn(x, *p, top_k=8,
+                                           interpret=False).out, None
+
+        return jax.lax.scan(layer, x, stacked)[0].astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        sds(tokens, SDAR_HIDDEN, dtype=jnp.bfloat16),
+        (sds(layers, SDAR_HIDDEN, experts),
+         sds(layers, SDAR_EXPERTS, SDAR_HIDDEN, SDAR_WIDTH),
+         sds(layers, SDAR_EXPERTS, SDAR_HIDDEN, SDAR_WIDTH),
+         sds(layers, SDAR_EXPERTS, SDAR_WIDTH, SDAR_HIDDEN))).compile(
+             ).as_text()
+    matrices = r"\[(\d+,)?%d,(%d,%d|%d,%d)\]" % (
+        SDAR_EXPERTS, SDAR_HIDDEN, SDAR_WIDTH, SDAR_WIDTH, SDAR_HIDDEN)
+    assert re.search("f32" + matrices, text)
+    assert not re.search("bf16" + matrices, text)
+    assert text.count("hvd_gmm") and text.count("hvd_tgmm")
 
 
 @pytest.mark.parametrize("kv", ["f32", "bf16", "int8"])
