@@ -64,8 +64,10 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import scopes as _scopes
 from ..utils import get_logger
-from .sdar_moe import Aux, head_loss, rms_norm, rotary, through_layers
+from .sdar_moe import (Aux, embed, head_loss, rms_norm, rotary,
+                       through_layers)
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 #: Layer types whose flash output and logsumexp are kept across the
@@ -81,6 +83,11 @@ SLIDING, FULL = "sliding_attention", "full_attention"
 #: expert half keeps only its products' operands and results (PR 34;
 #: before, 15.96 with both and 14.74 with the full layer's alone).
 KEPT_ATTENTION = (SLIDING, FULL)
+
+#: The model's parts as they appear in an ``op_name`` (``scopes.py``).
+PARTS = ("hvd::loss", "hvd::embed", "hvd::layer_loop",
+         "hvd::window_attention", "hvd::full_attention", "hvd::dense_mlp",
+         "hvd::moe", "hvd::lm_head_loss")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,8 +155,8 @@ def _attention_half(cfg: AfmoeConfig, window: bool, positions, x, p):
     seq, _ = x.shape
     dtype, eps = cfg.dtype, cfg.rms_norm_eps
     tile = min(cfg.attention_tile, seq)
-    with jax.named_scope("hvd::window_attention" if window
-                         else "hvd::full_attention"):
+    with _scopes.scope("hvd::window_attention" if window
+                       else "hvd::full_attention"):
         a = rms_norm(x, p["attn_norm"], eps)
         heads = lambda w, n: jnp.dot(a, w.astype(dtype)).reshape(
             1, seq, n, cfg.head_dim)
@@ -173,7 +180,7 @@ def _attention_half(cfg: AfmoeConfig, window: bool, positions, x, p):
 
 
 def _dense_half(cfg: AfmoeConfig, h, p):
-    with jax.named_scope("hvd::dense_mlp"):
+    with _scopes.scope("hvd::dense_mlp"):
         m = rms_norm(h, p["pre_mlp_norm"], cfg.rms_norm_eps)
         f = gated_mlp(m, p["mlp_gate"], p["mlp_up"], p["mlp_down"],
                       cfg.dtype)
@@ -182,9 +189,9 @@ def _dense_half(cfg: AfmoeConfig, h, p):
 
 def _expert_half(cfg: AfmoeConfig, h, p):
     from ..parallel.moe import dropless_expert_ffn
-    with jax.named_scope("hvd::moe"):
+    with _scopes.scope("hvd::moe"):
         m = rms_norm(h, p["pre_mlp_norm"], cfg.rms_norm_eps)
-        with jax.named_scope("hvd::moe::shared"):
+        with _scopes.scope("hvd::moe::shared"):
             shared = gated_mlp(m, p["shared_gate"], p["shared_up"],
                                p["shared_down"], cfg.dtype)
         moe = dropless_expert_ffn(
@@ -222,12 +229,10 @@ def hidden_states(params: dict, tokens, cfg: AfmoeConfig):
     only, in their order."""
     batch, seq = tokens.shape
     positions = jnp.arange(seq, dtype=jnp.int32)
-    x = params["embed"][tokens]
-    if cfg.mup_enabled:
-        x = x * (cfg.hidden_size ** 0.5)
-    x = x.astype(cfg.dtype)
+    x = embed(params, tokens, cfg.dtype,
+              cfg.hidden_size ** 0.5 if cfg.mup_enabled else None)
     routed_here, chosen = [], []
-    with jax.named_scope("decoder"):
+    with _scopes.scope("decoder"):
         for (dense, window, _), stacked in zip(layer_runs(cfg),
                                                params["runs"]):
             x, aux = through_layers(_layer(cfg, dense, window, positions),
@@ -242,10 +247,13 @@ def hidden_states(params: dict, tokens, cfg: AfmoeConfig):
     return x, Aux(jnp.concatenate(routed_here), jnp.concatenate(chosen))
 
 
+@_scopes.part_scope("hvd::loss")
 def loss_fn(params: dict, tokens, cfg: AfmoeConfig):
     """The next-token loss of ``tokens [batch, S]`` and its :class:`Aux`:
     position ``i`` predicts token ``i + 1``, one document a sequence, the
-    mean over the ``batch x (S - 1)`` predictions."""
+    mean over the ``batch x (S - 1)`` predictions.  ``hvd::loss`` is the
+    part of what this function does itself (targets, weights, the mean) and
+    of what ``hidden_states`` does between its parts."""
     batch, seq = tokens.shape
     hidden, aux = hidden_states(params, tokens, cfg)
     # Every position goes through the head's chunks; the last of a

@@ -80,9 +80,10 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from .. import scopes as _scopes
 from ..utils import get_logger
 from .afmoe import gated_mlp
-from .sdar_moe import Aux, head_loss, rms_norm, through_layers
+from .sdar_moe import Aux, embed, head_loss, rms_norm, through_layers
 
 #: Whether a layer's flash output and logsumexp are kept across the
 #: recomputation of its (layer, sequence): 68 MB each at the published
@@ -93,6 +94,12 @@ from .sdar_moe import Aux, head_loss, rms_norm, through_layers
 #: its products' operands and results (PR 34; before, 15.51 with them and
 #: 14.25 without).  Off, the forward kernel runs again in the backward pass.
 KEEP_ATTENTION = True
+
+#: The model's parts as they appear in an ``op_name`` (``scopes.py``);
+#: ``hvd::mtp`` is the part of what the module does outside its block's
+#: halves, its embedding and its pass through the head.
+PARTS = ("hvd::loss", "hvd::embed", "hvd::layer_loop", "hvd::mla_attention",
+         "hvd::dense_mlp", "hvd::moe", "hvd::lm_head_loss", "hvd::mtp")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -156,14 +163,14 @@ def _attention_half(cfg: JoyaiFlashConfig, positions, x, p):
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     tile = min(cfg.attention_tile, seq)
     dot = lambda t, w: jnp.dot(t, w.astype(dtype))
-    with jax.named_scope("hvd::mla_attention"):
+    with _scopes.scope("hvd::mla_attention"):
         a = rms_norm(x, p["attn_norm"], eps)
-        with jax.named_scope("hvd::mla_attention::compress"):
+        with _scopes.scope("hvd::mla_attention::compress"):
             c_q = rms_norm(dot(a, p["w_qa"]), p["qa_norm"], eps)
             kva = dot(a, p["w_kva"])
             c_kv = rms_norm(kva[:, :cfg.kv_lora_rank], p["kva_norm"], eps)
             k_rope = kva[:, None, cfg.kv_lora_rank:]        # one head
-        with jax.named_scope("hvd::mla_attention::expand"):
+        with _scopes.scope("hvd::mla_attention::expand"):
             q = dot(c_q, p["w_qb"]).reshape(seq, heads, nope + rope)
             kv = dot(c_kv, p["w_kvb"]).reshape(seq, heads,
                                                nope + cfg.v_head_dim)
@@ -179,12 +186,12 @@ def _attention_half(cfg: JoyaiFlashConfig, positions, x, p):
         attended = flash_attention(q[None], k[None], v[None],
                                    mask_mode=MASK_CAUSAL, block_q=tile,
                                    block_k=tile)
-        with jax.named_scope("hvd::mla_attention::out"):
+        with _scopes.scope("hvd::mla_attention::out"):
             return x + dot(attended.reshape(seq, -1), p["wo"])
 
 
 def _dense_half(cfg: JoyaiFlashConfig, h, p):
-    with jax.named_scope("hvd::dense_mlp"):
+    with _scopes.scope("hvd::dense_mlp"):
         m = rms_norm(h, p["mlp_norm"], cfg.rms_norm_eps)
         return h + gated_mlp(m, p["mlp_gate"], p["mlp_up"], p["mlp_down"],
                              cfg.dtype), ()
@@ -192,9 +199,9 @@ def _dense_half(cfg: JoyaiFlashConfig, h, p):
 
 def _expert_half(cfg: JoyaiFlashConfig, h, p):
     from ..parallel.moe import dropless_expert_ffn
-    with jax.named_scope("hvd::moe"):
+    with _scopes.scope("hvd::moe"):
         m = rms_norm(h, p["mlp_norm"], cfg.rms_norm_eps)
-        with jax.named_scope("hvd::moe::shared"):
+        with _scopes.scope("hvd::moe::shared"):
             shared = gated_mlp(m, p["shared_gate"], p["shared_up"],
                                p["shared_down"], cfg.dtype)
         moe = dropless_expert_ffn(
@@ -236,9 +243,9 @@ def hidden_states(params: dict, tokens, cfg: JoyaiFlashConfig):
     alone and in their order."""
     batch, seq = tokens.shape
     positions = jnp.arange(seq, dtype=jnp.int32)
-    x = params["embed"][tokens].astype(cfg.dtype)
+    x = embed(params, tokens, cfg.dtype)
     dense, experts = params["runs"]
-    with jax.named_scope("decoder"):
+    with _scopes.scope("decoder"):
         x, _ = through_layers(_layer(cfg, True, positions), x, dense)
         x, aux = through_layers(_layer(cfg, False, positions), x, experts)
     return x, _routing(aux, batch, seq)
@@ -251,7 +258,7 @@ def mtp_hidden_states(params: dict, tokens, hidden, cfg: JoyaiFlashConfig):
     sequence is the dummy."""
     batch, seq = tokens.shape
     mtp, eps = params["mtp"], cfg.rms_norm_eps
-    ahead = params["embed"][jnp.roll(tokens, -1, axis=1)].astype(cfg.dtype)
+    ahead = embed(params, jnp.roll(tokens, -1, axis=1), cfg.dtype)
     joined = jnp.concatenate([rms_norm(ahead, mtp["enorm"], eps),
                               rms_norm(hidden, mtp["hnorm"], eps)], axis=-1)
     u = jnp.dot(joined, mtp["w_eh"].astype(cfg.dtype))
@@ -287,7 +294,7 @@ def losses(params: dict, tokens, cfg: JoyaiFlashConfig):
         return main, jnp.zeros_like(main), Aux(routed_here, chosen)
     # JAX writes the outermost scope of a differentiated function into its
     # ``jvp(...)`` marker; ``mtp`` takes that place, as ``decoder`` does.
-    with jax.named_scope("mtp"), jax.named_scope("hvd::mtp"):
+    with _scopes.scope("mtp"), _scopes.scope("hvd::mtp"):
         y, (mtp_routed, mtp_chosen) = mtp_hidden_states(params, tokens,
                                                         hidden, cfg)
         ahead = mean_loss({"head": params["head"],
@@ -296,7 +303,10 @@ def losses(params: dict, tokens, cfg: JoyaiFlashConfig):
                             jnp.concatenate([chosen, mtp_chosen]))
 
 
+@_scopes.part_scope("hvd::loss")
 def loss_fn(params: dict, tokens, cfg: JoyaiFlashConfig):
-    """``L_main + mtp_loss_weight * L_mtp`` and ``(Aux, L_main, L_mtp)``."""
+    """``L_main + mtp_loss_weight * L_mtp`` and ``(Aux, L_main, L_mtp)``.
+    ``hvd::loss`` is the part of what ``losses`` does between its parts
+    (targets, weights, the two means) and of the weighted sum."""
     main, ahead, aux = losses(params, tokens, cfg)
     return main + cfg.mtp_loss_weight * ahead, (aux, main, ahead)

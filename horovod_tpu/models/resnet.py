@@ -23,7 +23,13 @@ import jax.numpy as jnp
 
 import flax.linen as nn
 
+from .. import scopes as _scopes
+
 ModuleDef = Any
+
+#: The model's parts as they appear in an ``op_name`` (``scopes.py``): the
+#: scopes of ``ResNet.__call__`` with four stages.
+PARTS = ("stem", "max_pool", "stage1", "stage2", "stage3", "stage4", "head")
 
 
 def _space_to_depth(x):
@@ -206,7 +212,7 @@ class ResNet(nn.Module):
         # The named scopes are the model's parts in a device trace: they
         # enter the ``op_name`` of every operation traced under them,
         # forward and backward, and leave flax's parameter names alone.
-        with jax.named_scope("stem"):
+        with _scopes.scope("stem"):
             x = x.astype(self.dtype)
             if self.s2d_stem:
                 x = SpaceToDepthStem(self.num_filters, dtype=self.dtype,
@@ -219,19 +225,19 @@ class ResNet(nn.Module):
                          name="conv_init")(x)
             x = norm(name="bn_init")(x)
             x = nn.relu(x)
-        with jax.named_scope("max_pool"):
+        with _scopes.scope("max_pool"):
             if self.eq_pool_grad:
                 x = max_pool_eq_grad(x)
             else:
                 x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
         for i, block_size in enumerate(self.stage_sizes):
-            with jax.named_scope(f"stage{i + 1}"):
+            with _scopes.scope(f"stage{i + 1}"):
                 for j in range(block_size):
                     strides = (2, 2) if i > 0 and j == 0 else (1, 1)
                     x = self.block_cls(self.num_filters * 2 ** i,
                                        strides=strides, conv=conv,
                                        norm=norm)(x)
-        with jax.named_scope("head"):
+        with _scopes.scope("head"):
             x = jnp.mean(x, axis=(1, 2))
             x = nn.Dense(self.num_classes, dtype=jnp.float32)(x)
         return x

@@ -50,6 +50,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import scopes as _scopes
+
 
 @dataclasses.dataclass(frozen=True)
 class SdarMoeConfig:
@@ -72,6 +74,11 @@ class SdarMoeConfig:
     expert_axis: Optional[str] = None   # mesh axis the experts are over
     attention_tile: int = 512       # flash tile (queries and keys)
     loss_chunk: int = 4096          # positions a chunk of the head's logits
+
+
+#: The model's parts as they appear in an ``op_name`` (``scopes.py``).
+PARTS = ("hvd::loss", "hvd::embed", "hvd::layer_loop", "hvd::bd_attention",
+         "hvd::moe", "hvd::lm_head_loss")
 
 
 class Aux(NamedTuple):
@@ -111,7 +118,7 @@ def _layer(cfg: SdarMoeConfig, positions, mask_mode, x, p):
     seq, d = x.shape
     dtype = cfg.dtype
     tile = min(cfg.attention_tile, seq)
-    with jax.named_scope("hvd::bd_attention"):
+    with _scopes.scope("hvd::bd_attention"):
         h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
         heads = lambda w, n: jnp.dot(h, w.astype(dtype)).reshape(
             1, seq, n, cfg.head_dim)
@@ -126,7 +133,7 @@ def _layer(cfg: SdarMoeConfig, positions, mask_mode, x, p):
                                    block_q=tile, block_k=tile)
         x = x + jnp.dot(attended.reshape(seq, -1), p["wo"].astype(dtype))
 
-    with jax.named_scope("hvd::moe"):
+    with _scopes.scope("hvd::moe"):
         h = rms_norm(x, p["moe_norm"], cfg.rms_norm_eps)
         moe = dropless_expert_ffn(
             h, p["router"], p["w_gate"], p["w_up"], p["w_down"],
@@ -141,9 +148,27 @@ def through_layers(layer, x, stacked):
     ``stacked`` on a leading axis: the layers under ``lax.scan`` and, inside
     a layer, the sequences one after the other.  ``layer(x [S, hidden], p)
     -> (x, aux)`` comes with its own ``jax.checkpoint``; returns ``(x, aux
-    [layers, batch, ...])``."""
-    return lax.scan(lambda x, p: lax.map(lambda xs: layer(xs, p), x),
-                    x, stacked)
+    [layers, batch, ...])``.
+
+    ``hvd::layer_loop`` is around the whole scan, so that it is the part of
+    everything a (layer, sequence) costs outside the layer's own parts, in
+    both loops and both passes: the slices of the stacked parameters, the
+    sums of their gradients over a layer's sequences, the auxiliary
+    outputs."""
+    with _scopes.scope("hvd::layer_loop"):
+        return lax.scan(lambda x, p: lax.map(lambda xs: layer(xs, p), x),
+                        x, stacked)
+
+
+def embed(params: dict, tokens, dtype, scale: Optional[float] = None):
+    """The rows of ``params["embed"]`` for ``tokens``, times ``scale``
+    where one is given, in ``dtype``; the gather, and so the scatter-add of
+    the backward pass, under ``hvd::embed``."""
+    with _scopes.part_scope("hvd::embed"):
+        x = params["embed"][tokens]
+        if scale is not None:
+            x = x * scale
+        return x.astype(dtype)
 
 
 def hidden_states(params: dict, tokens, cfg: SdarMoeConfig, mask_mode):
@@ -162,14 +187,14 @@ def hidden_states(params: dict, tokens, cfg: SdarMoeConfig, mask_mode):
     positions = jnp.arange(seq, dtype=jnp.int32)
     if isinstance(mask_mode, tuple) and mask_mode[0] == MASK_BLOCK_DIFFUSION:
         positions = positions % mask_mode[2]
-    x = params["embed"][tokens].astype(cfg.dtype)
+    x = embed(params, tokens, cfg.dtype)
     # Recomputed in the backward pass, but for the flash kernel's output
     # and logsumexp (65 MB a layer and sequence at the published sizes):
     # kept, they spare the forward kernel's second run.
     one = jax.checkpoint(
         lambda x, p: _layer(cfg, positions, mask_mode, x, p),
         policy=jax.checkpoint_policies.save_only_these_names(*SAVED))
-    with jax.named_scope("decoder"):
+    with _scopes.scope("decoder"):
         x, (routed_here, chosen) = through_layers(one, x, params["layers"])
     return x, Aux(routed_here.sum(axis=1),
                   chosen.reshape(chosen.shape[0], batch * seq, -1))
@@ -198,7 +223,7 @@ def head_loss(params: dict, hidden, targets, weight, cfg: SdarMoeConfig):
 
     # JAX writes the outermost scope of a differentiated function into its
     # ``jvp(...)`` marker; ``head`` takes that place, as ``decoder`` does.
-    with jax.named_scope("head"), jax.named_scope("hvd::lm_head_loss"):
+    with _scopes.scope("head"), _scopes.scope("hvd::lm_head_loss"):
         total = jnp.sum(lax.map(
             chunk_loss,
             (hidden.reshape(n // chunk, chunk, d),
@@ -207,10 +232,13 @@ def head_loss(params: dict, hidden, targets, weight, cfg: SdarMoeConfig):
     return total
 
 
+@_scopes.part_scope("hvd::loss")
 def loss_fn(params: dict, xt, x0, weight, cfg: SdarMoeConfig):
     """The block-diffusion loss of one batch and its :class:`Aux`: ``xt``,
     ``x0`` ``[batch, L]`` noised and clean tokens, ``weight [batch, L]``
-    ``1 / t_b`` at the masked positions and 0 elsewhere."""
+    ``1 / t_b`` at the masked positions and 0 elsewhere.  ``hvd::loss`` is
+    the part of what this function does itself: the two copies side by
+    side, the noised half cut out for the head, the mean."""
     from ..parallel.flash import block_diffusion_mask
     batch, length = x0.shape
     hidden, aux = hidden_states(

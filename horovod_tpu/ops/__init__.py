@@ -30,6 +30,7 @@ from .collective_ops import (  # noqa: F401
 )
 from . import collective_ops as C
 from .. import core as _core
+from .. import scopes as _scopes
 from ..compression import Compression
 from ..process_sets import ProcessSet, global_process_set
 
@@ -48,14 +49,6 @@ def _axis_bound(axis_name: str) -> bool:
         return True
     except NameError:
         return False
-
-
-def _scope(kind: str, name: Optional[str]):
-    """``hvd::<kind>[::<name>]`` on every operation a traced collective
-    compiles to: the label ops/eager.py gives the profiler for an eager
-    dispatch, written into the compiled program's ``op_name`` metadata
-    (it changes no instruction and costs nothing at run time)."""
-    return jax.named_scope(f"hvd::{kind}::{name}" if name else f"hvd::{kind}")
 
 
 def _engine():
@@ -139,7 +132,7 @@ def allreduce(tensor,
         # break replicated out_specs that plain psum satisfies.  The
         # explicit form stays available for 2-D mesh experts as
         # collective_ops.hierarchical_allreduce.
-        with _scope("allreduce", name):
+        with _scopes.collective("allreduce", name):
             out = C.allreduce(tensor, rop, axis_name=axis, members=members,
                               prescale_factor=prescale_factor,
                               postscale_factor=postscale_factor)
@@ -204,7 +197,7 @@ def grouped_allreduce(tensors: Sequence,
     ts = [c[0] for c in compressed]
     ctxs = [c[1] for c in compressed]
     if _axis_bound(axis):
-        with _scope("grouped_allreduce", name):
+        with _scopes.collective("grouped_allreduce", name):
             outs = C.grouped_allreduce(ts, rop, axis_name=axis,
                                        members=members,
                                        prescale_factor=prescale_factor,
@@ -330,7 +323,7 @@ def allgather(tensor, name: Optional[str] = None,
     axis = _axis()
     members = _members(process_set)
     if _axis_bound(axis):
-        with _scope("allgather", name):
+        with _scopes.collective("allgather", name):
             return C.allgather(tensor, axis_name=axis, members=members)
     eng = _engine()
     if isinstance(tensor, (list, tuple)) and eng.topo.emulated:
@@ -450,7 +443,7 @@ def broadcast(tensor, root_rank: int = 0, name: Optional[str] = None,
     axis = _axis()
     members = _members(process_set)
     if _axis_bound(axis):
-        with _scope("broadcast", name):
+        with _scopes.collective("broadcast", name):
             return C.broadcast(tensor, root_rank, axis_name=axis,
                                members=members)
     eng = _engine()
@@ -495,7 +488,7 @@ def alltoall(tensor, splits=None, name: Optional[str] = None,
     members = _members(process_set)
     if splits is None:
         if _axis_bound(axis):
-            with _scope("alltoall", name):
+            with _scopes.collective("alltoall", name):
                 return C.alltoall(tensor, axis_name=axis, members=members)
         eng = _engine()
 
@@ -603,7 +596,7 @@ def reducescatter(tensor, op=ReduceOp.SUM, name: Optional[str] = None,
     axis = _axis()
     members = _members(process_set)
     if _axis_bound(axis):
-        with _scope("reducescatter", name):
+        with _scopes.collective("reducescatter", name):
             return C.reducescatter(tensor, rop, axis_name=axis,
                                    members=members,
                                    prescale_factor=prescale_factor,
@@ -666,7 +659,7 @@ def barrier(process_set: ProcessSet = global_process_set) -> None:
     BarrierOp collective_operations.h:335)."""
     axis = _axis()
     if _axis_bound(axis):
-        with _scope("barrier", None):
+        with _scopes.collective("barrier", None):
             C.barrier(axis_name=axis)
         return
     eng = _engine()

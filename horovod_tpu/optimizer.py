@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from . import ops as _ops
+from . import scopes as _scopes
 from .compression import Compression
 from .ops import ReduceOp
 from .process_sets import ProcessSet, global_process_set
@@ -315,7 +316,7 @@ def _scoped(transformation, scope: str):
     inner = optax.with_extra_args_support(transformation)
 
     def update_fn(updates, state, params=None, **extra_args):
-        with jax.named_scope(scope):
+        with _scopes.scope(scope):
             return inner.update(updates, state, params, **extra_args)
 
     return optax.GradientTransformationExtraArgs(inner.init, update_fn)
@@ -326,7 +327,7 @@ def _scoped(transformation, scope: str):
 #: ``reduce_gradients`` (the gradients' reduction or, where they arrive
 #: already summed, their rescaling) and ``inner_update`` (the wrapped
 #: optimizer).
-_OPTIMIZER_SCOPE = "hvd::optimizer"
+_OPTIMIZER_SCOPE = _scopes.OPTIMIZER
 
 
 class DistributedState(NamedTuple):
@@ -376,7 +377,7 @@ def _gradient_reduction(op, compression, gradient_predivide_factor,
         return optax.EmptyState()
 
     def update_fn(updates, state, params=None):
-        with jax.named_scope("reduce_gradients"):
+        with _scopes.scope("reduce_gradients"):
             reduced = _allreduce_tree(updates, op, compression, prescale,
                                       postscale, process_set, groups,
                                       reduce_axes=reduce_axes, params=params)
@@ -563,7 +564,7 @@ def PartialDistributedOptimizer(optimizer,
     def update_fn(updates, state, params=None):
         flat, treedef = jax.tree_util.tree_flatten_with_path(updates)
         reduced = []
-        with jax.named_scope("reduce_gradients"):
+        with _scopes.scope("reduce_gradients"):
             for path, leaf in flat:
                 if local_filter(path, leaf):
                     reduced.append(leaf)

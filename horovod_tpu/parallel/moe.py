@@ -40,6 +40,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from .. import scopes as _scopes
+
 
 class MoEOutput(NamedTuple):
     out: jax.Array          # [T_local, d] combined expert outputs
@@ -322,14 +324,14 @@ def _held_experts(x, expert, weights, w_gate, w_up, w_down, rows: int,
                                                ends[:-1]]))
     slot_of_pair, pair_of_slot, in_use = (
         keep(slot_of_pair.reshape(n, k)), keep(pair_of_slot), keep(in_use))
-    with jax.named_scope("hvd::moe::experts"):
+    with _scopes.scope("hvd::moe::experts"):
         xs = keep(_dispatch(x, slot_of_pair, pair_of_slot))
         gate = keep(gmm(xs, w_gate, group_sizes, interpret=interpret))
         up = keep(gmm(xs, w_up, group_sizes, interpret=interpret))
         hidden = (jax.nn.silu(gate.astype(jnp.float32))
                   * up.astype(jnp.float32)).astype(xs.dtype)
         ys = keep(gmm(hidden, w_down, group_sizes, interpret=interpret))
-    with jax.named_scope("hvd::moe::combine"):
+    with _scopes.scope("hvd::moe::combine"):
         return _combine(ys, weights, slot_of_pair, pair_of_slot, in_use)
 
 
@@ -453,7 +455,7 @@ def dropless_expert_ffn(x: jax.Array,
     # VMEM, so no copy of the stack in ``x``'s dtype is ever made.
     experts = (w_gate, w_up, w_down)
 
-    with jax.named_scope("hvd::moe::route"):
+    with _scopes.scope("hvd::moe::route"):
         logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
         chosen, weights, _ = _top_k_gating(logits, top_k, score_func,
@@ -487,7 +489,7 @@ def dropless_expert_ffn(x: jax.Array,
         return (starts[dest] + place,
                 place < starts[dest + 1] - starts[dest])
 
-    with jax.named_scope("hvd::moe::route"):
+    with _scopes.scope("hvd::moe::route"):
         slot_of_pair, pair_of_slot, in_use, _ = _slots(
             flat, num_experts, shards * per_dest, slot_of_rank,
             rank_of_slot)
@@ -503,7 +505,7 @@ def dropless_expert_ffn(x: jax.Array,
         received, received_expert[:, None],
         jnp.ones((shards * per_dest, 1), jnp.float32), *experts,
         share=1.0 / shards, interpret=interpret)
-    with jax.named_scope("hvd::moe::combine"):
+    with _scopes.scope("hvd::moe::combine"):
         back = exchange(results.astype(x.dtype))
         out = _combine(back, weights, slot_of_pair, pair_of_slot, in_use)
     return DroplessOutput(out.astype(x.dtype),

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import scopes as _scopes
 from .process_sets import ProcessSet
 
 
@@ -46,7 +47,7 @@ def sync_batch_stats(x: jax.Array,
     # The scope is what tells a statistic's all-reduce (and its transpose
     # in the backward pass) from a parameter gradient's in the compiled
     # step: both are ``psum_invariant`` under the same flax module.
-    with jax.named_scope("hvd::sync_bn_stats"):
+    with _scopes.scope("hvd::sync_bn_stats"):
         vec = jnp.concatenate([s.ravel(), sq.ravel(),
                                jnp.full((1,), n_local, x.dtype)])
         vec = C.allreduce(vec, C.Sum, axis_name=axis_name, members=members)
@@ -114,7 +115,7 @@ def _fused_bn_cls():
         # The scope is in the ``op_name`` of every operation of the layer,
         # forward and backward; flax's parameter names do not see it.
         @nn.compact
-        @jax.named_scope("hvd::batch_norm")
+        @_scopes.scope("hvd::batch_norm")
         def __call__(self, x, use_running_average: Optional[bool] = None):
             ura = nn.merge_param("use_running_average",
                                  self.use_running_average,

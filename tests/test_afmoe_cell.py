@@ -146,8 +146,7 @@ def test_a_gradient_outside_its_limit_fails_the_loss_comparison(
     "test_cell_and_its_reference",
     "test_cell_traced_reports_counts_but_no_device_metric",
     "test_control_in_a_lower_precision_comes_out_not_correct",
-    "test_operations_count_the_pairs_the_masks_keep",
-    "test_every_new_reader_has_its_file_and_its_entry"])
+    "test_operations_count_the_pairs_the_masks_keep"])
 def test_rehearsal_cell_through_the_train_runner(test):
     """``benchmarks/tests/test_trinity_cell.py`` (the rehearsal cell of
     ``benchmarks/tests/cells/`` through ``runners/train.py``, in a child
@@ -161,6 +160,33 @@ def test_rehearsal_cell_through_the_train_runner(test):
         cwd=ROOT, env=dict(env, JAX_PLATFORMS="cpu"), capture_output=True,
         text=True, timeout=900)
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-2000:]
+
+
+def test_every_reader_of_the_cell_has_its_file_and_its_entry():
+    """What ``benchmarks/tests/test_trinity_cell.py::
+    test_every_new_reader_has_its_file_and_its_entry`` holds, without its
+    count of the metrics that list the cell (14 when the cell was added;
+    a later PR appends the cell to a new metric's list, as PR 35 did to
+    five): PR 31's eight readers are there and are the cell's own, and
+    every metric that lists the cell has its reader."""
+    new = {"window_attention_share.train", "full_attention_share.train",
+           "shared_expert_share.train", "dense_mlp_share.train",
+           "mixed_attention_roofline.train", "mixed_flash_fwd_tile_us.train",
+           "mixed_flash_grid_steps_per_tile.train",
+           "window_tiles_kept_share.train"}
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    entries = {m["name"]: m for m in manifest["per_layer"]}
+    for name in new:
+        assert entries[name]["workloads"] == ["trinity-mini-train-8k"]
+        assert entries[name]["moves"] == "train_samples_per_s"
+    joined = [m["name"] for m in manifest["per_layer"]
+              if "trinity-mini-train-8k" in m["workloads"]]
+    assert len(joined) >= 14 and len(set(joined)) == len(joined)
+    assert new <= set(joined)
+    for name in joined:
+        assert os.path.exists(os.path.join(BENCH, "layer_metrics",
+                                           name + ".py")), name
 
 
 @pytest.mark.parametrize("kept,tile_runs", [
