@@ -49,7 +49,8 @@ experts]``, ``shared_gate``, ``shared_up`` ``[hidden, shared width]``,
 ``final_norm [hidden]``; ``head [hidden, vocab]``
 (``benchmarks/jobs/afmoe.py: seeded_params`` makes one).  The step names
 itself for the device trace (``docs/timeline.md``): under ``decoder``
-``hvd::window_attention`` or ``hvd::full_attention``, then
+``hvd::window_attention`` or ``hvd::full_attention`` (``hvd::qk_rope``
+inside them), then
 ``hvd::dense_mlp`` or ``hvd::moe`` (``::shared``, ``::route``,
 ``::experts``, ``::combine`` inside it), under ``head``
 ``hvd::lm_head_loss``.
@@ -66,7 +67,7 @@ import jax.numpy as jnp
 
 from .. import scopes as _scopes
 from ..utils import get_logger
-from .sdar_moe import (Aux, embed, head_loss, rms_norm, rotary,
+from .sdar_moe import (Aux, embed, head_loss, heads_first_qkv, rms_norm,
                        through_layers)
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -148,31 +149,26 @@ def gated_mlp(x, w_gate, w_up, w_down, dtype):
     return jnp.dot(hidden.astype(dtype), w_down.astype(dtype))
 
 
-def _attention_half(cfg: AfmoeConfig, window: bool, positions, x, p):
+def _attention_half(cfg: AfmoeConfig, window: bool, tables, x, p):
     """``h = x + RMSNorm((o * sigmoid(g)) Wo)`` of one sequence ``x [S,
-    hidden]``."""
-    from ..parallel.flash import MASK_CAUSAL, flash_attention, window_mask
+    hidden]``; ``tables`` are the rotary positions'
+    (``qk_rope.rope_tables``), which a window layer alone takes."""
+    from ..parallel.flash import (MASK_CAUSAL, flash_attention_heads_first,
+                                  window_mask)
     seq, _ = x.shape
     dtype, eps = cfg.dtype, cfg.rms_norm_eps
     tile = min(cfg.attention_tile, seq)
     with _scopes.scope("hvd::window_attention" if window
                        else "hvd::full_attention"):
         a = rms_norm(x, p["attn_norm"], eps)
-        heads = lambda w, n: jnp.dot(a, w.astype(dtype)).reshape(
-            1, seq, n, cfg.head_dim)
-        q = rms_norm(heads(p["wq"], cfg.num_attention_heads), p["q_norm"],
-                     eps)
-        k = rms_norm(heads(p["wk"], cfg.num_key_value_heads), p["k_norm"],
-                     eps)
-        v = heads(p["wv"], cfg.num_key_value_heads)
+        q, k, v = heads_first_qkv(
+            a, p, tables if window else None, cfg.num_attention_heads,
+            cfg.num_key_value_heads, eps, dtype)
         gate = jnp.dot(a, p["wg"].astype(dtype))
-        if window:      # positions on the window's layers only
-            q = rotary(q, positions, cfg.rope_theta)
-            k = rotary(k, positions, cfg.rope_theta)
-        attended = flash_attention(
+        attended = flash_attention_heads_first(
             q, k, v, block_q=tile, block_k=tile,
             mask_mode=window_mask(cfg.sliding_window) if window
-            else MASK_CAUSAL)
+            else MASK_CAUSAL).transpose(1, 0, 2)
         gated = attended.reshape(seq, -1).astype(jnp.float32) \
             * jax.nn.sigmoid(gate.astype(jnp.float32))
         out = jnp.dot(gated.astype(dtype), p["wo"].astype(dtype))
@@ -205,7 +201,7 @@ def _expert_half(cfg: AfmoeConfig, h, p):
         return h + out.astype(h.dtype), (moe.routed_here, moe.chosen)
 
 
-def _layer(cfg: AfmoeConfig, dense: bool, window: bool, positions):
+def _layer(cfg: AfmoeConfig, dense: bool, window: bool, tables):
     """One layer of a kind over one sequence, ``(x [S, hidden], p) -> (x,
     aux)``, under its ``jax.checkpoint``."""
     from ..parallel.flash import SAVED
@@ -217,7 +213,7 @@ def _layer(cfg: AfmoeConfig, dense: bool, window: bool, positions):
         "recomputation", "dense" if dense else "expert", kind,
         "keeps" if keep else "computes again")
     return jax.checkpoint(
-        lambda x, p: mlp(cfg, _attention_half(cfg, window, positions, x, p),
+        lambda x, p: mlp(cfg, _attention_half(cfg, window, tables, x, p),
                          p),
         policy=jax.checkpoint_policies.save_only_these_names(*SAVED)
         if keep else None)
@@ -228,14 +224,16 @@ def hidden_states(params: dict, tokens, cfg: AfmoeConfig):
     Aux)`` for ``tokens [batch, S]``; ``Aux`` counts the expert layers
     only, in their order."""
     batch, seq = tokens.shape
-    positions = jnp.arange(seq, dtype=jnp.int32)
+    from ..parallel.qk_rope import rope_tables
+    tables = rope_tables(jnp.arange(seq, dtype=jnp.int32), cfg.head_dim,
+                         cfg.rope_theta)
     x = embed(params, tokens, cfg.dtype,
               cfg.hidden_size ** 0.5 if cfg.mup_enabled else None)
     routed_here, chosen = [], []
     with _scopes.scope("decoder"):
         for (dense, window, _), stacked in zip(layer_runs(cfg),
                                                params["runs"]):
-            x, aux = through_layers(_layer(cfg, dense, window, positions),
+            x, aux = through_layers(_layer(cfg, dense, window, tables),
                                     x, stacked)
             if not dense:
                 routed_here.append(aux[0].sum(axis=1))

@@ -37,7 +37,9 @@ x head_dim]``, ``wk``, ``wv`` ``[hidden, kv heads x head_dim]``, ``q_norm``,
 vocab]`` (``benchmarks/jobs/sdar_moe.py: seeded_params`` makes one, and
 ``seeded_batch`` a corrupted batch).  The step names
 itself for the device trace (``docs/timeline.md``): under ``decoder``
-``hvd::bd_attention`` and ``hvd::moe`` (``::route``, ``::experts``,
+``hvd::bd_attention`` (inside it ``hvd::qk_rope``: the head norms, the
+rotary embedding and the head-major layout, one pass of
+``parallel/qk_rope.py``) and ``hvd::moe`` (``::route``, ``::experts``,
 ``::combine`` inside it), under ``head`` ``hvd::lm_head_loss``.
 """
 
@@ -110,28 +112,42 @@ def rotary(x, positions, theta: float):
                            axis=-1).astype(x.dtype)
 
 
-def _layer(cfg: SdarMoeConfig, positions, mask_mode, x, p):
+def heads_first_qkv(h, p, tables, heads: int, kv_heads: int, eps: float,
+                    dtype):
+    """``(q [heads, S, head_dim], k, v [kv_heads, S, head_dim])`` of one
+    sequence's normed input ``h [S, hidden]``, as
+    ``flash_attention_heads_first`` takes them: the three projections,
+    every query and key head under its RMSNorm (``p["q_norm"]``,
+    ``p["k_norm"]``) and rotated by ``tables`` (``qk_rope.rope_tables``;
+    ``None``: no positions).  Norm, rotation and the head-major layout of
+    ``q`` and ``k`` are one pass over the projection's output
+    (``parallel/qk_rope.py``), under ``hvd::qk_rope``."""
+    from ..parallel.qk_rope import qk_norm_rope
+    project = lambda w: jnp.dot(h, p[w].astype(dtype))
+    q, k, v = project("wq"), project("wk"), project("wv")
+    with _scopes.scope("hvd::qk_rope"):
+        q = qk_norm_rope(q, p["q_norm"], tables, heads, eps)
+        k = qk_norm_rope(k, p["k_norm"], tables, kv_heads, eps)
+    return q, k, v.reshape(v.shape[0], kv_heads, -1).transpose(1, 0, 2)
+
+
+def _layer(cfg: SdarMoeConfig, tables, mask_mode, x, p):
     """One decoder layer over one sequence ``x [S, hidden]``; ``p`` is the
-    layer's slice of the stacked parameters."""
-    from ..parallel.flash import flash_attention
+    layer's slice of the stacked parameters, ``tables`` the rotary
+    positions' (``qk_rope.rope_tables``)."""
+    from ..parallel.flash import flash_attention_heads_first
     from ..parallel.moe import dropless_expert_ffn
     seq, d = x.shape
     dtype = cfg.dtype
     tile = min(cfg.attention_tile, seq)
     with _scopes.scope("hvd::bd_attention"):
         h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-        heads = lambda w, n: jnp.dot(h, w.astype(dtype)).reshape(
-            1, seq, n, cfg.head_dim)
-        q = heads(p["wq"], cfg.num_heads)
-        k = heads(p["wk"], cfg.num_kv_heads)
-        v = heads(p["wv"], cfg.num_kv_heads)
-        q = rotary(rms_norm(q, p["q_norm"], cfg.rms_norm_eps), positions,
-                   cfg.rope_theta)
-        k = rotary(rms_norm(k, p["k_norm"], cfg.rms_norm_eps), positions,
-                   cfg.rope_theta)
-        attended = flash_attention(q, k, v, mask_mode=mask_mode,
-                                   block_q=tile, block_k=tile)
-        x = x + jnp.dot(attended.reshape(seq, -1), p["wo"].astype(dtype))
+        q, k, v = heads_first_qkv(h, p, tables, cfg.num_heads,
+                                  cfg.num_kv_heads, cfg.rms_norm_eps, dtype)
+        attended = flash_attention_heads_first(
+            q, k, v, mask_mode=mask_mode, block_q=tile, block_k=tile)
+        x = x + jnp.dot(attended.transpose(1, 0, 2).reshape(seq, -1),
+                        p["wo"].astype(dtype))
 
     with _scopes.scope("hvd::moe"):
         h = rms_norm(x, p["moe_norm"], cfg.rms_norm_eps)
@@ -183,16 +199,18 @@ def hidden_states(params: dict, tokens, cfg: SdarMoeConfig, mask_mode):
     input and the attention kernel's output a layer and sequence, and what
     it holds while it recomputes is one sequence's worth of one layer."""
     from ..parallel.flash import MASK_BLOCK_DIFFUSION, SAVED
+    from ..parallel.qk_rope import rope_tables
     batch, seq = tokens.shape
     positions = jnp.arange(seq, dtype=jnp.int32)
     if isinstance(mask_mode, tuple) and mask_mode[0] == MASK_BLOCK_DIFFUSION:
         positions = positions % mask_mode[2]
+    tables = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
     x = embed(params, tokens, cfg.dtype)
     # Recomputed in the backward pass, but for the flash kernel's output
     # and logsumexp (65 MB a layer and sequence at the published sizes):
     # kept, they spare the forward kernel's second run.
     one = jax.checkpoint(
-        lambda x, p: _layer(cfg, positions, mask_mode, x, p),
+        lambda x, p: _layer(cfg, tables, mask_mode, x, p),
         policy=jax.checkpoint_policies.save_only_these_names(*SAVED))
     with _scopes.scope("decoder"):
         x, (routed_here, chosen) = through_layers(one, x, params["layers"])

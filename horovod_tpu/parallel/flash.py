@@ -72,8 +72,10 @@ the kernels are compiled for the chip in tests/test_tpu_compile.py and run
 against the same reference on it by chip_smoke.py.
 
 Layout: [B, S, H, D] public API (``v``: [B, S, Hkv, Dv]); internally
-[B*H, S, D], per-row statistics [B*H, 1, S].  Block sizes default to 128
-(MXU tile) and clamp to the sequence length.
+[B*H, S, D], per-row statistics [B*H, 1, S], which
+:func:`flash_attention_heads_first` takes and returns as they are (a
+caller that makes its operands head-major itself: ``qk_rope.py``).  Block
+sizes default to 128 (MXU tile) and clamp to the sequence length.
 """
 
 from __future__ import annotations
@@ -779,11 +781,22 @@ def _heads_first(x):
     return x.transpose(0, 2, 1, 3).reshape(B * H, S, D)
 
 
+def _all_heads_first(name, q, k, v):
+    """``q``, ``k``, ``v`` ``[B, S, heads, D]`` head-major, for the calls
+    that take that layout."""
+    if not q.shape[0] == k.shape[0] == v.shape[0]:
+        raise ValueError(f"{name}: queries, keys and values {q.shape}, "
+                         f"{k.shape}, {v.shape} of different batches")
+    return _heads_first(q), _heads_first(k), _heads_first(v)
+
+
 def _checked(name, q, k, v, scale, block_q, block_k, interpret):
-    """Defaults filled in and shapes checked for the two public calls."""
-    B, S, H, D = q.shape
-    if k.shape[:3] != v.shape[:3] or k.shape[:2] != (B, S) \
-            or k.shape[3] != D or H % k.shape[2]:
+    """Defaults filled in and shapes checked for the public calls, on
+    head-major ``q [B*H, S, D]``, ``k [B*Hkv, S, D]``, ``v [B*Hkv, S,
+    Dv]``."""
+    BH, S, D = q.shape
+    if k.shape[0] != v.shape[0] or k.shape[1:] != (S, D) \
+            or v.shape[1] != S or BH % k.shape[0]:
         raise ValueError(
             f"{name}: keys and values {k.shape}, {v.shape} do not fit "
             f"queries {q.shape}: keys as wide as the queries, as many value "
@@ -798,6 +811,28 @@ def _checked(name, q, k, v, scale, block_q, block_k, interpret):
             block_q, block_k,
             jax.default_backend() != "tpu" if interpret is None
             else interpret)
+
+
+def flash_attention_heads_first(q: jax.Array, k: jax.Array, v: jax.Array,
+                                *,
+                                causal: bool = False,
+                                mask_mode=None,
+                                scale: Optional[float] = None,
+                                block_q: int = 128,
+                                block_k: int = 128,
+                                interpret: Optional[bool] = None
+                                ) -> jax.Array:
+    """:func:`flash_attention` in the kernels' own layout: ``q [B*H, S,
+    D]``, ``k [B*Hkv, S, D]``, ``v [B*Hkv, S, Dv]`` (a batch's heads
+    together, ``Hkv`` dividing ``H``) to ``[B*H, S, Dv]``, for a caller
+    that makes its operands head-major itself (``qk_rope.py``) and so
+    spares the transposes."""
+    scale, block_q, block_k, interpret = _checked(
+        "flash_attention_heads_first", q, k, v, scale, block_q, block_k,
+        interpret)
+    if mask_mode is None:
+        mask_mode = MASK_CAUSAL if causal else MASK_NONE
+    return _flash(q, k, v, mask_mode, scale, block_q, block_k, interpret)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -825,13 +860,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     under shard_map, pass ``check_vma=False`` to the shard_map (the
     interpreter inlines the kernel, mixing invariant loop indices with
     varying data); the compiled TPU path needs no such escape hatch."""
-    B, S, H, D = q.shape
-    scale, block_q, block_k, interpret = _checked(
-        "flash_attention", q, k, v, scale, block_q, block_k, interpret)
-    if mask_mode is None:
-        mask_mode = MASK_CAUSAL if causal else MASK_NONE
-    out = _flash(_heads_first(q), _heads_first(k), _heads_first(v),
-                 mask_mode, scale, block_q, block_k, interpret)
+    B, S, H, _ = q.shape
+    out = flash_attention_heads_first(
+        *_all_heads_first("flash_attention", q, k, v), causal=causal,
+        mask_mode=mask_mode, scale=scale, block_q=block_q, block_k=block_k,
+        interpret=interpret)
     return out.reshape(B, H, S, v.shape[3]).transpose(0, 2, 1, 3)
 
 
@@ -850,11 +883,12 @@ def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array,
     MASK_STRICT applied on LOCAL block indices (ring hops pick the mode
     per hop from the block owner), :func:`window_mask` or
     :func:`block_diffusion_mask`."""
-    B, S, H, D = q.shape
+    B, S, H, _ = q.shape
+    heads_first = _all_heads_first("flash_attention_lse", q, k, v)
     scale, block_q, block_k, interpret = _checked(
-        "flash_attention_lse", q, k, v, scale, block_q, block_k, interpret)
-    out, lse = _flash_lse(_heads_first(q), _heads_first(k), _heads_first(v),
-                          mask_mode, scale, block_q, block_k, interpret,
-                          out_dtype)
+        "flash_attention_lse", *heads_first, scale, block_q, block_k,
+        interpret)
+    out, lse = _flash_lse(*heads_first, mask_mode, scale, block_q, block_k,
+                          interpret, out_dtype)
     return (out.reshape(B, H, S, v.shape[3]).transpose(0, 2, 1, 3),
             lse.reshape(B, H, S))

@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu.models import afmoe
+from horovod_tpu.parallel.qk_rope import rope_tables
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmarks")
@@ -221,7 +222,9 @@ def test_full_layers_get_no_positions(job, config, seeded):
     cfg = job.model_config(config)
     run = jax.tree_util.tree_map(lambda a: a[0], seeded[0]["runs"][2])
     x = jnp.asarray(np.random.RandomState(5).randn(64, 32), jnp.float32)
-    here, there = jnp.arange(64), jnp.arange(64) + 1000
+    tables = lambda positions: rope_tables(positions, cfg.head_dim,
+                                           cfg.rope_theta)
+    here, there = tables(jnp.arange(64)), tables(jnp.arange(64) + 1000)
     full = [afmoe._attention_half(cfg, False, p, x, run)
             for p in (here, there)]
     np.testing.assert_array_equal(*full)
@@ -231,7 +234,7 @@ def test_full_layers_get_no_positions(job, config, seeded):
     # rounding, and they are there: against no rotation the result differs.
     np.testing.assert_allclose(*window, rtol=1e-3, atol=1e-4)
     assert float(jnp.abs(window[0] - afmoe._attention_half(
-        cfg, True, jnp.zeros(64, jnp.int32), x, run)).max()) > 1e-3
+        cfg, True, tables(jnp.zeros(64, jnp.int32)), x, run)).max()) > 1e-3
 
 
 def test_window_layers_read_the_window_and_nothing_before_it(job, config,
@@ -244,7 +247,7 @@ def test_window_layers_read_the_window_and_nothing_before_it(job, config,
     rng = np.random.RandomState(6)
     x = jnp.asarray(rng.randn(64, 32), jnp.float32)
     y = x.at[:8].set(jnp.asarray(rng.randn(8, 32), jnp.float32))
-    positions = jnp.arange(64)
+    positions = rope_tables(jnp.arange(64), cfg.head_dim, cfg.rope_theta)
     window = [afmoe._attention_half(cfg, True, positions, v, run)
               for v in (x, y)]
     np.testing.assert_allclose(window[0][24:], window[1][24:], atol=1e-6)

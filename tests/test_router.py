@@ -361,7 +361,12 @@ class _SlowPrefillAdapter(MLPAdapter):
     """Holds each request in flight long enough for the drain tests to
     observe it."""
 
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self.entered = threading.Event()    # a request is in its prefill
+
     def prefill_chunk(self, cache, chunks, starts, tables):
+        self.entered.set()
         time.sleep(0.4)
         return super().prefill_chunk(cache, chunks, starts, tables)
 
@@ -397,7 +402,9 @@ def test_serve_server_drains_gracefully():
 
     t = threading.Thread(target=inflight, daemon=True)
     t.start()
-    time.sleep(0.15)  # request is inside the slow prefill
+    # The request is inside the slow prefill (on a loaded machine a fixed
+    # sleep let the drain begin before the request had arrived).
+    assert adapter.entered.wait(10)
     server.httpd.begin_drain()
     # New work is refused — with the drain contract's exact headers,
     # Retry-After clamped by the header budget even though no Request

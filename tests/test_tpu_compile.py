@@ -193,6 +193,55 @@ def test_latent_attention_flash_compiles_for_v5e(one_chip,
                   for line in kernels) == [(2, 2), (3, 2), (3, 3)]
 
 
+@pytest.mark.parametrize("rotate", [True, False],
+                         ids=["rotary", "no_positions"])
+def test_qk_norm_rope_into_the_flash_kernels_compiles_for_v5e(
+        one_chip, no_persistent_cache, rotate):
+    """The attention of ``sdar`` and ``trinity`` between the projections'
+    outputs and the output projection's input as their cells run it: one
+    sequence of 8,192 positions, ``[S, 32 x 128]`` and ``[S, 4 x 128]``
+    bf16 through ``qk_norm_rope`` (row blocks of ``qk_rope.ROWS``; with
+    the two rotary tables, and without as on a full layer) into
+    ``flash_attention_heads_first``, forward and backward: two calls of
+    each of the pass's kernels, one of each flash kernel, and the pass's
+    results head-major as they leave it."""
+    from horovod_tpu.parallel.flash import flash_attention_heads_first
+    from horovod_tpu.parallel.qk_rope import qk_norm_rope
+    seq = 2 * SDAR_L
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v, q_norm, k_norm, tables):
+        q = qk_norm_rope(q, q_norm, tables, SDAR_H, 1e-6, interpret=False)
+        k = qk_norm_rope(k, k_norm, tables, SDAR_HKV, 1e-6, interpret=False)
+        return flash_attention_heads_first(
+            q, k, v, causal=True, block_q=SDAR_TILE, block_k=SDAR_TILE,
+            interpret=False).astype(jnp.float32).sum()
+
+    table = sds(seq, SDAR_D, dtype=jnp.float32)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        sds(seq, SDAR_H * SDAR_D), sds(seq, SDAR_HKV * SDAR_D),
+        sds(SDAR_HKV, seq, SDAR_D), sds(SDAR_D, dtype=jnp.float32),
+        sds(SDAR_D, dtype=jnp.float32),
+        (table, table) if rotate else None).compile().as_text()
+    kernels = [line for line in text.split("\n")
+               if "tpu_custom_call" in line and " custom-call(" in line]
+    named = lambda name: [line for line in kernels
+                          if name in line.split(" = ")[0]]
+    assert len(kernels) == 7
+    assert len(named("hvd_qk_rope_fwd")) == len(named("hvd_qk_rope_bwd")) == 2
+    for heads in (SDAR_H, SDAR_HKV):
+        flat, first = (f"bf16[{seq},{heads * SDAR_D}]",
+                       f"bf16[{heads},{seq},{SDAR_D}]")
+        assert sum(line.split(" custom-call(")[0].count(first)
+                   and flat in line for line in named("hvd_qk_rope_fwd")) == 1
+        assert sum(line.split(" custom-call(")[0].count(flat)
+                   and first in line for line in named("hvd_qk_rope_bwd")) == 1
+    assert all((f"f32[{seq},{SDAR_D}]" in line) == rotate
+               for line in named("hvd_qk_rope"))
+
+
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
 @pytest.mark.parametrize("stored", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])

@@ -70,9 +70,10 @@ def compile_in_child(path, mode_name):
         argnums=(0, 1, 2))).lower(q, k, v).compile()
 
 
-def regions(path):
+def regions(path, counted=COUNTED):
     """``[(bundles, {opcode: count})]`` of the schedule's regions (between
-    the control targets the dump marks) that hold over 30 bundles."""
+    the control targets the dump marks) that hold over 30 bundles; the
+    opcodes ``counted`` finds."""
     lines = [line for line in open(path)
              if re.match(r" *(0x)?[0-9a-f]+ ", line)]
     marks = [n for n, line in enumerate(lines)
@@ -82,7 +83,7 @@ def regions(path):
         if end - start > 30:
             ops = collections.Counter(
                 re.sub(r"^v\w+\.xlane", "xlane", op)
-                for op in COUNTED.findall("".join(lines[start:end])))
+                for op in counted.findall("".join(lines[start:end])))
             found.append((end - start, dict(sorted(ops.items()))))
     return found
 

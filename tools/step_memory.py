@@ -10,7 +10,8 @@ nothing runs: ``tests/test_tpu_compile.py`` has the method).  Prints the
 TPU compiler's own total for the program (its ``memory-usage-report``, the
 number that must stay under the allocator's 15.75 GiB;
 ``compiled.memory_analysis()`` overcounts temporaries), how often XLA had to
-re-lay or recompute a value to fit (``remat_``), the Pallas calls in the
+re-lay or recompute a value to fit (``remat_``; fusions it runs a second
+time are ``<name>.remat``, listed with their shapes), the Pallas calls in the
 text, and every bf16 value shaped like a stack of the held experts'
 matrices (a copy of the float32 parameters that lives through the step).  A
 step that does not fit fails with the compiler's list of the largest
@@ -106,10 +107,17 @@ def report(args, dump):
             print("the compiler's report:", f.readline().strip())
     print("values XLA re-laid or recomputed to fit:",
           len(re.findall(r"^\s*%\S*remat_\S* = ", text, re.M)))
+    # Fusions the compiler runs a second time rather than keep their result
+    # (``<name>.remat``), with more than a vector to make.
+    again = re.findall(r"^\s*%(\S+\.remat\d*) = \(?(\w+\[\d+,\d[\d,]*\])"
+                       r".*?op_name=\"([^\"]*)\"", text, re.M)
+    print("fusions run again to fit:", len(again))
+    for name, shape, op_name in again:
+        print(f"  {name} {shape} {op_name[-70:]}")
     print("Pallas calls:", {k: len(re.findall(
         rf"custom-call\(.*{k}", text)) for k in (
             "hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv",
-            "hvd_gmm", "hvd_tgmm")})
+            "hvd_gmm", "hvd_tgmm", "hvd_qk_rope_fwd", "hvd_qk_rope_bwd")})
     z = job.sizes(config)
     held, d, f = z["held"], z["d"], z["width"]
     stacks = sorted(set(re.findall(
