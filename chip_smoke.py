@@ -118,6 +118,18 @@ JOYAI_CONFIG = "benchmarks/configs/joyai-llm-flash-ep16.json"
 JOYAI_SEQUENCES = 4     # the cell's step, which its limits were read on
 JOYAI_LOSS_TOL = 2e-2   # about ln 16,160 = 9.7; as SDAR_LOSS_TOL
 
+# -- the chunked scan of Kimi Delta Attention, alone --------------------------
+# One sequence at the kimi-linear-train-8k cell's shapes, bf16 operands at
+# the decays its seeded gates give, against the token-by-token recurrence in
+# float32 on the same (rounded) operands: the output in the largest absolute
+# difference as a share of the largest output, the five gradients in the
+# 2-norm.
+KDA_SEQ, KDA_HEADS, KDA_D = 8192, 32, 128
+KDA_SEGMENT = 256       # tokens whose states the recurrence's backward keeps
+KDA_OUT_TOL = 3e-2
+KDA_GRAD_TOL = 3e-2
+KDA_REPEATS = 5
+
 # -- trainer ---------------------------------------------------------------
 TRAINER_CMD = [sys.executable, "-m", "horovod_tpu.runner.launch", "-np", "1",
                sys.executable, "examples/synthetic_benchmark.py",
@@ -143,7 +155,7 @@ DP_LOSS_TOL = 5e-2
 # Seconds a phase may take, compilation included; the whole stays inside
 # the 1200 s the contract allows.
 LIMITS = {"kernels": 300, "sdar": 600, "afmoe": 600, "joyai": 600,
-          "tile_times": 300,
+          "kda": 300, "tile_times": 300,
           "trainer": 400, "server": 400, "dp4": 900}
 
 
@@ -572,9 +584,102 @@ def phase_joyai() -> dict:
     return device
 
 
+def phase_kda() -> dict:
+    """The scan kernels of ``parallel/kda.py`` alone at the Kimi-Linear
+    cell's shapes against the recurrence they stand for (output and all five
+    gradients), and their microseconds a chunk, forward and backward, by the
+    host's clock around ``KDA_REPEATS`` calls."""
+    device = require_platform()
+    import jax
+    import jax.numpy as jnp
+    from horovod_tpu.parallel import kda
+    seq, heads, d = KDA_SEQ, KDA_HEADS, KDA_D
+    keys = jax.random.split(jax.random.PRNGKey(SEED), 7)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    shape = (seq, heads, d)
+    q = (unit(jax.random.normal(keys[0], shape)) * d ** -0.5).astype(
+        jnp.bfloat16)
+    # Keys that share a direction, as the positions of a seeded decoder's
+    # deeper layers do (a chunk's keys 0.9 alike): the triangular system of
+    # a chunk is then far from the identity.
+    k = unit(jax.random.normal(keys[1], shape) + 3.0 * jax.random.normal(
+        jax.random.PRNGKey(SEED + 2), (1, heads, d))).astype(jnp.bfloat16)
+    v = jax.nn.silu(jax.random.normal(keys[2], shape)).astype(jnp.bfloat16)
+    # The seeded gates' range: A in [1, 16) a head, dt in [1e-3, 0.1) a
+    # channel, a unit normal in front of the softplus.
+    rate = jax.random.uniform(keys[3], (heads, 1), minval=1.0, maxval=16.0)
+    dt = jnp.exp(jax.random.uniform(keys[4], (heads, d),
+                                    minval=math.log(1e-3),
+                                    maxval=math.log(0.1)))
+    g = -rate * jax.nn.softplus(jax.random.normal(keys[5], shape)
+                                + jnp.log(jnp.expm1(dt)))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[6], (seq, heads)))
+    weight = jax.random.normal(jax.random.PRNGKey(SEED + 1), shape)
+
+    def recurrence(q, k, v, g, beta):
+        pieces = lambda x: x.reshape(seq // KDA_SEGMENT, KDA_SEGMENT,
+                                     *x.shape[1:])
+
+        @jax.checkpoint
+        def segment(state, rows):
+            o, state = kda.kda_recurrence(*rows, state=state)
+            return state, o
+
+        _, o = jax.lax.scan(
+            segment, jnp.zeros((heads, d, d), jnp.float32),
+            tuple(pieces(x) for x in (q, k, v, g, beta)))
+        return o.reshape(shape)
+
+    forward = jax.jit(kda.kda_scan)
+    # The weight is an argument: closed over, its 134 MB would be compiled
+    # into both executables.
+    both = lambda f: jax.jit(jax.value_and_grad(
+        lambda q, k, v, g, beta, weight: jnp.sum(
+            f(q, k, v, g, beta).astype(jnp.float32) * weight),
+        argnums=(0, 1, 2, 3, 4)))
+    program, plain = both(kda.kda_scan), both(recurrence)
+    args = (q, k, v, g, beta)
+    got = jax.block_until_ready(forward(*args))
+    want = jax.jit(recurrence)(*args)
+    error, size = max_abs_error(got, want)
+    print(f"kda: output off the recurrence by at most {error:.2e} of "
+          f"{size:.2e} (tolerance {KDA_OUT_TOL:g} of that; decays down to "
+          f"{float(g.min()):.1f} a step, {float(g.sum(0).min() / seq * kda.CHUNK):.1f} "
+          f"a chunk)", flush=True)
+    check(error <= KDA_OUT_TOL * size, f"kda: output off by {error}")
+    (_, grads), (_, want_grads) = program(*args, weight), plain(*args,
+                                                                weight)
+    for name, mine, theirs in zip(("q", "k", "v", "g", "beta"), grads,
+                                  want_grads):
+        mine, theirs = (x.astype(jnp.float32) for x in (mine, theirs))
+        off = float(jnp.linalg.norm(mine - theirs)
+                    / jnp.linalg.norm(theirs))
+        print(f"kda: gradient of {name} off by {off:.2e} in the 2-norm "
+              f"(tolerance {KDA_GRAD_TOL:g})", flush=True)
+        check(off <= KDA_GRAD_TOL, f"kda: gradient of {name} off by {off}")
+
+    def seconds(f, *args):
+        jax.block_until_ready(f(*args))
+        t0 = time.monotonic()
+        for _ in range(KDA_REPEATS):
+            out = f(*args)
+        jax.block_until_ready(out)
+        return (time.monotonic() - t0) / KDA_REPEATS
+
+    count = kda.chunks(seq, kda.CHUNK, heads)[1]
+    t_forward = seconds(forward, *args)
+    t_both = seconds(program, *args, weight)
+    print(f"kda: {1e6 * t_forward / count:.2f} us a chunk forward, "
+          f"{1e6 * (t_both - t_forward) / count:.2f} us a chunk backward "
+          f"({count} chunks of {kda.CHUNK} a sequence of {seq} and {heads} "
+          f"heads; {1e3 * t_forward:.1f} ms and {1e3 * t_both:.1f} ms a "
+          f"call, the second forward and backward together)", flush=True)
+    return device
+
+
 CHILD_PHASES = {"kernels": phase_kernels, "dp4": phase_dp4,
                 "sdar": phase_sdar, "afmoe": phase_afmoe,
-                "joyai": phase_joyai}
+                "joyai": phase_joyai, "kda": phase_kda}
 
 
 # ---------------------------------------------------------------------------
@@ -746,6 +851,7 @@ def main(argv=None) -> int:
             timed("sdar", child_report, "sdar")
             timed("afmoe", child_report, "afmoe")
             timed("joyai", child_report, "joyai")
+            timed("kda", child_report, "kda")
             timed("tile_times", run_child, "tile_times", AFMOE_TILE_TIMES)
             timed("trainer", run_trainer)
             timed("server", run_server)

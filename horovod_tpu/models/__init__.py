@@ -5,15 +5,18 @@ Mirrors the reference's benchmark surface (SURVEY.md §6): ResNet-50/101/152
 (keras mnist examples), and transformer families (BERT-large / GPT-2) for the
 BASELINE.json north-star configs.
 
-Three further models are plain functions over a dict of arrays, each a
-module imported by name (``from horovod_tpu.models import afmoe``):
+Four further models are plain functions over a dict of arrays, each a
+module imported by name (``from horovod_tpu.models import afmoe``) and not
+from here:
 ``sdar_moe`` (SDAR-MoE trained by block diffusion), ``afmoe`` (Trinity-Mini's
 block: window and full attention in one stack, a gated attention output, a
-sigmoid router with a shared expert, leading dense layers) and
+sigmoid router with a shared expert, leading dense layers),
 ``joyai_flash`` (JoyAI-LLM-Flash: latent attention with one rotary key
 shared by all heads, and a multi-token-prediction module on the shared
-embedding and head); the benchmark's jobs (``benchmarks/jobs/``) train all
-three.
+embedding and head) and ``kimi_linear`` (Kimi-Linear: Kimi Delta Attention,
+a gated delta rule with a decay for every channel through the chunked scan
+kernels of ``parallel/kda.py``, beside latent attention without positions);
+the benchmark's jobs (``benchmarks/jobs/``) train all four.
 """
 
 from .resnet import (  # noqa: F401

@@ -322,7 +322,7 @@ def test_serving_programs_have_names_of_their_own():
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmarks")
 MODELS = {"sdar_moe": "sdar-tiny", "afmoe": "trinity-tiny",
-          "joyai_flash": "joyai-tiny"}
+          "joyai_flash": "joyai-tiny", "kimi_linear": "kimi-tiny"}
 #: What the package traced: inside a model's loss (JAX's marker holds the
 #: outermost scope) or under any scope of ours.  The rest of a step is the
 #: caller's own code (the job's ``optax.apply_updates``, its outputs).
@@ -391,18 +391,21 @@ def _without_a_part(parts, text):
 
 def test_every_model_exports_its_parts():
     from horovod_tpu import scopes
-    from horovod_tpu.models import afmoe, joyai_flash, resnet, sdar_moe
+    from horovod_tpu.models import (afmoe, joyai_flash, kimi_linear, resnet,
+                                    sdar_moe)
     exported, collectives = scopes.exported_parts()
     assert exported[0] == "hvd::optimizer"
-    for module in (resnet, sdar_moe, afmoe, joyai_flash):
+    for module in (resnet, sdar_moe, afmoe, joyai_flash, kimi_linear):
         assert set(module.PARTS) <= set(exported), module.__name__
     assert resnet.PARTS == ("stem", "max_pool", "stage1", "stage2",
                             "stage3", "stage4", "head")
-    for module in (sdar_moe, afmoe, joyai_flash):
+    for module in (sdar_moe, afmoe, joyai_flash, kimi_linear):
         assert {"hvd::loss", "hvd::embed", "hvd::layer_loop", "hvd::moe",
                 "hvd::lm_head_loss"} <= set(module.PARTS)
         assert not [p for p in module.PARTS if "::" in p[len("hvd::"):]]
     assert "hvd::mtp" in joyai_flash.PARTS
+    assert {"hvd::kda_attention", "hvd::mla_attention",
+            "hvd::dense_mlp"} <= set(kimi_linear.PARTS)
     assert "hvd::allreduce" in collectives and len(collectives) == 7
 
 
@@ -457,6 +460,28 @@ def test_gradient_sums_and_scatter_add_carry_their_part(model_texts, parts):
     if model == "joyai_flash":
         assert any("/mtp/hvd::mtp/embed/hvd::embed/" in n for n in names)
         assert any("/hvd::mtp/hvd::layer_loop/" in n for n in names)
+    if model == "kimi_linear":
+        # Kimi Delta Attention's five spans and the latent half's three
+        # (the projections and the scan in both passes); the scan's own span
+        # holds the kernels' operations (the interpreter inlines them here)
+        # and lies inside the part.
+        for half, spans in (("kda_attention", ("project", "conv", "gates",
+                                               "scan", "out")),
+                            ("mla_attention", ("compress", "expand",
+                                               "out"))):
+            for span in spans:
+                inside = f"/hvd::{half}/hvd::{half}::{span}/"
+                assert any(inside in n for n in names), inside
+        for span in ("project", "scan"):
+            inside = f"/hvd::kda_attention/hvd::kda_attention::{span}/"
+            assert any(inside in n and "/jvp(loss)/" in n for n in names)
+            assert any(inside in n and "transpose(jvp(loss))" in n
+                       for n in names), inside
+        scanned = [n for n in names if "hvd::kda_attention::scan" in n]
+        assert all(parts.part_of(n, *exported) == "hvd::kda_attention"
+                   for n in scanned)
+        assert any("/hvd_kda_fwd" in n for n in scanned) \
+            or any("/dot_general" in n for n in scanned)
 
 
 # -- the reader of self time on a hand-built step -------------------------------

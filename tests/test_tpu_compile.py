@@ -193,6 +193,31 @@ def test_latent_attention_flash_compiles_for_v5e(one_chip,
                   for line in kernels) == [(2, 2), (3, 2), (3, 3)]
 
 
+def test_kda_scan_kernels_compile_for_v5e(one_chip, no_persistent_cache):
+    """The chunked scan of Kimi Delta Attention as the Kimi-Linear cell runs
+    it: one sequence of 8,192 positions, 32 heads of 128, chunks of 64 in
+    blocks of 512 rows, bf16 operands and float32 log-decays, forward and
+    backward: the triangular system's float32 products, the cumulative sum
+    at the highest precision, ``beta`` turned between rows and columns."""
+    from horovod_tpu.parallel.kda import kda_scan
+    shape = (2 * SDAR_L, SDAR_H, SDAR_D)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    wide = sds(shape, jnp.bfloat16)
+    text = jax.jit(jax.grad(
+        lambda *a: kda_scan(*a, interpret=False).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2, 3, 4))).lower(
+            wide, wide, wide, sds(shape, jnp.float32),
+            sds(shape[:2], jnp.float32)).compile().as_text()
+    kernels = [line for line in text.split("\n")
+               if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(kernels) == 2
+    # The forward kernel writes the state at the start of every chunk.
+    assert sum(f"f32[{SDAR_H},{2 * SDAR_L // 64},{SDAR_D},{SDAR_D}]" in line
+               for line in kernels) == 2
+    assert (2 * SDAR_L, SDAR_H, SDAR_D) == (8192, 32, 128)
+
+
 @pytest.mark.parametrize("rotate", [True, False],
                          ids=["rotary", "no_positions"])
 def test_qk_norm_rope_into_the_flash_kernels_compiles_for_v5e(
