@@ -32,6 +32,13 @@ repo's own references:
   (latent attention through the flash kernels with keys of 192 and values
   of 128, the MTP module on the shared embedding and head) against the
   plain float32 reference of ``benchmarks/jobs/joyai_flash.py``;
+* **kda** — Kimi Delta Attention alone at the Kimi-Linear cell's shapes (one
+  sequence of 8,192 positions, 32 heads of 128): the scan kernels of
+  ``parallel/kda.py`` against the token-by-token recurrence, and the passes
+  round the scan (``parallel/kda_surround.py``: the short convolutions with
+  SiLU and the L2 norms, the decay, the gated norm) against the plain
+  definitions of ``models/kimi_linear.py``, with their times: microseconds
+  a chunk, milliseconds a pass and its share of the HBM's rate;
 * **trainer** — ``horovodrun -np 1 python examples/synthetic_benchmark.py``:
   ResNet-50, 1000 classes, 224², bf16, sync-BN, batch 128, seven steps;
 * **server** — ``hvdserve --model gpt2-small`` answering ``/generate``
@@ -129,6 +136,15 @@ KDA_SEGMENT = 256       # tokens whose states the recurrence's backward keeps
 KDA_OUT_TOL = 3e-2
 KDA_GRAD_TOL = 3e-2
 KDA_REPEATS = 5
+# What surrounds the scan (``parallel/kda_surround.py``), alone at the same
+# shapes: each pass's results and gradients against the plain definitions
+# in float32 on the same (rounded) operands, in the 2-norm (a bf16 store is
+# 2e-3 of it), and its time against the bytes it has to move, every operand
+# read and every result written once: bytes an element of ``[S, P]``.
+KDA_PASS_TOL = 1e-2
+KDA_PASS_BYTES = {"front": (4 * 2 + 3 * 2 + 4, 3 * 2 + 4 + 4 * 2 + 4 * 2),
+                  "out": (2 * 2 + 2, 3 * 2 + 2 * 2)}
+HBM_BYTES_PER_S = 819e9
 
 # -- trainer ---------------------------------------------------------------
 TRAINER_CMD = [sys.executable, "-m", "horovod_tpu.runner.launch", "-np", "1",
@@ -584,11 +600,24 @@ def phase_joyai() -> dict:
     return device
 
 
+def seconds_a_call(f, *args) -> float:
+    """The host's clock around ``KDA_REPEATS`` calls of a compiled ``f``,
+    after one that compiles it."""
+    import jax
+    jax.block_until_ready(f(*args))
+    t0 = time.monotonic()
+    for _ in range(KDA_REPEATS):
+        out = f(*args)
+    jax.block_until_ready(out)
+    return (time.monotonic() - t0) / KDA_REPEATS
+
+
 def phase_kda() -> dict:
     """The scan kernels of ``parallel/kda.py`` alone at the Kimi-Linear
     cell's shapes against the recurrence they stand for (output and all five
     gradients), and their microseconds a chunk, forward and backward, by the
-    host's clock around ``KDA_REPEATS`` calls."""
+    host's clock around ``KDA_REPEATS`` calls; then what surrounds the scan
+    (:func:`kda_passes`)."""
     device = require_platform()
     import jax
     import jax.numpy as jnp
@@ -658,23 +687,105 @@ def phase_kda() -> dict:
               f"(tolerance {KDA_GRAD_TOL:g})", flush=True)
         check(off <= KDA_GRAD_TOL, f"kda: gradient of {name} off by {off}")
 
-    def seconds(f, *args):
-        jax.block_until_ready(f(*args))
-        t0 = time.monotonic()
-        for _ in range(KDA_REPEATS):
-            out = f(*args)
-        jax.block_until_ready(out)
-        return (time.monotonic() - t0) / KDA_REPEATS
-
     count = kda.chunks(seq, kda.CHUNK, heads)[1]
-    t_forward = seconds(forward, *args)
-    t_both = seconds(program, *args, weight)
+    t_forward = seconds_a_call(forward, *args)
+    t_both = seconds_a_call(program, *args, weight)
     print(f"kda: {1e6 * t_forward / count:.2f} us a chunk forward, "
           f"{1e6 * (t_both - t_forward) / count:.2f} us a chunk backward "
           f"({count} chunks of {kda.CHUNK} a sequence of {seq} and {heads} "
           f"heads; {1e3 * t_forward:.1f} ms and {1e3 * t_both:.1f} ms a "
           f"call, the second forward and backward together)", flush=True)
+    kda_passes(seq, heads, d)
     return device
+
+
+def kda_passes(seq, heads, d) -> None:
+    """The passes in front of the scan (three convolutions with SiLU, two
+    L2 norms, the decay) and behind it (the gated norm) at one (layer,
+    sequence) of the cell: held to ``models/kimi_linear.py``'s plain
+    definitions, and their milliseconds forward and backward against the
+    bytes they move."""
+    import jax
+    import jax.numpy as jnp
+    from horovod_tpu.models import kimi_linear
+    from horovod_tpu.models.sdar_moe import rms_norm
+    from horovod_tpu.parallel import kda_surround as surround
+    wide, f32 = heads * d, jnp.float32
+    keys = iter(jax.random.split(jax.random.PRNGKey(SEED + 3), 24))
+    rows = lambda dtype=jnp.bfloat16, scale=1.0: (scale * jax.random.normal(
+        next(keys), (seq, wide))).astype(dtype)
+    by_head = lambda t: t.reshape(seq, heads, d)
+    flat = lambda t: t.reshape(seq, wide)
+    units = (d ** -0.5, 1.0, None)
+
+    def plain_front(xq, xk, xv, xd, wq, wk, wv, dt_bias, a_log):
+        made = []
+        for x, w, unit in zip((xq, xk, xv), (wq, wk, wv), units):
+            a = kimi_linear.short_conv(x.astype(f32), w)
+            made.append(a if unit is None else flat(
+                kimi_linear._unit(by_head(a), 1e-6) * unit))
+        return (*made, flat(-jnp.exp(a_log)[None, :, None] * by_head(
+            jax.nn.softplus(xd.astype(f32) + dt_bias))))
+
+    def front(xq, xk, xv, xd, wq, wk, wv, dt_bias, a_log):
+        return (*(surround.short_conv_silu(x, w, heads, unit, 1e-6)
+                  for x, w, unit in zip((xq, xk, xv), (wq, wk, wv), units)),
+                surround.decay(xd, dt_bias, a_log, heads))
+
+    def plain_out(o, gate, weight):
+        return flat(rms_norm(by_head(o.astype(f32)), weight, 1e-5)) \
+            * jax.nn.sigmoid(gate.astype(f32))
+
+    def out(o, gate, weight):
+        return (surround.gated_norm(o, gate, weight, heads, 1e-5),)
+
+    # The seeded gates' range, as the scan's operands above.
+    dt = jnp.exp(jax.random.uniform(next(keys), (wide,), minval=math.log(
+        1e-3), maxval=math.log(0.1)))
+    passes = {
+        "front": (front, plain_front, (
+            rows(), rows(), rows(), rows(),
+            *(0.5 * jax.random.normal(next(keys), (wide, 4))
+              for _ in range(3)), jnp.log(jnp.expm1(dt)),
+            jnp.log(jax.random.uniform(next(keys), (heads,), minval=1.0,
+                                       maxval=16.0))),
+            (rows(), rows(), rows(), rows(f32)),
+            ("q", "k", "v", "g", "x_q", "x_k", "x_v", "x_decay", "conv_q",
+             "conv_k", "conv_v", "dt_bias", "A_log")),
+        "out": (out, lambda *a: (plain_out(*a),), (
+            rows(scale=0.1), rows(scale=2.0),
+            1 + 0.2 * jax.random.normal(next(keys), (d,))), (rows(),),
+            ("y", "o", "gate", "o_norm"))}
+    # Operands and cotangents are arguments: closed over, their 67 to 134
+    # MB each would be compiled into the executables.
+    both = lambda f: jax.jit(lambda args, cts: (
+        lambda made, vjp: (*made, *vjp(cts)))(*jax.vjp(f, *args)))
+    for which, (fast, plain, args, cotangents, names) in passes.items():
+        got = both(fast)(args, cotangents)
+        want = both(plain)(args, tuple(c.astype(f32) for c in cotangents))
+        for name, mine, theirs in zip(names, got, want):
+            mine, theirs = mine.astype(f32), theirs.astype(f32)
+            check(bool(jnp.isfinite(mine).all()),
+                  f"kda: non-finite {name} from the {which} pass")
+            off = float(jnp.linalg.norm(mine - theirs)
+                        / jnp.linalg.norm(theirs))
+            print(f"kda: {which} pass, {name} off its definition by "
+                  f"{off:.2e} in the 2-norm (tolerance {KDA_PASS_TOL:g})",
+                  flush=True)
+            check(off <= KDA_PASS_TOL, f"kda: {which} pass: {name} off by "
+                                       f"{off}")
+        del got, want
+        # The backward kernels alone: nothing reads the forward's results.
+        times = (seconds_a_call(jax.jit(fast), *args),
+                 seconds_a_call(jax.jit(lambda args, cts: jax.vjp(
+                     fast, *args)[1](cts)), args, cotangents))
+        print(f"kda: the {which} pass " + ", ".join(
+            f"{1e3 * t:.3f} ms {way} ({size * seq * wide / 1e6:.0f} MB: "
+            f"{100 * size * seq * wide / t / HBM_BYTES_PER_S:.1f} % of "
+            f"{HBM_BYTES_PER_S / 1e9:.0f} GB/s)" for way, t, size in zip(
+                ("forward", "backward"), times, KDA_PASS_BYTES[which]))
+              + f" a (layer, sequence) of {seq} x {wide}, row blocks of "
+              f"{surround.ROWS}", flush=True)
 
 
 CHILD_PHASES = {"kernels": phase_kernels, "dp4": phase_dp4,

@@ -73,8 +73,12 @@ where there is one, ``e_score_correction_bias [experts]``; ``final_norm
 [hidden]``; ``head [hidden, vocab]`` (``benchmarks/jobs/kimi_linear.py:
 seeded_params`` makes one).  The step names itself for the device trace
 (``docs/timeline.md``): under ``decoder`` ``hvd::kda_attention``
-(``::project``, ``::conv``, ``::gates``, ``::scan`` around the Pallas calls
-and nothing else, ``::out`` inside it) or ``hvd::mla_attention``
+(``::project`` the products; ``::conv`` the three passes of
+``parallel/kda_surround.py: short_conv_silu``, each a convolution, SiLU and
+for ``q`` and ``k`` the L2 norm, and the sums of the convolutions'
+weight gradients; ``::gates`` the decay's pass and ``beta``; ``::scan``
+around the scan's Pallas calls and nothing else; ``::out`` the gated
+norm's pass and ``W_o``) or ``hvd::mla_attention``
 (``::compress``, ``::expand``, ``::out``), then ``hvd::dense_mlp`` or
 ``hvd::moe`` (``::shared``, ``::route``, ``::experts``, ``::combine``), and
 under ``head`` ``hvd::lm_head_loss``.
@@ -156,7 +160,9 @@ def layer_runs(cfg: KimiLinearConfig):
 def short_conv(x, weight):
     """Causal depthwise convolution of ``x [S, P]`` with ``weight [P,
     width]`` from a zero history, ``y_t = sum_j weight[:, j] x_{t - (width
-    - 1) + j}``, then SiLU; float32 inside, ``x``'s dtype out."""
+    - 1) + j}``, then SiLU; float32 inside, ``x``'s dtype out.  With
+    :func:`_unit` the plain definition that the tests hold
+    ``kda_surround.short_conv_silu`` to; the model runs the pass."""
     seq, width = x.shape[0], weight.shape[1]
     padded = jnp.pad(x.astype(jnp.float32), ((width - 1, 0), (0, 0)))
     y = sum(padded[j:j + seq] * weight[:, j] for j in range(width))
@@ -173,7 +179,11 @@ def kda_operands(cfg: KimiLinearConfig, a, p):
     """``(q, k, v [S, H, d], g [S, H, d] float32, beta [S, H] float32,
     the output gate's pre-activation [S, P])`` of one sequence's normed
     input ``a [S, hidden]``: what the scan takes, and what waits for its
-    output."""
+    output.  Between the projections and the scan every operand is one
+    pass of ``parallel/kda_surround.py`` over ``[S, P]``, where a head is a
+    block of lanes: the views by head on the way out cost nothing, and
+    ``kda_scan`` takes them back."""
+    from ..parallel import kda_surround as surround
     seq, _ = a.shape
     dtype, heads, d = cfg.dtype, cfg.kda_num_heads, cfg.kda_head_dim
     dot = lambda t, w: jnp.dot(t, w.astype(dtype))
@@ -184,21 +194,21 @@ def kda_operands(cfg: KimiLinearConfig, a, p):
         gate = dot(dot(a, p["w_ga"]), p["w_gb"])
         write = dot(a, p["w_b"])
     with _scopes.scope("hvd::kda_attention::conv"):
-        q, k, v = (short_conv(t, p[w]) for t, w in (
-            (q, "conv_q"), (k, "conv_k"), (v, "conv_v")))
+        q, k, v = (surround.short_conv_silu(t, p[w], heads, unit,
+                                            cfg.l2_norm_eps)
+                   for t, w, unit in ((q, "conv_q", d ** -0.5),
+                                      (k, "conv_k", 1.0),
+                                      (v, "conv_v", None)))
     with _scopes.scope("hvd::kda_attention::gates"):
-        q = (_unit(by_head(q), cfg.l2_norm_eps) * d ** -0.5).astype(dtype)
-        k = _unit(by_head(k), cfg.l2_norm_eps).astype(dtype)
-        g = -jnp.exp(p["A_log"].astype(jnp.float32))[None, :, None] \
-            * by_head(jax.nn.softplus(decay.astype(jnp.float32)
-                                      + p["dt_bias"]))
+        g = surround.decay(decay, p["dt_bias"], p["A_log"], heads)
         beta = jax.nn.sigmoid(write.astype(jnp.float32))
-    return q, k, by_head(v), g, beta, gate
+    return by_head(q), by_head(k), by_head(v), by_head(g), beta, gate
 
 
 def _kda_half(cfg: KimiLinearConfig, x, p):
     """``h = x + y W_o`` of one sequence ``x [S, hidden]`` through Kimi
     Delta Attention."""
+    from ..parallel import kda_surround as surround
     from ..parallel.kda import kda_scan
     seq, _ = x.shape
     with _scopes.scope("hvd::kda_attention"):
@@ -207,11 +217,9 @@ def _kda_half(cfg: KimiLinearConfig, x, p):
         with _scopes.scope("hvd::kda_attention::scan"):
             o = kda_scan(q, k, v, g, beta, chunk=cfg.kda_chunk)
         with _scopes.scope("hvd::kda_attention::out"):
-            y = rms_norm(o, p["o_norm"], cfg.rms_norm_eps).reshape(seq, -1) \
-                .astype(jnp.float32) * jax.nn.sigmoid(
-                    gate.astype(jnp.float32))
-            return x + jnp.dot(y.astype(cfg.dtype),
-                               p["w_o"].astype(cfg.dtype))
+            y = surround.gated_norm(o.reshape(seq, -1), gate, p["o_norm"],
+                                    cfg.kda_num_heads, cfg.rms_norm_eps)
+            return x + jnp.dot(y, p["w_o"].astype(cfg.dtype))
 
 
 def _mla_half(cfg: KimiLinearConfig, x, p):

@@ -218,6 +218,50 @@ def test_kda_scan_kernels_compile_for_v5e(one_chip, no_persistent_cache):
     assert (2 * SDAR_L, SDAR_H, SDAR_D) == (8192, 32, 128)
 
 
+def test_kda_surround_passes_compile_for_v5e(one_chip, no_persistent_cache):
+    """What surrounds the scan of Kimi Delta Attention as the Kimi-Linear
+    cell runs it: one sequence of 8,192 positions, ``[S, 32 x 128]`` bf16,
+    row blocks of ``kda_surround.ROWS`` with their halo blocks: the three
+    convolutions with SiLU (two of them with the L2 norm), the decay in
+    float32 and the gated norm, forward and backward: ten kernels, every
+    one on ``[S, P]`` as the projections write it, no view by head."""
+    from horovod_tpu.parallel import kda_surround as surround
+    seq, heads, d = 2 * SDAR_L, SDAR_H, SDAR_D
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(xq, xk, xv, xd, o, gate, wq, wk, wv, dt_bias, a_log, o_norm):
+        made = [surround.short_conv_silu(x, w, heads, unit, 1e-6,
+                                         interpret=False)
+                for x, w, unit in ((xq, wq, d ** -0.5), (xk, wk, 1.0),
+                                   (xv, wv, None))]
+        made.append(surround.decay(xd, dt_bias, a_log, heads,
+                                   interpret=False))
+        made.append(surround.gated_norm(o, gate, o_norm, heads, 1e-5,
+                                        interpret=False))
+        return sum(t.astype(jnp.float32).sum() for t in made)
+
+    wide, f32 = sds(seq, heads * d), jnp.float32
+    text = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(12)))).lower(
+        *[wide] * 6, *[sds(heads * d, 4, dtype=f32)] * 3,
+        sds(heads * d, dtype=f32), sds(heads, dtype=f32),
+        sds(d, dtype=f32)).compile().as_text()
+    kernels = [line for line in text.split("\n")
+               if "tpu_custom_call" in line and " custom-call(" in line]
+    named = lambda name: [line for line in kernels
+                          if name in line.split(" = ")[0]]
+    assert len(kernels) == 10
+    assert len(named("hvd_kda_conv_fwd")) == len(named("hvd_kda_conv_bwd")) == 3
+    for name in ("hvd_kda_decay_fwd", "hvd_kda_decay_bwd", "hvd_kda_out_fwd",
+                 "hvd_kda_out_bwd"):
+        assert len(named(name)) == 1
+    assert f"f32[{seq},{heads * d}]" in named("hvd_kda_decay_fwd")[0].split(
+        " custom-call(")[0]
+    assert f"[{seq},{heads},{d}]" not in text
+    assert (seq, heads, d) == (8192, 32, 128)
+
+
 @pytest.mark.parametrize("rotate", [True, False],
                          ids=["rotary", "no_positions"])
 def test_qk_norm_rope_into_the_flash_kernels_compiles_for_v5e(

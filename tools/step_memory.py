@@ -118,7 +118,9 @@ def report(args, dump):
         rf"custom-call\(.*{k}", text)) for k in (
             "hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv",
             "hvd_gmm", "hvd_tgmm", "hvd_qk_rope_fwd", "hvd_qk_rope_bwd",
-            "hvd_kda_fwd", "hvd_kda_bwd")})
+            "hvd_kda_fwd", "hvd_kda_bwd", "hvd_kda_conv_fwd",
+            "hvd_kda_conv_bwd", "hvd_kda_decay_fwd", "hvd_kda_decay_bwd",
+            "hvd_kda_out_fwd", "hvd_kda_out_bwd")})
     z = job.sizes(config)
     held, d, f = z["held"], z["d"], z["width"]
     stacks = sorted(set(re.findall(
