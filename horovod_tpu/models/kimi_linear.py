@@ -98,6 +98,23 @@ from .afmoe import gated_mlp
 from .sdar_moe import Aux, embed, head_loss, rms_norm, through_layers
 
 KDA, MLA = "kda", "mla"
+#: KDA layers, counted from 1 as ``kda_layers`` counts them, whose scan
+#: keeps its output and the chunks' starting states (``kda.SAVED``; the
+#: states are 268 MB a (layer, sequence) at 8,192 positions) across the
+#: recomputation, so that its forward kernel runs once; a run of layers
+#: (``layer_runs``) that holds one is kept whole, and a KDA layer not kept
+#: runs its forward kernel again in the backward pass.  As many as the step
+#: has room for.  The training step of one chip's share of a
+#: thirty-two-way expert split (published layers 1-5), by the TPU
+#: compiler's report for a v5e (``tools/step_memory.py``, of 15.75 GiB):
+#: 12.20 GiB with none kept; 13.43 with layers 1 and 5 (two runs of one
+#: layer), nothing re-laid or run again to fit; layers 2 and 3, one run and
+#: one scan over both, do not fit (15.77 GiB: 24 MB over), nor do all four.
+#: On the TPU a recomputation differs from its forward pass in the last
+#: bits, so a kept layer's backward pass, which reads the forward pass's
+#: own output, does not give the bits of one that keeps nothing: the
+#: gradients come out nearer the float32 reference (``PERF.md``, section 6).
+KEPT_KDA = (1, 5)
 
 #: The model's parts as they appear in an ``op_name`` (``scopes.py``).
 PARTS = ("hvd::loss", "hvd::embed", "hvd::layer_loop", "hvd::kda_attention",
@@ -276,20 +293,26 @@ def _expert_half(cfg: KimiLinearConfig, h, p):
         return h + f.astype(h.dtype), (moe.routed_here, moe.chosen)
 
 
-def _layer(cfg: KimiLinearConfig, mixer: str, dense: bool):
+def _layer(cfg: KimiLinearConfig, mixer: str, dense: bool, layers):
     """One layer of a kind over one sequence, ``(x [S, hidden], p) -> (x,
-    aux)``, under its ``jax.checkpoint``.  A latent layer keeps its flash
-    output and logsumexp across the recomputation (68 MB a sequence); a KDA
-    layer keeps nothing: its forward kernel runs again in the backward pass
-    and writes the chunks' states (268 MB a sequence at 8,192 positions)
-    for the backward kernel there."""
-    from ..parallel.flash import SAVED
+    aux)``, under its ``jax.checkpoint``; ``layers`` are the run's, counted
+    from 1.  A latent layer keeps its flash output and logsumexp across the
+    recomputation (68 MB a sequence).  A KDA layer of a run that holds one
+    of ``KEPT_KDA`` keeps its scan's output and the chunks' states (268 MB
+    a sequence at 8,192 positions), which the backward kernel and the
+    gated norm's backward pass read; any other KDA layer keeps nothing: its
+    forward kernel runs again in the backward pass and writes them there."""
+    from ..parallel import flash, kda
     mix = _kda_half if mixer == KDA else _mla_half
     mlp = _dense_half if dense else _expert_half
+    if mixer == MLA:
+        kept = flash.SAVED
+    else:
+        kept = kda.SAVED if set(layers) & set(KEPT_KDA) else ()
     return jax.checkpoint(
         lambda x, p: mlp(cfg, mix(cfg, x, p), p),
-        policy=jax.checkpoint_policies.save_only_these_names(*SAVED)
-        if mixer == MLA else None)
+        policy=jax.checkpoint_policies.save_only_these_names(*kept)
+        if kept else None)
 
 
 def hidden_states(params: dict, tokens, cfg: KimiLinearConfig):
@@ -299,10 +322,14 @@ def hidden_states(params: dict, tokens, cfg: KimiLinearConfig):
     batch, seq = tokens.shape
     x = embed(params, tokens, cfg.dtype)
     routed_here, chosen = [], []
+    first = 1
     with _scopes.scope("decoder"):
-        for (mixer, dense, _), stacked in zip(layer_runs(cfg),
-                                              params["runs"]):
-            x, aux = through_layers(_layer(cfg, mixer, dense), x, stacked)
+        for (mixer, dense, count), stacked in zip(layer_runs(cfg),
+                                                  params["runs"]):
+            layers = range(first, first + count)
+            first += count
+            x, aux = through_layers(_layer(cfg, mixer, dense, layers), x,
+                                    stacked)
             if not dense:
                 routed_here.append(aux[0].sum(axis=1))
                 chosen.append(aux[1].reshape(aux[1].shape[0], batch * seq,

@@ -51,7 +51,8 @@ counted from the chunk's start and ``e(i, j) = exp(G_i - G_j)`` a channel:
   lane block), walks their chunks with the state, transposed (values by
   keys, so that the decay scales its lanes), in VMEM scratch, and writes
   ``o`` and the state at the start of every chunk (``[heads, S / C, d,
-  d]`` float32: the residual the backward pass reads).
+  d]`` float32: the residual the backward pass reads), both named
+  (``SAVED``) for a caller's ``jax.checkpoint`` policy to keep.
 * **Backward** (``hvd_kda_bwd``) — the same grid walked in reverse: a chunk
   recomputes its ``G, M, P, T, w, u, u'`` from its operands and its saved
   starting state, takes ``dO`` and the gradient of the state it hands on,
@@ -86,6 +87,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -94,6 +96,7 @@ from .flash import LANES, _col_to_row, _out_struct, _row_to_lanes
 CHUNK = 64      # positions a chunk, a power of two
 BLOCK = 512     # rows a grid step
 UNROLL = 8      # chunks of a block the compiler schedules as one
+SAVED = ("hvd_kda_out", "hvd_kda_states")  # checkpoint names, _kda_fwd
 
 
 def kda_recurrence(q, k, v, g, beta, state=None):
@@ -413,6 +416,12 @@ def _kda_fwd(q, k, v, g, beta, chunk, block, interpret):
         interpret=interpret,
         name="hvd_kda_fwd",
     )(q, k, v, g, beta)
+    # Named for ``jax.checkpoint`` policies: a caller that recomputes a
+    # layer in its backward pass can keep these two (``save_only_these_
+    # names(*SAVED)``: ``o`` for what reads it there, the states for the
+    # backward kernel) and spare the forward kernel's second run.
+    o = checkpoint_name(o, SAVED[0])
+    saved = checkpoint_name(saved, SAVED[1])
     return o, (q, k, v, g, beta, saved)
 
 

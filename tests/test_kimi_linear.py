@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu.models import kimi_linear
+from horovod_tpu.parallel import flash, kda
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmarks")
@@ -187,6 +188,102 @@ def test_reference_by_layers_equals_reference_whole(job, config, both,
                                               imposed=chosen)
     assert again == loss
     assert max(job.gradient_errors(same, grads).values()) == 0.0
+
+
+@pytest.fixture(scope="module")
+def kept_and_not(job, config):
+    """``{kept KDA layers: (loss, gradients)}`` of the jitted step with the
+    tiny stack's layer 1 kept (the default: its layer 2 is not) and with
+    none, at one KDA head of 128 lanes: the width at which the passes round
+    the scan are the Pallas kernels the cell runs.  Below it they are plain
+    ``jnp``, and XLA's CPU backend fuses the L2 norm of ``q`` and ``k``
+    differently in the forward pass and in the recomputation, so those two
+    differ in their last bits between the passes whatever is kept."""
+    assert 1 in kimi_linear.KEPT_KDA and 2 not in kimi_linear.KEPT_KDA
+    wide = json.loads(json.dumps(config))
+    wide["linear_attn_config"].update(head_dim=128, num_heads=1)
+    cfg = job.model_config(wide)
+    params = job.seeded_params(wide, SEED)
+    batch = job.seeded_batch(wide, SEED, BATCH)
+    step = jax.value_and_grad(
+        lambda p: kimi_linear.loss_fn(p, *batch, cfg)[0])
+    found = {kimi_linear.KEPT_KDA: jax.jit(step)(params)}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(kimi_linear, "KEPT_KDA", ())
+        found[()] = jax.jit(step)(params)
+    return found
+
+
+@pytest.mark.parametrize("leaf", ["loss"] + LEAVES)
+def test_keeping_the_scans_residuals_changes_no_bit(kept_and_not, leaf):
+    """The kept output and states are the forward kernel's own: where the
+    recomputation repeats the forward pass bit for bit, as XLA's CPU
+    backend does here, the loss and every gradient leaf are the same bits
+    with and without them."""
+    (loss, grads), (loss_none, grads_none) = kept_and_not.values()
+    if leaf == "loss":
+        assert np.asarray(loss).tobytes() == np.asarray(loss_none).tobytes()
+    else:
+        assert float(jnp.abs(leaves(grads)[leaf]).max()) > 0
+        np.testing.assert_array_equal(leaves(grads)[leaf],
+                                      leaves(grads_none)[leaf])
+
+
+def _one_layer(cfg, seeded, run, layers):
+    """``(the layer's function, its input x [S, hidden], its parameters)``
+    for the first layer of run ``run`` of the tiny stack."""
+    mixer, dense, _ = kimi_linear.layer_runs(cfg)[run]
+    layer = kimi_linear._layer(cfg, mixer, dense, layers)
+    p = jax.tree.map(lambda a: a[0], seeded[0]["runs"][run])
+    x = jax.random.normal(jax.random.PRNGKey(3),
+                          (seeded[1][0].shape[1], cfg.hidden_size))
+    return lambda x, p: jnp.sum(layer(x, p)[0]), x.astype(cfg.dtype), p
+
+
+def _kernel_calls(jaxpr, name):
+    from horovod_tpu.analysis.jaxpr_check import _sub_jaxprs
+    calls = 0
+    for eqn in jaxpr.eqns:
+        calls += eqn.primitive.name == "pallas_call" \
+            and eqn.params.get("name") == name
+        calls += sum(_kernel_calls(getattr(sub, "jaxpr", sub), name)
+                     for sub in _sub_jaxprs(eqn))
+    return calls
+
+
+@pytest.mark.parametrize("layers,forward", [(range(1, 2), 1),
+                                            (range(2, 3), 2)],
+                         ids=["kept", "not_kept"])
+def test_a_kept_layer_runs_the_forward_scan_kernel_once(cfg, seeded, layers,
+                                                        forward):
+    """``jax.grad`` of a KDA layer's body holds one call of ``hvd_kda_fwd``
+    where the layer is kept and two (the forward pass and the
+    recomputation) where it is not; the backward kernel once either way."""
+    f, x, p = _one_layer(cfg, seeded, 0, layers)
+    jaxpr = jax.make_jaxpr(jax.grad(f))(x, p).jaxpr
+    assert _kernel_calls(jaxpr, "hvd_kda_fwd") == forward
+    assert _kernel_calls(jaxpr, "hvd_kda_bwd") == 1
+
+
+@pytest.mark.parametrize("run,layers,names", [
+    (0, range(1, 2), ("hvd_kda_states",)), (1, range(2, 3), ()),
+    (2, range(3, 4), ("hvd_flash_lse",))], ids=["kda_kept", "kda", "mla"])
+def test_each_layer_keeps_its_own_kernels_residuals_only(cfg, seeded, capsys,
+                                                         run, layers, names):
+    """What a layer hands its backward pass beyond its arguments: a kept
+    KDA layer its scan's output and states, another KDA layer nothing, a
+    latent layer its flash output and logsumexp.  A ``custom_vjp``'s first
+    output shows as the ``reduce_precision`` that wraps it, so the names
+    seen are the second outputs'."""
+    f, x, p = _one_layer(cfg, seeded, run, layers)
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(f, x, p)
+    kept = [line for line in capsys.readouterr().out.splitlines()
+            if "from the argument" not in line]
+    assert len(kept) == 2 * len(names)
+    seen = {name for line in kept
+            for name in kda.SAVED + flash.SAVED if f"'{name}'" in line}
+    assert seen == set(names)
 
 
 def test_the_recurrence_is_the_delta_rule_token_by_token(job):
