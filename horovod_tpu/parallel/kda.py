@@ -34,17 +34,20 @@ counted from the chunk's start and ``e(i, j) = exp(G_i - G_j)`` a channel:
   the level's product ``[2C, d] x [d, C]`` is kept where row and column lie
   in one block.  Every pair ``j < i`` is parted at exactly one of the
   ``log2 C`` levels; ``M``'s diagonal is ``q_i . k_i``.  All exponents, and
-  ``G`` itself, are ONE product of a 0/1 matrix with ``g`` at the highest
-  precision (``_sums``); ``exp G`` and ``exp(G_C - G)`` are at or under 1
-  by themselves.
+  ``G`` itself, are ONE product of a 0/1 matrix with ``g`` (``_sums``);
+  ``exp G`` and ``exp(G_C - G)`` are at or under 1 by themselves.
 * **The triangular system on the MXU, by blocks.**  ``A`` is strictly lower
   triangular; ``(I + A)^-1`` is built from single rows up, ``[[T11, 0],
   [-T22 A21 T11, T22]]`` a level, ``log2 C`` levels of two ``C x C``
   products (``_tri_inverse`` says why not the nilpotent product).
 * **Precision.**  ``G``, the state, ``A``, ``T``, ``w`` and ``u`` are
-  float32 (the cumulative sum is a product with a triangle of ones at the
-  highest precision); every other product takes its operands in the dtype
-  of ``q`` (bf16) and accumulates in float32.
+  float32.  The 0/1 matrix of the exponents is bf16, which holds it
+  exactly, and its products (``g`` forward, the exponents' gradient
+  backward) are three bf16 passes, one for each of the float32 operand's
+  exact bf16 pieces (``_exact_sums_product``): float32's precision at half
+  the passes ``Precision.HIGHEST`` takes, which splits both operands.
+  Every other product takes its operands in the dtype of ``q`` (bf16) and
+  accumulates in float32.
 * **Forward** (``hvd_kda_fwd``) — grid ``(heads, S / block)``, the second
   dimension sequential: a grid step holds ``block`` rows of one head's 128
   lanes of ``q, k, v, g [S, heads x d]`` (no head-major copy: a head is a
@@ -65,10 +68,11 @@ counted from the chunk's start and ``e(i, j) = exp(G_i - G_j)`` a channel:
   and the compiler schedules ``UNROLL`` of them as one block of
   instructions: a chunk is a chain of some thirty dependent products, and
   most of the next chunk's (its decays, pairs and triangular system need no
-  state) fills the gaps.  On the v5e a chunk alone takes 2.80 us forward
-  and 3.55 backward a loop iteration a chunk, 2.58 and 3.41 with all 8
-  unrolled (``chip_smoke.py --phase kda``, PR 37; blocks of 256 or 1,024
-  rows read the same within 2 %).
+  state) fills the gaps.  On the v5e a chunk alone takes 2.33 us forward
+  and 3.38 backward with all 8 unrolled (``chip_smoke.py --phase kda``;
+  2.58 and 3.66 with the 0/1 matrix's products at ``Precision.HIGHEST``,
+  2.80 and 3.55 at one chunk a loop iteration before that; blocks of 256
+  or 1,024 rows read the same within 2 %).
 * ``beta`` and ``dbeta`` go in and out as rows ``[heads, 1, S]`` and are
   turned to columns a block at a time, as ``flash.py`` keeps its
   logsumexp.
@@ -144,12 +148,12 @@ def _block_rows(seq, chunk, block):
 
 # -- one chunk -----------------------------------------------------------------
 
-def _dot(a, b, contract=(1, 0), dtype=None, precision=None):
+def _dot(a, b, contract=(1, 0), dtype=None):
     if dtype is not None:
         a, b = a.astype(dtype), b.astype(dtype)
     return lax.dot_general(
         a, b, (((contract[0],), (contract[1],)), ((), ())),
-        preferred_element_type=jnp.float32, precision=precision)
+        preferred_element_type=jnp.float32)
 
 
 _NT, _TN = (1, 1), (0, 0)   # a b^T, a^T b
@@ -194,13 +198,34 @@ def _sums(size):
     reference row ``m``, the last row of the lower half of the block of
     ``2 s`` rows that holds ``i``: ``G_i - G_m`` (``m < t <= i``) for a row
     of the upper half, ``G_m - G_i`` (``i < t <= m``) for one of the lower.
-    Sums of ``g <= 0`` only: no exponent is above 0."""
+    Sums of ``g <= 0`` only: no exponent is above 0.  In bf16, which holds
+    0 and 1 exactly: its products with float32 operands are three passes
+    of their exact pieces (:func:`_exact_sums_product`)."""
     i, t = _iota((size, size), 0), _iota((size, size), 1)
     blocks = [t <= i]
     for s in _levels(size):
         m = i - (i & (2 * s - 1)) + (s - 1)
         blocks.append((t > jnp.minimum(i, m)) & (t <= jnp.maximum(i, m)))
-    return jnp.concatenate([b.astype(jnp.float32) for b in blocks], 0)
+    return jnp.concatenate([b.astype(jnp.bfloat16) for b in blocks], 0)
+
+
+def _split(x):
+    """``(hi, mid, lo)``, three bf16 pieces of the float32 ``x`` whose sum
+    is ``x`` exactly: each takes the leading 8 bits of what the ones before
+    left, and 3 x 8 bits hold float32's 24."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    return hi, mid, (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+def _exact_sums_product(sums, x, contract=(1, 0)):
+    """The product of the 0/1 matrix ``sums`` (bf16, which holds it
+    exactly) with the float32 ``x`` to float32's precision: three bf16
+    passes, one a piece of ``x`` (:func:`_split`), summed smallest first.
+    ``Precision.HIGHEST`` would split both operands and run six."""
+    hi, mid, lo = (_dot(sums, piece, contract) for piece in _split(x))
+    return hi + (mid + lo)
 
 
 def _chunk_parts(q, k, v, g, beta, state, dtype):
@@ -215,7 +240,7 @@ def _chunk_parts(q, k, v, g, beta, state, dtype):
     cols = _iota((2 * size, size), 1)
     qf, kf, vf = q.astype(f32), k.astype(f32), v.astype(f32)
     sums = _sums(size)
-    exponents = _dot(sums, g, precision=lax.Precision.HIGHEST)
+    exponents = _exact_sums_product(sums, g)
     cumulative = exponents[:size]
     total = cumulative[size - 1][None, :]
     z = dict(qf=qf, kf=kf, vf=vf, sums=sums, levels=[],
@@ -297,8 +322,7 @@ def _chunk_backward(q, k, v, g, beta, state, do, dstate, dtype):
         dq = dq + dsides[:size]
         dk = dk + dsides[size:] + dkeys
         dexponents.append(qf * dsides[:size] + kf * (dsides[size:] + dkeys))
-    dg = _dot(z["sums"], jnp.concatenate(dexponents, 0), _TN,
-              precision=lax.Precision.HIGHEST)
+    dg = _exact_sums_product(z["sums"], jnp.concatenate(dexponents, 0), _TN)
     return dq, dk, beta * dbu, dg, dbeta, dstate0
 
 

@@ -182,6 +182,79 @@ def test_a_term_of_the_delta_rule_left_out_is_seen():
     assert off(o, plain) > 1e-2
 
 
+@pytest.mark.parametrize("sign", [1.0, -1.0], ids=["positive", "negative"])
+@pytest.mark.parametrize("magnitude", [1e-6, 1e-3, 1.0, 1e2, 1e4])
+def test_the_three_bf16_pieces_of_a_float32_sum_to_it_exactly(magnitude,
+                                                             sign):
+    """``hi + mid + lo == x`` bit for bit, eagerly and jitted (a compiler
+    that took ``x - f32(bf16(x))`` for 0 would leave one piece of 8 bits),
+    for every mantissa over a decade of magnitudes."""
+    x = sign * magnitude * jax.random.uniform(
+        jax.random.PRNGKey(int(np.log10(magnitude)) + 7), (64, 128),
+        minval=1.0, maxval=10.0)
+    for split in (kda._split, jax.jit(kda._split)):
+        pieces = split(x)
+        assert all(p.dtype == jnp.bfloat16 for p in pieces)
+        hi, mid, lo = (np.asarray(p, np.float32) for p in pieces)
+        np.testing.assert_array_equal(hi + (mid + lo), np.asarray(x))
+        assert np.abs(mid).max() > 0
+
+
+@pytest.mark.parametrize("orientation", ["S_g", "St_d"])
+@pytest.mark.parametrize("decay", [1e-3, 1.0, 25.0, 100.0])
+def test_the_three_pass_product_of_the_sums_is_float32s(decay, orientation):
+    """The exponents ``S g`` (forward) and their gradient ``S^T d``
+    (backward) in three bf16 passes against the float64 product: within 8
+    float32 ulps of the sum of the terms' magnitudes (of the result itself
+    where all terms share a sign, as the decays' do)."""
+    sums = kda._sums(CHUNK)
+    assert sums.dtype == jnp.bfloat16
+    exact = np.asarray(sums, np.float64)
+    seed = int(decay * 1000) % 97
+    if orientation == "S_g":
+        x = operands(CHUNK, seed=seed, decay=decay, heads=1, d=128)[3][:, 0]
+        contract = (1, 0)
+    else:
+        x = decay * jax.random.normal(jax.random.PRNGKey(seed),
+                                      (sums.shape[0], 128))
+        contract, exact = (0, 0), exact.T
+    got = jax.jit(kda._exact_sums_product, static_argnums=2)(sums, x,
+                                                             contract)
+    assert got.dtype == jnp.float32
+    x64 = np.asarray(x, np.float64)
+    want, scale = exact @ x64, exact @ np.abs(x64)
+    # Rows of no term (a row against itself as reference) are exactly 0.
+    assert np.all(np.abs(np.asarray(got, np.float64) - want)
+                  <= 8 * np.finfo(np.float32).eps * scale)
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_no_product_of_a_chunk_runs_at_the_highest_precision(direction):
+    """The 0/1 matrix's products are three bf16 passes each (one in the
+    forward chunk; the same again and its transpose in the backward one),
+    and no product of a chunk asks for ``Precision.HIGHEST``: that stays in
+    the recurrence, the definition."""
+    q, k, v, g, b = (x[:, 0] for x in operands(CHUNK, heads=1, d=128))
+    state = jnp.zeros((128, 128), jnp.float32)
+    if direction == "forward":
+        run = lambda: kda._chunk_forward(q, k, v, g, b[:, None], state,
+                                         q.dtype)
+    else:
+        run = lambda: kda._chunk_backward(q, k, v, g, b[:, None], state, q,
+                                          state, q.dtype)
+    dots = [e for e in jax.make_jaxpr(run)().eqns
+            if e.primitive.name == "dot_general"]
+    assert dots and all(e.params["precision"] is None for e in dots)
+    rows = kda._sums(CHUNK).shape
+    of_sums = [e for e in dots if e.invars[0].aval.shape == rows]
+    assert len(of_sums) == (3 if direction == "forward" else 6)
+    assert all(v.aval.dtype == jnp.bfloat16
+               for e in of_sums for v in e.invars)
+    recurrence = jax.make_jaxpr(kda.kda_recurrence)(
+        *(x[:2] for x in operands(CHUNK)))
+    assert "HIGHEST" in str(recurrence)
+
+
 def test_chunks_counts_grid_steps_and_chunks():
     # The cell: 8,192 positions, 32 heads, chunks of 64 in blocks of 512.
     assert kda.chunks(8192, 64, 32) == (32 * 16, 32 * 128)
