@@ -34,7 +34,8 @@ repo's own references:
   plain float32 reference of ``benchmarks/jobs/joyai_flash.py``;
 * **kda** — Kimi Delta Attention alone at the Kimi-Linear cell's shapes (one
   sequence of 8,192 positions, 32 heads of 128): the scan kernels of
-  ``parallel/kda.py`` against the token-by-token recurrence, and the passes
+  ``parallel/kda.py`` against the token-by-token recurrence, their 0/1
+  matrix's products against float64 in float32 ulps, and the passes
   round the scan (``parallel/kda_surround.py``: the short convolutions with
   SiLU and the L2 norms, the decay, the gated norm) against the plain
   definitions of ``models/kimi_linear.py``, with their times: microseconds
@@ -143,6 +144,9 @@ KDA_SEGMENT = 256       # tokens whose states the recurrence's backward keeps
 KDA_OUT_TOL = 3e-2
 KDA_GRAD_TOL = 3e-2
 KDA_REPEATS = 5
+# The 0/1 matrix's products inside the kernels, in float32 ulps of the sum of
+# their terms' magnitudes against float64 (the tests' bound on the CPU).
+KDA_SUMS_ULPS = 8
 # What surrounds the scan (``parallel/kda_surround.py``), alone at the same
 # shapes: each pass's results and gradients against the plain definitions
 # in float32 on the same (rounded) operands, in the 2-norm (a bf16 store is
@@ -700,6 +704,7 @@ def phase_kda() -> dict:
         print(f"kda: gradient of {name} off by {off:.2e} in the 2-norm "
               f"(tolerance {KDA_GRAD_TOL:g})", flush=True)
         check(off <= KDA_GRAD_TOL, f"kda: gradient of {name} off by {off}")
+    kda_sums_products(g)
 
     count = kda.chunks(seq, kda.CHUNK, heads)[1]
     t_forward = seconds_a_call(forward, *args)
@@ -711,6 +716,78 @@ def phase_kda() -> dict:
           f"call, the second forward and backward together)", flush=True)
     kda_passes(seq, heads, d)
     return device
+
+
+def kda_sums_products(g, interpret=False) -> None:
+    """The 0/1 matrix's two products in the scan kernels
+    (``kda._exact_sums_product``: the exponents ``S g`` forward, their
+    gradient ``S^T d`` backward) in a Pallas kernel, a chunk of the steepest
+    head's decays ``g`` a grid step, against float64: the largest error in
+    float32 ulps of the sum of the terms' magnitudes, beside that of
+    ``Precision.HIGHEST`` in the same kernel (the six passes the three stand
+    for).  With a piece of the float32 operand lost it reads some 2**6."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from horovod_tpu.parallel import kda
+    size, d = kda.CHUNK, g.shape[-1]
+    sums = kda._sums(size)
+    rows = sums.shape[0]
+    x = g[:, int(jnp.argmin(g.sum((0, 2))))]
+    chunks = x.shape[0] // size
+    dx = jax.random.normal(jax.random.PRNGKey(SEED + 3), (chunks * rows, d))
+
+    def highest(a, b, contract):
+        return lax.dot_general(
+            a.astype(jnp.float32), b,
+            (((contract[0],), (contract[1],)), ((), ())),
+            precision=lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+
+    def kernel(s_ref, x_ref, dx_ref, fwd_ref, bwd_ref):
+        s, x, dx = s_ref[...], x_ref[...], dx_ref[...]
+        fwd_ref[0] = kda._exact_sums_product(s, x)
+        fwd_ref[1] = highest(s, x, (1, 0))
+        bwd_ref[0] = kda._exact_sums_product(s, dx, kda._TN)
+        bwd_ref[1] = highest(s, dx, kda._TN)
+
+    fwd, bwd = pl.pallas_call(
+        kernel, grid=(chunks,),
+        in_specs=[pl.BlockSpec((rows, size), lambda i: (0, 0)),
+                  pl.BlockSpec((size, d), lambda i: (i, 0)),
+                  pl.BlockSpec((rows, d), lambda i: (i, 0))],
+        out_specs=[pl.BlockSpec((2, rows, d), lambda i: (0, i, 0)),
+                   pl.BlockSpec((2, size, d), lambda i: (0, i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((2, chunks * rows, d), jnp.float32),
+                   jax.ShapeDtypeStruct((2, chunks * size, d),
+                                        jnp.float32)],
+        interpret=interpret)(sums, x, dx)
+    s64 = np.asarray(sums, np.float64)
+    x64 = np.asarray(x, np.float64).reshape(chunks, size, d)
+    dx64 = np.asarray(dx, np.float64).reshape(chunks, rows, d)
+
+    def ulps(got, spec, *operands):
+        got = np.asarray(got, np.float64).reshape(2, chunks, -1, d)
+        want = np.einsum(spec, s64, *operands)
+        scale = np.einsum(spec, s64, *map(np.abs, operands))
+        off = np.abs(got - want)
+        # Rows of no term are exactly 0: any error there is unbounded.
+        off = np.divide(off, scale, out=np.where(off > 0, np.inf, 0.0),
+                        where=scale > 0)
+        return off.max(axis=(1, 2, 3)) / np.finfo(np.float32).eps
+
+    (fwd_ulps, fwd_highest), (bwd_ulps, bwd_highest) = (
+        ulps(fwd, "rt,ctd->crd", x64), ulps(bwd, "rt,crd->ctd", dx64))
+    print(f"kda: the 0/1 matrix's products off float64 by at most "
+          f"{fwd_ulps:.2f} / {bwd_ulps:.2f} float32 ulps of their terms' "
+          f"magnitudes, forward / backward, over {chunks} chunks "
+          f"(Precision.HIGHEST in the same kernel: {fwd_highest:.2f} / "
+          f"{bwd_highest:.2f}; tolerance {KDA_SUMS_ULPS:g})", flush=True)
+    check(max(fwd_ulps, bwd_ulps) <= KDA_SUMS_ULPS,
+          f"kda: the 0/1 matrix's products off by {fwd_ulps} / {bwd_ulps} "
+          "ulps")
 
 
 def kda_passes(seq, heads, d) -> None:

@@ -3,12 +3,15 @@
 The script's value is that a pass means the chip ran the system.  On the
 CPU these tests hold it to the other half of that contract — a platform
 other than ``tpu`` is a failure, not a fallback — and pin the output of
-the example that the trainer phase parses.
+the example that the trainer phase parses, and run the checks that need
+no chip in Pallas's interpreter.
 """
 
 import os
 import subprocess
 import sys
+
+import pytest
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -41,3 +44,28 @@ def test_trainer_example_prints_what_chip_smoke_parses():
         r"Loss after warm-up: (\S+), after 5 more steps: (\S+)",
         r.stdout).groups()
     assert float(warm) > float(final) > 0  # seven SGD steps on one batch
+
+
+@pytest.mark.parametrize("pieces", [3, 2])
+def test_the_kda_products_check_passes_float32s_and_refuses_a_lost_piece(
+        pieces, monkeypatch):
+    """``kda_sums_products`` (``--phase kda``'s hold of the 0/1 matrix's
+    products on float64, in float32 ulps) in the interpreter at a toy size:
+    the kernels' three pieces of the float32 operand pass, two (its last 8
+    bits lost) do not."""
+    import jax
+    import jax.numpy as jnp
+
+    import chip_smoke
+    from horovod_tpu.parallel import kda
+    if pieces == 2:
+        split = kda._split
+        monkeypatch.setattr(kda, "_split", lambda x: (
+            *split(x)[:2], jnp.zeros(x.shape, jnp.bfloat16)))
+    g = -16.0 * jax.nn.softplus(jax.random.normal(
+        jax.random.PRNGKey(0), (4 * kda.CHUNK, 2, 128)))
+    if pieces == 3:
+        chip_smoke.kda_sums_products(g, interpret=True)
+    else:
+        with pytest.raises(chip_smoke.SmokeFailure, match="ulps"):
+            chip_smoke.kda_sums_products(g, interpret=True)

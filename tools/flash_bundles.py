@@ -44,20 +44,28 @@ COUNTED = re.compile(  # the lane units' pushes, not their ``vpop``s
     r"= (vst|vld|vmatmul|vmatpush|vpow2|vperm|v(?:max|add)\.xlane)")
 
 
-def compile_in_child(path, mode_name):
-    """Compile forward and backward through the copy of ``flash.py`` at
-    ``path`` under ``mode_name``, for the described chip."""
+def described_chip():
+    """One chip of a described v5e as a sharding, for a child process that
+    compiles for it with JAX held to the CPU (no chip, no compile cache)."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     os.environ["JAX_PLATFORMS"] = "cpu"
+    sys.path.insert(0, ROOT)
     import jax
-    import jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     jax.config.update("jax_enable_compilation_cache", False)
+    return SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
+
+
+def compile_in_child(path, mode_name):
+    """Compile forward and backward through the copy of ``flash.py`` at
+    ``path`` under ``mode_name``, for the described chip."""
+    chip = described_chip()
+    import jax
+    import jax.numpy as jnp
     flash = sizes.load(path)
     mode = sizes.mask_modes(flash)[mode_name]
-    chip = SingleDeviceSharding(topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2").devices[0])
     q, k, v = (jax.ShapeDtypeStruct((1, sizes.SEQ, heads, width),
                                     jnp.bfloat16, sharding=chip)
                for heads, width in ((sizes.HEADS, sizes.HEAD_DIM),
@@ -88,23 +96,31 @@ def regions(path, counted=COUNTED):
     return found
 
 
-def schedules_of(path, mode):
-    """``{kernel: regions}`` of the three kernels compiled through the copy
-    of ``flash.py`` at ``path`` under ``mode``; empty where the compiler
-    wrote no schedule."""
+def dump_schedules(prefix, argv, counted=COUNTED):
+    """``{kernel: regions}`` of the kernels named ``prefix...`` that the
+    child ``argv`` (a script and its arguments, run by this interpreter)
+    compiles, read from the final schedules the TPU compiler dumps; empty
+    where it wrote none.  The compiler aborts after the dump, so the
+    child's exit code is not read."""
     with tempfile.TemporaryDirectory() as dump:
         subprocess.run(
-            [sys.executable, __file__, "--child", path, mode, "--widths",
-             str(sizes.HEAD_DIM), str(sizes.V_DIM), "--kv-heads",
-             str(sizes.KV_HEADS)],
+            [sys.executable] + argv,
             env=dict(os.environ, LIBTPU_INIT_ARGS=(
                 f"--xla_jf_dump_to={dump} --xla_jf_dump_llo_text=true"
                 " --xla_jf_dump_llo_pass_label_regex=final_bundles")),
             capture_output=True)
-        return {re.search(r"hvd_flash_\w+?(?=_*\.)", name).group(0):
-                regions(name)
+        return {re.search(prefix + r"\w+?(?=_*\.)", name).group(0):
+                regions(name, counted)
                 for name in glob.glob(os.path.join(
-                    dump, "*hvd_flash*-final_bundles.txt"))}
+                    dump, f"*{prefix}*-final_bundles.txt"))}
+
+
+def schedules_of(path, mode):
+    """``{kernel: regions}`` of the three kernels compiled through the copy
+    of ``flash.py`` at ``path`` under ``mode``."""
+    return dump_schedules("hvd_flash_", [
+        __file__, "--child", path, mode, "--widths", str(sizes.HEAD_DIM),
+        str(sizes.V_DIM), "--kv-heads", str(sizes.KV_HEADS)])
 
 
 def steps(found):

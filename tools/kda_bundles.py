@@ -1,16 +1,17 @@
 #!/usr/bin/env python3
 """The KDA scan kernels' instruction schedules, counted without the chip.
 
-    python3 tools/kda_bundles.py [--against other/kda.py ...] [--seq 8192] [--heads 32]
+    python3 tools/kda_bundles.py [--against other/kda.py ...]
 
 Compiles ``kda.kda_scan`` forward and backward over one sequence at the
 Kimi-Linear cell's sizes (8,192 positions, 32 heads of 128, bf16 ``q, k,
 v``, float32 ``g`` and ``beta``) for a *described* v5e (no chip; the method
-of ``tools/flash_bundles.py``, whose reader of the compiler's final
-schedules this takes) through this tree's ``parallel/kda.py`` and every
-``--against`` copy, and prints, for each copy, the regions over 30 bundles
-of ``hvd_kda_fwd`` and ``hvd_kda_bwd`` with what they hold (loads, stores,
-MXU pushes, exponents), then one line a kernel: its unrolled loop of
+of ``tools/flash_bundles.py``, whose child process and reader of the
+compiler's final schedules this takes) through this tree's
+``parallel/kda.py`` and every ``--against`` copy, and prints, for each
+copy, the regions over 30 bundles of ``hvd_kda_fwd`` and ``hvd_kda_bwd``
+with what they hold (loads, stores, MXU pushes, exponents), then one line
+a kernel: its unrolled loop of
 ``kda.UNROLL`` chunks (the region that pushes most to the MXU), in bundles,
 ``vmatmul``, ``vld``, ``vst`` and ``vpow2``, and bundles a chunk, for every
 copy side by side.
@@ -19,23 +20,21 @@ A count, not a time: it orders variants of a chunk's chain of products
 before a chip call is spent on them; a bundle issues in a cycle at best
 (1.5 GHz), the scan kernels measure 1.7 to 2.6 cycles a bundle in the cell,
 and bundles a variant takes out come back at under a cycle each, since the
-chain's stalls grow into what they leave (PERF.md section 6).  The compiler
-aborts after the dump, so each compile runs in a child process whose exit
-code is not read.
+chain's stalls grow into what they leave (PERF.md section 6).  The counted
+loop is one grid step's: the number of heads and a sequence of whole
+blocks do not change it.
 """
 
 import argparse
-import glob
 import importlib.util
 import os
 import re
-import subprocess
 import sys
-import tempfile
 
 import flash_bundles  # beside this file: the schedules' reader
 
 ROOT = flash_bundles.ROOT
+SEQ, HEADS, HEAD_DIM = 8192, 32, 128
 COUNTED = re.compile(r"= (vmatmul|vld|vst|vpow2)\b")
 COLUMNS = ("vmatmul", "vld", "vst", "vpow2")
 
@@ -52,46 +51,22 @@ def load(path):
     return module
 
 
-def compile_in_child(path, seq, heads):
+def compile_in_child(path):
     """Compile forward and backward through the copy of ``kda.py`` at
     ``path`` for the described chip."""
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    sys.path.insert(0, ROOT)
+    chip = flash_bundles.described_chip()
     import jax
     import jax.numpy as jnp
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-    jax.config.update("jax_enable_compilation_cache", False)
     kda = load(path)
-    chip = SingleDeviceSharding(topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2").devices[0])
     shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                        sharding=chip)
-    rows = shaped((seq, heads, 128), jnp.bfloat16)
+    rows = shaped((SEQ, HEADS, HEAD_DIM), jnp.bfloat16)
     jax.jit(jax.grad(
         lambda q, k, v, g, beta: kda.kda_scan(
             q, k, v, g, beta, interpret=False).astype(jnp.float32).sum(),
         argnums=(0, 1, 2, 3, 4))).lower(
-        rows, rows, rows, shaped((seq, heads, 128), jnp.float32),
-        shaped((seq, heads), jnp.float32)).compile()
-
-
-def schedules_of(path, seq, heads):
-    """``{kernel: regions}`` of the two kernels compiled through the copy of
-    ``kda.py`` at ``path``; empty where the compiler wrote no schedule."""
-    with tempfile.TemporaryDirectory() as dump:
-        subprocess.run(
-            [sys.executable, __file__, "--child", path, "--seq", str(seq),
-             "--heads", str(heads)],
-            env=dict(os.environ, LIBTPU_INIT_ARGS=(
-                f"--xla_jf_dump_to={dump} --xla_jf_dump_llo_text=true"
-                " --xla_jf_dump_llo_pass_label_regex=final_bundles")),
-            capture_output=True)
-        return {re.search(r"hvd_kda_\w+?(?=_*\.)", name).group(0):
-                flash_bundles.regions(name, COUNTED)
-                for name in glob.glob(os.path.join(
-                    dump, "*hvd_kda*-final_bundles.txt"))}
+        rows, rows, rows, shaped((SEQ, HEADS, HEAD_DIM), jnp.float32),
+        shaped((SEQ, HEADS), jnp.float32)).compile()
 
 
 def loop(parts):
@@ -103,18 +78,16 @@ def loop(parts):
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--against", nargs="*", default=[])
-    ap.add_argument("--seq", type=int, default=8192)
-    ap.add_argument("--heads", type=int, default=32)
     ap.add_argument("--child", help=argparse.SUPPRESS)
     args = ap.parse_args()
     if args.child:
-        return compile_in_child(args.child, args.seq, args.heads)
+        return compile_in_child(args.child)
     sys.path.insert(0, ROOT)
     from horovod_tpu.parallel import kda
     paths = [os.path.join(ROOT, "horovod_tpu", "parallel", "kda.py")] \
         + args.against
-    found = {path: schedules_of(path, args.seq, args.heads)
-             for path in paths}
+    found = {path: flash_bundles.dump_schedules(
+        "hvd_kda_", [__file__, "--child", path], COUNTED) for path in paths}
     for path, kernels in found.items():
         print(os.path.relpath(path, ROOT)
               + ("" if kernels else ": no schedule was written"))

@@ -23,16 +23,10 @@ grid step costs about 0.35 us whatever it holds (PERF.md section 6).
 """
 
 import argparse
-import glob
-import os
 import re
-import subprocess
-import sys
-import tempfile
 
 import flash_bundles  # beside this file: the schedules' reader
 
-ROOT = flash_bundles.ROOT
 SEQ, HEAD_DIM = 8192, 128
 COUNTED = re.compile(
     r"= (vst|vld|vrsqrt|vrot|vperm|v(?:max|add)\.xlane)")
@@ -40,18 +34,11 @@ COUNTED = re.compile(
 
 def compile_in_child(rows, heads, tables_dtype, plain):
     """Compile the pass forward and backward for the described chip."""
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    sys.path.insert(0, ROOT)
+    chip = flash_bundles.described_chip()
     import jax
     import jax.numpy as jnp
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-    jax.config.update("jax_enable_compilation_cache", False)
     from horovod_tpu.parallel import qk_rope
     qk_rope.ROWS = rows
-    chip = SingleDeviceSharding(topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2").devices[0])
     shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                        sharding=chip)
     table = shaped((SEQ, HEAD_DIM), jnp.dtype(tables_dtype))
@@ -65,21 +52,11 @@ def compile_in_child(rows, heads, tables_dtype, plain):
 
 
 def schedules_of(rows, heads, tables_dtype, plain):
-    """``{kernel: regions}`` of the two kernels; empty where the compiler
-    wrote no schedule."""
-    with tempfile.TemporaryDirectory() as dump:
-        subprocess.run(
-            [sys.executable, __file__, "--child", "--rows", str(rows),
-             "--heads", str(heads), "--tables", tables_dtype]
-            + ["--plain"] * plain,
-            env=dict(os.environ, LIBTPU_INIT_ARGS=(
-                f"--xla_jf_dump_to={dump} --xla_jf_dump_llo_text=true"
-                " --xla_jf_dump_llo_pass_label_regex=final_bundles")),
-            capture_output=True)
-        return {re.search(r"hvd_qk_rope_\w+?(?=_*\.)", name).group(0):
-                flash_bundles.regions(name, COUNTED)
-                for name in glob.glob(os.path.join(
-                    dump, "*hvd_qk_rope*-final_bundles.txt"))}
+    """``{kernel: regions}`` of the two kernels."""
+    return flash_bundles.dump_schedules(
+        "hvd_qk_rope_", [__file__, "--child", "--rows", str(rows), "--heads",
+                         str(heads), "--tables", tables_dtype]
+        + ["--plain"] * plain, COUNTED)
 
 
 def main():
