@@ -10,28 +10,11 @@ repo's own references:
 
 * **kernels** — the four Pallas kernels compiled for the chip
   (``interpret=False``) against their dense / gather references;
-* **sdar** — SDAR-30B-A3B-Chat's block-diffusion step at the benchmark
-  cell's sizes (``benchmarks/configs/sdar-30b-a3b-ep8.json``, 4 sequences of
-  4,096 clean tokens): loss and every gradient leaf of
-  ``models/sdar_moe.py`` (flash kernels under the block-diffusion mask,
-  the dropless expert layer) against the plain float32 reference of
-  ``benchmarks/jobs/sdar_moe.py``;
-* **afmoe** — Trinity-Mini's next-token step at the benchmark cell's sizes
-  (``benchmarks/configs/trinity-mini-ep8.json``, 4 sequences of 8,192
-  tokens): loss
-  and every gradient leaf of ``models/afmoe.py`` (window and full attention
-  in one stack, the gated attention output, the sigmoid router with a
-  shared expert, a leading dense layer) against the plain float32 reference
-  of ``benchmarks/jobs/afmoe.py``; then the three flash kernels' time a tile
-  under the window, ``MASK_CAUSAL`` and ``MASK_NONE``
-  (``tools/flash_tile_times.py``);
-* **joyai** — JoyAI-LLM-Flash's step (next-token loss plus the depth-1
-  multi-token-prediction loss) at the benchmark cell's sizes
-  (``benchmarks/configs/joyai-llm-flash-ep16.json``, 4 sequences of 8,192
-  tokens): both losses and every gradient leaf of ``models/joyai_flash.py``
-  (latent attention through the flash kernels with keys of 192 and values
-  of 128, the MTP module on the shared embedding and head) against the
-  plain float32 reference of ``benchmarks/jobs/joyai_flash.py``;
+* **sdar** — the dropless expert layer (``parallel/moe.py``) alone on one
+  sequence of the SDAR cell (``benchmarks/configs/sdar-30b-a3b-ep8.json``),
+  forward and every gradient against every held expert on every position,
+  once at about the even load and once under routing skewed past the
+  buffer, which no cell reaches;
 * **kda** — Kimi Delta Attention alone at the Kimi-Linear cell's shapes (one
   sequence of 8,192 positions, 32 heads of 128): the scan kernels of
   ``parallel/kda.py`` against the token-by-token recurrence, their 0/1
@@ -47,6 +30,8 @@ repo's own references:
   microseconds a block forward and backward at row blocks of 512, 1,024
   and 2,048 beside XLA's fusion of the plain formulas, and their share of
   the HBM's rate;
+* **tile_times** — the three flash kernels' time a tile under Trinity-Mini's
+  window, ``MASK_CAUSAL`` and ``MASK_NONE`` (``tools/flash_tile_times.py``);
 * **trainer** — ``horovodrun -np 1 python examples/synthetic_benchmark.py``:
   ResNet-50, 1000 classes, 224², bf16, sync-BN, batch 128, seven steps;
 * **server** — ``hvdserve --model gpt2-small`` answering ``/generate``
@@ -99,16 +84,8 @@ SERVE_BATCH, TABLE_BLOCKS, PREFILL_CHUNK = 8, 16, 64
 # at the highest matmul precision.
 MXU_BF16_TOL = 2.0 ** -7
 
-# -- block diffusion -------------------------------------------------------
+# -- the dropless expert layer ----------------------------------------------
 SDAR_CONFIG = "benchmarks/configs/sdar-30b-a3b-ep8.json"
-SDAR_SEQUENCES = 4      # the cell's step
-# The program computes in bf16 on float32 parameters, the reference in
-# float32 at the highest matmul precision.  Loss (about ln 18,992 = 9.85):
-# absolute; measured 3.1e-4.  Gradients: |got - want| / |want| in the
-# 2-norm by leaf, each held to the limit the cell's own check has for it
-# (``correct.gradient_limits`` of the configuration, where the readings
-# behind each limit are given).
-SDAR_LOSS_TOL = 2e-2
 # The expert layer alone, a sequence of the cell (8,192 positions) under
 # independent seeded router columns: once as they fall (about the even
 # load: one buffer) and once with the held experts' columns moved up by
@@ -119,19 +96,11 @@ SDAR_LOSS_TOL = 2e-2
 SDAR_SKEW = 1.0
 SDAR_LAYER_TOL = 3e-2
 
-# -- window and full attention, a shared expert ------------------------------
-AFMOE_CONFIG = "benchmarks/configs/trinity-mini-ep8.json"
-AFMOE_SEQUENCES = 4     # the cell's step, which its limits were read on
-AFMOE_LOSS_TOL = 2e-2   # about ln 25,024 = 10.1; as SDAR_LOSS_TOL
+# -- the flash kernels' time a tile, Trinity-Mini's masks --------------------
 # The three flash kernels alone under the window, the causal mask and no
-# mask (us a tile, from a device trace): run after the phase, by the parent.
+# mask (us a tile, from a device trace), a child of the parent.
 AFMOE_TILE_TIMES = [sys.executable, "tools/flash_tile_times.py", "--masks",
                     "window", "causal", "none"]
-
-# -- latent attention, a multi-token-prediction module -----------------------
-JOYAI_CONFIG = "benchmarks/configs/joyai-llm-flash-ep16.json"
-JOYAI_SEQUENCES = 4     # the cell's step, which its limits were read on
-JOYAI_LOSS_TOL = 2e-2   # about ln 16,160 = 9.7; as SDAR_LOSS_TOL
 
 # -- the chunked scan of Kimi Delta Attention, alone --------------------------
 # One sequence at the kimi-linear-train-8k cell's shapes, bf16 operands at
@@ -188,7 +157,7 @@ DP_LOSS_TOL = 5e-2
 
 # Seconds a phase may take, compilation included; the whole stays inside
 # the 1200 s the contract allows.
-LIMITS = {"kernels": 300, "sdar": 600, "afmoe": 600, "joyai": 600,
+LIMITS = {"kernels": 300, "sdar": 600,
           "kda": 300, "gated_conv": 300, "tile_times": 300,
           "trainer": 400, "server": 400, "dp4": 900}
 
@@ -398,14 +367,13 @@ def phase_dp4() -> dict:
 
 
 def phase_sdar() -> dict:
-    """Loss and gradients of the block-diffusion step at the published
-    widths and the cell's sizes, program against reference."""
+    """The dropless expert layer on one sequence of the SDAR cell, at about
+    the even load and under skewed routing.  The whole step's loss and
+    gradients against the reference are the cell's own check
+    (``python3 benchmarks/run.py --workload sdar30b-train-blockdiff-4k``)."""
     device = require_platform()
     import importlib.util
-    import jax
-    import numpy as np
     import horovod_tpu as hvd
-    from horovod_tpu.models import sdar_moe
     hvd.init()  # the compile cache
     spec = importlib.util.spec_from_file_location(
         "sdar_job", os.path.join(REPO, "benchmarks/jobs/sdar_moe.py"))
@@ -413,29 +381,6 @@ def phase_sdar() -> dict:
     spec.loader.exec_module(job)
     with open(os.path.join(REPO, SDAR_CONFIG)) as f:
         config = json.load(f)
-    cfg = job.model_config(config)
-    params = job.seeded_params(config, SEED)
-    batch = job.seeded_batch(config, SEED, SDAR_SEQUENCES)
-    t0 = time.monotonic()
-    (loss, aux), grads = jax.jit(jax.value_and_grad(
-        lambda p, *b: sdar_moe.loss_fn(p, *b, cfg), has_aux=True))(
-            params, *batch)
-    loss = float(loss)
-    print(f"sdar: program loss {loss:.6f}, pairs routed to the held "
-          f"experts by layer {np.asarray(aux.routed_here).tolist()} "
-          f"({time.monotonic() - t0:.0f} s)", flush=True)
-    t0 = time.monotonic()
-    want_loss, want, chosen = job.ReferenceSteps(
-        config, SDAR_SEQUENCES).loss_and_grads(job.unstacked(params), *batch)
-    print(f"sdar: reference loss {want_loss:.6f} "
-          f"({time.monotonic() - t0:.0f} s); "
-          f"{100 * job.choices_that_differ(aux.chosen, chosen):.3f} % of the "
-          f"program's routing choices are not the reference's", flush=True)
-    check(math.isfinite(loss) and abs(loss - want_loss) <= SDAR_LOSS_TOL,
-          f"sdar: loss {loss} against the reference's {want_loss}")
-    outside = job.leaves_outside(config, job.gradient_errors(grads, want))
-    check(not outside, f"sdar: gradients out of their limits: {outside}")
-    del params, grads, want
     for skew in (0.0, SDAR_SKEW):
         sdar_expert_layer(job, config, skew)
     return device
@@ -510,112 +455,6 @@ def sdar_expert_layer(job, config: dict, skew: float) -> None:
           f"w_up, w_down: {[f'{e:.2e}' for e in errors]}", flush=True)
     check(bool(np.isfinite(errors).all()) and max(errors) <= SDAR_LAYER_TOL,
           f"sdar: expert layer with held columns up by {skew}: {errors}")
-
-
-def phase_afmoe() -> dict:
-    """Loss and every gradient leaf of the Trinity-Mini cell's step at the
-    published widths, program against reference, each leaf held to the
-    limit the cell's own check has for it."""
-    device = require_platform()
-    import jax
-    import numpy as np
-    import horovod_tpu as hvd
-    from horovod_tpu.models import afmoe
-    hvd.init()  # the compile cache
-    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
-    from harness import manifest as mf
-    job = mf.load_module("jobs", "afmoe")
-    with open(os.path.join(REPO, AFMOE_CONFIG)) as f:
-        config = json.load(f)
-    cfg = job.model_config(config)
-    params = job.seeded_params(config, SEED)
-    batch = job.seeded_batch(config, SEED, AFMOE_SEQUENCES)
-    t0 = time.monotonic()
-    (loss, aux), grads = jax.jit(jax.value_and_grad(
-        lambda p, *b: afmoe.loss_fn(p, *b, cfg), has_aux=True))(
-            params, *batch)
-    loss = float(loss)
-    # To the host, and the stacked tree gone, before the reference's own
-    # 5.6 GB of parameters and gradients.
-    grads, chosen = jax.tree_util.tree_map(np.asarray, (grads, aux.chosen))
-    print(f"afmoe: program loss {loss:.6f}, pairs routed to the held "
-          f"experts by expert layer {np.asarray(aux.routed_here).tolist()} "
-          f"({time.monotonic() - t0:.0f} s)", flush=True)
-    layers = job.unstacked(params)
-    del params, aux
-    t0 = time.monotonic()
-    # As the cell's check: the reference follows the program's choices, and
-    # those are held to its own by their own limit.
-    want_loss, want, want_chosen = job.ReferenceSteps(
-        config, AFMOE_SEQUENCES).loss_and_grads(layers, *batch,
-                                                imposed=chosen)
-    differ = job.choices_that_differ(chosen, want_chosen)
-    print(f"afmoe: reference loss {want_loss:.6f} "
-          f"({time.monotonic() - t0:.0f} s); {100 * differ:.3f} % of "
-          f"the program's routing choices are not the reference's",
-          flush=True)
-    check(math.isfinite(loss) and abs(loss - want_loss) <= AFMOE_LOSS_TOL,
-          f"afmoe: loss {loss} against the reference's {want_loss}")
-    check(differ <= config["correct"]["choices_limit"],
-          f"afmoe: {differ} of the routing choices are not the reference's")
-    outside = job.leaves_outside(config, job.gradient_errors(grads, want))
-    check(not outside, f"afmoe: gradients out of their limits: {outside}")
-    return device
-
-
-def phase_joyai() -> dict:
-    """Both losses and every gradient leaf of the JoyAI-LLM-Flash cell's
-    step at the published widths, program against reference, each leaf held
-    to the limit the cell's own check has for it."""
-    device = require_platform()
-    import jax
-    import numpy as np
-    import horovod_tpu as hvd
-    from horovod_tpu.models import joyai_flash
-    hvd.init()  # the compile cache
-    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
-    from harness import manifest as mf
-    job = mf.load_module("jobs", "joyai_flash")
-    with open(os.path.join(REPO, JOYAI_CONFIG)) as f:
-        config = json.load(f)
-    cfg = job.model_config(config)
-    params = job.seeded_params(config, SEED)
-    batch = job.seeded_batch(config, SEED, JOYAI_SEQUENCES)
-    t0 = time.monotonic()
-    (_, (aux, *got)), grads = jax.jit(jax.value_and_grad(
-        lambda p, *b: joyai_flash.loss_fn(p, *b, cfg), has_aux=True))(
-            params, *batch)
-    got = [float(x) for x in got]
-    # To the host, and the stacked tree gone, before the reference's own
-    # 5.4 GB of parameters and gradients.
-    grads, chosen = jax.tree_util.tree_map(np.asarray, (grads, aux.chosen))
-    print(f"joyai: program L_main {got[0]:.6f} L_mtp {got[1]:.6f}, pairs "
-          f"routed to the held experts by expert layer, the MTP block last, "
-          f"{np.asarray(aux.routed_here).tolist()} "
-          f"({time.monotonic() - t0:.0f} s)", flush=True)
-    layers = job.unstacked(params)
-    del params, aux
-    t0 = time.monotonic()
-    # As the cell's check: the reference follows the program's choices, and
-    # those are held to its own by their own limit.
-    want, want_grads, want_chosen = job.ReferenceSteps(
-        config, JOYAI_SEQUENCES).loss_and_grads(layers, *batch,
-                                                imposed=chosen)
-    want = [want[0], want[1] / cfg.mtp_loss_weight]
-    differ = job.choices_that_differ(chosen, want_chosen)
-    print(f"joyai: reference L_main {want[0]:.6f} L_mtp {want[1]:.6f} "
-          f"({time.monotonic() - t0:.0f} s); {100 * differ:.3f} % of "
-          f"the program's routing choices are not the reference's",
-          flush=True)
-    check(all(math.isfinite(mine) and abs(mine - theirs) <= JOYAI_LOSS_TOL
-              for mine, theirs in zip(got, want)),
-          f"joyai: losses {got} against the reference's {want}")
-    check(differ <= config["correct"]["choices_limit"],
-          f"joyai: {differ} of the routing choices are not the reference's")
-    outside = job.leaves_outside(config,
-                                 job.gradient_errors(grads, want_grads))
-    check(not outside, f"joyai: gradients out of their limits: {outside}")
-    return device
 
 
 def seconds_a_call(f, *args) -> float:
@@ -799,7 +638,7 @@ def kda_passes(seq, heads, d) -> None:
     import jax
     import jax.numpy as jnp
     from horovod_tpu.models import kimi_linear
-    from horovod_tpu.models.sdar_moe import rms_norm
+    from horovod_tpu.models.decoder import rms_norm
     from horovod_tpu.parallel import kda_surround as surround
     wide, f32 = heads * d, jnp.float32
     keys = iter(jax.random.split(jax.random.PRNGKey(SEED + 3), 24))
@@ -940,8 +779,7 @@ def phase_gated_conv() -> dict:
 
 
 CHILD_PHASES = {"kernels": phase_kernels, "dp4": phase_dp4,
-                "sdar": phase_sdar, "afmoe": phase_afmoe,
-                "joyai": phase_joyai, "kda": phase_kda,
+                "sdar": phase_sdar, "kda": phase_kda,
                 "gated_conv": phase_gated_conv}
 
 
@@ -1112,8 +950,6 @@ def main(argv=None) -> int:
         else:
             device = timed("kernels", child_report, "kernels")
             timed("sdar", child_report, "sdar")
-            timed("afmoe", child_report, "afmoe")
-            timed("joyai", child_report, "joyai")
             timed("kda", child_report, "kda")
             timed("gated_conv", child_report, "gated_conv")
             timed("tile_times", run_child, "tile_times", AFMOE_TILE_TIMES)

@@ -19,6 +19,10 @@ kernels of ``parallel/kda.py``, beside latent attention without positions)
 and ``lfm2_moe`` (LFM2-8B-A1B: a gated short convolution as the token mixer
 of most layers, through ``parallel/gated_conv.py``, beside grouped-query
 attention); the benchmark's jobs (``benchmarks/jobs/``) train all five.
+They build from one module of shared parts, ``decoder`` (the norm, the
+embedding, the loop over runs of stacked layers, the dense and
+shared-expert halves, the chunked head and the next-token loss), and none
+imports another.
 """
 
 from .resnet import (  # noqa: F401

@@ -28,10 +28,10 @@ anywhere, four RMSNorms a layer.  With ``x [S, hidden]`` a layer's input:
     x' = h + RMSNorm_post_mlp(f)
     loss = mean over i < S - 1 of -log softmax(W_head RMSNorm(x_L))_i[token_{i+1}]
 
-Plain functions over a dict of arrays, as ``sdar_moe.py``, whose norm,
-rotary embedding, chunked head loss and loop over (layer, sequence) these
-are.  A layer is of one of three kinds, (dense or experts) x (window or
-full); a *run* is a stretch of consecutive layers of one kind
+Plain functions over a dict of arrays, from the parts of ``decoder.py``:
+its norm, head projections, chunked head loss and loop over runs.  A layer
+is of one of three kinds, (dense or experts) x (window or full); a *run*
+is a stretch of consecutive layers of one kind
 (:func:`layer_runs`), its parameters stacked on a leading axis and run
 under ``lax.scan``, each (layer, sequence) under its own ``jax.checkpoint``,
 one traced body a run.  Parameters are float32, products run in
@@ -59,7 +59,6 @@ inside them), then
 from __future__ import annotations
 
 import dataclasses
-import itertools
 from typing import Any, Optional, Tuple
 
 import jax
@@ -67,8 +66,8 @@ import jax.numpy as jnp
 
 from .. import scopes as _scopes
 from ..utils import get_logger
-from .sdar_moe import (Aux, embed, head_loss, heads_first_qkv, rms_norm,
-                       through_layers)
+from .decoder import (embed, gated_mlp, group_runs, heads_first_qkv,
+                      next_token_loss, rms_norm, through_runs)
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 #: Layer types whose flash output and logsumexp are kept across the
@@ -136,17 +135,7 @@ def layer_runs(cfg: AfmoeConfig):
     if not cfg.route_norm:
         raise ValueError("route_norm false (chosen scores not divided by "
                          "their sum) is not built")
-    return [(dense, window, len(list(run)))
-            for (dense, window), run in itertools.groupby(kinds)]
-
-
-def gated_mlp(x, w_gate, w_up, w_down, dtype):
-    """``(silu(x W_gate) * (x W_up)) W_down``: three plain products in
-    ``dtype``, the gated unit in float32."""
-    gate = jnp.dot(x, w_gate.astype(dtype))
-    up = jnp.dot(x, w_up.astype(dtype))
-    hidden = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-    return jnp.dot(hidden.astype(dtype), w_down.astype(dtype))
+    return [(*kind, count) for kind, count in group_runs(kinds)]
 
 
 def _attention_half(cfg: AfmoeConfig, window: bool, tables, x, p):
@@ -223,42 +212,24 @@ def hidden_states(params: dict, tokens, cfg: AfmoeConfig):
     """``(final hidden states [batch, S, hidden] before the last norm,
     Aux)`` for ``tokens [batch, S]``; ``Aux`` counts the expert layers
     only, in their order."""
-    batch, seq = tokens.shape
+    seq = tokens.shape[1]
     from ..parallel.qk_rope import rope_tables
     tables = rope_tables(jnp.arange(seq, dtype=jnp.int32), cfg.head_dim,
                          cfg.rope_theta)
     x = embed(params, tokens, cfg.dtype,
               cfg.hidden_size ** 0.5 if cfg.mup_enabled else None)
-    routed_here, chosen = [], []
-    with _scopes.scope("decoder"):
-        for (dense, window, _), stacked in zip(layer_runs(cfg),
-                                               params["runs"]):
-            x, aux = through_layers(_layer(cfg, dense, window, tables),
-                                    x, stacked)
-            if not dense:
-                routed_here.append(aux[0].sum(axis=1))
-                chosen.append(aux[1].reshape(aux[1].shape[0], batch * seq,
-                                             -1))
-    if not chosen:      # a stack of dense layers alone routes nothing
-        return x, Aux(jnp.zeros((0,), jnp.int32), jnp.zeros(
-            (0, batch * seq, cfg.num_experts_per_tok), jnp.int32))
-    return x, Aux(jnp.concatenate(routed_here), jnp.concatenate(chosen))
+    return through_runs(
+        x, layer_runs(cfg), params["runs"],
+        lambda dense, window, _: _layer(cfg, dense, window, tables),
+        cfg.num_experts_per_tok)
 
 
 @_scopes.part_scope("hvd::loss")
 def loss_fn(params: dict, tokens, cfg: AfmoeConfig):
-    """The next-token loss of ``tokens [batch, S]`` and its :class:`Aux`:
+    """The next-token loss of ``tokens [batch, S]`` and its ``Aux``:
     position ``i`` predicts token ``i + 1``, one document a sequence, the
     mean over the ``batch x (S - 1)`` predictions.  ``hvd::loss`` is the
-    part of what this function does itself (targets, weights, the mean) and
-    of what ``hidden_states`` does between its parts."""
-    batch, seq = tokens.shape
+    part of what ``decoder.next_token_loss`` does itself (targets, weights,
+    the mean) and of what ``hidden_states`` does between its parts."""
     hidden, aux = hidden_states(params, tokens, cfg)
-    # Every position goes through the head's chunks; the last of a
-    # sequence, which predicts nothing, with weight 0.
-    targets = jnp.roll(tokens, -1, axis=1)
-    weight = jnp.broadcast_to(
-        (jnp.arange(seq) < seq - 1).astype(jnp.float32), (batch, seq))
-    total = head_loss(params, hidden.reshape(batch * seq, -1),
-                      targets.reshape(-1), weight.reshape(-1), cfg)
-    return total / (batch * (seq - 1)), aux
+    return next_token_loss(params, hidden, tokens, cfg), aux

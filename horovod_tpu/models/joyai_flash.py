@@ -45,9 +45,9 @@ sum over the heads of the kernel's dK.  The MTP block runs over all ``S``
 rows, the last a dummy (fed ``E[t_0]``) that causal attention shows to no
 other row and no loss weighs, so ``S`` stays a multiple of the tile.
 
-Plain functions over a dict of arrays, as ``afmoe.py``, whose gated unit
-this uses, and ``sdar_moe.py``, whose norm, chunked head loss and loop over
-(layer, sequence) these are.  Parameters are float32, products run in
+Plain functions over a dict of arrays, from the parts of ``decoder.py``:
+its norm, dense and shared-expert halves, chunked head loss and loop over
+(layer, sequence).  Parameters are float32, products run in
 ``cfg.dtype`` (bf16) with float32 accumulation; norms, the sigmoid scores,
 softmax and loss in float32.  The tree: ``embed [vocab, hidden]``; ``runs``,
 a list of two dicts, the dense layers and then the expert layers, every leaf
@@ -75,6 +75,7 @@ its head loss inside it.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import jax
@@ -82,8 +83,8 @@ import jax.numpy as jnp
 
 from .. import scopes as _scopes
 from ..utils import get_logger
-from .afmoe import gated_mlp
-from .sdar_moe import Aux, embed, head_loss, rms_norm, through_layers
+from .decoder import (Aux, dense_half, embed, expert_half, next_token_loss,
+                      rms_norm, routing, through_layers)
 
 #: Whether a layer's flash output and logsumexp are kept across the
 #: recomputation of its (layer, sequence): 68 MB each at the published
@@ -190,35 +191,12 @@ def _attention_half(cfg: JoyaiFlashConfig, positions, x, p):
             return x + dot(attended.reshape(seq, -1), p["wo"])
 
 
-def _dense_half(cfg: JoyaiFlashConfig, h, p):
-    with _scopes.scope("hvd::dense_mlp"):
-        m = rms_norm(h, p["mlp_norm"], cfg.rms_norm_eps)
-        return h + gated_mlp(m, p["mlp_gate"], p["mlp_up"], p["mlp_down"],
-                             cfg.dtype), ()
-
-
-def _expert_half(cfg: JoyaiFlashConfig, h, p):
-    from ..parallel.moe import dropless_expert_ffn
-    with _scopes.scope("hvd::moe"):
-        m = rms_norm(h, p["mlp_norm"], cfg.rms_norm_eps)
-        with _scopes.scope("hvd::moe::shared"):
-            shared = gated_mlp(m, p["shared_gate"], p["shared_up"],
-                               p["shared_down"], cfg.dtype)
-        moe = dropless_expert_ffn(
-            m, p["router"], p["w_gate"], p["w_up"], p["w_down"],
-            top_k=cfg.num_experts_per_tok, first_expert=cfg.first_expert,
-            axis_name=cfg.expert_axis, score_func="sigmoid",
-            selection_bias=p.get("e_score_correction_bias"),
-            route_scale=cfg.routed_scaling_factor)
-        f = shared.astype(jnp.float32) + moe.out.astype(jnp.float32)
-        return h + f.astype(h.dtype), (moe.routed_here, moe.chosen)
-
-
 def _layer(cfg: JoyaiFlashConfig, dense: bool, positions):
     """One layer of a kind over one sequence, ``(x [S, hidden], p) -> (x,
     aux)``, under its ``jax.checkpoint``."""
     from ..parallel.flash import SAVED
-    mlp = _dense_half if dense else _expert_half
+    mlp = dense_half if dense else functools.partial(
+        expert_half, top_k=cfg.num_experts_per_tok)
     get_logger().info(
         "joyai_flash: a run of %s layers %s its flash output across the "
         "recomputation", "dense" if dense else "expert",
@@ -227,14 +205,6 @@ def _layer(cfg: JoyaiFlashConfig, dense: bool, positions):
         lambda x, p: mlp(cfg, _attention_half(cfg, positions, x, p), p),
         policy=jax.checkpoint_policies.save_only_these_names(*SAVED)
         if KEEP_ATTENTION else None)
-
-
-def _routing(aux, batch, seq):
-    """``(pairs routed here [layers], chosen [layers, batch x S, k])`` of
-    a run of expert layers, from ``through_layers``' ``[layers, batch,
-    ...]``."""
-    return aux[0].sum(axis=1), aux[1].reshape(aux[1].shape[0], batch * seq,
-                                              -1)
 
 
 def hidden_states(params: dict, tokens, cfg: JoyaiFlashConfig):
@@ -248,7 +218,7 @@ def hidden_states(params: dict, tokens, cfg: JoyaiFlashConfig):
     with _scopes.scope("decoder"):
         x, _ = through_layers(_layer(cfg, True, positions), x, dense)
         x, aux = through_layers(_layer(cfg, False, positions), x, experts)
-    return x, _routing(aux, batch, seq)
+    return x, routing(aux, batch, seq)
 
 
 def mtp_hidden_states(params: dict, tokens, hidden, cfg: JoyaiFlashConfig):
@@ -265,7 +235,7 @@ def mtp_hidden_states(params: dict, tokens, hidden, cfg: JoyaiFlashConfig):
     y, aux = through_layers(
         _layer(cfg, False, jnp.arange(seq, dtype=jnp.int32)), u,
         mtp["block"])
-    return y, _routing(aux, batch, seq)
+    return y, routing(aux, batch, seq)
 
 
 def losses(params: dict, tokens, cfg: JoyaiFlashConfig):
@@ -274,22 +244,8 @@ def losses(params: dict, tokens, cfg: JoyaiFlashConfig):
     over ``batch x (S - 1)`` predictions), row ``i`` of the MTP module on
     token ``i + 2`` (``batch x (S - 2)``).  ``Aux`` counts the expert
     layers in their order and the module's block last."""
-    batch, seq = tokens.shape
     hidden, (routed_here, chosen) = hidden_states(params, tokens, cfg)
-    # Every position goes through the head's chunks; those of a sequence
-    # that predict nothing with weight 0.
-    rows = jnp.arange(seq)
-
-    def mean_loss(top, states, ahead):
-        weight = jnp.broadcast_to((rows < seq - ahead).astype(jnp.float32),
-                                  (batch, seq))
-        total = head_loss(
-            top, states.reshape(batch * seq, -1),
-            jnp.roll(tokens, -ahead, axis=1).reshape(-1),
-            weight.reshape(-1), cfg)
-        return total / (batch * (seq - ahead))
-
-    main = mean_loss(params, hidden, 1)
+    main = next_token_loss(params, hidden, tokens, cfg)
     if not cfg.num_nextn_predict_layers:
         return main, jnp.zeros_like(main), Aux(routed_here, chosen)
     # JAX writes the outermost scope of a differentiated function into its
@@ -297,8 +253,9 @@ def losses(params: dict, tokens, cfg: JoyaiFlashConfig):
     with _scopes.scope("mtp"), _scopes.scope("hvd::mtp"):
         y, (mtp_routed, mtp_chosen) = mtp_hidden_states(params, tokens,
                                                         hidden, cfg)
-        ahead = mean_loss({"head": params["head"],
-                           "final_norm": params["mtp"]["mtp_norm"]}, y, 2)
+        ahead = next_token_loss({"head": params["head"],
+                                 "final_norm": params["mtp"]["mtp_norm"]},
+                                y, tokens, cfg, 2)
     return main, ahead, Aux(jnp.concatenate([routed_here, mtp_routed]),
                             jnp.concatenate([chosen, mtp_chosen]))
 

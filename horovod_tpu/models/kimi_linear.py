@@ -49,10 +49,10 @@ hidden]`` a layer's input, ``H`` heads of ``d`` (``linear_attn_config``:
     loss    = mean over i < S - 1 of
               -log softmax(W_head RMSNorm_f(x_L))_i[t_{i+1}]
 
-Plain functions over a dict of arrays, as ``joyai_flash.py``, whose latent
-half this is without the query latent and the rotation, and ``afmoe.py``,
-whose gated unit and runs of layers these are: here the runs differ in
-their parameter trees (two token mixers, two second halves), not in a mask.
+Plain functions over a dict of arrays, from the parts of ``decoder.py``;
+the latent half is ``joyai_flash.py``'s without the query latent and the
+rotation; the runs of layers differ in their parameter trees (two token
+mixers, two second halves), not in a mask.
 Parameters are float32, products run in ``cfg.dtype`` (bf16) with float32
 accumulation; norms, the gates (``g``, ``beta``, both sigmoids), the scan's
 state, softmax and loss in float32.  The tree: ``embed [vocab, hidden]``;
@@ -87,15 +87,15 @@ under ``head`` ``hvd::lm_head_loss``.
 from __future__ import annotations
 
 import dataclasses
-import itertools
+import functools
 from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from .. import scopes as _scopes
-from .afmoe import gated_mlp
-from .sdar_moe import Aux, embed, head_loss, rms_norm, through_layers
+from .decoder import (dense_half, embed, expert_half, group_runs,
+                      next_token_loss, rms_norm, through_runs)
 
 KDA, MLA = "kda", "mla"
 #: KDA layers, counted from 1 as ``kda_layers`` counts them, whose scan
@@ -170,8 +170,7 @@ def layer_runs(cfg: KimiLinearConfig):
             f"do not split layers 1 to {cfg.num_hidden_layers}")
     kinds = [(KDA if i + 1 in kda else MLA, i < cfg.first_k_dense_replace)
              for i in range(cfg.num_hidden_layers)]
-    return [(mixer, dense, len(list(run)))
-            for (mixer, dense), run in itertools.groupby(kinds)]
+    return [(*kind, count) for kind, count in group_runs(kinds)]
 
 
 def short_conv(x, weight):
@@ -269,30 +268,6 @@ def _mla_half(cfg: KimiLinearConfig, x, p):
             return x + dot(heads_first(attended).reshape(seq, -1), p["wo"])
 
 
-def _dense_half(cfg: KimiLinearConfig, h, p):
-    with _scopes.scope("hvd::dense_mlp"):
-        m = rms_norm(h, p["mlp_norm"], cfg.rms_norm_eps)
-        return h + gated_mlp(m, p["mlp_gate"], p["mlp_up"], p["mlp_down"],
-                             cfg.dtype), ()
-
-
-def _expert_half(cfg: KimiLinearConfig, h, p):
-    from ..parallel.moe import dropless_expert_ffn
-    with _scopes.scope("hvd::moe"):
-        m = rms_norm(h, p["mlp_norm"], cfg.rms_norm_eps)
-        with _scopes.scope("hvd::moe::shared"):
-            shared = gated_mlp(m, p["shared_gate"], p["shared_up"],
-                               p["shared_down"], cfg.dtype)
-        moe = dropless_expert_ffn(
-            m, p["router"], p["w_gate"], p["w_up"], p["w_down"],
-            top_k=cfg.num_experts_per_token, first_expert=cfg.first_expert,
-            axis_name=cfg.expert_axis, score_func="sigmoid",
-            selection_bias=p.get("e_score_correction_bias"),
-            route_scale=cfg.routed_scaling_factor)
-        f = shared.astype(jnp.float32) + moe.out.astype(jnp.float32)
-        return h + f.astype(h.dtype), (moe.routed_here, moe.chosen)
-
-
 def _layer(cfg: KimiLinearConfig, mixer: str, dense: bool, layers):
     """One layer of a kind over one sequence, ``(x [S, hidden], p) -> (x,
     aux)``, under its ``jax.checkpoint``; ``layers`` are the run's, counted
@@ -304,7 +279,8 @@ def _layer(cfg: KimiLinearConfig, mixer: str, dense: bool, layers):
     forward kernel runs again in the backward pass and writes them there."""
     from ..parallel import flash, kda
     mix = _kda_half if mixer == KDA else _mla_half
-    mlp = _dense_half if dense else _expert_half
+    mlp = dense_half if dense else functools.partial(
+        expert_half, top_k=cfg.num_experts_per_token)
     if mixer == MLA:
         kept = flash.SAVED
     else:
@@ -319,41 +295,18 @@ def hidden_states(params: dict, tokens, cfg: KimiLinearConfig):
     """``(final hidden states [batch, S, hidden] before the last norm,
     Aux)`` for ``tokens [batch, S]``; ``Aux`` counts the expert layers
     only, in their order."""
-    batch, seq = tokens.shape
     x = embed(params, tokens, cfg.dtype)
-    routed_here, chosen = [], []
-    first = 1
-    with _scopes.scope("decoder"):
-        for (mixer, dense, count), stacked in zip(layer_runs(cfg),
-                                                  params["runs"]):
-            layers = range(first, first + count)
-            first += count
-            x, aux = through_layers(_layer(cfg, mixer, dense, layers), x,
-                                    stacked)
-            if not dense:
-                routed_here.append(aux[0].sum(axis=1))
-                chosen.append(aux[1].reshape(aux[1].shape[0], batch * seq,
-                                             -1))
-    if not chosen:      # a stack of dense layers alone routes nothing
-        return x, Aux(jnp.zeros((0,), jnp.int32), jnp.zeros(
-            (0, batch * seq, cfg.num_experts_per_token), jnp.int32))
-    return x, Aux(jnp.concatenate(routed_here), jnp.concatenate(chosen))
+    return through_runs(x, layer_runs(cfg), params["runs"],
+                        functools.partial(_layer, cfg),
+                        cfg.num_experts_per_token)
 
 
 @_scopes.part_scope("hvd::loss")
 def loss_fn(params: dict, tokens, cfg: KimiLinearConfig):
-    """The next-token loss of ``tokens [batch, S]`` and its :class:`Aux`:
+    """The next-token loss of ``tokens [batch, S]`` and its ``Aux``:
     position ``i`` predicts token ``i + 1``, one document a sequence, the
     mean over the ``batch x (S - 1)`` predictions.  ``hvd::loss`` is the
-    part of what this function does itself (targets, weights, the mean) and
-    of what ``hidden_states`` does between its parts."""
-    batch, seq = tokens.shape
+    part of what ``decoder.next_token_loss`` does itself (targets, weights,
+    the mean) and of what ``hidden_states`` does between its parts."""
     hidden, aux = hidden_states(params, tokens, cfg)
-    # Every position goes through the head's chunks; the last of a
-    # sequence, which predicts nothing, with weight 0.
-    targets = jnp.roll(tokens, -1, axis=1)
-    weight = jnp.broadcast_to(
-        (jnp.arange(seq) < seq - 1).astype(jnp.float32), (batch, seq))
-    total = head_loss(params, hidden.reshape(batch * seq, -1),
-                      targets.reshape(-1), weight.reshape(-1), cfg)
-    return total / (batch * (seq - 1)), aux
+    return next_token_loss(params, hidden, tokens, cfg), aux

@@ -37,11 +37,11 @@ layer's input and ``D = hidden``:
     loss    = mean over i < S - 1 of
               -log softmax(W_head RMSNorm_f(x_L))_i[t_{i+1}]    (untied)
 
-Plain functions over a dict of arrays, as ``kimi_linear.py``, whose runs of
-layers that differ in their parameter trees (two token mixers beside two
-second halves) these are.  Parameters are float32, products run in
-``cfg.dtype`` (bf16) with float32 accumulation; the convolution's pass
-(float32 inside), norms, router, softmax and loss in float32.  The tree:
+Plain functions over a dict of arrays, from the parts of ``decoder.py``;
+runs of layers differ in their parameter trees (two token mixers beside two
+second halves), as in ``kimi_linear.py``.  Parameters are float32, products
+run in ``cfg.dtype`` (bf16) with float32 accumulation; the convolution's
+pass (float32 inside), norms, router, softmax and loss in float32.  The tree:
 ``embed [vocab, hidden]``; ``runs``, a list of dicts, one a stretch of
 consecutive layers of one kind (``layer_runs``), every leaf with the run's
 layers on its leading axis: ``attn_norm``, ``mlp_norm`` ``[hidden]``; a
@@ -68,16 +68,14 @@ of 64), then ``hvd::dense_mlp`` or ``hvd::moe`` (``::route``,
 from __future__ import annotations
 
 import dataclasses
-import itertools
 from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from .. import scopes as _scopes
-from .afmoe import gated_mlp
-from .sdar_moe import (Aux, embed, head_loss, heads_first_qkv, rms_norm,
-                       through_layers)
+from .decoder import (dense_half, embed, group_runs, heads_first_qkv,
+                      next_token_loss, rms_norm, through_runs)
 
 CONV, ATTENTION = "conv", "full_attention"
 
@@ -122,7 +120,7 @@ class Lfm2MoeConfig:
         return self.hidden_size // self.num_attention_heads
 
     @property
-    def rms_norm_eps(self) -> float:    # the name ``head_loss`` reads
+    def rms_norm_eps(self) -> float:    # the name ``decoder``'s parts read
         return self.norm_eps
 
 
@@ -136,8 +134,7 @@ def layer_runs(cfg: Lfm2MoeConfig):
             f"{ATTENTION!r} for each of {cfg.num_hidden_layers} layers")
     kinds = [(mixer, i < cfg.num_dense_layers)
              for i, mixer in enumerate(cfg.layer_types)]
-    return [(mixer, dense, len(list(run)))
-            for (mixer, dense), run in itertools.groupby(kinds)]
+    return [(*kind, count) for kind, count in group_runs(kinds)]
 
 
 def _conv_half(cfg: Lfm2MoeConfig, x, p):
@@ -173,13 +170,6 @@ def _attention_half(cfg: Lfm2MoeConfig, tables, x, p):
                            p["wo"].astype(cfg.dtype))
 
 
-def _dense_half(cfg: Lfm2MoeConfig, h, p):
-    with _scopes.scope("hvd::dense_mlp"):
-        m = rms_norm(h, p["mlp_norm"], cfg.norm_eps)
-        return h + gated_mlp(m, p["mlp_gate"], p["mlp_up"], p["mlp_down"],
-                             cfg.dtype), ()
-
-
 def _expert_half(cfg: Lfm2MoeConfig, h, p):
     from ..parallel.moe import dropless_expert_ffn
     with _scopes.scope("hvd::moe"):
@@ -201,7 +191,7 @@ def _layer(cfg: Lfm2MoeConfig, mixer: str, dense: bool, tables):
     layer keeps nothing: its pass reads the projection's output, which the
     recomputation makes again."""
     from ..parallel.flash import SAVED
-    mlp = _dense_half if dense else _expert_half
+    mlp = dense_half if dense else _expert_half
     if mixer == CONV:
         return jax.checkpoint(lambda x, p: mlp(cfg, _conv_half(cfg, x, p),
                                                p))
@@ -215,40 +205,22 @@ def hidden_states(params: dict, tokens, cfg: Lfm2MoeConfig):
     Aux)`` for ``tokens [batch, S]``; ``Aux`` counts the expert layers
     only, in their order."""
     from ..parallel.qk_rope import rope_tables
-    batch, seq = tokens.shape
+    seq = tokens.shape[1]
     tables = rope_tables(jnp.arange(seq, dtype=jnp.int32), cfg.head_dim,
                          cfg.rope_theta)
     x = embed(params, tokens, cfg.dtype)
-    routed_here, chosen = [], []
-    with _scopes.scope("decoder"):
-        for (mixer, dense, _), stacked in zip(layer_runs(cfg),
-                                              params["runs"]):
-            x, aux = through_layers(_layer(cfg, mixer, dense, tables), x,
-                                    stacked)
-            if not dense:
-                routed_here.append(aux[0].sum(axis=1))
-                chosen.append(aux[1].reshape(aux[1].shape[0], batch * seq,
-                                             -1))
-    if not chosen:      # a stack of dense layers alone routes nothing
-        return x, Aux(jnp.zeros((0,), jnp.int32), jnp.zeros(
-            (0, batch * seq, cfg.num_experts_per_tok), jnp.int32))
-    return x, Aux(jnp.concatenate(routed_here), jnp.concatenate(chosen))
+    return through_runs(
+        x, layer_runs(cfg), params["runs"],
+        lambda mixer, dense, _: _layer(cfg, mixer, dense, tables),
+        cfg.num_experts_per_tok)
 
 
 @_scopes.part_scope("hvd::loss")
 def loss_fn(params: dict, tokens, cfg: Lfm2MoeConfig):
-    """The next-token loss of ``tokens [batch, S]`` and its :class:`Aux`:
+    """The next-token loss of ``tokens [batch, S]`` and its ``Aux``:
     position ``i`` predicts token ``i + 1``, one document a sequence, the
     mean over the ``batch x (S - 1)`` predictions.  ``hvd::loss`` is the
-    part of what this function does itself (targets, weights, the mean) and
-    of what ``hidden_states`` does between its parts."""
-    batch, seq = tokens.shape
+    part of what ``decoder.next_token_loss`` does itself (targets, weights,
+    the mean) and of what ``hidden_states`` does between its parts."""
     hidden, aux = hidden_states(params, tokens, cfg)
-    # Every position goes through the head's chunks; the last of a
-    # sequence, which predicts nothing, with weight 0.
-    targets = jnp.roll(tokens, -1, axis=1)
-    weight = jnp.broadcast_to(
-        (jnp.arange(seq) < seq - 1).astype(jnp.float32), (batch, seq))
-    total = head_loss(params, hidden.reshape(batch * seq, -1),
-                      targets.reshape(-1), weight.reshape(-1), cfg)
-    return total / (batch * (seq - 1)), aux
+    return next_token_loss(params, hidden, tokens, cfg), aux

@@ -24,10 +24,11 @@ and the loss is the ``1 / t_b``-weighted cross-entropy of the clean token
 at every masked position, over the noised copy only (no next-token shift),
 divided by ``batch x L`` (:func:`loss_fn`).
 
-Plain functions over a dict of arrays, not flax: the layers are stacked on
-a leading axis and run under ``lax.scan`` with one ``jax.checkpoint`` a
-layer; parameters are float32, products run in ``cfg.dtype`` (bf16) with
-float32 accumulation, norms, softmax and loss in float32.  The tree:
+Plain functions over a dict of arrays, not flax, from the parts of
+``decoder.py``: the layers are stacked on a leading axis and run under
+``lax.scan`` with one ``jax.checkpoint`` a layer; parameters are float32,
+products in ``cfg.dtype`` (bf16) with float32 accumulation, norms, softmax
+and loss in float32.  The tree:
 ``embed [vocab, hidden]``; ``layers``, every leaf with the layers on its
 leading axis: ``attn_norm``, ``moe_norm`` ``[hidden]``, ``wq [hidden, heads
 x head_dim]``, ``wk``, ``wv`` ``[hidden, kv heads x head_dim]``, ``q_norm``,
@@ -46,13 +47,14 @@ rotary embedding and the head-major layout, one pass of
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, NamedTuple, Optional
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from .. import scopes as _scopes
+from .decoder import (Aux, embed, head_loss, heads_first_qkv, rms_norm,
+                      routing, through_layers)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,54 +85,6 @@ PARTS = ("hvd::loss", "hvd::embed", "hvd::layer_loop", "hvd::bd_attention",
          "hvd::moe", "hvd::lm_head_loss")
 
 
-class Aux(NamedTuple):
-    """What leaves the step beside the loss, a layer: the (position,
-    choice) pairs the held experts computed, and every position's chosen
-    experts."""
-    routed_here: jax.Array      # [layers] int32
-    chosen: jax.Array           # [layers, positions, experts_per_token]
-
-
-def rms_norm(x, weight, eps: float):
-    x32 = x.astype(jnp.float32)
-    scale = lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (x32 * scale * weight).astype(x.dtype)
-
-
-def rotary(x, positions, theta: float):
-    """Rotary embedding over the whole last dimension of ``x [batch, S,
-    heads, head_dim]`` (the half-split convention of the published
-    model: pairs ``(i, i + head_dim / 2)``)."""
-    half = x.shape[-1] // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
-    cos = jnp.cos(angles)[None, :, None, :]
-    sin = jnp.sin(angles)[None, :, None, :]
-    x32 = x.astype(jnp.float32)
-    x1, x2 = x32[..., :half], x32[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
-
-
-def heads_first_qkv(h, p, tables, heads: int, kv_heads: int, eps: float,
-                    dtype):
-    """``(q [heads, S, head_dim], k, v [kv_heads, S, head_dim])`` of one
-    sequence's normed input ``h [S, hidden]``, as
-    ``flash_attention_heads_first`` takes them: the three projections,
-    every query and key head under its RMSNorm (``p["q_norm"]``,
-    ``p["k_norm"]``) and rotated by ``tables`` (``qk_rope.rope_tables``;
-    ``None``: no positions).  Norm, rotation and the head-major layout of
-    ``q`` and ``k`` are one pass over the projection's output
-    (``parallel/qk_rope.py``), under ``hvd::qk_rope``."""
-    from ..parallel.qk_rope import qk_norm_rope
-    project = lambda w: jnp.dot(h, p[w].astype(dtype))
-    q, k, v = project("wq"), project("wk"), project("wv")
-    with _scopes.scope("hvd::qk_rope"):
-        q = qk_norm_rope(q, p["q_norm"], tables, heads, eps)
-        k = qk_norm_rope(k, p["k_norm"], tables, kv_heads, eps)
-    return q, k, v.reshape(v.shape[0], kv_heads, -1).transpose(1, 0, 2)
-
-
 def _layer(cfg: SdarMoeConfig, tables, mask_mode, x, p):
     """One decoder layer over one sequence ``x [S, hidden]``; ``p`` is the
     layer's slice of the stacked parameters, ``tables`` the rotary
@@ -159,34 +113,6 @@ def _layer(cfg: SdarMoeConfig, tables, mask_mode, x, p):
     return x, (moe.routed_here, moe.chosen)
 
 
-def through_layers(layer, x, stacked):
-    """``x [batch, S, hidden]`` through the layers whose parameters are
-    ``stacked`` on a leading axis: the layers under ``lax.scan`` and, inside
-    a layer, the sequences one after the other.  ``layer(x [S, hidden], p)
-    -> (x, aux)`` comes with its own ``jax.checkpoint``; returns ``(x, aux
-    [layers, batch, ...])``.
-
-    ``hvd::layer_loop`` is around the whole scan, so that it is the part of
-    everything a (layer, sequence) costs outside the layer's own parts, in
-    both loops and both passes: the slices of the stacked parameters, the
-    sums of their gradients over a layer's sequences, the auxiliary
-    outputs."""
-    with _scopes.scope("hvd::layer_loop"):
-        return lax.scan(lambda x, p: lax.map(lambda xs: layer(xs, p), x),
-                        x, stacked)
-
-
-def embed(params: dict, tokens, dtype, scale: Optional[float] = None):
-    """The rows of ``params["embed"]`` for ``tokens``, times ``scale``
-    where one is given, in ``dtype``; the gather, and so the scatter-add of
-    the backward pass, under ``hvd::embed``."""
-    with _scopes.part_scope("hvd::embed"):
-        x = params["embed"][tokens]
-        if scale is not None:
-            x = x * scale
-        return x.astype(dtype)
-
-
 def hidden_states(params: dict, tokens, cfg: SdarMoeConfig, mask_mode):
     """``(final hidden states [batch, S, hidden] before the last norm,
     Aux)`` for ``tokens [batch, S]`` under ``mask_mode`` (a mode of
@@ -213,41 +139,8 @@ def hidden_states(params: dict, tokens, cfg: SdarMoeConfig, mask_mode):
         lambda x, p: _layer(cfg, tables, mask_mode, x, p),
         policy=jax.checkpoint_policies.save_only_these_names(*SAVED))
     with _scopes.scope("decoder"):
-        x, (routed_here, chosen) = through_layers(one, x, params["layers"])
-    return x, Aux(routed_here.sum(axis=1),
-                  chosen.reshape(chosen.shape[0], batch * seq, -1))
-
-
-def head_loss(params: dict, hidden, targets, weight, cfg: SdarMoeConfig):
-    """``sum_i weight_i * -log softmax(W_head RMSNorm(hidden_i))[target_i]``
-    over all positions of ``hidden [N, hidden]``, the logits over the rows
-    of the vocabulary held here, a chunk of positions at a time so that no
-    ``[N, vocab]`` array is ever whole."""
-    n, d = hidden.shape
-    chunk = min(cfg.loss_chunk, n)
-    if n % chunk:
-        raise ValueError(f"{n} positions are no multiple of the loss's "
-                         f"chunk of {chunk}")
-    head = params["head"].astype(cfg.dtype)
-
-    @jax.checkpoint
-    def chunk_loss(part):
-        h, target, w = part
-        h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-        logits = jnp.dot(h, head, preferred_element_type=jnp.float32)
-        nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
-            logits, target[:, None], axis=-1)[:, 0]
-        return jnp.sum(w * nll)
-
-    # JAX writes the outermost scope of a differentiated function into its
-    # ``jvp(...)`` marker; ``head`` takes that place, as ``decoder`` does.
-    with _scopes.scope("head"), _scopes.scope("hvd::lm_head_loss"):
-        total = jnp.sum(lax.map(
-            chunk_loss,
-            (hidden.reshape(n // chunk, chunk, d),
-             targets.reshape(n // chunk, chunk),
-             weight.reshape(n // chunk, chunk))))
-    return total
+        x, aux = through_layers(one, x, params["layers"])
+    return x, Aux(*routing(aux, batch, seq))
 
 
 @_scopes.part_scope("hvd::loss")

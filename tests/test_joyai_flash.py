@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import joyai_flash
+from horovod_tpu.models import decoder, joyai_flash
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmarks")
@@ -228,7 +228,7 @@ def test_mtp_row_i_is_judged_on_token_i_plus_2(seeded, cfg):
         hidden, _ = joyai_flash.hidden_states(params, tokens, cfg)
         y, _ = joyai_flash.mtp_hidden_states(params, tokens, hidden, cfg)
         nll = lambda states, norm, targets: -jnp.take_along_axis(
-            jax.nn.log_softmax(joyai_flash.rms_norm(
+            jax.nn.log_softmax(decoder.rms_norm(
                 states, norm, cfg.rms_norm_eps) @ params["head"]),
             targets[:, None], axis=-1)[:, 0]
         return (nll(hidden[0], params["final_norm"],
@@ -298,9 +298,8 @@ def test_interleaved_rotary_is_complex_multiplication(job, rope, heads):
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(job.rotary_pairs(jnp.asarray(x), theta), want,
                                rtol=1e-4, atol=1e-5)
-    # Not the half-split convention of sdar_moe.rotary.
-    from horovod_tpu.models.sdar_moe import rotary
-    half = rotary(jnp.asarray(x)[None], jnp.asarray(positions), theta)[0]
+    # Not the half-split convention of decoder.rotary.
+    half = decoder.rotary(jnp.asarray(x)[None], jnp.asarray(positions), theta)[0]
     assert float(jnp.abs(half - want).max()) > 0.1
 
 
@@ -396,18 +395,18 @@ def test_the_sixteen_shares_add_up(job):
         mine = dict(layer, **{name: layer[name][share * held:
                                                 (share + 1) * held]
                               for name in ("w_gate", "w_up", "w_down")})
-        out, (here, picked) = joyai_flash._expert_half(
+        out, (here, picked) = decoder.expert_half(
             dataclasses.replace(cfg, experts_held=held,
-                                first_expert=share * held), h, mine)
+                                first_expert=share * held), h, mine,
+            cfg.num_experts_per_tok)
         np.testing.assert_array_equal(picked, chosen)
         routed += int(here)
         # out = h + shared + this share's routed part.
         summed = out if summed is None else summed + out
     assert routed == chosen.size
-    m = joyai_flash.rms_norm(h, layer["mlp_norm"], cfg.rms_norm_eps)
-    shared = joyai_flash.gated_mlp(m, layer["shared_gate"],
-                                   layer["shared_up"], layer["shared_down"],
-                                   cfg.dtype)
+    m = decoder.rms_norm(h, layer["mlp_norm"], cfg.rms_norm_eps)
+    shared = decoder.gated_mlp(m, layer["shared_gate"], layer["shared_up"],
+                               layer["shared_down"], cfg.dtype)
     np.testing.assert_allclose(summed - (shares - 1) * (h + shared), uncut,
                                rtol=2e-4, atol=2e-5)
 

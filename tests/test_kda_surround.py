@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu.models import kimi_linear
-from horovod_tpu.models.sdar_moe import rms_norm
+from horovod_tpu.models.decoder import rms_norm
 from horovod_tpu.parallel import kda_surround as surround
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

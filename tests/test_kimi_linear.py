@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import kimi_linear
+from horovod_tpu.models import decoder, kimi_linear
 from horovod_tpu.parallel import flash, kda
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -390,9 +390,9 @@ def test_the_thirty_two_shares_add_up(job):
         # own router and one program serves all 32.
         rolled = dict(layer, router=jnp.roll(layer["router"], -first,
                                              axis=1), **mine)
-        return kimi_linear._expert_half(
+        return decoder.expert_half(
             dataclasses.replace(cfg, experts_held=held, first_expert=0),
-            h, rolled)
+            h, rolled, cfg.num_experts_per_token)
 
     summed, routed = None, 0
     for n in range(shares):
@@ -406,10 +406,9 @@ def test_the_thirty_two_shares_add_up(job):
         # out = h + shared + this share's routed part.
         summed = out if summed is None else summed + out
     assert routed == chosen.size
-    m = kimi_linear.rms_norm(h, layer["mlp_norm"], cfg.rms_norm_eps)
-    shared = kimi_linear.gated_mlp(m, layer["shared_gate"],
-                                   layer["shared_up"], layer["shared_down"],
-                                   cfg.dtype)
+    m = decoder.rms_norm(h, layer["mlp_norm"], cfg.rms_norm_eps)
+    shared = decoder.gated_mlp(m, layer["shared_gate"], layer["shared_up"],
+                               layer["shared_down"], cfg.dtype)
     # 32 sums of values of a few units each, in float32.
     np.testing.assert_allclose(summed - (shares - 1) * (h + shared), uncut,
                                rtol=5e-4, atol=1e-4)

@@ -1,6 +1,6 @@
 """QK-norm and rotary embedding as one pass (``parallel/qk_rope.py``)
 against the separate passes it replaces, ``rotary(rms_norm(...))`` of
-``models/sdar_moe.py`` transposed head-major; the head-major way into the
+``models/decoder.py`` transposed head-major; the head-major way into the
 flash kernels against ``flash_attention``; both decoders through the
 kernels against their jobs' plain references.  The Pallas kernels run under
 the interpreter here; ``tests/test_tpu_compile.py`` compiles them for the
@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import afmoe, sdar_moe
+from horovod_tpu.models import afmoe, decoder, sdar_moe
 from horovod_tpu.parallel import flash, qk_rope
 from horovod_tpu.parallel.qk_rope import qk_norm_rope, rope_tables
 
@@ -34,9 +34,9 @@ def positions_of(kind, seq):
 def separate_passes(x, weight, positions, heads):
     """What the models did before the pass: ``[heads, S, head_dim]``."""
     seq = x.shape[0]
-    n = sdar_moe.rms_norm(x.reshape(1, seq, heads, -1), weight, EPS)
+    n = decoder.rms_norm(x.reshape(1, seq, heads, -1), weight, EPS)
     if positions is not None:
-        n = sdar_moe.rotary(n, positions, THETA)
+        n = decoder.rotary(n, positions, THETA)
     return n[0].transpose(1, 0, 2)
 
 

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import sdar_moe
+from horovod_tpu.models import decoder, sdar_moe
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEED, BATCH = 11, 2
@@ -161,8 +161,8 @@ def test_sliced_vocabulary_loss_in_chunks(job, config, seeded):
     targets = jnp.asarray(rng.randint(0, 39, 64), jnp.int32)
     weight = jnp.asarray(rng.rand(64) * (rng.rand(64) < 0.5), jnp.float32)
     import dataclasses
-    got = sdar_moe.head_loss(params, hidden, targets, weight,
-                             dataclasses.replace(cfg, loss_chunk=16))
+    got = decoder.head_loss(params, hidden, targets, weight,
+                            dataclasses.replace(cfg, loss_chunk=16))
     normed = hidden * jax.lax.rsqrt(
         jnp.mean(hidden ** 2, -1, keepdims=True) + 1e-6)
     logp = jax.nn.log_softmax(normed @ params["head"], axis=-1)
@@ -170,8 +170,8 @@ def test_sliced_vocabulary_loss_in_chunks(job, config, seeded):
     assert logp.shape[1] == config["vocab_size"] == 40
     np.testing.assert_allclose(got, want, rtol=1e-5)
     with pytest.raises(ValueError, match="chunk"):
-        sdar_moe.head_loss(params, hidden[:50], targets[:50], weight[:50],
-                           dataclasses.replace(cfg, loss_chunk=16))
+        decoder.head_loss(params, hidden[:50], targets[:50], weight[:50],
+                          dataclasses.replace(cfg, loss_chunk=16))
 
 
 def test_step_through_shard_step_and_distributed_optimizer(hvd8, job,
